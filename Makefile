@@ -6,7 +6,8 @@ PYTEST = env JAX_PLATFORMS=cpu $(PY) -m pytest -p no:cacheprovider
 
 .PHONY: test tier1 lint chaos chaos-multi-gateway chaos-soak \
 	distill-smoke bench-kv bench-mixed bench-megastep bench-fused \
-	bench-autopilot bench-swarm bench-spec-rtt trace-demo obs-demo
+	bench-autopilot bench-swarm bench-spec-rtt trace-demo obs-demo \
+	chip-smoke
 
 # Full suite (slow soaks included).  Runs lint + the chaos matrix FIRST:
 # swarmlint finishes in seconds and the fault-injection scenarios are the
@@ -18,6 +19,14 @@ test: lint chaos chaos-soak
 # The tier-1 gate: what CI (and ROADMAP.md) holds the repo to.
 tier1: lint
 	$(PYTEST) tests/ -q -m 'not slow' --continue-on-collection-errors
+
+# CPU rehearsal of chip_smoke.py (the chip check: DHT + worker + gateway
+# as three processes, /api/chat requests, kernel parity) at tiny-test size
+# with interpret-mode kernels.  Never prints "ok": true — the real run is
+# `python chip_smoke.py` on a machine with a TPU.
+chip-smoke:
+	env JAX_PLATFORMS=cpu CROWDLLAMA_PALLAS_INTERPRET=1 \
+		$(PY) chip_smoke.py --rehearse
 
 # swarmlint (docs/STATIC_ANALYSIS.md): async-hotpath / jax-purity /
 # contract-exhaustiveness checkers over the package.  Exit 1 on any

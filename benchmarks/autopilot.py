@@ -32,7 +32,7 @@ Run (repo root, CPU):
     JAX_PLATFORMS=cpu python benchmarks/autopilot.py
 """
 
-import _common  # noqa: F401  (repo-root sys.path + platform re-pin)
+import _common  # noqa: E402 - repo path + compile cache bootstrap
 
 import argparse
 import asyncio
@@ -321,7 +321,7 @@ def main() -> None:
                   f"AUTOTUNE_{result['platform']}_{date}.json")
     Path(out).parent.mkdir(parents=True, exist_ok=True)
     Path(out).write_text(json.dumps(result, indent=1) + "\n")
-    print(json.dumps(result))
+    _common.emit(result)
 
 
 if __name__ == "__main__":

@@ -15,7 +15,7 @@ import sys
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent))
-import _common  # noqa: F401,E402 - repo path + JAX platform bootstrap
+import _common  # noqa: E402 - repo path + compile cache bootstrap
 
 import json
 import os
@@ -51,14 +51,18 @@ def model_bytes(cfg, quant: bool, bits: int = 8) -> tuple[int, int]:
     return param_bytes, kv_bytes
 
 
-def main() -> None:
+def report() -> dict:
+    """The capacity result for the device this process holds.  A device
+    that does not report its HBM is an error, not an assumed v5e."""
     from crowdllama_tpu.models.config import get_config, list_models
     from crowdllama_tpu.peer.peer import _tpu_capabilities
 
     caps = _tpu_capabilities()
-    hbm_gb = caps.get("hbm_gb_per_chip") or 0.0
+    hbm_gb = caps["hbm_gb_per_chip"]
     if not hbm_gb:
-        hbm_gb = 16.0  # assume one v5e chip when introspection unavailable
+        raise RuntimeError(
+            f"device {caps['accelerator']!r} reports no HBM size; capacity "
+            f"accounting needs the chip it is asked about")
     budget = hbm_gb * (1 << 30) * 0.9  # leave 10% for XLA scratch
     slots = int(os.environ.get("CROWDLLAMA_BENCH_SLOTS", "8"))
 
@@ -96,15 +100,15 @@ def main() -> None:
               f"(fits={fits16}), int8 {pb8/2**30:.1f} GiB (fits={fits8}, "
               f"ctx<={ctx_fit})", file=sys.stderr)
 
-    print(json.dumps({
+    return {
         "metric": f"largest model servable on one chip ({hbm_gb:.0f} GiB HBM, int8)",
         "value": best[1] if best else 0.0,
         "unit": "B params",
         "vs_baseline": None,
         "extra": {"model": best[0] if best else None, "slots": slots,
                   "accelerator": caps.get("accelerator"), "rows": rows},
-    }))
+    }
 
 
 if __name__ == "__main__":
-    main()
+    _common.emit(report())

@@ -31,7 +31,7 @@ import sys
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent))
-import _common  # noqa: F401,E402 - repo path + JAX platform bootstrap
+import _common  # noqa: E402 - repo path + compile cache bootstrap
 
 import asyncio  # noqa: F401 - used by the bench body below
 import json
@@ -218,7 +218,7 @@ def main() -> None:
     os.environ.setdefault("CROWDLLAMA_TPU_TEST_MODE", "1")
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     result = asyncio.run(run())
-    print(json.dumps(result))
+    _common.emit(result)
 
 
 if __name__ == "__main__":

@@ -21,7 +21,7 @@ Run (repo root, CPU):
     JAX_PLATFORMS=cpu python benchmarks/spec_decode.py
 """
 
-import _common  # noqa: F401  (repo-root sys.path + platform re-pin)
+import _common  # noqa: E402 - repo path + compile cache bootstrap
 
 import argparse
 import hashlib
@@ -235,6 +235,7 @@ def main() -> int:
     line = run_sweep(model=args.model, draft_path=args.draft_path,
                      ks=[int(k) for k in args.ks.split(",") if k],
                      steps=args.steps, slots=args.slots)
+    line.setdefault("device", _common.device_info())
     out = json.dumps(line)
     print(out)
     if args.out:

@@ -43,7 +43,7 @@ import sys
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent))
-import _common  # noqa: F401,E402 - repo path + JAX platform bootstrap
+import _common  # noqa: E402 - repo path + compile cache bootstrap
 
 import asyncio  # noqa: E402
 import json  # noqa: E402
@@ -293,6 +293,7 @@ def main() -> None:
     os.environ.setdefault("CROWDLLAMA_TPU_TEST_MODE", "1")
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     result = asyncio.run(run())
+    result.setdefault("device", _common.device_info())
     out = json.dumps(result)
     print(out)
     res_dir = Path(__file__).resolve().parent / "results"

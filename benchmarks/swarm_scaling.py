@@ -25,7 +25,7 @@ import sys
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent))
-import _common  # noqa: F401,E402 - repo path + JAX platform bootstrap
+import _common  # noqa: E402 - repo path + compile cache bootstrap
 
 import asyncio
 import json
@@ -385,13 +385,13 @@ def main() -> None:
     os.environ.setdefault("CROWDLLAMA_TPU_TEST_MODE", "1")
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     if "--arms" in sys.argv[1:]:
-        print(json.dumps(run_arms()))
+        _common.emit(run_arms())
         return
     if not os.environ.get("CROWDLLAMA_NO_NATIVE"):
         from crowdllama_tpu import native
         native.ensure_built()  # pay the g++ run before the loop starts
     result = asyncio.run(run())
-    print(json.dumps(result))
+    _common.emit(result)
 
 
 if __name__ == "__main__":

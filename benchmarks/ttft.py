@@ -24,7 +24,7 @@ import sys
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent))
-import _common  # noqa: F401,E402 - repo path + JAX platform bootstrap
+import _common  # noqa: E402 - repo path + compile cache bootstrap
 
 import asyncio
 import json
@@ -256,7 +256,7 @@ async def run() -> dict:
 def main() -> None:
     os.environ.setdefault("CROWDLLAMA_TPU_TEST_MODE", "1")
     result = asyncio.run(run())
-    print(json.dumps(result))
+    _common.emit(result)
 
 
 if __name__ == "__main__":

@@ -18,22 +18,9 @@ from __future__ import annotations
 import argparse
 import asyncio
 import logging
-import os
 import re
 import signal
 import sys
-
-# Honor JAX_PLATFORMS even when the interpreter pre-imported jax (some images
-# pin a platform via sitecustomize, which makes the env var alone too late) —
-# without this a worker asked to run a CPU-simulated multi-device mesh sees
-# only the pinned single chip.  Must happen before any jax backend init.
-if os.environ.get("JAX_PLATFORMS"):
-    try:
-        import jax
-
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-    except Exception:  # pragma: no cover - jax absent or already initialized
-        pass
 
 from crowdllama_tpu.config import Configuration
 from crowdllama_tpu.logutil import new_app_logger
@@ -172,6 +159,13 @@ def main(argv: list[str] | None = None) -> int:
         logging.getLogger().setLevel(
             logging.DEBUG if cfg.verbose else logging.INFO)
         logging.basicConfig(stream=sys.stderr)
+        if args.worker_mode and cfg.engine_backend != "fake":
+            # Before the first compile (leader engine and follower alike).
+            # Gateway/consumer and fake-engine nodes never compile — and
+            # never touch JAX at all (one process per chip).
+            from crowdllama_tpu.utils.jaxcache import enable_compile_cache
+
+            log.info("jax compile cache: %s", enable_compile_cache())
         if cfg.dist_coordinator:
             # Multi-host pod-slice serving (parallel/replicated.py):
             # initialize the global mesh BEFORE any backend touch, then

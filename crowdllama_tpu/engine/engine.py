@@ -115,6 +115,12 @@ class Engine:
     # other engine GenerateRequest.remote_draft streams run unpaced and the
     # peer nacks DraftChunk credits so the gateway degrades to plain mode.
     supports_remote_draft = False
+    # True for engines that run JAX programs in THIS process.  An
+    # accelerator belongs to one process at a time, so only such a node may
+    # ask the JAX runtime about devices (peer capabilities, device-memory
+    # gauges): a gateway or DHT process that initialized a backend would
+    # take the chip from the worker beside it.
+    on_device = False
 
     async def start(self) -> None: ...
     async def stop(self) -> None: ...
@@ -224,7 +230,7 @@ class Engine:
         The queue/prefill split comes from the engine's own stamps on the
         final chunk when available (JaxEngine: scheduler admission times);
         otherwise prefill defaults to the first-chunk latency — the same
-        taxonomy either way, so FakeEngine traces read like real ones.
+        catalogue either way, so FakeEngine traces read like real ones.
         """
         if self.obs is None:
             return
@@ -479,6 +485,7 @@ class JaxEngine(Engine):
     """The real engine: ModelRunner + continuous-batching Scheduler."""
 
     supports_kv_donor = True
+    on_device = True
 
     def __init__(self, config: Configuration | None = None, **overrides):
         self.config = config or Configuration.from_environment()

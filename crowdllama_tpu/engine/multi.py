@@ -23,6 +23,7 @@ log = logging.getLogger("crowdllama.engine.multi")
 
 class MultiEngine(Engine):
     supports_kv_donor = True
+    on_device = True
 
     def __init__(self, config):
         self.config = config

@@ -61,9 +61,9 @@ from crowdllama_tpu.ops.pallas.megastep import (run_decode_megastep,
 from crowdllama_tpu.ops.pallas.paged import (
     flash_paged_decode_attention,
     flash_paged_decode_attention_tp,
-    paged_pallas_supported,
+    paged_pallas_refusal,
     ragged_paged_attention,
-    ragged_pallas_supported,
+    ragged_pallas_refusal,
 )
 from crowdllama_tpu.ops.quant import quantize_kv
 from crowdllama_tpu.ops.rope import rope_table
@@ -150,6 +150,9 @@ class PagedModelRunner(ModelRunner):
                     "--kv-layout contiguous for dp batching.",
                     tp, len(jax.devices()), len(jax.devices()) - tp)
             kwargs["mesh_spec"] = f"1x{tp}"
+        # Before super().__init__: the base constructor ends by resolving
+        # the attention paths, and the paged gates need the page size.
+        self.page_size = page_size
         super().__init__(cfg, *args, **kwargs)
         from crowdllama_tpu.parallel.mesh import AXIS_DP
 
@@ -157,7 +160,6 @@ class PagedModelRunner(ModelRunner):
                 and self.mesh.shape.get(AXIS_DP, 1) == 1), (
             "paged KV composes with plain/tp meshes only (the shared page "
             "pool cannot shard over dp; sp/pp use the contiguous layout)")
-        self.page_size = page_size
         self.max_pages_per_slot = math.ceil(self.max_seq / page_size)
         total_tokens = pool_tokens or self.max_slots * self.max_seq
         self.total_pages = max(self.max_pages_per_slot,
@@ -220,6 +222,25 @@ class PagedModelRunner(ModelRunner):
         self._ragged_mega_fn = jax.jit(self._ragged_mega_impl,
                                        donate_argnums=(1,),
                                        static_argnums=(9,))
+
+    def _attention_refusals(self) -> dict[str, str]:
+        from crowdllama_tpu.parallel.mesh import AXIS_TP
+
+        quant = self.kv_dtype == "int8"
+        gate = (self.page_size, self.cfg.resolved_head_dim(),
+                self.mesh.shape.get(AXIS_TP, 1), self.cfg.num_kv_heads,
+                jnp.dtype(jnp.int8 if quant else self.dtype).itemsize,  # pool
+                quant)
+        # Multi-device meshes take the jnp reference path for the ragged
+        # step (GSPMD partitions the gather views; the kernel's shard_map
+        # wiring is future work) — the unified step still saves the
+        # dispatch, which is what the decode-jitter problem is about.
+        ragged = (f"mesh has {self.mesh.size} devices (the ragged kernel is "
+                  f"not shard_map-wrapped)" if self.mesh.size > 1
+                  else ragged_pallas_refusal(*gate))
+        return {**super()._attention_refusals(),
+                "decode": paged_pallas_refusal(*gate),
+                "ragged_step": ragged}
 
     # ------------------------------------------------------------ allocator
 
@@ -599,23 +620,14 @@ class PagedModelRunner(ModelRunner):
         slot_idx = jnp.arange(b)
         quant = self.kv_dtype == "int8"
         # Fused kernel reads pages via the scalar-prefetched table; the jnp
-        # gather view is the portable (CPU) fallback.  tp>1 meshes run the
+        # gather view is the portable (CPU) path.  tp>1 meshes run the
         # kernel per-shard through the shard_map wrapper (the pool is
-        # tp-sharded over kv heads, so shards are independent).
-        from crowdllama_tpu.parallel.mesh import AXIS_TP
-
-        tp = self.mesh.shape.get(AXIS_TP, 1)
-        # Any multi-device mesh (ep×tp, even with tp=1) must go through the
+        # tp-sharded over kv heads, so shards are independent).  Any
+        # multi-device mesh (ep×tp, even with tp=1) must go through the
         # shard_map wrapper: a raw pallas_call can't be partitioned by
         # GSPMD, and shard_map is also what replicates it over ep.
         sharded = self.mesh.size > 1
-        pool_itemsize = jnp.dtype(
-            jnp.int8 if quant else self.dtype).itemsize  # = init_state's pool
-        use_kernel = paged_pallas_supported(
-            pg, dh, tp, hkv, itemsize=pool_itemsize, quant=quant)
-        if not use_kernel and self.mesh.size > 1:
-            log.info("paged decode: fused kernel unavailable on this "
-                     "mesh/backend; using the jnp gather view")
+        use_kernel = self.attention_paths["decode"] != "jnp"
 
         def step(st: PagedDecodeState, _):
             positions = jnp.minimum(st.seq_lens, self.max_seq - 1)
@@ -755,13 +767,7 @@ class PagedModelRunner(ModelRunner):
         windows = T.layer_sliding_windows(cfg)
         slot_idx = jnp.arange(b)
         quant = self.kv_dtype == "int8"
-        pool_itemsize = jnp.dtype(jnp.int8 if quant else self.dtype).itemsize
-        # Multi-device meshes take the jnp reference path (GSPMD partitions
-        # the gather views; the kernel pair's shard_map wiring is future
-        # work) — the unified step still saves the dispatch, which is what
-        # the decode-jitter problem is about.
-        use_pallas = (self.mesh.size == 1 and ragged_pallas_supported(
-            pg, dh, 1, hkv, itemsize=pool_itemsize, quant=quant))
+        use_pallas = self.attention_paths["ragged_step"] != "jnp"
 
         def step(st: PagedDecodeState, xs):
             ctx_i, ctoks = xs

@@ -105,6 +105,8 @@ def sample_host(logits: np.ndarray, temperature: float, top_p: float,
 class ShardedEngine(Engine):
     """One member of a pipeline-sharded model group (leader when index 0)."""
 
+    on_device = True
+
     def __init__(self, config: Configuration | None = None, **overrides):
         self.config = config or Configuration.from_environment()
         for k, v in overrides.items():

@@ -24,17 +24,30 @@ from crowdllama_tpu.models.convert import params_from_hf
 log = logging.getLogger("crowdllama.engine.weights")
 
 
+def _checkpoint_kind(model_path: str) -> str:
+    """"native" | "safetensors" | "" (no checkpoint: random init)."""
+    if not model_path:
+        return ""
+    path = Path(model_path).expanduser()
+    if is_native_checkpoint(path):
+        return "native"
+    if path.is_dir() and list(path.glob("*.safetensors")):
+        return "safetensors"
+    log.warning("model_path %s has no safetensors; using random init", path)
+    return ""
+
+
 def load_or_init_params(cfg: ModelConfig, model_path: str = "",
                         dtype=jnp.bfloat16, seed: int = 0) -> dict:
-    if model_path:
-        path = Path(model_path).expanduser()
-        if is_native_checkpoint(path):
-            log.info("loading native checkpoint from %s", path)
-            return load_native_params(cfg, path, dtype=dtype)
-        if path.is_dir() and list(path.glob("*.safetensors")):
-            log.info("loading weights from %s", path)
-            return load_safetensors_params(cfg, path, dtype=dtype)
-        log.warning("model_path %s has no safetensors; using random init", path)
+    kind = _checkpoint_kind(model_path)
+    if kind == "native":
+        log.info("loading native checkpoint from %s", model_path)
+        return load_native_params(cfg, Path(model_path).expanduser(),
+                                  dtype=dtype)
+    if kind == "safetensors":
+        log.info("loading weights from %s", model_path)
+        return load_safetensors_params(cfg, Path(model_path).expanduser(),
+                                       dtype=dtype)
     return T.init_params(cfg, jax.random.PRNGKey(seed), dtype=dtype)
 
 
@@ -218,11 +231,23 @@ def resolve_clamped_model_config(config) -> ModelConfig:
 def load_params_for(config, cfg: ModelConfig):
     """Load-or-init + optional quantization, exactly as the engines do
     (shared with the multi-host follower for the same reason as
-    :func:`resolve_clamped_model_config`)."""
+    :func:`resolve_clamped_model_config`: every process must build the
+    same tree, so it is a function of config + seed alone).
+
+    Random init under ``--quantize`` is born quantized, leaf by leaf
+    (ops/quant.py random_quantized_params): building the bf16 tree first
+    (14.5 GB for a 7B model) and quantizing it after would not fit beside
+    its own int8 copy on the 16 GB chip the int8 model serves from."""
+    from crowdllama_tpu.ops.quant import (
+        quantize_params,
+        random_quantized_params,
+    )
+
+    if config.quantize and not _checkpoint_kind(config.model_path):
+        return random_quantized_params(cfg, jax.random.PRNGKey(0),
+                                       mode=config.quantize)
     params = load_or_init_params(cfg, config.model_path)
     if config.quantize:
-        from crowdllama_tpu.ops.quant import quantize_params
-
         params = quantize_params(params, mode=config.quantize)
     return params
 

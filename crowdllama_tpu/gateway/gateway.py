@@ -1125,7 +1125,8 @@ class Gateway:
         # numbers and a pure consumer reports the zero series (present
         # families keep absent()-style alerts working).
         lines.extend(ENGINE_TELEMETRY.expose())
-        lines.extend(device_memory_lines())
+        lines.extend(device_memory_lines(
+            getattr(engine, "on_device", False)))
         lines.extend(host_stat_lines(self.peer.host))
         lines.extend(native_metric_lines())
         # SLO burn-rate plane (PR 13): objective/burn-rate/fast-burn

@@ -25,6 +25,7 @@ import ctypes
 import hashlib
 import logging
 import os
+import platform
 import subprocess
 import threading
 from pathlib import Path
@@ -144,8 +145,10 @@ class ClGenRespView(ctypes.Structure):
 # -O3/-march=native matter here: the AEAD keystream and tag loops run
 # ~2x faster than at -O2 on the bench host (the library is built on the
 # machine that runs it, so tuning for the local CPU is safe).  The flag
-# set participates in the .so cache key (_so_path) so changing it
-# invalidates stale artifacts.
+# set AND the host CPU's identity participate in the .so cache key
+# (_so_path): changing the flags invalidates stale artifacts, and a build
+# directory that travels to another machine (a copied checkout) is rebuilt
+# there instead of loading code tuned for the CPU it came from.
 _CXX_FLAGS = ["-O3", "-march=native", "-funroll-loops", "-std=c++17",
               "-shared", "-fPIC"]
 
@@ -242,9 +245,27 @@ def _declare(lib: ctypes.CDLL) -> ctypes.CDLL:
     return lib
 
 
+def _cpu_identity() -> bytes:
+    """What -march=native keyed the build on: the CPU model and its
+    feature flags (first processor of /proc/cpuinfo), or the bare machine
+    architecture where that file does not exist."""
+    try:
+        lines = Path("/proc/cpuinfo").read_text().splitlines()
+    except OSError:
+        return platform.machine().encode()
+    picked = {}
+    for line in lines:
+        name, _, value = line.partition(":")
+        name = name.strip()
+        if name in ("model name", "flags", "Features") and name not in picked:
+            picked[name] = value.strip()
+    return (platform.machine() + repr(sorted(picked.items()))).encode()
+
+
 def _so_path() -> Path:
     src_hash = hashlib.sha256(
-        _SRC.read_bytes() + " ".join(_CXX_FLAGS).encode()).hexdigest()[:16]
+        _SRC.read_bytes() + " ".join(_CXX_FLAGS).encode()
+        + _cpu_identity()).hexdigest()[:16]
     return _BUILD_DIR / f"crowdllama_native-{src_hash}.so"
 
 

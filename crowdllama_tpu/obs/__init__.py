@@ -13,7 +13,7 @@ Trace ids ride the ``llama.v1.BaseMessage`` envelope (``trace_id`` /
 ``parent_span``, proto fields 5/6 outside the oneof) so one id follows a
 request gateway -> stream pool -> worker peer -> engine, including across
 the relay splice (the splice forwards sealed ciphertext, so the fields
-cross it untouched).  See docs/OBSERVABILITY.md for the span taxonomy and
+cross it untouched).  See docs/OBSERVABILITY.md for the span catalogue and
 the ``/debug/trace`` schema.
 """
 
@@ -64,7 +64,7 @@ class NodeObs:
         """Record one served generate exchange: worker-side spans + histograms.
 
         Called at the Engine seam so FakeEngine and JaxEngine produce the
-        same span taxonomy (worker_queue / prefill / decode_step).
+        same span catalogue (worker_queue / prefill / decode_step).
         """
         self.metrics.request_seconds.labels(model).observe(
             total_ns / 1e9, exemplar=trace_id)

@@ -97,7 +97,7 @@ def node_metric_lines(peer) -> list[str]:
     # XLA compile/padding telemetry + device memory (PR 8): process
     # singletons, real numbers on the node that actually compiles.
     lines.extend(ENGINE_TELEMETRY.expose())
-    lines.extend(device_memory_lines())
+    lines.extend(device_memory_lines(getattr(engine, "on_device", False)))
     lines.extend(host_stat_lines(peer.host))
     lines.extend(native_metric_lines())
     return lines

@@ -405,6 +405,11 @@ class EngineTelemetry:
         # a signature", not which bucket did.
         self._cache_hits: dict[str, int] = {}
         self._padding = {"waste": 0, "useful": 0}
+        # program -> "pallas" | "pallas_interpret" | "jnp": which attention
+        # implementation the newest runner built in this process dispatches
+        # (set at runner build; a refused kernel must be visible to a
+        # scrape, not just to whoever reads the worker's log).
+        self._attention_paths: dict[str, str] = {}
         # Unified ragged batch (docs/RAGGED_BATCH.md): wall time per
         # prefill chunk carried inside a decode dispatch.  Engine-plane
         # like the compile histogram (the scheduler's dispatch loop
@@ -447,6 +452,10 @@ class EngineTelemetry:
             self._compiles[key] = self._compiles.get(key, 0) + 1
         self.compile_seconds.observe(dt)
 
+    def attention_paths_set(self, paths: dict[str, str]) -> None:
+        with self._lock:
+            self._attention_paths = dict(paths)
+
     def padding_inc(self, useful: int, waste: int) -> None:
         """Account one padded dispatch: ``useful`` real tokens rode it,
         ``waste`` were padding (bucket rounding, inactive decode slots)."""
@@ -478,6 +487,14 @@ class EngineTelemetry:
             compiles = sorted(self._compiles.items())
             padding = dict(self._padding)
             cache_hits = sorted(self._cache_hits.items())
+            attention = sorted(self._attention_paths.items())
+        out.append("# TYPE crowdllama_engine_attention_path gauge")
+        if not attention:
+            out.append('crowdllama_engine_attention_path{program="none",'
+                       'path="none"} 0')
+        for program, path in attention:
+            out.append(f'crowdllama_engine_attention_path{{'
+                       f'program="{program}",path="{path}"}} 1')
         out.append("# TYPE crowdllama_xla_compiles_total counter")
         if not compiles:
             out.append('crowdllama_xla_compiles_total{program="none",'
@@ -513,34 +530,29 @@ class EngineTelemetry:
 ENGINE_TELEMETRY = EngineTelemetry()
 
 
-def device_memory_lines() -> list[str]:
+def device_memory_lines(on_device: bool) -> list[str]:
     """Per-device memory gauges from jax.local_devices()[*].memory_stats(),
-    sampled at scrape time.  Platforms without the API (CPU) report zeros —
-    the series must exist for absent()-style alerts either way."""
-    devices = []
-    try:
+    sampled at scrape time.  Only a node whose engine runs on the device
+    (``Engine.on_device``) asks the runtime — a scrape must not make a
+    gateway or DHT process initialize a backend and take the chip from the
+    worker beside it.  Everyone else, and platforms without the API (CPU),
+    report zeros: the series must exist for absent()-style alerts either
+    way."""
+    per_device: list[dict] = []
+    if on_device:
         import jax
 
-        devices = jax.local_devices()
-    except Exception:
-        pass
+        # memory_stats() is None on platforms without the API (CPU).
+        per_device = [d.memory_stats() or {} for d in jax.local_devices()]
     out = ["# TYPE crowdllama_device_memory_bytes_in_use gauge",
+           "# TYPE crowdllama_device_memory_peak_bytes_in_use gauge",
            "# TYPE crowdllama_device_memory_bytes_limit gauge"]
-    if not devices:
-        out.append('crowdllama_device_memory_bytes_in_use{device="0"} 0')
-        out.append('crowdllama_device_memory_bytes_limit{device="0"} 0')
-        return out
-    for i, d in enumerate(devices):
-        stats: dict = {}
-        try:
-            stats = d.memory_stats() or {}
-        except Exception:
-            pass
-        in_use = int(stats.get("bytes_in_use") or 0)
-        limit = int(stats.get("bytes_limit")
-                    or stats.get("bytes_reservable_limit") or 0)
-        out.append(f'crowdllama_device_memory_bytes_in_use{{'
-                   f'device="{i}"}} {in_use}')
-        out.append(f'crowdllama_device_memory_bytes_limit{{'
-                   f'device="{i}"}} {limit}')
+    for i, stats in enumerate(per_device or [{}]):
+        limit = stats.get("bytes_limit") or stats.get("bytes_reservable_limit")
+        for family, value in (("bytes_in_use", stats.get("bytes_in_use")),
+                              ("peak_bytes_in_use",
+                               stats.get("peak_bytes_in_use")),
+                              ("bytes_limit", limit)):
+            out.append(f'crowdllama_device_memory_{family}{{device="{i}"}} '
+                       f'{int(value or 0)}')
     return out

@@ -41,25 +41,35 @@ from crowdllama_tpu.utils.env import env_flag
 _VMEM_KV_BUDGET_BYTES = 8 * 1024 * 1024
 
 
+def pallas_refusal(seq_len: int, head_dim: int, itemsize: int = 2,
+                   n_shards: int = 1) -> str:
+    """Why the pallas path does NOT apply ("" when it does): it needs a TPU
+    backend (or interpret mode forced via CROWDLLAMA_PALLAS_INTERPRET), an
+    unsharded mesh (``pallas_call`` cannot be auto-partitioned by GSPMD —
+    multi-chip callers stay on the XLA path until the kernels are
+    shard_map-wrapped), a hardware-sized tile (≥32; odd/prime extents
+    would degenerate), and DOUBLE-BUFFERED K+V rows fitting the VMEM
+    budget (the 4x bound is what the decode kernel's head-batch loop
+    actually requires at hb=1 — a 2x gate here let the hb=1 grid run over
+    budget in the gap, ADVICE r4).  Runners log the reason at build so a
+    refused shape on a chip is never silent."""
+    if env_flag("CROWDLLAMA_NO_PALLAS"):
+        return "CROWDLLAMA_NO_PALLAS is set"
+    if not _interpret() and jax.default_backend() != "tpu":
+        return f"backend is {jax.default_backend()}, not tpu"
+    if n_shards > 1:
+        return f"mesh has {n_shards} devices (kernel is not shard_map-wrapped)"
+    if 4 * seq_len * head_dim * itemsize > _VMEM_KV_BUDGET_BYTES:
+        return (f"double-buffered K+V rows ({4 * seq_len * head_dim * itemsize}"
+                f" B at T={seq_len}) exceed the VMEM budget")
+    if _tile(seq_len) < 32:
+        return f"T={seq_len} has no power-of-two tile >= 32"
+    return ""
+
+
 def pallas_supported(seq_len: int, head_dim: int, itemsize: int = 2,
                      n_shards: int = 1) -> bool:
-    """True when the pallas path applies: TPU backend (or interpret mode
-    forced via CROWDLLAMA_PALLAS_INTERPRET), an unsharded mesh
-    (``pallas_call`` cannot be auto-partitioned by GSPMD — multi-chip
-    callers stay on the XLA path until the kernels are shard_map-wrapped),
-    a hardware-sized tile (≥32; odd/prime extents would degenerate), and
-    DOUBLE-BUFFERED K+V rows fitting the VMEM budget (the 4x bound is what
-    the decode kernel's head-batch loop actually requires at hb=1 — a 2x
-    gate here let the hb=1 grid run over budget in the gap, ADVICE r4)."""
-    if env_flag("CROWDLLAMA_NO_PALLAS"):
-        return False
-    if not _interpret() and jax.default_backend() != "tpu":
-        return False
-    if n_shards > 1:
-        return False
-    if 4 * seq_len * head_dim * itemsize > _VMEM_KV_BUDGET_BYTES:
-        return False
-    return _tile(seq_len) >= 32
+    return not pallas_refusal(seq_len, head_dim, itemsize, n_shards)
 
 
 def _interpret() -> bool:

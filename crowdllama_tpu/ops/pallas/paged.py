@@ -62,28 +62,30 @@ def _pairs_bytes(hkv: int, page: int, dh: int, itemsize: int) -> int:
     return 2 * hkv * page * dh * itemsize  # one page's K + V tiles
 
 
-def paged_pallas_supported(page_size: int, head_dim: int,
-                           n_shards: int = 1,
-                           num_kv_heads: int = 0,
-                           itemsize: int = 2,
-                           quant: bool = False) -> bool:
-    """The fused paged kernel applies on TPU (or forced interpret mode)
-    with hardware-aligned page tiles.  tp-sharded pools are supported via
-    the shard_map wrapper (:func:`flash_paged_decode_attention_tp`) when
-    every shard owns whole kv heads; ``n_shards`` is the TP axis extent.
-    ``itemsize`` is the KV POOL's element size (1 for int8 pools — gating
-    on the bf16 size refused the kernel for wide-Hkv int8 configs that
-    actually fit, ADVICE r4); ``quant`` adds the int8 scale tiles to the
-    VMEM budget, matching the kernel's real footprint."""
+def paged_pallas_refusal(page_size: int, head_dim: int,
+                         n_shards: int = 1,
+                         num_kv_heads: int = 0,
+                         itemsize: int = 2,
+                         quant: bool = False) -> str:
+    """Why the fused paged kernel does NOT apply ("" when it does).  It
+    applies on TPU (or forced interpret mode) with hardware-aligned page
+    tiles.  tp-sharded pools are supported via the shard_map wrapper
+    (:func:`flash_paged_decode_attention_tp`) when every shard owns whole
+    kv heads; ``n_shards`` is the TP axis extent.  ``itemsize`` is the KV
+    POOL's element size (1 for int8 pools — gating on the bf16 size
+    refused the kernel for wide-Hkv int8 configs that actually fit,
+    ADVICE r4); ``quant`` adds the int8 scale tiles to the VMEM budget,
+    matching the kernel's real footprint."""
     if env_flag("CROWDLLAMA_NO_PALLAS"):
-        return False
+        return "CROWDLLAMA_NO_PALLAS is set"
     if not _interpret() and jax.default_backend() != "tpu":
-        return False
+        return f"backend is {jax.default_backend()}, not tpu"
     if n_shards > 1 and (num_kv_heads <= 0 or num_kv_heads % n_shards):
         # pallas_call cannot be auto-partitioned by GSPMD; tp meshes run
         # the kernel per-shard via shard_map, which needs the kv-head dim
         # (pool axis 1) to split evenly so each shard's grid is whole heads.
-        return False
+        return (f"{num_kv_heads} kv heads do not split evenly over "
+                f"tp={n_shards}")
     # Per grid step the kernel holds [Hkv/shard, page, Dh] K and V tiles
     # (double-buffered) in VMEM; gate wide-Hkv (MHA-style) configs that
     # would blow the budget.  num_kv_heads=0 (a generic availability
@@ -96,10 +98,23 @@ def paged_pallas_supported(page_size: int, head_dim: int,
         # buffered like the KV tiles they ride with.
         step_bytes += 2 * 2 * hkv_local * page_size * 2
     if step_bytes > _VMEM_TILE_BUDGET:
-        return False
+        return (f"per-step K/V tiles ({step_bytes} B for {hkv_local} kv "
+                f"heads) exceed the VMEM budget")
     # Block last-two dims are (page, head_dim); Mosaic pads sub-tile
     # extents, so sublane alignment suffices (TinyLlama Dh=64, Llama 128).
-    return page_size % 8 == 0 and page_size >= 32 and head_dim % 8 == 0
+    if page_size % 8 or page_size < 32 or head_dim % 8:
+        return (f"page {page_size} / head_dim {head_dim} not tile-aligned "
+                f"(page % 8 == 0, page >= 32, head_dim % 8 == 0)")
+    return ""
+
+
+def paged_pallas_supported(page_size: int, head_dim: int,
+                           n_shards: int = 1,
+                           num_kv_heads: int = 0,
+                           itemsize: int = 2,
+                           quant: bool = False) -> bool:
+    return not paged_pallas_refusal(page_size, head_dim, n_shards,
+                                    num_kv_heads, itemsize, quant)
 
 
 def _decode_kernel(
@@ -287,28 +302,42 @@ def flash_paged_decode_attention(
 _CHUNK_QB = 32
 
 
-def ragged_pallas_supported(page_size: int, head_dim: int,
-                            n_shards: int = 1,
-                            num_kv_heads: int = 0,
-                            itemsize: int = 2,
-                            quant: bool = False) -> bool:
-    """Gate for the fused ragged (decode + prefill-chunk) kernel.
+def ragged_pallas_refusal(page_size: int, head_dim: int,
+                          n_shards: int = 1,
+                          num_kv_heads: int = 0,
+                          itemsize: int = 2,
+                          quant: bool = False) -> str:
+    """Why the fused ragged (decode + prefill-chunk) kernel does NOT apply
+    ("" when it does).
 
     The unified step runs the whole mixed batch through the v2 kernel
     (:func:`flash_ragged_paged_attention`), whose blocks are uniform
     [Hkv, QB, G, Dh] query tiles, so the constraints are the decode gate
     plus the chunk-sized VMEM footprint (QB*G query rows instead of G
     per kv head) — identical bounds to the v1 kernel pair."""
-    if not paged_pallas_supported(page_size, head_dim, n_shards,
-                                  num_kv_heads, itemsize, quant):
-        return False
+    why = paged_pallas_refusal(page_size, head_dim, n_shards,
+                               num_kv_heads, itemsize, quant)
+    if why:
+        return why
     # Chunk kernel holds [Hkv, QB*G, Dh] fp32 acc + 2x [Hkv, QB*G, _LANES]
     # carries; with num_kv_heads=0 (availability probe) assume one head.
     hkv_local = max(max(num_kv_heads, 1) // max(n_shards, 1), 1)
     # G is unknown at probe time; bound by a generous 16 query groups.
     rows = _CHUNK_QB * 16
     scratch = hkv_local * rows * (head_dim + 2 * _LANES) * 4
-    return scratch <= 2 * _VMEM_TILE_BUDGET
+    if scratch > 2 * _VMEM_TILE_BUDGET:
+        return (f"chunk scratch ({scratch} B for {hkv_local} kv heads) "
+                f"exceeds the VMEM budget")
+    return ""
+
+
+def ragged_pallas_supported(page_size: int, head_dim: int,
+                            n_shards: int = 1,
+                            num_kv_heads: int = 0,
+                            itemsize: int = 2,
+                            quant: bool = False) -> bool:
+    return not ragged_pallas_refusal(page_size, head_dim, n_shards,
+                                     num_kv_heads, itemsize, quant)
 
 
 def _chunk_kernel(
@@ -884,7 +913,6 @@ def flash_paged_decode_attention_tp(
     GSPMD treats attention on an ep×tp mesh."""
     from jax.sharding import PartitionSpec as P
 
-    from crowdllama_tpu.ops.ring import shard_map
     from crowdllama_tpu.parallel.mesh import AXIS_TP
 
     window = jnp.asarray(sliding_window, jnp.int32).reshape(1)
@@ -906,5 +934,5 @@ def flash_paged_decode_attention_tp(
             k_scale=scales[0] if scales else None,
             v_scale=scales[1] if scales else None)
 
-    return shard_map(local, mesh=mesh, in_specs=in_specs,
-                     out_specs=q_spec, check_rep=False)(*args)
+    return jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                         out_specs=q_spec, check_vma=False)(*args)

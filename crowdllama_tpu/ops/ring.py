@@ -35,26 +35,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:
-    from jax import shard_map as _shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# The replication-check kwarg was renamed check_rep -> check_vma (jax 0.8);
-# detect what this jax accepts instead of guessing from the import location.
-import inspect as _inspect
-
-_CHECK_KW = (
-    "check_vma"
-    if "check_vma" in _inspect.signature(_shard_map).parameters
-    else "check_rep"
-)
-
-
-def shard_map(f, mesh, in_specs, out_specs, check_rep=False):
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **{_CHECK_KW: check_rep})
-
 from crowdllama_tpu.ops.attention import NEG_INF, _softcap
 
 
@@ -165,11 +145,11 @@ def ring_prefill_attention(
     qspec = P(dp_axis, axis_name, tp_axis, None)
     kspec = P(dp_axis, axis_name, tp_axis, None)
     pspec = P(dp_axis, axis_name)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(qspec, kspec, kspec, pspec, pspec, P()),
         out_specs=qspec,
-        check_rep=False,
+        check_vma=False,
     )(q, k, v, positions, kv_valid, jnp.asarray(sliding_window, jnp.int32))
 
 
@@ -215,11 +195,11 @@ def sp_cache_update(
     starts = jnp.arange(sp, dtype=jnp.int32) * (s // sp)
     newspec = P(dp_axis, tp_axis, None)
     cspec = P(dp_axis, tp_axis, axis_name, None)
-    return shard_map(
+    return jax.shard_map(
         _sp_update_body, mesh=mesh,
         in_specs=(newspec, newspec, P(dp_axis), cspec, cspec, P(axis_name)),
         out_specs=(cspec, cspec),
-        check_rep=False,
+        check_vma=False,
     )(k_new, v_new, positions, k_cache, v_cache, starts)
 
 
@@ -287,10 +267,10 @@ def sp_decode_attention(
     )
     qspec = P(dp_axis, tp_axis, None)
     cspec = P(dp_axis, tp_axis, axis_name, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(qspec, cspec, cspec, P(dp_axis), P(axis_name), P()),
         out_specs=qspec,
-        check_rep=False,
+        check_vma=False,
     )(q, k_cache, v_cache, seq_lens, starts,
       jnp.asarray(sliding_window, jnp.int32))

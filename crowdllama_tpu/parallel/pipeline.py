@@ -21,8 +21,6 @@ model-parallelism strategies"); this is part of the TPU-native superset
 
 from __future__ import annotations
 
-import inspect
-
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
@@ -32,20 +30,6 @@ from crowdllama_tpu.models.config import ModelConfig
 from crowdllama_tpu.parallel.mesh import AXIS_PP
 
 Params = dict
-
-# Partial-manual shard_map (axis_names=) landed with the new jax.shard_map
-# API; pp cannot work without it, so fail fast with a clear message.
-_HAS_PARTIAL_MANUAL = (
-    hasattr(jax, "shard_map")
-    and "axis_names" in inspect.signature(jax.shard_map).parameters
-)
-
-
-def _require_partial_manual() -> None:
-    if not _HAS_PARTIAL_MANUAL:
-        raise RuntimeError(
-            "pipeline parallelism needs jax.shard_map with axis_names= "
-            "(partial-manual mode); upgrade jax or use a pp=1 mesh")
 
 
 def pick_n_microbatches(batch: int, pp: int) -> int:
@@ -114,7 +98,6 @@ def _pp_forward(
     n_microbatches: int,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Shared pipeline body: (pre-final-norm activations [B,T,D], k, v)."""
-    _require_partial_manual()
     npp = mesh.shape[AXIS_PP]
     b, t = tokens.shape
     n_mb = n_microbatches or pick_n_microbatches(b, npp)
@@ -203,7 +186,6 @@ def pp_decode_step(
     Microbatches over batch slots so all stages decode concurrently after
     the fill bubble; each stage updates only its local cache slice.
     """
-    _require_partial_manual()
     npp = mesh.shape[AXIS_PP]
     b = tokens.shape[0]
     n_mb = n_microbatches or pick_n_microbatches(b, npp)

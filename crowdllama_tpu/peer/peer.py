@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import asyncio
 import logging
+import math
 import time
 
 from crowdllama_tpu.utils.crypto_compat import Ed25519PrivateKey
@@ -58,55 +59,44 @@ def _tpu_capabilities() -> dict:
     platform exposes them.  Nothing is hardcoded — the reference advertises
     a fake RTX 4090 (peer.go:320-343); a capability the runtime cannot
     report is reported as 0/unknown, not invented.
+
+    Initializes the JAX backend, so only a node whose engine runs on the
+    device calls it (``Engine.on_device``); a backend that fails to
+    initialize there raises — a worker must not advertise "unknown" over a
+    chip it could not open.
     """
-    try:
-        import jax
+    import jax
 
-        devs = jax.devices()
-        if not devs:
-            raise RuntimeError("no devices")
-        d0 = devs[0]
-        kind = getattr(d0, "device_kind", "cpu") or "cpu"
-        n = len(devs)
+    devs = jax.devices()
+    d0 = devs[0]
+    kind = getattr(d0, "device_kind", "cpu") or "cpu"
+    n = len(devs)
 
-        hbm_gb = 0.0
-        try:
-            stats = d0.memory_stats() or {}
-            limit = stats.get("bytes_limit") or stats.get("bytes_reservable_limit")
-            if limit:
-                hbm_gb = round(limit / (1 << 30), 1)
-        except Exception:
-            pass  # platform without memory_stats (e.g. some CPU builds)
+    hbm_gb = 0.0
+    stats = d0.memory_stats() or {}  # None on platforms without the API
+    limit = stats.get("bytes_limit") or stats.get("bytes_reservable_limit")
+    if limit:
+        hbm_gb = round(limit / (1 << 30), 1)
 
-        # Physical mesh extent per axis from device coordinates; fall back
-        # to a flat 1xN when the platform has no coords (CPU), a backend's
-        # coords accessor misbehaves, or the extents don't cover the
-        # device count.
-        topology = f"1x{n}"
-        try:
-            coords = [getattr(d, "coords", None) for d in devs]
-            if coords and all(c is not None for c in coords):
-                dims = [max(c[i] for c in coords) - min(c[i] for c in coords) + 1
-                        for i in range(len(coords[0]))]
-                dims = [d for d in dims if d > 1]
-                prod = 1
-                for d in dims:
-                    prod *= d
-                if dims and prod == n:
-                    topology = "x".join(str(d) for d in dims) if len(dims) > 1 \
-                        else f"1x{dims[0]}"
-        except Exception:
-            pass  # keep the 1xN fallback; kind/count/HBM are already known
+    # Physical mesh extent per axis from device coordinates; a flat 1xN
+    # when the platform has no coords (CPU) or the extents don't cover the
+    # device count.
+    topology = f"1x{n}"
+    coords = [getattr(d, "coords", None) for d in devs]
+    if all(c is not None for c in coords):
+        dims = [max(c[i] for c in coords) - min(c[i] for c in coords) + 1
+                for i in range(len(coords[0]))]
+        dims = [d for d in dims if d > 1]
+        if dims and math.prod(dims) == n:
+            topology = ("x".join(str(d) for d in dims) if len(dims) > 1
+                        else f"1x{dims[0]}")
 
-        return {
-            "accelerator": kind.lower().replace(" ", "-"),
-            "tpu_chip_count": n,
-            "hbm_gb_per_chip": hbm_gb,
-            "ici_topology": topology,
-        }
-    except Exception:  # pragma: no cover - jax always importable here
-        return {"accelerator": "unknown", "tpu_chip_count": 0,
-                "hbm_gb_per_chip": 0.0, "ici_topology": ""}
+    return {
+        "accelerator": kind.lower().replace(" ", "-"),
+        "tpu_chip_count": n,
+        "hbm_gb_per_chip": hbm_gb,
+        "ici_topology": topology,
+    }
 
 
 class Peer:
@@ -172,7 +162,7 @@ class Peer:
                 model_dir=self.engine.model_dir, pull=self.pull_model,
                 allow_pull=(
                     getattr(self.config, "allow_swarm_pull", True)
-                    and _single_process()))
+                    and (not self.engine.on_device or _single_process())))
             self.host.set_stream_handler(MODEL_PROTOCOL,
                                          self._model_share.handle)
         shard_service = getattr(self.engine, "shard_service", None)
@@ -562,8 +552,9 @@ class Peer:
         r.worker_mode = self.worker_mode
         r.max_context_length = self.config.max_context_length
         r.embeddings = bool(d.get("embeddings", True))
-        for k, v in _tpu_capabilities().items():
-            setattr(r, k, v)
+        if self.engine.on_device:
+            for k, v in _tpu_capabilities().items():
+                setattr(r, k, v)
         sg = d.get("shard_group")
         if sg is not None:
             r.shard_group = sg

@@ -120,8 +120,14 @@ def test_window_burn_requires_objective_and_full_short_window():
 
 
 def test_grid_gating_tracks_runner_capabilities():
-    t = _tuner()
-    assert list(t._order) == list(DIALS)  # fully-capable fake: all four
+    sched = FakeScheduler()
+    sched.runner.supports_remote_draft = True  # hosted spec verify program
+    sched.spec_pipeline_depth = 2
+    t = _tuner(sched)
+    assert list(t._order) == list(DIALS)  # fully-capable fake: all five
+    # Without the remote-draft verify program the fifth dial is gated off.
+    assert list(_tuner()._order) == [d for d in DIALS
+                                     if d != "pipeline_depth"]
 
     r = FakeRunner(prefill_chunk=0, step_token_budget=0)
     r.supports_megastep = False

@@ -658,7 +658,7 @@ async def test_trace_propagates_across_two_worker_swarm():
         other = obs_servers[1 - idx].peer.obs.trace
         assert other.get(tid) is None, "idle worker recorded the trace"
 
-        # Span taxonomy + parentage.
+        # Span catalogue + parentage.
         gw_spans = {sp["name"]: sp for sp in gw_trace["spans"]}
         assert {"route", "serde", "aead", "io_wait"} <= set(gw_spans)
         wk_spans = {sp["name"]: sp for sp in wk_trace["spans"]}

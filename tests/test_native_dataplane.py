@@ -62,7 +62,7 @@ def test_aead_seal_golden_corpus_byte_identity():
     """Native seal output == Python seal output, frame for frame, across
     chunk boundaries, EOF markers and an advancing nonce counter — on ONE
     session so the sequence numbers themselves are exercised."""
-    nat = native.AeadSession(lib, KEY, native.FLAVOR_COMPAT)
+    nat = native.AeadSession(lib, KEY, secure._NATIVE_FLAVOR)
     aead = ChaCha20Poly1305(KEY)
     ctr = 0
     for data, chunk, with_eof in AEAD_CORPUS:
@@ -78,7 +78,7 @@ def test_aead_open_parity_and_counter_on_tamper():
     """Python-sealed frames open natively; a corrupted frame is rejected
     by BOTH paths and both counters still advance (replay alignment)."""
     aead = ChaCha20Poly1305(KEY)
-    nat = native.AeadSession(lib, KEY, native.FLAVOR_COMPAT)
+    nat = native.AeadSession(lib, KEY, secure._NATIVE_FLAVOR)
     pt0, pt1, pt2 = b"alpha", b"bravo" * 100, b"charlie"
     cts = []
     for i, p in enumerate((pt0, pt1, pt2)):

@@ -1,0 +1,162 @@
+"""Deviceless TPU compiles of the main-path kernels at Mistral-7B widths.
+
+The TPU's compiler is installed here and compiles for a chip that is
+described, not attached (``v5e:2x2``): what it refuses — a slice not aligned
+to the tiling, too much VMEM, a kernel that cannot be partitioned — would be
+refused on the chip too, and interpret-mode tests cannot see it.  Nothing
+runs, so these say nothing about results or times (``chip_smoke.py`` does).
+
+All of it lives in this ONE file, and the topology is described inside a
+module-scoped fixture: only one process at a time may load the TPU's
+library, so nothing here touches it at import or collection time.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from crowdllama_tpu.ops.pallas.flash import flash_prefill_attention
+from crowdllama_tpu.ops.pallas.paged import (
+    flash_paged_decode_attention,
+    flash_paged_decode_attention_tp,
+    flash_ragged_paged_attention,
+)
+from crowdllama_tpu.parallel.mesh import AXIS_TP
+
+# mistral-7b (models/config.py) under the serving defaults: page 128,
+# 8 slots, context 2048 (16 pages per slot), ragged chunk 512.
+H, HKV, DH = 32, 8, 128
+PAGE, SLOTS, PAGES_PER_SLOT = 128, 8, 16
+POOL_PAGES = SLOTS * PAGES_PER_SLOT + 1
+PREFILL_T, CHUNK = 2048, 512
+SCALE = DH ** -0.5
+WINDOW = 4096
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs to /tmp
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    """A deviceless compile is written to the persistent cache but cannot
+    be read back without a chip (the next run would warn and recompile)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _pool(kv, sharding, scale_sharding=None):
+    """(pool_k, pool_v, k_scale, v_scale) shapes for a bf16 or int8 pool."""
+    dtype = jnp.int8 if kv == "int8" else jnp.bfloat16
+    pool = _sds((POOL_PAGES, HKV, PAGE, DH), dtype, sharding)
+    if kv != "int8":
+        return pool, pool, None, None
+    sc = _sds((POOL_PAGES, HKV, PAGE), jnp.bfloat16,
+              scale_sharding or sharding)
+    return pool, pool, sc, sc
+
+
+def _assert_kernel(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_prefill_kernel_compiles(one_chip):
+    q = _sds((1, PREFILL_T, H, DH), jnp.bfloat16, one_chip)
+    kv = _sds((1, HKV, PREFILL_T, DH), jnp.bfloat16, one_chip)
+    pos = _sds((1, PREFILL_T), jnp.int32, one_chip)
+    valid = _sds((1, PREFILL_T), jnp.bool_, one_chip)
+
+    def f(q, k, v, pos, valid):
+        return flash_prefill_attention(q, k, v, pos, SCALE,
+                                       sliding_window=WINDOW, kv_valid=valid)
+
+    _assert_kernel(jax.jit(f).lower(q, kv, kv, pos, valid).compile())
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_paged_decode_kernel_compiles(one_chip, kv):
+    q = _sds((SLOTS, H, DH), jnp.bfloat16, one_chip)
+    pk, pv, ks, vs = _pool(kv, one_chip)
+    table = _sds((SLOTS, PAGES_PER_SLOT), jnp.int32, one_chip)
+    lens = _sds((SLOTS,), jnp.int32, one_chip)
+
+    def f(q, pk, pv, table, lens, ks, vs):
+        return flash_paged_decode_attention(
+            q, pk, pv, table, lens, SCALE, sliding_window=WINDOW,
+            k_scale=ks, v_scale=vs)
+
+    _assert_kernel(jax.jit(f).lower(q, pk, pv, table, lens, ks, vs).compile())
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_ragged_v2_kernel_compiles(one_chip, kv):
+    q = _sds((SLOTS + CHUNK, H, DH), jnp.bfloat16, one_chip)
+    pk, pv, ks, vs = _pool(kv, one_chip)
+    table = _sds((SLOTS, PAGES_PER_SLOT), jnp.int32, one_chip)
+    lens = _sds((SLOTS + 1,), jnp.int32, one_chip)
+    slot = _sds((), jnp.int32, one_chip)
+
+    def f(q, pk, pv, table, q_lens, kv_lens, slot, ks, vs):
+        return flash_ragged_paged_attention(
+            q, pk, pv, table, q_lens, kv_lens, slot, SCALE,
+            sliding_window=WINDOW, k_scale=ks, v_scale=vs)
+
+    _assert_kernel(jax.jit(f).lower(
+        q, pk, pv, table, lens, lens, slot, ks, vs).compile())
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_tp4_wrapped_decode_kernel_compiles(topo, kv):
+    """The shard_map wrapper the auto tp=4 mesh of a four-chip host takes:
+    one kernel per shard over its own kv heads, no collective."""
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("dp", AXIS_TP))
+    rep = NamedSharding(mesh, P())
+    heads = NamedSharding(mesh, P(None, AXIS_TP, None))
+    q = _sds((SLOTS, H, DH), jnp.bfloat16, heads)
+    pk, pv, ks, vs = _pool(
+        kv, NamedSharding(mesh, P(None, AXIS_TP, None, None)), heads)
+    table = _sds((SLOTS, PAGES_PER_SLOT), jnp.int32, rep)
+    lens = _sds((SLOTS,), jnp.int32, rep)
+
+    def f(q, pk, pv, table, lens, ks, vs):
+        return flash_paged_decode_attention_tp(
+            q, pk, pv, table, lens, SCALE, mesh, sliding_window=WINDOW,
+            k_scale=ks, v_scale=vs)
+
+    compiled = jax.jit(f).lower(q, pk, pv, table, lens, ks, vs).compile()
+    _assert_kernel(compiled)
+    text = compiled.as_text()
+    for op in ("all-gather", "all-reduce", "all-to-all"):
+        assert f" {op}(" not in text, f"unexpected {op} in the tp kernel"
+    # Per device: a quarter of the pool (kv heads are the sharded dim).
+    ma = compiled.memory_analysis()
+    per_dev_pool = 2 * POOL_PAGES * (HKV // 4) * PAGE * DH * (
+        1 if kv == "int8" else 2)
+    assert ma.argument_size_in_bytes < 1.5 * per_dev_pool
