@@ -1,0 +1,505 @@
+#!/usr/bin/env python3
+"""The chip benchmark's command.
+
+    python3 benchmarks/chip/run.py --workload <cell> --seed <n> \
+        --seconds <s> --trace <0|1>
+
+One run of one cell of BENCHMARK.json: starts DHT node, worker and gateway
+as child processes, warms up, drives the cell's traffic at the gateway's
+``/api/generate`` for ``--seconds``, checks a seeded sample of the outputs
+against the plain reference on the chip, and prints the contract's one JSON
+object as the last line of standard output (``--trace 0``: the cell's
+end-to-end metrics; ``--trace 1``: its per-layer metrics, from spans,
+counters and a device trace of a few seconds of the window).
+
+    --rehearse      the same flow at tiny size on the CPU (Pallas in
+                    interpret mode); prints "device" as the CPU and never a
+                    device metric
+    --sweep R1,R2   open-loop cells: one set-up, one window per rate, a
+                    table instead of a result (how the knee was found)
+
+This process never imports JAX: the chip belongs to the worker, and after
+the worker has exited to the reference check.  See README.md beside this
+file for the layout and for how to add a configuration, a traffic mix, a
+cell or a metric as files.
+"""
+
+from __future__ import annotations
+
+import time
+
+T_PROCESS_START = time.monotonic()
+
+import argparse  # noqa: E402
+import ast  # noqa: E402
+import asyncio  # noqa: E402
+import json  # noqa: E402
+import os  # noqa: E402
+import shutil  # noqa: E402
+import signal  # noqa: E402
+import subprocess  # noqa: E402
+import sys  # noqa: E402
+from pathlib import Path  # noqa: E402
+
+CHIP_DIR = Path(__file__).resolve().parent
+sys.path.insert(0, str(CHIP_DIR))
+
+from harness import generators, launcher, metrics, reducers  # noqa: E402
+from harness.launcher import BenchFailure  # noqa: E402
+from harness.loadgen import LoadGen  # noqa: E402
+
+ROOT = launcher.ROOT
+REHEARSAL_CONFIG = "rehearsal-tiny-mistral"
+TRACE_AT, TRACE_LEN = 0.4, 3.0     # traced run: where in the window, how long
+TRACE_BUFFER = 8192                # span ring of a traced run, both nodes
+
+
+def say(msg: str) -> None:
+    print(msg, flush=True)
+
+
+def load_json(path: Path) -> dict:
+    return json.loads(path.read_text())
+
+
+def load_cell(name: str, rehearse: bool) -> tuple[dict, dict, dict, dict]:
+    """(benchmark, cell, configuration, traffic) by the names in
+    BENCHMARK.json."""
+    bench = load_json(ROOT / "BENCHMARK.json")
+    cell = next((w for w in bench["workloads"] if w["name"] == name), None)
+    if cell is None:
+        raise BenchFailure(f"no workload {name!r} in BENCHMARK.json "
+                           f"({[w['name'] for w in bench['workloads']]})")
+    cfg_entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
+    config = load_json(ROOT / cfg_entry["file"])
+    traffic = load_json(CHIP_DIR / "traffic" / f"{cell['traffic']}.json")
+    if rehearse:
+        config = load_json(CHIP_DIR / "configs" / f"{REHEARSAL_CONFIG}.json")
+        traffic.update(traffic.get("rehearsal") or {})
+    return bench, cell, config, traffic
+
+
+def cell_metrics(bench: dict, group: str, cell: str) -> list[dict]:
+    return [m for m in bench[group]
+            if "workloads" not in m or cell in m["workloads"]]
+
+
+# ---------------------------------------------------------------- worker io
+
+
+def engine_stats_after(log: Path, offset: int) -> dict | None:
+    """The newest ``engine: {...}`` stats line the worker wrote after byte
+    ``offset`` of its log (it logs one every 10 s)."""
+    with log.open("rb") as f:
+        f.seek(offset)
+        text = f.read().decode(errors="replace")
+    for line in reversed(text.splitlines()):
+        if "| engine: {" in line:
+            try:
+                return ast.literal_eval(line.split("| engine: ", 1)[1])
+            except (ValueError, SyntaxError):
+                return None
+    return None
+
+
+async def fresh_prefix_count(log: Path, timeout: float = 12.0) -> int | None:
+    """``prefix_cache.tokens_reused`` from a stats line written from now
+    on, summed over the worker's engines."""
+    offset = log.stat().st_size
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline:
+        stats = engine_stats_after(log, offset)
+        if stats is not None:
+            engines = stats.get("engines") or {"": stats}
+            return sum((e.get("prefix_cache") or {}).get("tokens_reused", 0)
+                       for e in engines.values())
+        await asyncio.sleep(0.25)
+    return None
+
+
+def device_of(worker_metrics: str) -> tuple[int, int]:
+    """(devices with memory the worker reports, peak bytes on the fullest):
+    a worker that is not on an accelerator reports limit 0."""
+    limits = reducers.samples(
+        worker_metrics, "crowdllama_device_memory_bytes_limit")
+    peaks = reducers.samples(
+        worker_metrics, "crowdllama_device_memory_peak_bytes_in_use")
+    return sum(1 for x in limits if x > 0), int(max(peaks, default=0))
+
+
+# ------------------------------------------------------------------ one run
+
+
+class Run:
+    def __init__(self, args, cell, config, traffic) -> None:
+        self.args, self.cell = args, cell
+        self.config, self.traffic = config, traffic
+        self.traced = bool(args.trace)
+        self.out = launcher.RUN_DIR / "out" / cell["name"] / (
+            f"s{args.seed}-t{args.trace}" + ("-rehearse" if args.rehearse
+                                             else ""))
+        shutil.rmtree(self.out, ignore_errors=True)
+        self.out.mkdir(parents=True)
+        self.nodes = launcher.Nodes(self.out)
+        self.window_unix0 = 0.0
+        self.profile_started = False
+
+    def plan_ctx(self, seconds: float) -> dict:
+        b = self.config["bench"]
+        return {"seed": self.args.seed, "seconds": seconds,
+                "slots": b["slots"], "context": b["context"],
+                "vocab_size": self.config["vocab_size"]}
+
+    def start_system(self) -> None:
+        model_dir = launcher.write_model_dir(self.config)
+        self.model_dir = model_dir
+        self.nodes.start(self.config, model_dir, traced=self.traced,
+                         trace_buffer=TRACE_BUFFER if self.traced else 0,
+                         extra_worker_flags=self.args.worker_flag or [],
+                         require_chips=0 if self.args.rehearse
+                         else self.cell["chips"])
+        health = self.nodes.wait_ready(self.config["bench"]["ready_timeout_s"])
+        say(f"info: ready after {time.monotonic() - T_PROCESS_START:.1f}s; "
+            f"workers: " + json.dumps([
+                {k: w.get(k) for k in ("accelerator", "tpu_chip_count",
+                                       "supported_models")}
+                for w in (health.get("workers") or {}).values()]))
+        self.device = load_json(self.out / "device.json")
+        m = self.scrape("metrics")
+        n_dev, _ = device_of(m)
+        paths = [ln for ln in m.splitlines()
+                 if ln.startswith("crowdllama_engine_attention_path")]
+        say("info: attention paths: " + " ".join(paths))
+        if not self.args.rehearse:
+            if n_dev < self.cell["chips"]:
+                raise BenchFailure(
+                    f"the worker reports {n_dev} accelerator device(s), the "
+                    f"cell needs {self.cell['chips']}: no TPU, no result")
+            if any('path="pallas"' not in p for p in paths):
+                raise BenchFailure("an attention path is not the Pallas "
+                                   "kernel on the chip: " + " ".join(paths))
+
+    def scrape(self, node: str, path: str = "/metrics") -> str:
+        return launcher.http_get(self.nodes.ports[node], path, timeout=30)
+
+    async def ascrape(self, node: str, path: str = "/metrics") -> str:
+        return await asyncio.get_running_loop().run_in_executor(
+            None, self.scrape, node, path)
+
+    async def measure(self, traffic: dict, seconds: float) -> reducers.RunData:
+        """One timeline (ladder, ramp, window, drain) against the running
+        system; everything a metric reader may want, gathered."""
+        b = self.config["bench"]
+        plan = generators.build_plan(traffic, self.plan_ctx(seconds))
+        gen = LoadGen(self.nodes.ports["gateway"], b["name"], b["sampling"],
+                      self.args.seed, seconds)
+        run = reducers.RunData(
+            records=gen.records, seconds=seconds, config=self.config,
+            min_beyond=0 if self.args.rehearse else 10,
+            rehearse=self.args.rehearse, checked=plan.checked)
+        scr = {"worker": {}, "gateway": {}}
+        tasks: dict[str, asyncio.Task] = {}
+        prefix0: int | None = None
+        wlog = self.out / "worker.log"
+
+        async def after_ladder() -> None:
+            nonlocal prefix0
+            if self.traced:
+                prefix0 = await fresh_prefix_count(wlog)
+
+        async def sample_gauges() -> None:
+            while True:
+                run.gauge_samples.append(await self.ascrape("metrics"))
+                await asyncio.sleep(0.5)
+
+        async def profile() -> None:
+            await asyncio.sleep(TRACE_AT * seconds)
+            self.nodes.signal_worker(signal.SIGUSR1)
+            await asyncio.sleep(min(TRACE_LEN, 0.5 * seconds))
+            self.nodes.signal_worker(signal.SIGUSR2)
+
+        async def window_start() -> None:
+            self.window_unix0 = time.time()
+            self.setup_s = time.monotonic() - T_PROCESS_START
+            scr["worker"]["start"] = await self.ascrape("metrics")
+            scr["gateway"]["start"] = await self.ascrape("gateway")
+            if self.traced:
+                tasks["gauges"] = asyncio.create_task(sample_gauges())
+                if not self.profile_started:   # one trace to a worker
+                    self.profile_started = True
+                    tasks["profile"] = asyncio.create_task(profile())
+
+        async def window_end() -> None:
+            if "gauges" in tasks:
+                tasks["gauges"].cancel()
+            scr["worker"]["end"] = await self.ascrape("metrics")
+            scr["gateway"]["end"] = await self.ascrape("gateway")
+
+        await gen.run(plan, after_ladder, window_start, window_end)
+        if "profile" in tasks:
+            await tasks["profile"]
+        self.nodes.assert_alive()
+        run.scrapes = scr
+        lo, hi = self.window_unix0, self.window_unix0 + seconds
+        for node, port in (("worker", "metrics"), ("gateway", "gateway")):
+            snap = json.loads(await self.ascrape(
+                port, f"/debug/trace?limit={TRACE_BUFFER}"))
+            run.traces[node] = [t for t in snap.get("traces", [])
+                                if lo <= t.get("started_at", 0) < hi]
+        if prefix0 is not None:
+            end = await fresh_prefix_count(wlog)
+            if end is not None:
+                run.prefix = {
+                    "tokens_reused": end - prefix0,
+                    "prompt_tokens": sum(r.prompt_len for r in gen.records
+                                         if r.actor >= 0)}
+        scr["worker"]["final"] = await self.ascrape("metrics")
+        return run
+
+    # ---- after the window ------------------------------------------------
+
+    def reference_check(self, run: reducers.RunData) -> tuple[bool, dict]:
+        """The plain reference on the chip, in a process of its own after
+        the worker has exited.  False also when a sampled request did not
+        end with a ``done`` frame."""
+        sample = [r for r in run.records if r.check]
+        problems = [f"checked request actor {r.actor} turn {r.turn}: "
+                    f"status {r.status} {r.error or 'no done frame'}"
+                    for r in sample if not r.ok]
+        sample = [r for r in sample if r.ok and r.reply_ids]
+        if len(sample) < run.checked:
+            problems.append(f"only {len(sample)} of the {run.checked} "
+                            f"requests chosen for the check completed")
+        job = {"config": self.config, "model_dir": str(self.model_dir),
+               "rehearse": self.args.rehearse,
+               "samples": [{"prompt_ids": r.prompt_ids,
+                            "reply_ids": r.reply_ids} for r in sample]}
+        (self.out / "check_input.json").write_text(json.dumps(job))
+        env = launcher.child_env(
+            {"JAX_PLATFORMS": "cpu"} if self.args.rehearse else None)
+        log = self.out / "check.log"
+        with log.open("w") as f:
+            rc = subprocess.run(
+                [sys.executable, "-m", "harness.reference.check",
+                 "--input", str(self.out / "check_input.json"),
+                 "--output", str(self.out / "check_output.json")],
+                cwd=CHIP_DIR, env=env, stdout=f, stderr=subprocess.STDOUT,
+                timeout=900).returncode
+        if rc != 0:
+            raise BenchFailure(f"reference check exited {rc}:\n"
+                               + launcher.tail(log))
+        res = load_json(self.out / "check_output.json")
+        tol = load_json(CHIP_DIR / "harness" / "reference" / "tolerance.json"
+                        )[self.config["bench"]["reference"]]
+        ok = (not problems and res["max_deficit"] <= tol["max_deficit"]
+              and res["mean_deficit"] <= tol["mean_deficit"])
+        say("info: reference check: " + json.dumps(
+            {**{k: res[k] for k in (
+                "tokens", "max_deficit", "mean_deficit", "argmax_agree_share",
+                "weights_s", "forward_s")},
+             "limits": {k: tol[k] for k in ("max_deficit", "mean_deficit")},
+             "problems": problems}))
+        return ok, res
+
+    def wait_profile(self) -> None:
+        """Until the worker's tracer thread has written the trace."""
+        pdir = self.out / "profile"
+        deadline = time.monotonic() + 120
+        while not (pdir / "done.json").exists():
+            if time.monotonic() > deadline:
+                raise BenchFailure("the worker wrote no device trace")
+            self.nodes.assert_alive()
+            time.sleep(0.5)
+
+    def run_trace_reduce(self) -> dict | None:
+        """The device trace's reduction, in a child on the CPU backend."""
+        pdir = self.out / "profile"
+        outp = self.out / "profile_reduced.json"
+        with (self.out / "trace_reduce.log").open("w") as f:
+            rc = subprocess.run(
+                [sys.executable, "-m", "harness.trace_reduce", str(pdir),
+                 "--json", str(outp)], cwd=CHIP_DIR, stdout=f,
+                stderr=subprocess.STDOUT, timeout=600,
+                env=launcher.child_env({"JAX_PLATFORMS": "cpu"})).returncode
+        if rc != 0:
+            if self.args.rehearse:
+                say("info: rehearsal: the CPU trace has no device plane; "
+                    "no device metric is printed")
+                shutil.rmtree(pdir / "plugins", ignore_errors=True)
+                return None
+            raise BenchFailure("trace reduction failed:\n" + launcher.tail(
+                self.out / "trace_reduce.log"))
+        if not os.environ.get("BENCH_KEEP_TRACE"):
+            shutil.rmtree(pdir / "plugins", ignore_errors=True)
+        return load_json(outp)
+
+
+def result_line(run: reducers.RunData, names: list[dict], group: str,
+                setup_s: float) -> dict:
+    kind = "e2e_metrics" if group == "end_to_end" else "layer_metrics"
+    out = {}
+    for m in names:
+        if m["name"] == "setup_s":      # the harness's own clock, no reader
+            out["setup_s"] = {"value": setup_s, "unit": "s"}
+            continue
+        try:
+            v = reducers.compute(kind, m["name"], run)
+        except metrics.TooFewSamples as e:
+            if group == "end_to_end":
+                raise
+            say(f"info: {m['name']} left out: {e}")
+            continue
+        if v is not None:
+            out[m["name"]] = {"value": v, "unit": m["unit"]}
+    return out
+
+
+def write_rows(out: Path, run: reducers.RunData) -> None:
+    with (out / "requests.jsonl").open("w") as f:
+        for r in run.records:
+            f.write(json.dumps(r.to_json(with_ids=r.check)) + "\n")
+    for node, sc in run.scrapes.items():
+        for when, text in sc.items():
+            (out / f"{node}_metrics_{when}.txt").write_text(text)
+    (out / "traces.json").write_text(json.dumps(run.traces))
+
+
+def info_lines(run: reducers.RunData) -> None:
+    recs, w = run.records, run.seconds
+    win = metrics.window_records(recs, w)
+    tt = [t for t in metrics.ttfts(recs, w) if t != float("inf")]
+    late = metrics.lateness(recs, w)
+    say(f"info: requests: {len(recs)} sent in all, {len(win)} due in the "
+        f"window, {sum(1 for r in win if r.ok)} of those completed; "
+        f"frames/token {metrics.frames_per_token(recs):.4f}; tokens in "
+        f"window {metrics.tokens_in_window(recs, w)}; gaps "
+        f"{len(metrics.gaps(recs, w))}")
+    if tt:
+        tt.sort()
+        say(f"info: ttft ms min/median/max {tt[0] * 1e3:.1f}/"
+            f"{tt[len(tt) // 2] * 1e3:.1f}/{tt[-1] * 1e3:.1f} over {len(tt)}")
+    if late:
+        late.sort()
+        hist = [sum(1 for x in late if lo <= x * 1e3 < hi) for lo, hi in
+                ((-1e9, 1), (1, 5), (5, 20), (20, 100), (100, 1e9))]
+        say(f"info: generator lateness ms histogram [<1, 1-5, 5-20, 20-100, "
+            f">=100]: {hist}; max {late[-1] * 1e3:.2f}")
+    duty = [ln for ln in run.scrapes["worker"].get("end", "").splitlines()
+            if ln.startswith("crowdllama_engine_duty_cycle") and
+            not ln.endswith(" 0")]
+    if duty:
+        say("info: worker's own host-clock duty cycle (NOT a device metric; "
+            "beside the trace's busy share for ROADMAP S6): "
+            + "; ".join(duty))
+
+
+async def sweep(r: Run, rates: list[float], seconds: float) -> None:
+    """One set-up, one timeline per rate, lowest first; stops at the first
+    rate at which a request fails (past its capacity the worker starts to
+    refuse requests, and what it holds afterwards is no longer a clean
+    state to measure the next rate on)."""
+    say("sweep: rate_per_s offered completed_share drain_s failed ttft_p50_ms "
+        "ttft_p80_ms ttft_p90_ms itl_p95_ms queue_wait_ms_first_half "
+        "queue_wait_ms_second_half tokens_per_s")
+    for rate in sorted(rates):
+        run = await r.measure({**r.traffic, "rate_per_s": rate}, seconds)
+        (r.out / f"rate{rate}").mkdir(exist_ok=True)
+        write_rows(r.out / f"rate{rate}", run)
+        win = metrics.window_records(run.records, seconds)
+        done = sum(1 for x in win if x.ok)
+        drain = max((x.done_t for x in win if x.ok), default=0.0) - seconds
+        failed = sum(1 for x in run.records if not x.ok)
+        tt = metrics.ttfts(run.records, seconds)
+        halves = [[], []]
+        for t in run.traces["worker"]:
+            q = sum(sp["dur_us"] for sp in t["spans"]
+                    if sp["name"] == "worker_queue") / 1e3
+            halves[t["started_at"] - r.window_unix0 >= seconds / 2].append(q)
+        mean = lambda xs: sum(xs) / len(xs) if xs else float("nan")
+        say(f"sweep: {rate} {len(win)} {done / max(1, len(win)):.3f} {drain:.1f} {failed} "
+            f"{1e3 * metrics.percentile(tt, 0.5, 0):.1f} "
+            f"{1e3 * metrics.percentile(tt, 0.8, 0):.1f} "
+            f"{1e3 * metrics.percentile(tt, 0.9, 0):.1f} "
+            f"{1e3 * metrics.percentile(metrics.gaps(run.records, seconds), 0.95, 0):.1f} "
+            f"{mean(halves[0]):.1f} {mean(halves[1]):.1f} "
+            f"{metrics.tokens_in_window(run.records, seconds) / seconds:.1f}")
+        if failed:
+            say(f"sweep: stopped: {failed} request(s) failed at {rate}/s")
+            break
+        await asyncio.sleep(2.0)
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--seconds", type=float, default=None)
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--sweep", default="")
+    ap.add_argument("--worker-flag", action="append",
+                    help="extra worker CLI flag (experiments only)")
+    args = ap.parse_args()
+    # a terminated run still stops its children (the finally below)
+    signal.signal(signal.SIGTERM, lambda *_: sys.exit(143))
+    try:
+        bench, cell, config, traffic = load_cell(args.workload, args.rehearse)
+        seconds = args.seconds or float(bench["run_seconds"])
+        r = Run(args, cell, config, traffic)
+        try:
+            r.start_system()
+            if args.sweep:
+                asyncio.run(sweep(r, [float(x) for x in
+                                      args.sweep.split(",")], seconds))
+                return 0
+            run = asyncio.run(r.measure(traffic, seconds))
+            if r.traced:
+                r.wait_profile()
+        finally:
+            r.nodes.stop()
+        write_rows(r.out, run)
+        info_lines(run)
+        one = metrics.one_frame_streams(run.records)
+        if one:
+            raise BenchFailure(
+                f"{len(one)} streams of more than one token arrived as ONE "
+                f"frame: the client cannot time their tokens")
+        n_dev, peak = device_of(run.scrapes["worker"]["final"])
+        if r.traced:
+            run.profile = r.run_trace_reduce()
+            if run.profile:
+                say(f"info: trace: device busy {run.profile['busy_s']:.4f}s of "
+                    f"{run.profile['window_s']:.4f}s traced")
+        correct, check = r.reference_check(run)
+        run.device_kind = r.device["kind"]
+        group = "per_layer" if r.traced else "end_to_end"
+        window = metrics.window_records(run.records, seconds)
+        device = dict(r.device, memory_peak_bytes=peak)
+        if check["device"] != r.device or (
+                not args.rehearse and device["count"] != n_dev):
+            raise BenchFailure(
+                f"devices disagree: worker {r.device} (memory on {n_dev}), "
+                f"reference check {check['device']}")
+        line = {
+            "correct": bool(correct),
+            "attempted": len(window),
+            "failed": sum(1 for x in window if not x.ok),
+            "metrics": result_line(run, cell_metrics(bench, group,
+                                                     cell["name"]),
+                                   group, r.setup_s),
+            "device": device,
+        }
+        if run.profile:
+            device["busy_s"] = run.profile["busy_s"]
+            device["window_s"] = run.profile["window_s"]
+            line["breakdown"] = run.profile["breakdown"]
+        (r.out / "result.json").write_text(json.dumps(line, indent=1))
+        print(json.dumps(line), flush=True)
+        return 0
+    except (BenchFailure, metrics.TooFewSamples) as e:
+        print(f"benchmark failed: {e}", file=sys.stderr, flush=True)
+        return 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())
