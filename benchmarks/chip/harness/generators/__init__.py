@@ -20,7 +20,10 @@ def load(kind: str):
 
 
 def build_plan(traffic: dict, ctx: dict) -> Plan:
-    """``ctx``: seed, seconds, slots, vocab_size, context (tokens)."""
+    """``ctx``: seed, seconds, slots, vocab_size, context (tokens) and,
+    where the run traces a tail after the window, tail_s.  Closed loops
+    need nothing for a tail (their actors go on); an open loop appends a
+    segment of its own, so the window's turns stay what they are."""
     return load(traffic["generator"]).plan(traffic, ctx)
 
 

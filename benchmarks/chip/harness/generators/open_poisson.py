@@ -16,6 +16,10 @@ prompt takes the 8-step ragged programs through their window widths), then
 takes the 1-step ragged programs through theirs — then ``ramp_s`` of the
 same arrival process before the window, so that the window opens on a
 system in its steady state with every program compiled.
+
+Where the run traces a tail after the window (``ctx["tail_s"]``), the tail
+is a segment of its own, made the way the ramp is: the window's turns are
+the same with and without it.
 """
 
 from __future__ import annotations
@@ -77,6 +81,9 @@ def plan(p: dict, ctx: dict) -> Plan:
                                                len(others)))]:
         window[i].greedy = window[i].check = True
     warm = _turns(p, ctx, max(1, round(rate * ramp)), ramp, -ramp, 3, "ramp")
+    tail_s = ctx.get("tail_s", 0.0)
+    tail = (_turns(p, ctx, max(1, round(rate * tail_s)), tail_s, seconds, 5,
+                   "tail") if tail_s else [])
     lrng = rng_for(ctx["seed"], 4)
 
     def rung(n: int) -> Turn:
@@ -85,5 +92,6 @@ def plan(p: dict, ctx: dict) -> Plan:
 
     ladder = [rung(n) for n in p["ladder_prompt_tokens"]]
     ladder += [[rung(n) for n in burst] for burst in p["ladder_bursts"]]
-    return Plan(ladder=ladder, actors=[_One(t) for t in warm + window],
+    return Plan(ladder=ladder,
+                actors=[_One(t) for t in warm + window + tail],
                 ramp_s=ramp, checked=sum(t.check for t in window))

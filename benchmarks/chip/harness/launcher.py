@@ -66,17 +66,23 @@ def tail(path: Path, n: int = 40) -> str:
         return f"<{path}: {e}>"
 
 
-def http_get(port: int, path: str, timeout: float = 10.0) -> str:
+def http_request(method: str, port: int, path: str,
+                 timeout: float = 10.0) -> str:
     conn = http.client.HTTPConnection("127.0.0.1", port, timeout=timeout)
     try:
-        conn.request("GET", path)
+        conn.request(method, path)
         resp = conn.getresponse()
         body = resp.read().decode()
         if resp.status != 200:
-            raise BenchFailure(f"GET :{port}{path} -> {resp.status}: {body}")
+            raise BenchFailure(
+                f"{method} :{port}{path} -> {resp.status}: {body}")
         return body
     finally:
         conn.close()
+
+
+def http_get(port: int, path: str, timeout: float = 10.0) -> str:
+    return http_request("GET", port, path, timeout)
 
 
 def write_model_dir(config: dict) -> Path:
@@ -112,9 +118,11 @@ class Nodes:
         self.procs.append((name, proc, log))
         return proc
 
-    def start(self, config: dict, model_dir: Path, *, traced: bool,
-              trace_buffer: int, extra_worker_flags: list[str],
-              require_chips: int) -> None:
+    def start(self, config: dict, model_dir: Path, *,
+              extra_worker_flags: list[str], require_chips: int) -> None:
+        """The same flags and environment whatever the run traces: the
+        worker's profiler is started and stopped over its own HTTP control
+        (``--profile-dir``), and the span rings' default holds a window."""
         b = config["bench"]
         self.ports = {k: free_port() for k in (
             "dht", "worker", "metrics", "gateway_p2p", "gateway")}
@@ -128,31 +136,26 @@ class Nodes:
             "--key-path", str(keys / "dht.key")], child_env())
         flags = [*b["worker_flags"], *extra_worker_flags]
         wenv = dict(b.get("worker_env") or {})
-        extra = ["--trace-buffer", str(trace_buffer)] if trace_buffer else []
-        # Always through the benchmark's worker_main, which calls the CLI's
-        # main unchanged: the traced and the untraced run start the same way.
-        wenv["BENCH_PROFILE_DIR"] = str(self.out / "profile") if traced else ""
+        # Through the benchmark's worker_main, which checks the devices and
+        # then calls the CLI's main unchanged.
         wenv["BENCH_DEVICE_FILE"] = str(self.out / "device.json")
         wenv["BENCH_REQUIRE_TPU_CHIPS"] = str(require_chips)
         self._start("worker", [
             str(CHIP_DIR / "harness" / "worker_main.py"),
             "start", "--worker-mode", "--model", b["name"],
-            "--model-path", str(model_dir), *flags, *extra,
+            "--model-path", str(model_dir), *flags,
+            "--profile-dir", str(self.out / "profile"),
             "--bootstrap-peers", boot,
             "--listen-port", str(self.ports["worker"]),
             "--worker-metrics-port", str(self.ports["metrics"]),
             "--key-path", str(keys / "worker.key")], child_env(wenv))
         self._start("gateway", [
-            "-m", "crowdllama_tpu.cli.main", "start", *extra,
+            "-m", "crowdllama_tpu.cli.main", "start",
             "--bootstrap-peers", boot,
             "--listen-port", str(self.ports["gateway_p2p"]),
             "--gateway-port", str(self.ports["gateway"]),
             "--key-path", str(keys / "gateway.key")],
             child_env({"JAX_PLATFORMS": "cpu"}))
-
-    @property
-    def worker(self) -> subprocess.Popen:
-        return next(p for n, p, _ in self.procs if n == "worker")
 
     def assert_alive(self) -> None:
         for name, proc, log in self.procs:
@@ -177,9 +180,6 @@ class Nodes:
             except (OSError, ValueError, BenchFailure):
                 pass
             time.sleep(0.5)
-
-    def signal_worker(self, sig: int) -> None:
-        self.worker.send_signal(sig)
 
     def stop(self) -> None:
         for _, proc, _ in reversed(self.procs):

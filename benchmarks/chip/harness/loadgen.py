@@ -11,7 +11,10 @@ timed from when it was DUE; a turn without is sent ``think`` seconds after
 the actor's previous turn completed (closed loop).  Turns sent before zero
 are the warm-up replay (the ramp): they bring the system to its steady state
 and touch its shapes, and only their tokens that arrive inside the window
-count.  No turn starts after the window's end; turns in flight are drained.
+count.  No turn starts after the window's end — or, where the run traces a
+``tail`` of the same traffic after it, after the tail's end; turns in
+flight are drained.  Every end-to-end reader cuts at the window, so a turn
+that starts in the tail counts nowhere.
 """
 
 from __future__ import annotations
@@ -96,12 +99,13 @@ class Record:
 
 class LoadGen:
     def __init__(self, port: int, model: str, sampling: dict, seed: int,
-                 seconds: float) -> None:
+                 seconds: float, tail: float = 0.0) -> None:
         self.url = f"http://127.0.0.1:{port}/api/generate"
         self.model = model
         self.sampling = sampling
         self.seed = seed
         self.seconds = seconds
+        self.tail = tail
         self.records: list[Record] = []
         self.t0 = 0.0            # monotonic clock at the window's start
         self._session: aiohttp.ClientSession | None = None
@@ -178,8 +182,8 @@ class LoadGen:
                 wait = turn.think
             if wait > 0:
                 await asyncio.sleep(wait)
-            if self.now() >= self.seconds:
-                return           # nothing starts after the window's end
+            if self.now() >= self.seconds + self.tail:
+                return           # nothing starts after the window (+ tail)
             rec = await self._run_turn(i, turn_i, turn)
             if not rec.ok:
                 return           # counted as failed; the actor stops

@@ -2,7 +2,7 @@
 """The chip benchmark's command.
 
     python3 benchmarks/chip/run.py --workload <cell> --seed <n> \
-        --seconds <s> --trace <0|1>
+        --seconds <s> --trace <0|1|2>
 
 One run of one cell of BENCHMARK.json: starts DHT node, worker and gateway
 as child processes, warms up, drives the cell's traffic at the gateway's
@@ -10,7 +10,10 @@ as child processes, warms up, drives the cell's traffic at the gateway's
 against the plain reference on the chip, and prints the contract's one JSON
 object as the last line of standard output (``--trace 0``: the cell's
 end-to-end metrics; ``--trace 1``: its per-layer metrics, from spans,
-counters and a device trace of a few seconds of the window).
+counters and a device trace of a few seconds of the window; ``--trace 2``:
+both — a ``--trace 0`` run up to the moment the window closes, then a few
+traced seconds of the same traffic, one last line with the end-to-end
+metrics of the window and the per-layer metrics side by side).
 
     --rehearse      the same flow at tiny size on the CPU (Pallas in
                     interpret mode); prints "device" as the CPU and never a
@@ -31,7 +34,6 @@ import time
 T_PROCESS_START = time.monotonic()
 
 import argparse  # noqa: E402
-import ast  # noqa: E402
 import asyncio  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
@@ -50,8 +52,11 @@ from harness.loadgen import LoadGen  # noqa: E402
 
 ROOT = launcher.ROOT
 REHEARSAL_CONFIG = "rehearsal-tiny-mistral"
-TRACE_AT, TRACE_LEN = 0.4, 3.0     # traced run: where in the window, how long
-TRACE_BUFFER = 8192                # span ring of a traced run, both nodes
+TRACE_AT, TRACE_LEN = 0.4, 3.0     # --trace 1: where in the window, how long
+# --trace 2 traces after the window: a second for the window's last requests
+# to get their first token undisturbed, the profiler's calls (a first start
+# and stop whose trace is thrown away, then the real ones), the trace.
+TAIL_GRACE, TAIL_PROFILER = 1.0, 8.0
 
 
 def say(msg: str) -> None:
@@ -87,36 +92,6 @@ def cell_metrics(bench: dict, group: str, cell: str) -> list[dict]:
 # ---------------------------------------------------------------- worker io
 
 
-def engine_stats_after(log: Path, offset: int) -> dict | None:
-    """The newest ``engine: {...}`` stats line the worker wrote after byte
-    ``offset`` of its log (it logs one every 10 s)."""
-    with log.open("rb") as f:
-        f.seek(offset)
-        text = f.read().decode(errors="replace")
-    for line in reversed(text.splitlines()):
-        if "| engine: {" in line:
-            try:
-                return ast.literal_eval(line.split("| engine: ", 1)[1])
-            except (ValueError, SyntaxError):
-                return None
-    return None
-
-
-async def fresh_prefix_count(log: Path, timeout: float = 12.0) -> int | None:
-    """``prefix_cache.tokens_reused`` from a stats line written from now
-    on, summed over the worker's engines."""
-    offset = log.stat().st_size
-    deadline = time.monotonic() + timeout
-    while time.monotonic() < deadline:
-        stats = engine_stats_after(log, offset)
-        if stats is not None:
-            engines = stats.get("engines") or {"": stats}
-            return sum((e.get("prefix_cache") or {}).get("tokens_reused", 0)
-                       for e in engines.values())
-        await asyncio.sleep(0.25)
-    return None
-
-
 def device_of(worker_metrics: str) -> tuple[int, int]:
     """(devices with memory the worker reports, peak bytes on the fullest):
     a worker that is not on an accelerator reports limit 0."""
@@ -135,6 +110,8 @@ class Run:
         self.args, self.cell = args, cell
         self.config, self.traffic = config, traffic
         self.traced = bool(args.trace)
+        self.profile: dict | None = None    # the worker's answer to "stop"
+        self.traced_at: tuple[float, float] | None = None   # --trace 2
         self.out = launcher.RUN_DIR / "out" / cell["name"] / (
             f"s{args.seed}-t{args.trace}" + ("-rehearse" if args.rehearse
                                              else ""))
@@ -142,19 +119,17 @@ class Run:
         self.out.mkdir(parents=True)
         self.nodes = launcher.Nodes(self.out)
         self.window_unix0 = 0.0
-        self.profile_started = False
 
-    def plan_ctx(self, seconds: float) -> dict:
+    def plan_ctx(self, seconds: float, tail: float) -> dict:
         b = self.config["bench"]
-        return {"seed": self.args.seed, "seconds": seconds,
+        return {"seed": self.args.seed, "seconds": seconds, "tail_s": tail,
                 "slots": b["slots"], "context": b["context"],
                 "vocab_size": self.config["vocab_size"]}
 
     def start_system(self) -> None:
         model_dir = launcher.write_model_dir(self.config)
         self.model_dir = model_dir
-        self.nodes.start(self.config, model_dir, traced=self.traced,
-                         trace_buffer=TRACE_BUFFER if self.traced else 0,
+        self.nodes.start(self.config, model_dir,
                          extra_worker_flags=self.args.worker_flag or [],
                          require_chips=0 if self.args.rehearse
                          else self.cell["chips"])
@@ -186,73 +161,103 @@ class Run:
         return await asyncio.get_running_loop().run_in_executor(
             None, self.scrape, node, path)
 
+    async def profile_for(self, length: float) -> dict:
+        """Trace the worker's chip for ``length`` seconds through its own
+        control; the answer to "stop" (artifact directory, host clocks)."""
+        def post(action: str) -> dict:
+            return json.loads(launcher.http_request(
+                "POST", self.nodes.ports["metrics"],
+                f"/debug/profile/{action}", timeout=120))
+
+        loop = asyncio.get_running_loop()
+        await loop.run_in_executor(None, post, "start")
+        await asyncio.sleep(length)
+        return await loop.run_in_executor(None, post, "stop")
+
     async def measure(self, traffic: dict, seconds: float) -> reducers.RunData:
-        """One timeline (ladder, ramp, window, drain) against the running
-        system; everything a metric reader may want, gathered."""
+        """One timeline (ladder, ramp, window, [traced tail,] drain) against
+        the running system; everything a metric reader may want, gathered.
+        Up to the moment the window closes every mode does the same."""
         b = self.config["bench"]
-        plan = generators.build_plan(traffic, self.plan_ctx(seconds))
+        mode = self.args.trace
+        trace_len = min(TRACE_LEN, 0.5 * seconds)
+        tail = TAIL_GRACE + TAIL_PROFILER + trace_len if mode == 2 else 0.0
+        plan = generators.build_plan(traffic, self.plan_ctx(seconds, tail))
         gen = LoadGen(self.nodes.ports["gateway"], b["name"], b["sampling"],
-                      self.args.seed, seconds)
+                      self.args.seed, seconds, tail)
         run = reducers.RunData(
             records=gen.records, seconds=seconds, config=self.config,
             min_beyond=0 if self.args.rehearse else 10,
             rehearse=self.args.rehearse, checked=plan.checked)
         scr = {"worker": {}, "gateway": {}}
         tasks: dict[str, asyncio.Task] = {}
-        prefix0: int | None = None
-        wlog = self.out / "worker.log"
-
-        async def after_ladder() -> None:
-            nonlocal prefix0
-            if self.traced:
-                prefix0 = await fresh_prefix_count(wlog)
 
         async def sample_gauges() -> None:
             while True:
                 run.gauge_samples.append(await self.ascrape("metrics"))
                 await asyncio.sleep(0.5)
 
-        async def profile() -> None:
+        async def trace_in_window() -> None:
             await asyncio.sleep(TRACE_AT * seconds)
-            self.nodes.signal_worker(signal.SIGUSR1)
-            await asyncio.sleep(min(TRACE_LEN, 0.5 * seconds))
-            self.nodes.signal_worker(signal.SIGUSR2)
+            self.profile = await self.profile_for(trace_len)
+
+        async def trace_tail() -> None:
+            await asyncio.sleep(TAIL_GRACE)
+            # the first start of the profiler costs more than the later
+            # ones: made once and thrown away, it falls into no number
+            first = await self.profile_for(0.0)
+            shutil.rmtree(first["artifact"], ignore_errors=True)
+            sampler = asyncio.create_task(sample_gauges())
+            try:
+                self.profile = await self.profile_for(trace_len)
+            finally:
+                sampler.cancel()
+            # the worker's monotonic clock is this machine's, as gen.t0 is
+            self.traced_at = (self.profile["started_monotonic"] - gen.t0,
+                              self.profile["stopped_monotonic"] - gen.t0)
+            if self.traced_at[1] > seconds + tail:
+                raise BenchFailure(
+                    f"the traced window ended {self.traced_at[1] - seconds:.1f}"
+                    f"s after the measured one, past the {tail:.1f}s of "
+                    f"traffic that follow it")
 
         async def window_start() -> None:
             self.window_unix0 = time.time()
             self.setup_s = time.monotonic() - T_PROCESS_START
             scr["worker"]["start"] = await self.ascrape("metrics")
             scr["gateway"]["start"] = await self.ascrape("gateway")
-            if self.traced:
+            if mode == 1 and self.profile is None:   # one trace to a worker
                 tasks["gauges"] = asyncio.create_task(sample_gauges())
-                if not self.profile_started:   # one trace to a worker
-                    self.profile_started = True
-                    tasks["profile"] = asyncio.create_task(profile())
+                tasks["profile"] = asyncio.create_task(trace_in_window())
 
         async def window_end() -> None:
             if "gauges" in tasks:
                 tasks["gauges"].cancel()
             scr["worker"]["end"] = await self.ascrape("metrics")
             scr["gateway"]["end"] = await self.ascrape("gateway")
+            if mode == 2:
+                await trace_tail()
 
-        await gen.run(plan, after_ladder, window_start, window_end)
+        await gen.run(plan, None, window_start, window_end)
         if "profile" in tasks:
             await tasks["profile"]
         self.nodes.assert_alive()
         run.scrapes = scr
         lo, hi = self.window_unix0, self.window_unix0 + seconds
         for node, port in (("worker", "metrics"), ("gateway", "gateway")):
-            snap = json.loads(await self.ascrape(
-                port, f"/debug/trace?limit={TRACE_BUFFER}"))
+            snap = json.loads(await self.ascrape(port, "/debug/trace"))
             run.traces[node] = [t for t in snap.get("traces", [])
                                 if lo <= t.get("started_at", 0) < hi]
-        if prefix0 is not None:
-            end = await fresh_prefix_count(wlog)
-            if end is not None:
-                run.prefix = {
-                    "tokens_reused": end - prefix0,
-                    "prompt_tokens": sum(r.prompt_len for r in gen.records
-                                         if r.actor >= 0)}
+        def grown(family: str) -> float | None:
+            end = reducers.samples(scr["worker"]["end"], family)
+            start = reducers.samples(scr["worker"]["start"], family)
+            return sum(end) - sum(start) if end else None
+
+        prompt_tokens = grown("crowdllama_prompt_tokens_total")
+        if prompt_tokens is not None:
+            run.prefix = {
+                "tokens_reused": grown("crowdllama_prefix_tokens_reused_total"),
+                "prompt_tokens": prompt_tokens}
         scr["worker"]["final"] = await self.ascrape("metrics")
         return run
 
@@ -301,19 +306,10 @@ class Run:
              "problems": problems}))
         return ok, res
 
-    def wait_profile(self) -> None:
-        """Until the worker's tracer thread has written the trace."""
-        pdir = self.out / "profile"
-        deadline = time.monotonic() + 120
-        while not (pdir / "done.json").exists():
-            if time.monotonic() > deadline:
-                raise BenchFailure("the worker wrote no device trace")
-            self.nodes.assert_alive()
-            time.sleep(0.5)
-
     def run_trace_reduce(self) -> dict | None:
-        """The device trace's reduction, in a child on the CPU backend."""
-        pdir = self.out / "profile"
+        """The device trace's reduction, in a child on the CPU backend;
+        the trace itself is deleted once reduced."""
+        pdir = Path(self.profile["artifact"])
         outp = self.out / "profile_reduced.json"
         with (self.out / "trace_reduce.log").open("w") as f:
             rc = subprocess.run(
@@ -325,12 +321,12 @@ class Run:
             if self.args.rehearse:
                 say("info: rehearsal: the CPU trace has no device plane; "
                     "no device metric is printed")
-                shutil.rmtree(pdir / "plugins", ignore_errors=True)
+                shutil.rmtree(pdir, ignore_errors=True)
                 return None
             raise BenchFailure("trace reduction failed:\n" + launcher.tail(
                 self.out / "trace_reduce.log"))
         if not os.environ.get("BENCH_KEEP_TRACE"):
-            shutil.rmtree(pdir / "plugins", ignore_errors=True)
+            shutil.rmtree(pdir, ignore_errors=True)
         return load_json(outp)
 
 
@@ -393,6 +389,28 @@ def info_lines(run: reducers.RunData) -> None:
             + "; ".join(duty))
 
 
+def profiler_cost_line(run: reducers.RunData, traced_at: tuple) -> None:
+    """What the profiler costs while it is on: the gap tail and the tokens
+    per second of the traced seconds after the window, beside the
+    window's own (printed, never a metric)."""
+    a, b = traced_at
+    gaps, tokens = [], 0
+    for r in run.records:
+        ts = metrics.token_times(r)
+        gaps.extend(y - x for x, y in zip(ts, ts[1:]) if a <= y < b)
+        tokens += sum(1 for t in ts if a <= t < b)
+    w = run.seconds
+    if gaps:
+        say(f"info: profiler on for {b - a:.2f}s from {a - w:.2f}s after the "
+            f"window: itl_p95_ms "
+            f"{1e3 * metrics.percentile(gaps, 0.95, 0):.1f} over "
+            f"{len(gaps)} gaps, out_tokens_per_s {tokens / (b - a):.1f}; "
+            f"the window's: itl_p95_ms "
+            f"{1e3 * metrics.percentile(metrics.gaps(run.records, w), 0.95, 0):.1f}"
+            f", out_tokens_per_s "
+            f"{metrics.tokens_in_window(run.records, w) / w:.1f}")
+
+
 async def sweep(r: Run, rates: list[float], seconds: float) -> None:
     """One set-up, one timeline per rate, lowest first; stops at the first
     rate at which a request fails (past its capacity the worker starts to
@@ -434,7 +452,7 @@ def main() -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--seconds", type=float, default=None)
-    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--trace", type=int, choices=(0, 1, 2), default=0)
     ap.add_argument("--rehearse", action="store_true")
     ap.add_argument("--sweep", default="")
     ap.add_argument("--worker-flag", action="append",
@@ -453,12 +471,12 @@ def main() -> int:
                                       args.sweep.split(",")], seconds))
                 return 0
             run = asyncio.run(r.measure(traffic, seconds))
-            if r.traced:
-                r.wait_profile()
         finally:
             r.nodes.stop()
         write_rows(r.out, run)
         info_lines(run)
+        if r.traced_at:
+            profiler_cost_line(run, r.traced_at)
         one = metrics.one_frame_streams(run.records)
         if one:
             raise BenchFailure(
@@ -472,7 +490,8 @@ def main() -> int:
                     f"{run.profile['window_s']:.4f}s traced")
         correct, check = r.reference_check(run)
         run.device_kind = r.device["kind"]
-        group = "per_layer" if r.traced else "end_to_end"
+        groups = {0: ["end_to_end"], 1: ["per_layer"],
+                  2: ["end_to_end", "per_layer"]}[args.trace]
         window = metrics.window_records(run.records, seconds)
         device = dict(r.device, memory_peak_bytes=peak)
         if check["device"] != r.device or (
@@ -484,9 +503,9 @@ def main() -> int:
             "correct": bool(correct),
             "attempted": len(window),
             "failed": sum(1 for x in window if not x.ok),
-            "metrics": result_line(run, cell_metrics(bench, group,
-                                                     cell["name"]),
-                                   group, r.setup_s),
+            "metrics": {k: v for group in groups for k, v in result_line(
+                run, cell_metrics(bench, group, cell["name"]), group,
+                r.setup_s).items()},
             "device": device,
         }
         if run.profile:

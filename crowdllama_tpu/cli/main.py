@@ -749,7 +749,6 @@ async def run_node(cfg: Configuration, worker_mode: bool) -> None:
                           slo_decode_ms=cfg.slo_decode_ms,
                           stream_stall_ms=cfg.stream_stall_ms,
                           hedge_ttft_ms=cfg.hedge_ttft_ms,
-                          profile_dir=cfg.profile_dir,
                           spec_pipeline=cfg.gateway_spec_pipeline,
                           spec_draft_path=cfg.spec_draft_path)
         if gossip is not None:

@@ -14,6 +14,8 @@ import argparse
 import os
 from dataclasses import dataclass, field
 
+from crowdllama_tpu.obs.trace import DEFAULT_TRACE_CAPACITY
+
 
 def is_test_mode() -> bool:
     return os.environ.get("CROWDLLAMA_TPU_TEST_MODE", "") == "1"
@@ -226,15 +228,16 @@ class Configuration:
     # saved on SIGTERM, rehydrated on start so a gateway bounce keeps its
     # affinity hit-rate.  Empty = no persistence.
     gossip_snapshot_path: str = ""
-    # Directory for jax.profiler traces; empty disables the profile surface
-    # (SURVEY §5: "TPU build: JAX profiler traces + per-request timing").
+    # Directory for jax.profiler traces of THIS node's engine (the worker:
+    # POST /debug/profile/start|stop on --worker-metrics-port, the IPC
+    # "profile" op); empty disables the profile surface.
     profile_dir: str = ""
 
     # Observability plane (obs/): per-node span ring-buffer capacity
     # (GET /debug/trace on gateway and worker) and the worker-side
     # /metrics + /debug/trace listener port (0 = disabled; workers have
     # no other HTTP surface).
-    trace_buffer: int = 64
+    trace_buffer: int = DEFAULT_TRACE_CAPACITY
     worker_metrics_port: int = 0
     # Flight recorder (obs/collector.py): how many stitched traces of
     # "interesting" requests (p99 tail, failovers, migrations, sheds,
@@ -689,10 +692,13 @@ class Configuration:
                                  "prompts use the legacy alternating "
                                  "chunked-prefill dispatch")
         parser.add_argument("--profile-dir", dest="profile_dir",
-                            help="enable jax.profiler captures into this dir")
+                            help="worker: enable jax.profiler captures "
+                                 "(POST /debug/profile/start|stop) into "
+                                 "this dir")
         parser.add_argument("--trace-buffer", dest="trace_buffer", type=int,
                             help="span ring-buffer capacity for "
-                                 "GET /debug/trace (default 64)")
+                                 f"GET /debug/trace (default "
+                                 f"{DEFAULT_TRACE_CAPACITY})")
         parser.add_argument("--worker-metrics-port",
                             dest="worker_metrics_port", type=int,
                             help="worker-side /metrics + /debug/trace "

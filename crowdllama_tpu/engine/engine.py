@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import asyncio
 import logging
+import os
 import time
 from dataclasses import dataclass
 from typing import AsyncIterator
@@ -29,6 +30,7 @@ from crowdllama_tpu.core.messages import (
     migrate_frame_msg,
     verify_result_msg,
 )
+from crowdllama_tpu.obs.metrics import ENGINE_TELEMETRY
 from crowdllama_tpu.testing import faults
 
 log = logging.getLogger("crowdllama.engine")
@@ -46,6 +48,14 @@ class Chunk:
     # and the Engine seam falls back to first-chunk timing.
     queue_ns: int = 0
     prefill_ns: int = 0
+    # With them, the stamps that make the spans a timeline: when the
+    # request was submitted to the scheduler (absolute monotonic ns; the
+    # queue and prefill intervals follow it back to back), and the split
+    # of prefill into the wait for the dispatch in flight and the
+    # request's own program(s).  Zero means "unknown".
+    submitted_ns: int = 0
+    dispatch_wait_ns: int = 0
+    prefill_exec_ns: int = 0
     # KV shipping (docs/KV_TRANSFER.md): wall time the engine spent fetching
     # donor pages before prefill — becomes a kv_fetch span on the worker's
     # trace surface.  Zero = no fetch attempted.
@@ -60,6 +70,14 @@ class Chunk:
     # tokens, optionally prompt_ids on the chunk_id=0 handshake).  A pure
     # verify chunk carries no text and no done flag.
     verify: dict | None = None
+
+
+class ProfileDisabled(RuntimeError):
+    """No ``profile_dir`` is configured: the node cannot be traced."""
+
+
+class ProfileBusy(RuntimeError):
+    """A profiler trace is already running (or none is, on stop)."""
 
 
 class StopMatcher:
@@ -234,9 +252,11 @@ class Engine:
         """
         if self.obs is None:
             return
-        queue_ns = getattr(final, "queue_ns", 0) if final else 0
-        prefill_ns = getattr(final, "prefill_ns", 0) if final else 0
-        kv_ns = getattr(final, "kv_fetch_ns", 0) if final else 0
+        def stamp(name: str) -> int:
+            return getattr(final, name, 0) if final else 0
+
+        queue_ns, prefill_ns = stamp("queue_ns"), stamp("prefill_ns")
+        kv_ns = stamp("kv_fetch_ns")
         if kv_ns:
             # The donor fetch ran before submit, so it is in neither the
             # queue nor the prefill stamp — give it its own span and keep
@@ -249,14 +269,25 @@ class Engine:
         if not prefill_ns:
             prefill_ns = max(0, (first_ns or end_ns) - t0 - queue_ns - kv_ns)
         decode_ns = max(0, (end_ns - t0) - queue_ns - prefill_ns - kv_ns)
-        steps = getattr(final, "completion_tokens", 0) if final else 0
+        steps = stamp("completion_tokens")
         if steps > 0 and decode_ns > 0:
             self.obs.metrics.decode_step_seconds.observe(
                 decode_ns / steps / 1e9)
         self.obs.observe_generate(
             getattr(msg, "trace_id", ""), getattr(msg, "parent_span", ""),
             model, queue_ns, prefill_ns, decode_ns, steps, end_ns - t0,
-            node="worker")
+            start_ns=stamp("submitted_ns") or t0 + kv_ns,
+            dispatch_wait_ns=stamp("dispatch_wait_ns"),
+            prefill_exec_ns=stamp("prefill_exec_ns"), node="worker")
+
+    def _obs_begin(self, msg: pb.BaseMessage, model: str) -> int:
+        """Open the worker's trace record as the request arrives, so its
+        spans' ``start_us`` are offsets from the arrival; returns the
+        arrival stamp (monotonic ns)."""
+        if self.obs is not None:
+            self.obs.trace.begin(getattr(msg, "trace_id", ""), model=model,
+                                 node="worker")
+        return time.monotonic_ns()
 
     async def handle(self, msg: pb.BaseMessage, worker_id: str = "") -> pb.BaseMessage:
         """Blocking BaseMessage → BaseMessage (reference semantics)."""
@@ -283,7 +314,7 @@ class Engine:
         req = extract_generate_request(msg)
         await faults.inject("engine.request", worker=worker_id,
                             model=req.model)
-        t0 = time.monotonic_ns()
+        t0 = self._obs_begin(msg, req.model)
         first_ns = 0
         text_parts: list[str] = []
         final: Chunk | None = None
@@ -331,7 +362,7 @@ class Engine:
         built straight from engine scalars with zero intermediate pb
         objects when the native encoder is loaded."""
         req = extract_generate_request(msg)
-        t0 = time.monotonic_ns()
+        t0 = self._obs_begin(msg, req.model)
         first_ns = 0
         n_chunk = 0
         final: Chunk | None = None
@@ -501,6 +532,12 @@ class JaxEngine(Engine):
         # config.autotune is set; gossip may be wired before OR after.
         self.autotuner = None
         self._gossip = None
+        # The profiler's single flight: the latch holds from the start of
+        # profile_start to the end of profile_stop; _profile is the running
+        # trace (profile_start's answer) while it can be stopped.
+        self._profile_latch = False
+        self._profile: dict | None = None
+        self._profile_seq = 0
 
     def attach_peer(self, peer) -> None:
         self._peer = peer
@@ -550,7 +587,12 @@ class JaxEngine(Engine):
             for note in plan.notes:
                 log.warning("%s", note)
 
-            params = load_params_for(self.config, cfg)
+            t_w = time.monotonic()
+            # Waited for, so that the gauge is the weights' time and not
+            # their dispatch's (the warm-up would wait for them anyway).
+            params = jax.block_until_ready(
+                load_params_for(self.config, cfg))
+            ENGINE_TELEMETRY.startup_set("weights", time.monotonic() - t_w)
             # ONE builder shared with run_follower: leader and followers
             # must construct bit-identical runners (engine/factory.py).
             runner = build_runner(self.config, plan, cfg, params)
@@ -569,7 +611,9 @@ class JaxEngine(Engine):
 
         self._runner = await loop.run_in_executor(None, _build)
         if self.config.warmup:
+            t_w = time.monotonic()
             await loop.run_in_executor(None, self._warmup)
+            ENGINE_TELEMETRY.startup_set("warmup", time.monotonic() - t_w)
         self.scheduler = Scheduler(
             self._runner,
             decode_chunk=self.config.decode_chunk,
@@ -597,6 +641,8 @@ class JaxEngine(Engine):
                 gossip=self._gossip)
             self.scheduler.attach_autotuner(self.autotuner)
         self.scheduler.start()
+        ENGINE_TELEMETRY.startup_set(
+            "ready", time.monotonic() - ENGINE_TELEMETRY.t_import)
         log.info(
             "engine up: model=%s mesh=%s slots=%d max_seq=%d",
             cfg.name, dict(self._runner.mesh.shape), self._runner.max_slots,
@@ -995,30 +1041,84 @@ class JaxEngine(Engine):
             d["autotune"] = self.autotuner.describe()
         return d
 
-    async def capture_profile(self, seconds: float = 3.0) -> str:
-        """Capture a jax.profiler trace of live serving activity.
+    # ---- the profiler control (obs/http.py, the IPC "profile" op) --------
 
-        Requires ``profile_dir`` in config (SURVEY §5's profiler hook).  The
-        trace window spans whatever the scheduler dispatches during it —
-        decode chunks, prefills — because the profiler session is global
-        across threads.  Returns the trace directory (TensorBoard-loadable).
-        """
+    async def profile_start(self) -> dict:
+        """Begin a ``jax.profiler`` trace of this process — the one that
+        holds the chip — into a fresh directory under ``profile_dir``.
+
+        The profiler session is global across threads, so the trace spans
+        whatever the scheduler dispatches until :meth:`profile_stop`.  The
+        Python tracer is off (it slows the host it is meant to observe and
+        makes the trace hundreds of MB); the runtime's own host events and
+        the scheduler's ``sched.*`` annotations are on.  Single-flight:
+        :class:`ProfileBusy` while a trace runs."""
         if not self.config.profile_dir:
-            raise RuntimeError("profiling disabled: set profile_dir "
-                               "(--profile-dir / CROWDLLAMA_TPU_PROFILE_DIR)")
-        seconds = min(max(float(seconds), 0.1), 60.0)
-        loop = asyncio.get_running_loop()
+            raise ProfileDisabled(
+                "profiling disabled: set profile_dir "
+                "(--profile-dir / CROWDLLAMA_TPU_PROFILE_DIR)")
+        if self._profile_latch:
+            raise ProfileBusy("a profiler trace is already running")
+        self._profile_latch = True
+        self._profile_seq += 1
+        path = os.path.join(
+            self.config.profile_dir,
+            f"profile-{int(time.time())}-{self._profile_seq}")
+        started = {"artifact": path}
 
-        def _trace() -> str:
-            import time as _time
-
+        def _start() -> None:
             import jax
 
-            with jax.profiler.trace(self.config.profile_dir):
-                _time.sleep(seconds)
-            return self.config.profile_dir
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            opts.host_tracer_level = 2
+            os.makedirs(path, exist_ok=True)
+            jax.profiler.start_trace(path, profiler_options=opts)
+            started["started_monotonic"] = time.monotonic()
+            started["started_unix"] = time.time()
 
-        return await loop.run_in_executor(None, _trace)
+        try:
+            await asyncio.get_running_loop().run_in_executor(None, _start)
+        except BaseException:
+            self._profile_latch = False
+            raise
+        self._profile = started
+        return dict(started)
+
+    async def profile_stop(self) -> dict:
+        """End the running trace; answers once the ``.xplane.pb`` is
+        written, with the artifact directory and the host's clock at start
+        and stop, monotonic and unix."""
+        if not self.config.profile_dir:
+            raise ProfileDisabled("profiling disabled: set profile_dir")
+        if self._profile is None:
+            raise ProfileBusy("no running profiler trace to stop")
+        done, self._profile = self._profile, None
+
+        def _stop() -> None:
+            import jax
+
+            done["stopped_monotonic"] = time.monotonic()
+            done["stopped_unix"] = time.time()
+            jax.profiler.stop_trace()
+            done["written_monotonic"] = time.monotonic()
+
+        try:
+            await asyncio.get_running_loop().run_in_executor(None, _stop)
+        finally:
+            self._profile_latch = False
+        return done
+
+    async def capture_profile(self, seconds: float = 3.0) -> str:
+        """A trace of a fixed window of live serving: start, sleep, stop.
+        Returns the trace directory (TensorBoard-loadable)."""
+        seconds = min(max(float(seconds), 0.1), 60.0)
+        await self.profile_start()
+        try:
+            await asyncio.sleep(seconds)
+        finally:
+            done = await self.profile_stop()
+        return done["artifact"]
 
     def _format_chat(self, messages: list[dict], model: str = "") -> str:
         """Prefer the checkpoint's own chat template (Llama-3 headers,
@@ -1094,15 +1194,22 @@ class JaxEngine(Engine):
         completion = 0
         finished = False
 
-        def _trace_split() -> tuple[int, int]:
-            # Scheduler stamps → the final chunk's queue/prefill split
-            # (obs plane): worker_queue = submit→admission, prefill =
-            # admission→first token.
+        def _trace_stamps() -> dict:
+            # Scheduler stamps → the final chunk's spans (obs plane):
+            # worker_queue = submit→admission, prefill = admission→first
+            # token, split at exec_start_at into dispatch_wait (the flight
+            # queued ahead) and prefill_exec (the request's own programs).
             base = req.admitted_at or req.submitted_at
             q = max(0.0, base - req.submitted_at)
             p = (max(0.0, req.first_token_at - base)
                  if req.first_token_at else 0.0)
-            return int(q * 1e9), int(p * 1e9)
+            wait = (min(p, max(0.0, req.exec_start_at - base))
+                    if req.exec_start_at and p else 0.0)
+            return {"queue_ns": int(q * 1e9), "prefill_ns": int(p * 1e9),
+                    "submitted_ns": int(req.submitted_at * 1e9),
+                    "dispatch_wait_ns": int(wait * 1e9),
+                    "prefill_exec_ns": (int((p - wait) * 1e9)
+                                        if req.exec_start_at else 0)}
 
         try:
             while True:
@@ -1123,13 +1230,12 @@ class JaxEngine(Engine):
                         raise WedgedError(reason[len("error: "):])
                     if reason.startswith("error"):
                         raise RuntimeError(reason)
-                    q_ns, p_ns = _trace_split()
                     yield Chunk(
                         text=matcher.flush(), done=True, done_reason=reason,
                         prompt_tokens=len(prompt_ids),
                         completion_tokens=completion,
-                        queue_ns=q_ns, prefill_ns=p_ns,
                         kv_fetch_ns=kv_ns, kv_fallback=kv_fallback,
+                        **_trace_stamps(),
                     )
                     return
                 completion += 1
@@ -1151,13 +1257,12 @@ class JaxEngine(Engine):
                 if stopped:
                     finished = True
                     self.scheduler.cancel(req)
-                    q_ns, p_ns = _trace_split()
                     yield Chunk(
                         text=emit, done=True, done_reason="stop",
                         prompt_tokens=len(prompt_ids),
                         completion_tokens=completion,
-                        queue_ns=q_ns, prefill_ns=p_ns,
                         kv_fetch_ns=kv_ns, kv_fallback=kv_fallback,
+                        **_trace_stamps(),
                     )
                     return
                 if emit:

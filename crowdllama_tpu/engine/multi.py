@@ -201,5 +201,14 @@ class MultiEngine(Engine):
         return await self._child(model).embed(texts, model=model,
                                               truncate=truncate)
 
+    # The profiler session is global to the process, so one child's control
+    # is the control (and its latch the single flight).
+
+    async def profile_start(self) -> dict:
+        return await next(iter(self._engines.values())).profile_start()
+
+    async def profile_stop(self) -> dict:
+        return await next(iter(self._engines.values())).profile_stop()
+
     async def capture_profile(self, seconds: float = 3.0) -> str:
         return await next(iter(self._engines.values())).capture_profile(seconds)

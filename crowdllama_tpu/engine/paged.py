@@ -644,21 +644,26 @@ class PagedModelRunner(ModelRunner):
                 lp, pk, pv, ksc, vsc, window = scanned
                 pool = {}
 
-                def attn_fn(q, k, v):
+                @jax.named_scope("kv_write")
+                def write(k, v):
                     if quant:
                         kq, k_sc = quantize_kv(k, scale_dtype=ksc.dtype)
                         vq, v_sc = quantize_kv(v, scale_dtype=vsc.dtype)
-                        pk2 = pk.at[cur_page, :, offset].set(kq)
-                        pv2 = pv.at[cur_page, :, offset].set(vq)
-                        ks2 = ksc.at[cur_page, :, offset].set(k_sc)
-                        vs2 = vsc.at[cur_page, :, offset].set(v_sc)
-                    else:
-                        pk2 = pk.at[cur_page, :, offset].set(
-                            k.astype(pk.dtype))
-                        pv2 = pv.at[cur_page, :, offset].set(
-                            v.astype(pv.dtype))
-                        ks2 = vs2 = None
+                        return (pk.at[cur_page, :, offset].set(kq),
+                                pv.at[cur_page, :, offset].set(vq),
+                                ksc.at[cur_page, :, offset].set(k_sc),
+                                vsc.at[cur_page, :, offset].set(v_sc))
+                    return (pk.at[cur_page, :, offset].set(k.astype(pk.dtype)),
+                            pv.at[cur_page, :, offset].set(v.astype(pv.dtype)),
+                            None, None)
+
+                def attn_fn(q, k, v):
+                    pk2, pv2, ks2, vs2 = write(k, v)
                     pool.update(pk=pk2, pv=pv2, ks=ks2, vs=vs2)
+                    return read(q, pk2, pv2, ks2, vs2)
+
+                @jax.named_scope("attention")
+                def read(q, pk2, pv2, ks2, vs2):
                     if use_kernel:
                         if sharded:
                             return flash_paged_decode_attention_tp(
@@ -697,12 +702,13 @@ class PagedModelRunner(ModelRunner):
                 body, x, (params["layers"], st.pool_k, st.pool_v,
                           st.k_scale, st.v_scale, windows))
             logits = T._unembed(params, cfg, x)
-            carry, sub = split_slot_keys(st.keys)
-            logits = apply_repeat_penalty(logits, st.recent,
-                                          st.repeat_penalty)
-            next_tokens = sample_tokens_slots(logits, st.temperature,
-                                              st.top_p, sub, top_k=st.top_k)
-            next_tokens = jnp.where(st.active, next_tokens, 0)
+            with jax.named_scope("sample"):
+                carry, sub = split_slot_keys(st.keys)
+                logits = apply_repeat_penalty(logits, st.recent,
+                                              st.repeat_penalty)
+                next_tokens = sample_tokens_slots(
+                    logits, st.temperature, st.top_p, sub, top_k=st.top_k)
+                next_tokens = jnp.where(st.active, next_tokens, 0)
             bidx2 = jnp.arange(st.recent.shape[0])
             cursor = (st.seq_lens + 1) % REPEAT_LAST_N
             recent = st.recent.at[bidx2, cursor].set(
@@ -801,19 +807,26 @@ class PagedModelRunner(ModelRunner):
                 lp, pk, pv, ksc, vsc, window = scanned
                 pool = {}
 
-                def attn_fn(q, k, v):
+                @jax.named_scope("kv_write")
+                def write(k, v):
                     if quant:
                         kq, k_sc = quantize_kv(k, scale_dtype=ksc.dtype)
                         vq, v_sc = quantize_kv(v, scale_dtype=vsc.dtype)
-                        pk2 = pk.at[wpages, :, woffs].set(kq)
-                        pv2 = pv.at[wpages, :, woffs].set(vq)
-                        ks2 = ksc.at[wpages, :, woffs].set(k_sc)
-                        vs2 = vsc.at[wpages, :, woffs].set(v_sc)
-                    else:
-                        pk2 = pk.at[wpages, :, woffs].set(k.astype(pk.dtype))
-                        pv2 = pv.at[wpages, :, woffs].set(v.astype(pv.dtype))
-                        ks2 = vs2 = None
+                        return (pk.at[wpages, :, woffs].set(kq),
+                                pv.at[wpages, :, woffs].set(vq),
+                                ksc.at[wpages, :, woffs].set(k_sc),
+                                vsc.at[wpages, :, woffs].set(v_sc))
+                    return (pk.at[wpages, :, woffs].set(k.astype(pk.dtype)),
+                            pv.at[wpages, :, woffs].set(v.astype(pv.dtype)),
+                            None, None)
+
+                def attn_fn(q, k, v):
+                    pk2, pv2, ks2, vs2 = write(k, v)
                     pool.update(pk=pk2, pv=pv2, ks=ks2, vs=vs2)
+                    return read(q, k, v, pk2, pv2, ks2, vs2)
+
+                @jax.named_scope("attention")
+                def read(q, k, v, pk2, pv2, ks2, vs2):
                     # The chunk's fresh KV rides along as explicit operands
                     # so the reference path's self block matches monolithic
                     # prefill bitwise (bf16 pools).
@@ -839,12 +852,14 @@ class PagedModelRunner(ModelRunner):
             logits = T._unembed(params, cfg,
                                 jnp.concatenate([x[:b], x_last[None]]))
             chunk_logits = logits[b]
-            carry, sub = split_slot_keys(st.keys)
-            dec_logits = apply_repeat_penalty(logits[:b], st.recent,
-                                              st.repeat_penalty)
-            next_tokens = sample_tokens_slots(dec_logits, st.temperature,
-                                              st.top_p, sub, top_k=st.top_k)
-            next_tokens = jnp.where(st.active, next_tokens, 0)
+            with jax.named_scope("sample"):
+                carry, sub = split_slot_keys(st.keys)
+                dec_logits = apply_repeat_penalty(logits[:b], st.recent,
+                                                  st.repeat_penalty)
+                next_tokens = sample_tokens_slots(
+                    dec_logits, st.temperature, st.top_p, sub,
+                    top_k=st.top_k)
+                next_tokens = jnp.where(st.active, next_tokens, 0)
             bidx2 = jnp.arange(st.recent.shape[0])
             cursor = (st.seq_lens + 1) % REPEAT_LAST_N
             recent = st.recent.at[bidx2, cursor].set(

@@ -38,6 +38,14 @@ import numpy as np
 
 from crowdllama_tpu.engine.runner import ModelRunner
 from crowdllama_tpu.obs.metrics import ENGINE_TELEMETRY
+from crowdllama_tpu.obs.trace import (
+    SCHED_ADMIT,
+    SCHED_DISPATCH,
+    SCHED_EMIT,
+    SCHED_READBACK,
+    SCHED_WAIT_FOR_WORK,
+    SCHED_YIELD,
+)
 from crowdllama_tpu.testing import faults
 
 log = logging.getLogger("crowdllama.engine.scheduler")
@@ -93,6 +101,13 @@ class GenRequest:
     # admitted_at - submitted_at and prefill = first_token_at - admitted_at.
     admitted_at: float = 0.0
     first_token_at: float = 0.0
+    # The host's estimate of when the device could start this request's
+    # first program: the later of its own dispatch and the end of the
+    # flight queued ahead of it (``dispatch_watch`` resolves to that end).
+    # Splits prefill into dispatch_wait (admitted_at → here) and
+    # prefill_exec (here → first_token_at).
+    exec_start_at: float = 0.0
+    dispatch_watch: object | None = None
     cancelled: bool = False  # client went away: drop at admission / free slot
     # KV shipping (docs/KV_TRANSFER.md): pages fetched from a donor peer,
     # applied via runner.import_pages right before this request's prefill
@@ -715,6 +730,68 @@ class Scheduler:
                 return i
         return None
 
+    def _call(self, loop, what: str, fn, *args, note: dict | None = None,
+              **kwargs):
+        """Await ``fn(*args, **kwargs)`` on the dispatch executor under a
+        ``sched.dispatch.<what>`` annotation (``note``: its arguments in
+        the trace) — a host event on the profiler's clock, one flag test
+        while no profiler runs."""
+        def run():
+            with jax.profiler.TraceAnnotation(f"{SCHED_DISPATCH}.{what}",
+                                              **(note or {})):
+                return fn(*args, **kwargs)
+
+        return loop.run_in_executor(self._exec, run)
+
+    @staticmethod
+    def _watch_ready(loop, fl: "_InFlightChunk | None"):
+        """A future of the host's clock at the moment the device finished
+        flight ``fl``, waited for on a pool thread: the dispatch stream is
+        not touched, and the device runs its queue in order, so the program
+        dispatched next starts then.  None when nothing is queued."""
+        if fl is None:
+            return None
+
+        def wait() -> float:
+            try:
+                jax.block_until_ready(fl.tokens_dev)
+            except Exception:
+                return 0.0   # the flight's own retire reports the failure
+            return time.monotonic()
+
+        return loop.run_in_executor(None, wait)
+
+    @staticmethod
+    async def _stamp_first_token(req: GenRequest) -> None:
+        req.first_token_at = time.monotonic()
+        watch, req.dispatch_watch = req.dispatch_watch, None
+        if watch is not None:
+            req.exec_start_at = max(req.exec_start_at, await watch)
+
+    def _emit_first(self, req: GenRequest, first: int,
+                    info: "_SlotInfo") -> None:
+        """The first token to its request.  The trace event carries the
+        host's estimate of the request's own execution (``exec_us``), so a
+        device trace can be held against it (PERF.md §7)."""
+        exec_us = int(1e6 * (req.first_token_at - req.exec_start_at))
+        with jax.profiler.TraceAnnotation(SCHED_EMIT, first_token=1,
+                                          exec_us=exec_us):
+            self._emit(req, first, info)
+
+    def _prefix_mark(self) -> tuple[int, int]:
+        r = self.runner
+        return (getattr(r, "prefix_hits", 0),
+                getattr(r, "prefix_tokens_reused", 0))
+
+    def _count_admission(self, req: GenRequest,
+                         mark: tuple[int, int]) -> None:
+        """The prefix-cache counters of one admission: what the runner's
+        own counters grew by across its admission call."""
+        hits, reused = self._prefix_mark()
+        ENGINE_TELEMETRY.prefix_inc(
+            prompt_tokens=len(req.prompt_ids),
+            tokens_reused=reused - mark[1], hits=hits - mark[0])
+
     def _abort_fn(self, job):
         """Runner abort for a parked admission job: ragged jobs (marker
         attribute) abort via ragged_abort, monolithic chunked jobs via
@@ -765,18 +842,28 @@ class Scheduler:
             log.warning("kv import failed (%s); falling back to plain "
                         "prefill", e)
 
-    async def _admit_one(self, req: GenRequest, slot: int) -> None:
-        import functools
-
+    async def _admit_one(self, req: GenRequest, slot: int,
+                         ahead: "_InFlightChunk | None") -> None:
+        """Monolithic admission; ``ahead`` is the flight queued on the
+        device before this request's prefill."""
         req.admitted_at = time.monotonic()
-        sub = self._req_key(req, 0)
         loop = asyncio.get_running_loop()
-        first, ks, vs, plen = await loop.run_in_executor(
-            self._exec, functools.partial(
-                self.runner.prefill, req.prompt_ids, req.temperature,
-                req.top_p, sub, state=self.state, top_k=req.top_k,
-                repeat_penalty=req.repeat_penalty),
-        )
+        mark = self._prefix_mark()
+        req.dispatch_watch = self._watch_ready(loop, ahead)
+        req.exec_start_at = time.monotonic()
+
+        def prefill():
+            # The sampling key's small programs run on the dispatch thread
+            # with the call they serve: one phase, one trace event.
+            return self.runner.prefill(
+                req.prompt_ids, req.temperature, req.top_p,
+                self._req_key(req, 0), state=self.state, top_k=req.top_k,
+                repeat_penalty=req.repeat_penalty)
+
+        first, ks, vs, plen = await self._call(
+            loop, "prefill", prefill,
+            note={"prompt_tokens": len(req.prompt_ids)})
+        self._count_admission(req, mark)
         await self._place(req, slot, ks, vs, plen, first)
 
     async def _place(self, req: GenRequest, slot: int, ks, vs, plen: int,
@@ -786,20 +873,20 @@ class Scheduler:
         insert on the dispatch executor: under multi-host serving
         (parallel/replicated.py) every runner call is also a cross-host
         broadcast, which must never block the event loop."""
-        import functools
-
         loop = asyncio.get_running_loop()
-        self.state = await loop.run_in_executor(
-            self._exec, functools.partial(
-                self.runner.insert,
+
+        def insert():
+            return self.runner.insert(
                 self.state, slot, ks, vs, plen, first, req.temperature,
                 req.top_p, prompt_tokens=req.prompt_ids,
                 slot_key=self._req_key(req, 1), top_k=req.top_k,
-                repeat_penalty=req.repeat_penalty))
+                repeat_penalty=req.repeat_penalty)
+
+        self.state = await self._call(loop, "insert", insert)
+        await self._stamp_first_token(req)
         info = _SlotInfo(req=req, prompt_len=plen)
         self.slots[slot] = info
-        req.first_token_at = time.monotonic()
-        self._emit(req, first, info)
+        self._emit_first(req, first, info)
         await self._flush_releases(loop)
 
     async def _flush_releases(self, loop) -> None:
@@ -807,8 +894,8 @@ class Scheduler:
         emit loops) on the dispatch executor."""
         while self._to_release:
             slot = self._to_release.pop(0)
-            self.state = await loop.run_in_executor(
-                self._exec, self.runner.release, self.state, slot)
+            self.state = await self._call(
+                loop, "release", self.runner.release, self.state, slot)
 
     def _emit(self, req: GenRequest, token: int, info: _SlotInfo) -> None:
         info.generated += 1
@@ -942,8 +1029,6 @@ class Scheduler:
         wake event until credit arrives or the stall budget releases the
         stream to free_run.  Returns the in-flight chunk, or None when no
         dispatch happened this iteration."""
-        import functools
-
         if self._inflight is not None:
             # The previous round has not retired, so per-slot generated
             # counts are pre-retire — validating a pipelined credit here
@@ -985,11 +1070,13 @@ class Scheduler:
                     and not self._deferred and not self._exclusive
                     and self._migrating is None and self._chunking is None):
                 self._wake.clear()
-                try:
-                    await asyncio.wait_for(self._wake.wait(),
-                                           timeout=max(0.01, park))
-                except asyncio.TimeoutError:
-                    pass
+                with jax.profiler.TraceAnnotation(SCHED_WAIT_FOR_WORK,
+                                                  paced=1):
+                    try:
+                        await asyncio.wait_for(self._wake.wait(),
+                                               timeout=max(0.01, park))
+                    except asyncio.TimeoutError:
+                        pass
             return None
         kmax = int(getattr(self.runner, "draft_len", 0))
         meta: list[tuple[int, int]] = []
@@ -1027,15 +1114,16 @@ class Scheduler:
             for i, toks in token_chunks.items():
                 t = toks[:kk]
                 drafts[i, :len(t)] = t
-            tokens_dev, self.state = await loop.run_in_executor(
-                self._exec, functools.partial(
-                    self.runner.decode_steps_hosted, self.state, drafts))
+            tokens_dev, self.state = await self._call(
+                loop, "decode", self.runner.decode_steps_hosted, self.state,
+                drafts, note={"dispatch": "spec", "steps": 1})
         else:
             # Pure ack credits (worker-draft pacing): one round of the
             # worker's OWN program — a packed spec verify step while
             # drafting is on, a plain step while paused.
-            tokens_dev, self.state = await loop.run_in_executor(
-                self._exec, self.runner.decode_steps_device, self.state, 1)
+            tokens_dev, self.state = await self._call(
+                loop, "decode", self.runner.decode_steps_device, self.state,
+                1, note={"dispatch": "plain", "steps": 1})
         self._step_budget_used = float(len(meta))
         self.host_dispatches += 1
         return _InFlightChunk(
@@ -1087,7 +1175,8 @@ class Scheduler:
                 and not self._deferred and not self._exclusive
                 and self._migrating is None):
             self._wake.clear()
-            await self._wake.wait()
+            with jax.profiler.TraceAnnotation(SCHED_WAIT_FOR_WORK):
+                await self._wake.wait()
 
         # Free cancelled slots — only the loop touches device state, so a
         # release can never donate buffers out from under a dispatch, and
@@ -1096,8 +1185,8 @@ class Scheduler:
         for i, info in enumerate(self.slots):
             if isinstance(info, _SlotInfo) and info.req.cancelled:
                 self.slots[i] = None
-                self.state = await loop_.run_in_executor(
-                    self._exec, self.runner.release, self.state, i)
+                self.state = await self._call(
+                    loop_, "release", self.runner.release, self.state, i)
                 self.requests_served += 1
 
         # Live migration (migrate()): retire everything with "migrate" at
@@ -1229,14 +1318,15 @@ class Scheduler:
                 # Executor, not the loop: under multi-host serving the
                 # check broadcasts a frame (page growth must replay on
                 # followers in stream order) and must not block the loop.
-                starved = await loop.run_in_executor(self._exec, check, k)
+                starved = await self._call(loop, "pre_decode_check",
+                                           check, k)
                 if starved and self._inflight is not None:
                     # Drain the in-flight chunk first: force-finishing a
                     # starved slot now would drop its already-generated
                     # tokens, and retirement can itself free pages (EOS).
                     await self._retire_inflight(loop)
-                    starved = await loop.run_in_executor(self._exec,
-                                                         check, k)
+                    starved = await self._call(
+                        loop, "pre_decode_check", check, k)
                 while starved:
                     slot = starved[0]
                     info = self.slots[slot]
@@ -1246,14 +1336,13 @@ class Scheduler:
                         info.req.finish("length")
                         self.slots[slot] = None
                         self.requests_served += 1
-                    self.state = await loop.run_in_executor(
-                        self._exec, self.runner.release, self.state, slot)
-                    starved = await loop.run_in_executor(self._exec,
-                                                         check, k)
+                    self.state = await self._call(
+                        loop, "release", self.runner.release, self.state,
+                        slot)
+                    starved = await self._call(
+                        loop, "pre_decode_check", check, k)
             live = sum(1 for s in self.slots if isinstance(s, _SlotInfo))
             if rjob is not None:
-                import functools
-
                 req, slot, job = rjob
                 c = getattr(self.runner, "ragged_chunk", 1)
                 chunk_toks = min(k * c,
@@ -1272,20 +1361,27 @@ class Scheduler:
                         self.drain_requested_cb()
                     else:
                         loop.create_task(self.migrate())
+                if not req.exec_start_at:
+                    # The job's first flight: it starts on the device when
+                    # the flight still in flight ends (dispatch_wait).
+                    req.dispatch_watch = self._watch_ready(loop,
+                                                           self._inflight)
                 try:
                     if use_ragged_mega:
                         eos_ids, budgets = self._mega_limits()
                         tokens_dev, rdone_dev, self.state = (
-                            await loop.run_in_executor(
-                                self._exec, functools.partial(
-                                    self.runner.ragged_megastep,
-                                    self.state, job, k, eos_ids=eos_ids,
-                                    budgets=budgets)))
+                            await self._call(
+                                loop, "ragged", self.runner.ragged_megastep,
+                                self.state, job, k, eos_ids=eos_ids,
+                                budgets=budgets,
+                                note={"dispatch": "ragged_mega",
+                                      "steps": k}))
                     else:
                         rdone_dev = None
-                        tokens_dev, self.state = await loop.run_in_executor(
-                            self._exec, functools.partial(
-                                self.runner.ragged_step, self.state, job, k))
+                        tokens_dev, self.state = await self._call(
+                            loop, "ragged", self.runner.ragged_step,
+                            self.state, job, k,
+                            note={"dispatch": "ragged", "steps": k})
                 except ValueError as e:
                     # Pool cannot cover the job's next chunk pages
                     # (PagesExhausted is a ValueError): fail THIS request,
@@ -1309,6 +1405,8 @@ class Scheduler:
                         tokens_dev=tokens_dev, snapshot=list(self.slots),
                         dispatched_at=time.monotonic(),
                         ragged_steps=n_chunks, done_dev=rdone_dev)
+                    if not req.exec_start_at:
+                        req.exec_start_at = dispatched.dispatched_at
                     if job.finished:
                         # Whole prompt is in the pool: sample the first
                         # token and activate the slot (the ragged
@@ -1316,15 +1414,19 @@ class Scheduler:
                         # insert — the pages are already there).
                         self._chunking = None
                         self._admitting -= 1
-                        sub = self._req_key(req, 0)
+
+                        def finish():
+                            return self.runner.ragged_finish(
+                                self.state, job, req.temperature,
+                                req.top_p, self._req_key(req, 0),
+                                slot_key=self._req_key(req, 1),
+                                top_k=req.top_k,
+                                repeat_penalty=req.repeat_penalty)
+
                         try:
-                            first, self.state = await loop.run_in_executor(
-                                self._exec, functools.partial(
-                                    self.runner.ragged_finish, self.state,
-                                    job, req.temperature, req.top_p, sub,
-                                    slot_key=self._req_key(req, 1),
-                                    top_k=req.top_k,
-                                    repeat_penalty=req.repeat_penalty))
+                            first, self.state = await self._call(
+                                loop, "ragged_finish", finish)
+                            await self._stamp_first_token(req)
                         except BaseException:
                             self.slots[slot] = None
                             req.finish("error: engine failure")
@@ -1332,8 +1434,7 @@ class Scheduler:
                         info = _SlotInfo(req=req,
                                          prompt_len=len(req.prompt_ids))
                         self.slots[slot] = info
-                        req.first_token_at = time.monotonic()
-                        self._emit(req, first, info)
+                        self._emit_first(req, first, info)
                         await self._flush_releases(loop)
             elif paced:
                 dispatched = await self._dispatch_paced(loop, paced)
@@ -1343,18 +1444,16 @@ class Scheduler:
                     # K full steps in ONE device program, sampling +
                     # done-flags on device; the host reads the packed
                     # [K, B] block back in a single transfer at retire.
-                    import functools
-
                     eos_ids, budgets = self._mega_limits()
-                    tokens_dev, done_dev, self.state = (
-                        await loop.run_in_executor(
-                            self._exec, functools.partial(
-                                self.runner.decode_megastep, self.state,
-                                k, eos_ids=eos_ids, budgets=budgets)))
+                    tokens_dev, done_dev, self.state = await self._call(
+                        loop, "decode", self.runner.decode_megastep,
+                        self.state, k, eos_ids=eos_ids, budgets=budgets,
+                        note={"dispatch": "megastep", "steps": k})
                 else:
-                    tokens_dev, self.state = await loop.run_in_executor(
-                        self._exec, self.runner.decode_steps_device,
-                        self.state, k)  # [K,B] on device
+                    tokens_dev, self.state = await self._call(
+                        loop, "decode", self.runner.decode_steps_device,
+                        self.state, k,  # [K,B] on device
+                        note={"dispatch": "plain", "steps": k})
                 self._step_budget_used = float(live)
                 self.host_dispatches += 1
                 dispatched = _InFlightChunk(
@@ -1375,18 +1474,18 @@ class Scheduler:
                     abort = getattr(self.runner, "prefill_abort", None)
                     if abort is not None:
                         await loop.run_in_executor(self._exec, abort, job)
-                elif await loop.run_in_executor(
-                        self._exec, self.runner.prefill_step, job):
+                elif await self._call(loop, "prefill_step",
+                                      self.runner.prefill_step, job):
                     self._chunking = None
-                    sub = self._req_key(req, 0)
-                    import functools
 
-                    first, ks, vs, plen = await loop.run_in_executor(
-                        self._exec, functools.partial(
-                            self.runner.prefill_finish, job,
-                            req.temperature, req.top_p, sub,
-                            top_k=req.top_k,
-                            repeat_penalty=req.repeat_penalty))
+                    def finish():
+                        return self.runner.prefill_finish(
+                            job, req.temperature, req.top_p,
+                            self._req_key(req, 0), top_k=req.top_k,
+                            repeat_penalty=req.repeat_penalty)
+
+                    first, ks, vs, plen = await self._call(
+                        loop, "prefill_finish", finish)
                     await self._place(req, slot, ks, vs, plen, first)
             except ValueError as e:
                 # Bad request / pool exhaustion at insert (PagesExhausted
@@ -1438,9 +1537,14 @@ class Scheduler:
             # prompts the cache mostly covers — chunked admission would
             # re-prefill what cached pages already hold.
             hint = getattr(self.runner, "prefill_prefers_monolithic", None)
-            if (chunk and len(req.prompt_ids) > chunk
+            with jax.profiler.TraceAnnotation(SCHED_ADMIT):
+                incremental = bool(
+                    chunk and len(req.prompt_ids) > chunk
                     and not (hint is not None
-                             and hint(req.prompt_ids, chunk=chunk))):
+                             and hint(req.prompt_ids, chunk=chunk)))
+            # The flight queued on the device ahead of this admission.
+            ahead = dispatched or self._inflight
+            if incremental:
                 if self._chunking is not None:
                     # One chunked admission at a time; park it and keep
                     # admitting short requests from pending.
@@ -1455,22 +1559,25 @@ class Scheduler:
                     # loop must keep streaming while that happens.  The
                     # loop parks on this await, so allocator/index state
                     # stays single-flight.
-                    import functools
-
                     req.admitted_at = time.monotonic()
+                    mark = self._prefix_mark()
+                    note = {"prompt_tokens": len(req.prompt_ids)}
                     if self._ragged:
                         # Unified ragged admission: the job prefills inside
                         # subsequent decode dispatches (KV straight into
-                        # the slot's pool pages, no accumulators).
-                        job = await loop.run_in_executor(
-                            self._exec, functools.partial(
-                                self.runner.ragged_begin, req.prompt_ids,
-                                slot, state=self.state))
+                        # the slot's pool pages, no accumulators); its
+                        # first flight stamps exec_start_at.
+                        job = await self._call(
+                            loop, "ragged_begin", self.runner.ragged_begin,
+                            req.prompt_ids, slot, state=self.state,
+                            note=note)
                     else:
-                        job = await loop.run_in_executor(
-                            self._exec, functools.partial(
-                                self.runner.prefill_begin, req.prompt_ids,
-                                state=self.state))
+                        req.dispatch_watch = self._watch_ready(loop, ahead)
+                        req.exec_start_at = time.monotonic()
+                        job = await self._call(
+                            loop, "prefill_begin", self.runner.prefill_begin,
+                            req.prompt_ids, state=self.state, note=note)
+                    self._count_admission(req, mark)
                 except ValueError as e:
                     log.warning("admit failed: %s", e)
                     req.finish(f"error: {e}")
@@ -1488,7 +1595,7 @@ class Scheduler:
                 continue
             self._admitting += 1
             try:
-                await self._admit_one(req, slot)
+                await self._admit_one(req, slot, ahead)
             except ValueError as e:  # bad request (too long, etc.)
                 log.warning("admit failed: %s", e)
                 req.finish(f"error: {e}")
@@ -1509,7 +1616,8 @@ class Scheduler:
         await self._retire_inflight(loop)
         self._inflight = dispatched
         # Yield so submitters/streamers run between chunks.
-        await asyncio.sleep(0)
+        with jax.profiler.TraceAnnotation(SCHED_YIELD):
+            await asyncio.sleep(0)
 
     async def _retire_inflight(self, loop) -> None:
         """Read back and emit the in-flight chunk, if any."""
@@ -1519,10 +1627,36 @@ class Scheduler:
         # ONE host transfer per flight: tokens and (megastep) done-flags
         # come back together — device_get over the pair is the whole
         # readback, there is no per-step host sync anywhere in the loop.
-        tokens, done = await loop.run_in_executor(
-            self._exec, jax.device_get, (fl.tokens_dev, fl.done_dev))
-        tokens = np.asarray(tokens)  # [K,B] (or packed [K,2+J,B]) host
+        cls = self._flight_class(fl)
+
+        def readback():
+            with jax.profiler.TraceAnnotation(SCHED_READBACK, dispatch=cls):
+                tokens, done = jax.device_get((fl.tokens_dev, fl.done_dev))
+                # [K,B] (or packed [K,2+J,B]) on the host
+                return np.asarray(tokens), done
+
+        tokens, done = await loop.run_in_executor(self._exec, readback)
         now = time.monotonic()
+        with jax.profiler.TraceAnnotation(SCHED_EMIT, dispatch=cls):
+            emitted, dt = self._account_and_emit(fl, cls, tokens, done, now)
+        await self._flush_releases(loop)
+        if emitted == 0:
+            # Pure-overshoot chunk (dispatched before its slots' EOS was
+            # discovered): not a throughput sample, don't drag the EMA down.
+            return
+        rate = emitted / dt
+        self.throughput_ema = (
+            rate if self.throughput_ema == 0.0
+            else 0.9 * self.throughput_ema + 0.1 * rate
+        )
+
+    def _account_and_emit(self, fl: _InFlightChunk, cls: str,
+                          tokens: np.ndarray, done, now: float
+                          ) -> tuple[int, float]:
+        """The host side of one retire, between readback and the release
+        flush: duty-cycle and flight accounting, then every token of the
+        flight to its request.  Returns (tokens emitted, wall seconds
+        attributed to the flight)."""
         dt = max(now - max(self._last_retire_at, fl.dispatched_at), 1e-6)
         # Duty-cycle accounting (PR 13): the host gap is the stretch after
         # the previous flight retired with NOTHING queued on the device —
@@ -1533,7 +1667,6 @@ class Scheduler:
         # the device_get above is the one sync this loop already pays.
         gap = (max(0.0, fl.dispatched_at - self._last_retire_at)
                if self._last_retire_at else 0.0)
-        cls = self._flight_class(fl)
         ENGINE_TELEMETRY.host_gap_seconds.labels(cls).observe(gap)
         duty = dt / max(dt + gap, 1e-9)
         prev = self._duty.get(cls)
@@ -1572,8 +1705,9 @@ class Scheduler:
                     # Fused ragged flight: the chunk pins the loop open
                     # past all-fired, so every token-carrying step ran.
                     steps_run = max(steps_run, fl.ragged_steps)
-        ENGINE_TELEMETRY.padding_inc(useful=live * steps_run,
-                                     waste=max(0, batch - live) * steps_run)
+        ENGINE_TELEMETRY.flight_inc(
+            cls, seconds=dt, steps=steps_run, useful=live * steps_run,
+            waste=max(0, batch - live) * steps_run)
         emitted = 0
         chunk_acc = 0  # draft tokens accepted in this chunk (live slots)
         chunk_off = 0  # draft tokens offered in this chunk (live slots)
@@ -1673,16 +1807,7 @@ class Scheduler:
                 info.req.out.put_nowait((_VERIFY, {
                     "chunk_id": chunk_id, "position": info.generated,
                     "accepted": max(0, len(toks) - 1), "tokens": toks}))
-        await self._flush_releases(loop)
-        if emitted == 0:
-            # Pure-overshoot chunk (dispatched before its slots' EOS was
-            # discovered): not a throughput sample, don't drag the EMA down.
-            return
-        rate = emitted / dt
-        self.throughput_ema = (
-            rate if self.throughput_ema == 0.0
-            else 0.9 * self.throughput_ema + 0.1 * rate
-        )
+        return emitted, dt
 
 
 DONE = _DONE

@@ -35,7 +35,12 @@ from crowdllama_tpu.core.messages import (
     extract_generate_response,
 )
 from crowdllama_tpu.core.protocol import INFERENCE_PROTOCOL
-from crowdllama_tpu.obs import GATEWAY_ROOT_SPAN, NodeObs, new_trace_id
+from crowdllama_tpu.obs import (
+    DEFAULT_TRACE_CAPACITY,
+    GATEWAY_ROOT_SPAN,
+    NodeObs,
+    new_trace_id,
+)
 from crowdllama_tpu.obs.http import host_stat_lines, native_metric_lines
 from crowdllama_tpu.obs.metrics import (
     ENGINE_TELEMETRY,
@@ -135,14 +140,14 @@ class _StreamCtx:
 
 class Gateway:
     def __init__(self, peer: Peer, port: int = 9001, host: str = "0.0.0.0",
-                 trace_buffer: int = 64, request_timeout: float = 600.0,
+                 trace_buffer: int = DEFAULT_TRACE_CAPACITY, request_timeout: float = 600.0,
                  admission_max_inflight: int = 0,
                  retry_after_s: float = 1.0, kv_ship: bool = False,
                  gossip=None, tenant_quotas=None, flight_recorder: int = 32,
                  trace_ttl: float = 0.0, metrics_exemplars: bool = False,
                  slo_ttft_ms: float = 0.0, slo_decode_ms: float = 0.0,
                  stream_stall_ms: float = 0.0, hedge_ttft_ms: float = 0.0,
-                 profile_dir: str = "", spec_pipeline: str = "off",
+                 spec_pipeline: str = "off",
                  spec_draft_path: str = ""):
         self.peer = peer
         self.port = port
@@ -196,12 +201,12 @@ class Gateway:
         self.app.router.add_get("/debug/flightrecorder",
                                 self.handle_flightrecorder)
         # Swarm observatory (PR 13, docs/OBSERVABILITY.md): cluster-wide
-        # metric fan-in over the p2p plane, and an on-demand jax.profiler
-        # trace window.  Both are operator surfaces hit per request, never
-        # on the inference hot path.
+        # metric fan-in over the p2p plane — an operator surface hit per
+        # request, never on the inference hot path.  (The profiler trigger
+        # lives on the worker's ObsServer: only the process that holds the
+        # chip can trace it.)
         self.app.router.add_get("/metrics/cluster",
                                 self.handle_metrics_cluster)
-        self.app.router.add_get("/debug/profile", self.handle_profile)
         for route in ("/api/delete", "/api/create", "/api/copy", "/api/push"):
             self.app.router.add_route("*", route, self.handle_unsupported)
         # Prometheus-style counters fed by the logging middleware
@@ -255,17 +260,14 @@ class Gateway:
         from crowdllama_tpu.engine.autotune import BACKOFF_LOG
 
         self._autotune_backoffs_seen = BACKOFF_LOG.snapshot()[0]
-        # Swarm observatory (PR 13): the /metrics/cluster scraper, the SLO
-        # burn-rate engine (objectives in ms; 0 = disabled), and the
-        # /debug/profile artifact dir ("" = endpoint answers 501).
+        # Swarm observatory (PR 13): the /metrics/cluster scraper and the
+        # SLO burn-rate engine (objectives in ms; 0 = disabled).
         from crowdllama_tpu.obs.cluster import ClusterScraper
         from crowdllama_tpu.obs.slo import SloEngine
 
         self.cluster = ClusterScraper(peer)
         self.slo = SloEngine(ttft_ms=float(slo_ttft_ms),
                              decode_ms=float(slo_decode_ms))
-        self.profile_dir = str(profile_dir or "")
-        self._profiling = False  # /debug/profile single-flight latch
         # Inference-stream pool: a request to a worker reuses an idle
         # encrypted stream instead of paying TCP connect + signed-hello
         # handshake (Ed25519 sign/verify + X25519) per request — the
@@ -1148,45 +1150,6 @@ class Gateway:
         families = tuple(request.query.getall("family", []))
         text = await self.cluster.render(families)
         return web.Response(text=text, content_type="text/plain")
-
-    async def handle_profile(self, request: web.Request) -> web.Response:
-        """GET /debug/profile?seconds=N — capture a jax.profiler trace
-        window into the artifact dir and return its path (PR 13).
-
-        Gated on --profile-dir (501 when unset) and single-flight (409
-        while a capture is already running): profiler overhead is real,
-        an operator gets one window at a time."""
-        if not self.profile_dir:
-            return web.json_response(
-                {"error": "profiling disabled: start the gateway with "
-                          "--profile-dir to enable /debug/profile"},
-                status=501)
-        if self._profiling:
-            return web.json_response(
-                {"error": "a profile capture is already in flight"},
-                status=409)
-        try:
-            seconds = float(request.query.get("seconds", "3") or 3)
-        except ValueError:
-            seconds = 3.0
-        seconds = min(60.0, max(0.1, seconds))
-        path = os.path.join(
-            self.profile_dir, f"profile-{int(time.time())}")
-        self._profiling = True
-        try:
-            import jax
-
-            jax.profiler.start_trace(path)
-            try:
-                await asyncio.sleep(seconds)
-            finally:
-                jax.profiler.stop_trace()
-        except Exception as e:
-            return web.json_response(
-                {"error": f"profiler capture failed: {e}"}, status=500)
-        finally:
-            self._profiling = False
-        return web.json_response({"artifact": path, "seconds": seconds})
 
     async def handle_trace(self, request: web.Request) -> web.Response:
         """GET /debug/trace — JSON dump of the span ring buffer.

@@ -122,6 +122,7 @@ def _embed(params: Params, cfg: ModelConfig, tokens: jnp.ndarray) -> jnp.ndarray
     return x
 
 
+@jax.named_scope("head")
 def _unembed(params: Params, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps,
                  plus_one=cfg.family == "gemma2")
@@ -136,6 +137,7 @@ def _unembed(params: Params, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
     return logits
 
 
+@jax.named_scope("mlp")
 def _mlp(lp: Params, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
     """Dense SwiGLU (Llama) / GeGLU-tanh (Gemma) MLP. x: [..., D]."""
     gate = qeinsum("...d,df->...f", x, lp["w_gate"])
@@ -144,6 +146,7 @@ def _mlp(lp: Params, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
     return qeinsum("...f,fd->...d", act * up, lp["w_down"])
 
 
+@jax.named_scope("router")
 def _route_topk(lp: Params, cfg: ModelConfig, x: jnp.ndarray):
     """Router top-k: returns (weights [..., K] fp32 softmaxed, ids [..., K])."""
     router_logits = jnp.einsum("...d,de->...e", x.astype(jnp.float32),
@@ -206,6 +209,7 @@ def _moe_sorted(lp: Params, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
     return out.reshape(orig_shape).astype(x.dtype)
 
 
+@jax.named_scope("moe")
 def _moe(lp: Params, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
     """Mixtral top-k MoE.  x: [..., D].  Dispatches per cfg.moe_dispatch."""
     if cfg.moe_dispatch == "dense":
@@ -258,41 +262,46 @@ def scan_prefill_layers(
             lp, ck, cv, window = scanned
         else:
             lp, window = scanned
-        h = rms_norm(x, lp["ln1"], cfg.rms_norm_eps, plus_one=cfg.family == "gemma2")
-        q = qeinsum("btd,dk->btk", h, lp["wq"])
-        k = qeinsum("btd,dk->btk", h, lp["wk"])
-        v = qeinsum("btd,dk->btk", h, lp["wv"])
-        if "bq" in lp:  # Qwen2 qkv bias
-            q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
-        q = q.reshape(b, t, cfg.num_heads, dh)
-        k = k.reshape(b, t, hkv, dh)
-        v = v.reshape(b, t, hkv, dh)
-        if "q_norm" in lp:  # Qwen3 per-head qk-norm
-            q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
-            k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
-        q = apply_rope(q, positions, cos, sin)
-        k = apply_rope(k, positions, cos, sin)
-        kh = k.transpose(0, 2, 1, 3)  # [B, Hkv, T, Dh] — cache layout
-        vh = v.transpose(0, 2, 1, 3)
-        if has_ctx:
-            attn = prefill_attention_ctx(
-                q, kh, vh, positions, ck, cv, ctx_valid, scale,
-                softcap=cfg.attn_logit_softcap, sliding_window=window,
-                kv_valid=kv_valid)
-        elif sp_mesh is not None:
-            attn = ring_prefill_attention(
-                q, k, v, positions, scale, sp_mesh,
-                softcap=cfg.attn_logit_softcap, sliding_window=window,
-                kv_valid=kv_valid, dp_axis=sp_batch_axis)
-        else:
-            attn = prefill_attention(q, kh, vh, positions, scale,
-                                     softcap=cfg.attn_logit_softcap,
-                                     sliding_window=window, kv_valid=kv_valid,
-                                     n_shards=n_shards)
-        attn = qeinsum("btk,kd->btd", attn.reshape(b, t, -1), lp["wo"])
-        if cfg.post_norms:
-            attn = rms_norm(attn, lp["post_ln1"], cfg.rms_norm_eps, plus_one=True)
-        x = x + attn
+        with jax.named_scope("attn_proj"):
+            h = rms_norm(x, lp["ln1"], cfg.rms_norm_eps,
+                         plus_one=cfg.family == "gemma2")
+            q = qeinsum("btd,dk->btk", h, lp["wq"])
+            k = qeinsum("btd,dk->btk", h, lp["wk"])
+            v = qeinsum("btd,dk->btk", h, lp["wv"])
+            if "bq" in lp:  # Qwen2 qkv bias
+                q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
+            q = q.reshape(b, t, cfg.num_heads, dh)
+            k = k.reshape(b, t, hkv, dh)
+            v = v.reshape(b, t, hkv, dh)
+            if "q_norm" in lp:  # Qwen3 per-head qk-norm
+                q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
+                k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
+            q = apply_rope(q, positions, cos, sin)
+            k = apply_rope(k, positions, cos, sin)
+            kh = k.transpose(0, 2, 1, 3)  # [B, Hkv, T, Dh] — cache layout
+            vh = v.transpose(0, 2, 1, 3)
+        with jax.named_scope("attention"):
+            if has_ctx:
+                attn = prefill_attention_ctx(
+                    q, kh, vh, positions, ck, cv, ctx_valid, scale,
+                    softcap=cfg.attn_logit_softcap, sliding_window=window,
+                    kv_valid=kv_valid)
+            elif sp_mesh is not None:
+                attn = ring_prefill_attention(
+                    q, k, v, positions, scale, sp_mesh,
+                    softcap=cfg.attn_logit_softcap, sliding_window=window,
+                    kv_valid=kv_valid, dp_axis=sp_batch_axis)
+            else:
+                attn = prefill_attention(
+                    q, kh, vh, positions, scale,
+                    softcap=cfg.attn_logit_softcap, sliding_window=window,
+                    kv_valid=kv_valid, n_shards=n_shards)
+        with jax.named_scope("attn_proj"):
+            attn = qeinsum("btk,kd->btd", attn.reshape(b, t, -1), lp["wo"])
+            if cfg.post_norms:
+                attn = rms_norm(attn, lp["post_ln1"], cfg.rms_norm_eps,
+                                plus_one=True)
+            x = x + attn
         h = rms_norm(x, lp["ln2"], cfg.rms_norm_eps, plus_one=cfg.family == "gemma2")
         mlp_out = _moe(lp, cfg, h) if cfg.is_moe else _mlp(lp, cfg, h)
         if cfg.post_norms:
@@ -394,25 +403,31 @@ def decode_layer_body(
     """
     b = x.shape[0]
     dh = cfg.resolved_head_dim()
-    h = rms_norm(x, lp["ln1"], cfg.rms_norm_eps, plus_one=cfg.family == "gemma2")
-    q = qeinsum("bd,dk->bk", h, lp["wq"])
-    k = qeinsum("bd,dk->bk", h, lp["wk"])
-    v = qeinsum("bd,dk->bk", h, lp["wv"])
-    if "bq" in lp:  # Qwen2 qkv bias
-        q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
-    q = q.reshape(b, cfg.num_heads, dh)
-    k = k.reshape(b, cfg.num_kv_heads, dh)
-    v = v.reshape(b, cfg.num_kv_heads, dh)
-    if "q_norm" in lp:  # Qwen3 per-head qk-norm
-        q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
-        k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
-    q = apply_rope(q[:, None], positions[:, None], cos, sin)[:, 0]
-    k = apply_rope(k[:, None], positions[:, None], cos, sin)[:, 0]
+    # The named scopes are metadata on the ops (their path in a device
+    # trace), at the boundaries PERF.md's breakdowns talk in.
+    with jax.named_scope("attn_proj"):
+        h = rms_norm(x, lp["ln1"], cfg.rms_norm_eps,
+                     plus_one=cfg.family == "gemma2")
+        q = qeinsum("bd,dk->bk", h, lp["wq"])
+        k = qeinsum("bd,dk->bk", h, lp["wk"])
+        v = qeinsum("bd,dk->bk", h, lp["wv"])
+        if "bq" in lp:  # Qwen2 qkv bias
+            q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
+        q = q.reshape(b, cfg.num_heads, dh)
+        k = k.reshape(b, cfg.num_kv_heads, dh)
+        v = v.reshape(b, cfg.num_kv_heads, dh)
+        if "q_norm" in lp:  # Qwen3 per-head qk-norm
+            q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
+            k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
+        q = apply_rope(q[:, None], positions[:, None], cos, sin)[:, 0]
+        k = apply_rope(k[:, None], positions[:, None], cos, sin)[:, 0]
     attn = attn_fn(q, k, v)
-    attn = qeinsum("bk,kd->bd", attn.reshape(b, -1), lp["wo"])
-    if cfg.post_norms:
-        attn = rms_norm(attn, lp["post_ln1"], cfg.rms_norm_eps, plus_one=True)
-    x = x + attn
+    with jax.named_scope("attn_proj"):
+        attn = qeinsum("bk,kd->bd", attn.reshape(b, -1), lp["wo"])
+        if cfg.post_norms:
+            attn = rms_norm(attn, lp["post_ln1"], cfg.rms_norm_eps,
+                            plus_one=True)
+        x = x + attn
     h = rms_norm(x, lp["ln2"], cfg.rms_norm_eps, plus_one=cfg.family == "gemma2")
     mlp_out = _moe(lp, cfg, h) if cfg.is_moe else _mlp(lp, cfg, h)
     if cfg.post_norms:

@@ -28,7 +28,17 @@ from crowdllama_tpu.obs.metrics import (  # noqa: F401
     LabelGuard,
     NodeMetrics,
 )
-from crowdllama_tpu.obs.trace import Span, TraceBuffer, new_trace_id  # noqa: F401
+from crowdllama_tpu.obs.trace import (  # noqa: F401
+    DEFAULT_TRACE_CAPACITY,
+    SPAN_DECODE_STEP,
+    SPAN_DISPATCH_WAIT,
+    SPAN_PREFILL,
+    SPAN_PREFILL_EXEC,
+    SPAN_WORKER_QUEUE,
+    Span,
+    TraceBuffer,
+    new_trace_id,
+)
 
 GATEWAY_ROOT_SPAN = "gateway"
 
@@ -51,7 +61,8 @@ class NodeObs:
     OpenMetrics trace_id exemplar suffix on the request-path histograms.
     """
 
-    def __init__(self, trace_capacity: int = 64, node: str = "",
+    def __init__(self, trace_capacity: int = DEFAULT_TRACE_CAPACITY,
+                 node: str = "",
                  trace_ttl: float = 0.0, exemplars: bool = False) -> None:
         self.node = node
         self.trace = TraceBuffer(capacity=trace_capacity, node=node,
@@ -60,11 +71,18 @@ class NodeObs:
 
     def observe_generate(self, trace_id: str, parent: str, model: str,
                          queue_ns: int, prefill_ns: int, decode_ns: int,
-                         steps: int, total_ns: int, **meta) -> None:
+                         steps: int, total_ns: int, *, start_ns: int = 0,
+                         dispatch_wait_ns: int = 0, prefill_exec_ns: int = 0,
+                         **meta) -> None:
         """Record one served generate exchange: worker-side spans + histograms.
 
         Called at the Engine seam so FakeEngine and JaxEngine produce the
         same span catalogue (worker_queue / prefill / decode_step).
+        ``start_ns`` is the absolute monotonic_ns at which the queue wait
+        began; the three spans follow it back to back (they are
+        consecutive intervals of the engine's stamps), so ``/debug/trace``
+        shows a timeline.  A non-zero ``dispatch_wait_ns`` /
+        ``prefill_exec_ns`` pair adds the two children of ``prefill``.
         """
         self.metrics.request_seconds.labels(model).observe(
             total_ns / 1e9, exemplar=trace_id)
@@ -73,8 +91,20 @@ class NodeObs:
         if trace_id:
             t = self.trace
             t.begin(trace_id, model=model, **meta)
-            t.record(trace_id, "worker_queue", queue_ns, parent=parent)
-            t.record(trace_id, "prefill", prefill_ns, parent=parent)
-            t.record(trace_id, "decode_step", decode_ns, parent=parent,
-                     steps=steps)
+
+            def at(offset_ns: int) -> int | None:
+                return start_ns + offset_ns if start_ns else None
+
+            t.record(trace_id, SPAN_WORKER_QUEUE, queue_ns, parent=parent,
+                     start_ns=at(0))
+            t.record(trace_id, SPAN_PREFILL, prefill_ns, parent=parent,
+                     start_ns=at(queue_ns))
+            if dispatch_wait_ns or prefill_exec_ns:
+                t.record(trace_id, SPAN_DISPATCH_WAIT, dispatch_wait_ns,
+                         parent=SPAN_PREFILL, start_ns=at(queue_ns))
+                t.record(trace_id, SPAN_PREFILL_EXEC, prefill_exec_ns,
+                         parent=SPAN_PREFILL,
+                         start_ns=at(queue_ns + dispatch_wait_ns))
+            t.record(trace_id, SPAN_DECODE_STEP, decode_ns, parent=parent,
+                     start_ns=at(queue_ns + prefill_ns), steps=steps)
             t.finish(trace_id, total_ns)

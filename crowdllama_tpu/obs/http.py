@@ -1,4 +1,5 @@
-"""Worker-side observability HTTP server: /metrics + /debug/trace.
+"""Worker-side observability HTTP server: /metrics, /debug/trace, /drain
+and the profiler control (POST /debug/profile/start|stop).
 
 Workers have no consumer-facing HTTP surface (that is the gateway's job),
 but the tracing plane needs every node scrapeable: :class:`ObsServer` is a
@@ -18,6 +19,7 @@ import logging
 
 from aiohttp import web
 
+from crowdllama_tpu.engine.engine import ProfileBusy, ProfileDisabled
 from crowdllama_tpu.obs.metrics import (
     ENGINE_TELEMETRY,
     device_memory_lines,
@@ -118,6 +120,12 @@ class ObsServer:
         # SIGTERM, for orchestrators that reach workers over HTTP (e.g.
         # a preStop hook) instead of signaling the process.
         self.app.router.add_post("/drain", self.handle_drain)
+        # The profiler control: only this process — the one that holds the
+        # chip — can trace it (the gateway maps no jaxlib at all).
+        self.app.router.add_post("/debug/profile/start",
+                                 self.handle_profile_start)
+        self.app.router.add_post("/debug/profile/stop",
+                                 self.handle_profile_stop)
 
     async def start(self) -> None:
         self._runner = web.AppRunner(self.app, access_log=None)
@@ -160,3 +168,33 @@ class ObsServer:
             "already_draining": already,
             "migrated_streams": migrated,
         })
+
+    async def _profile(self, action: str) -> web.Response:
+        """``start`` / ``stop`` of the engine's profiler trace.  501 where
+        the node cannot be traced (its engine runs no JAX program here, or
+        no ``--profile-dir``), 409 against the single flight (a second
+        start, a stop with nothing running).  The profiler calls run on a
+        thread inside the engine, never on this loop."""
+        engine = getattr(self.peer, "engine", None)
+        control = getattr(engine, f"profile_{action}", None)
+        if control is None or not getattr(engine, "on_device", False):
+            return web.json_response(
+                {"error": "this node's engine holds no device to trace"},
+                status=501)
+        try:
+            return web.json_response(await control())
+        except ProfileDisabled as e:
+            return web.json_response({"error": str(e)}, status=501)
+        except ProfileBusy as e:
+            return web.json_response({"error": str(e)}, status=409)
+        except Exception as e:
+            log.exception("profiler %s failed", action)
+            return web.json_response(
+                {"error": f"profiler {action} failed: {e}"}, status=500)
+
+    async def handle_profile_start(self, request: web.Request
+                                   ) -> web.Response:
+        return await self._profile("start")
+
+    async def handle_profile_stop(self, request: web.Request) -> web.Response:
+        return await self._profile("stop")

@@ -43,6 +43,11 @@ _LABEL_VALUE_RE = re.compile(r"^[A-Za-z0-9_.:/\-]{1,64}$")
 # cycle): how a flight reached the device — plain per-step chunk,
 # kernel-looped megastep, unified ragged step, or speculative verify.
 DISPATCH_CLASSES = ("plain", "megastep", "ragged", "spec")
+# Every class Scheduler._flight_class names: the flight counters render
+# one series per class from the first scrape.
+FLIGHT_CLASSES = ("plain", "megastep", "ragged", "ragged_mega", "spec")
+# Phases of a worker's start that crowdllama_startup_seconds reports.
+STARTUP_PHASES = ("weights", "warmup", "ready")
 
 
 def _fmt(v: float) -> str:
@@ -405,6 +410,18 @@ class EngineTelemetry:
         # a signature", not which bucket did.
         self._cache_hits: dict[str, int] = {}
         self._padding = {"waste": 0, "useful": 0}
+        # Prefix-cache accounting at admission (the scheduler holds the
+        # numbers): reused / prompt tokens is the share of prefill the
+        # cache saved, hits counts admissions that reused anything.
+        self._prefix = {"tokens_reused": 0, "hits": 0, "prompt_tokens": 0}
+        # Per dispatch class, the wall time and decode steps of every
+        # retired flight: seconds / steps is the wall time of one step.
+        self._flight_seconds = {cls: 0.0 for cls in FLIGHT_CLASSES}
+        self._flight_steps = {cls: 0 for cls in FLIGHT_CLASSES}
+        # Seconds each phase of the start took ("ready": from the import
+        # of this module, which the CLI does first, to the engine serving).
+        self.t_import = time.monotonic()
+        self._startup: dict[str, float] = {}
         # program -> "pallas" | "pallas_interpret" | "jnp": which attention
         # implementation the newest runner built in this process dispatches
         # (set at runner build; a refused kernel must be visible to a
@@ -463,6 +480,30 @@ class EngineTelemetry:
             self._padding["useful"] += max(0, int(useful))
             self._padding["waste"] += max(0, int(waste))
 
+    def flight_inc(self, cls: str, seconds: float, steps: int,
+                   useful: int, waste: int) -> None:
+        """Account one retired decode flight under one lock: its wall time
+        and steps under its dispatch class, and its padding as
+        :meth:`padding_inc` would."""
+        with self._lock:
+            self._flight_seconds[cls] += max(0.0, float(seconds))
+            self._flight_steps[cls] += max(0, int(steps))
+            self._padding["useful"] += max(0, int(useful))
+            self._padding["waste"] += max(0, int(waste))
+
+    def prefix_inc(self, prompt_tokens: int, tokens_reused: int,
+                   hits: int) -> None:
+        """Account one admission: its prompt tokens, and how many of them
+        the prefix cache already held."""
+        with self._lock:
+            self._prefix["prompt_tokens"] += max(0, int(prompt_tokens))
+            self._prefix["tokens_reused"] += max(0, int(tokens_reused))
+            self._prefix["hits"] += max(0, int(hits))
+
+    def startup_set(self, phase: str, seconds: float) -> None:
+        with self._lock:
+            self._startup[phase] = max(0.0, float(seconds))
+
     def snapshot_compiles(self) -> dict[tuple[str, str], int]:
         """(program, bucket) -> count; tests diff two snapshots to assert
         e.g. a draft_len retune added exactly one new decode bucket."""
@@ -488,6 +529,10 @@ class EngineTelemetry:
             padding = dict(self._padding)
             cache_hits = sorted(self._cache_hits.items())
             attention = sorted(self._attention_paths.items())
+            prefix = dict(self._prefix)
+            flight_seconds = dict(self._flight_seconds)
+            flight_steps = dict(self._flight_steps)
+            startup = dict(self._startup)
         out.append("# TYPE crowdllama_engine_attention_path gauge")
         if not attention:
             out.append('crowdllama_engine_attention_path{program="none",'
@@ -517,6 +562,26 @@ class EngineTelemetry:
                    f"{padding['waste']}")
         out.append("# TYPE crowdllama_useful_tokens_total counter")
         out.append(f"crowdllama_useful_tokens_total {padding['useful']}")
+        out.append("# TYPE crowdllama_prefix_tokens_reused_total counter")
+        out.append(f"crowdllama_prefix_tokens_reused_total "
+                   f"{prefix['tokens_reused']}")
+        out.append("# TYPE crowdllama_prefix_hits_total counter")
+        out.append(f"crowdllama_prefix_hits_total {prefix['hits']}")
+        out.append("# TYPE crowdllama_prompt_tokens_total counter")
+        out.append(f"crowdllama_prompt_tokens_total "
+                   f"{prefix['prompt_tokens']}")
+        out.append("# TYPE crowdllama_engine_flight_seconds_total counter")
+        for cls in FLIGHT_CLASSES:
+            out.append(f'crowdllama_engine_flight_seconds_total{{'
+                       f'dispatch="{cls}"}} {flight_seconds[cls]:.6f}')
+        out.append("# TYPE crowdllama_engine_flight_steps_total counter")
+        for cls in FLIGHT_CLASSES:
+            out.append(f'crowdllama_engine_flight_steps_total{{'
+                       f'dispatch="{cls}"}} {flight_steps[cls]}')
+        out.append("# TYPE crowdllama_startup_seconds gauge")
+        for phase in STARTUP_PHASES:
+            out.append(f'crowdllama_startup_seconds{{phase="{phase}"}} '
+                       f'{startup.get(phase, 0.0):.3f}')
         out.append("# TYPE crowdllama_prefill_chunk_seconds histogram")
         out.extend(self.prefill_chunk_seconds.lines(
             "crowdllama_prefill_chunk_seconds"))

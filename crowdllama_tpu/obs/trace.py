@@ -20,6 +20,30 @@ from dataclasses import dataclass, field
 from typing import Any
 
 
+# Requests the ring holds by default: a benchmark window of the busiest
+# traffic there is (some 160 turns, with its ramp and tail) several times
+# over.  A record is a few hundred bytes (an id, two stamps, a meta dict and
+# up to six small spans), so a full ring is well under a megabyte.
+DEFAULT_TRACE_CAPACITY = 1024
+
+# The worker's request spans (docs/OBSERVABILITY.md, span catalogue).
+SPAN_WORKER_QUEUE = "worker_queue"
+SPAN_PREFILL = "prefill"
+SPAN_DISPATCH_WAIT = "dispatch_wait"    # child of prefill
+SPAN_PREFILL_EXEC = "prefill_exec"      # child of prefill
+SPAN_DECODE_STEP = "decode_step"
+
+# The scheduler's loop phases as jax.profiler.TraceAnnotation names: host
+# events on the profiler's clock, so a device trace's idle gaps can be
+# attributed to what the scheduler was doing (engine/scheduler.py).
+SCHED_WAIT_FOR_WORK = "sched.wait_for_work"
+SCHED_ADMIT = "sched.admit"
+SCHED_DISPATCH = "sched.dispatch"      # + ".<runner call>" on the executor
+SCHED_READBACK = "sched.readback"
+SCHED_EMIT = "sched.emit"
+SCHED_YIELD = "sched.yield"            # the loop's turn given to other tasks
+
+
 def new_trace_id() -> str:
     """64-bit random hex id, minted at the gateway per inference request."""
     return os.urandom(8).hex()
@@ -82,8 +106,8 @@ class TraceBuffer:
     lightly-loaded worker must not serve week-old fragments to the trace
     collector as if they described the request being debugged."""
 
-    def __init__(self, capacity: int = 64, node: str = "",
-                 ttl: float = 0.0) -> None:
+    def __init__(self, capacity: int = DEFAULT_TRACE_CAPACITY,
+                 node: str = "", ttl: float = 0.0) -> None:
         self.capacity = max(1, int(capacity))
         self.node = node
         self.ttl = max(0.0, float(ttl))

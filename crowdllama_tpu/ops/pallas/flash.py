@@ -210,6 +210,7 @@ def flash_prefill_attention(
                                lambda bi, hi, qi: (bi, qi, hi, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, t, hkv, g, dh), q.dtype),
         interpret=_interpret(),
+        name="flash_prefill_attention",
     )(window, qg, k, v, qpos, kpos, valid)
     return out.reshape(b, t, h, dh)
 
@@ -327,5 +328,6 @@ def flash_decode_attention(
                                lambda bi, hi: (bi, hi, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, dh), q.dtype),
         interpret=_interpret(),
+        name="flash_decode_attention",
     )(window, seq_lens, qg, k_cache, v_cache)
     return out.reshape(b, h, dh)

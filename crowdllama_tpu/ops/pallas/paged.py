@@ -292,6 +292,7 @@ def flash_paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, dh), q.dtype),
         interpret=_interpret(),
+        name="paged_decode_attention",
     )(table, seq_lens, window, *operands)
     return out.reshape(b, h, dh)
 
@@ -526,6 +527,7 @@ def flash_ragged_chunk_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((hkv, qblocks * qb, g, dh), q.dtype),
         interpret=_interpret(),
+        name="ragged_chunk_attention",
     )(pages, info, *operands)
     return out[:, :c].transpose(1, 0, 2, 3).reshape(c, h, dh)
 
@@ -838,6 +840,7 @@ def flash_ragged_paged_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nb, hkv, qb, g, dh), q.dtype),
         interpret=_interpret(),
+        name="ragged_paged_attention",
     )(blk_table, blk_info, window, *operands)
     out_dec = out[:b, :, 0].reshape(b, h, dh)
     out_chunk = out[b:].transpose(1, 0, 2, 3, 4).reshape(

@@ -150,7 +150,10 @@ def qeinsum(subscript: str, x: jnp.ndarray, w, dtype=None) -> jnp.ndarray:
 def qragged_dot(xs: jnp.ndarray, w, group_sizes: jnp.ndarray) -> jnp.ndarray:
     """``lax.ragged_dot`` against a possibly-quantized expert bank
     ([E, d_in, d_out])."""
-    return jax.lax.ragged_dot(xs, dequant(w), group_sizes)
+    with jax.named_scope("bank_dequant"):
+        bank = dequant(w)
+    with jax.named_scope("ragged_dot"):
+        return jax.lax.ragged_dot(xs, bank, group_sizes)
 
 
 def quantize_params(params: Params, extra_keys: tuple[str, ...] = ("lm_head",),

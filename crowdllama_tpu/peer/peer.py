@@ -33,7 +33,7 @@ from crowdllama_tpu.core.resource import Resource
 from crowdllama_tpu.engine.engine import Engine
 from crowdllama_tpu.net.discovery import discover_peers, new_host_and_dht, request_peer_metadata
 from crowdllama_tpu.net.host import Stream
-from crowdllama_tpu.obs import NodeObs
+from crowdllama_tpu.obs import DEFAULT_TRACE_CAPACITY, NodeObs
 from crowdllama_tpu.peermanager.manager import PeerHealthConfig, PeerManager
 from crowdllama_tpu.utils.aio import run_every
 from crowdllama_tpu.version import VERSION
@@ -128,7 +128,8 @@ class Peer:
         # Per-node observability plane (trace ring + histograms): served by
         # obs/http.ObsServer on workers, read directly by tests/benches.
         self.obs = NodeObs(
-            trace_capacity=getattr(config, "trace_buffer", 64) or 64,
+            trace_capacity=getattr(config, "trace_buffer",
+                                   DEFAULT_TRACE_CAPACITY),
             node="worker" if worker_mode else "consumer",
             trace_ttl=getattr(config, "trace_ttl", 0.0) or 0.0,
             exemplars=bool(getattr(config, "metrics_exemplars", False)))

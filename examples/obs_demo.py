@@ -92,7 +92,8 @@ async def main() -> int:
             if line.startswith(_EXCERPT_PREFIXES):
                 print(line)
         print("\n(full exposition also carries every worker histogram; "
-              "drill into a slow worker with GET /debug/profile?seconds=N "
+              "drill into a slow worker with POST /debug/profile/start|stop on "
+              "its --worker-metrics-port "
               "— see docs/OBSERVABILITY.md, 'Swarm observatory')")
         return 0
     finally:

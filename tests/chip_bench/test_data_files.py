@@ -77,13 +77,15 @@ def test_moves_names_an_end_to_end_metric_of_every_cell_it_is_in(metric):
 
 def test_contract_shape():
     assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
-                          "workloads", "end_to_end", "per_layer"}
+                          "workloads", "end_to_end", "per_layer",
+                          "trace_in_run"}
+    assert BENCH["trace_in_run"] is True
     assert 1 <= BENCH["run_seconds"] <= 51
     names = [m["name"] for g in ("end_to_end", "per_layer") for m in BENCH[g]]
     assert len(names) == len(set(names)) and all(map(NAME.match, names))
     layers = {m["layer"] for m in BENCH["per_layer"]}
     assert layers <= {"client", "gateway", "p2p plane", "scheduler",
-                      "engine step", "kernels", "device"}
+                      "engine start", "engine step", "kernels", "device"}
     for m in BENCH["end_to_end"]:
         assert 0.01 <= m["bound"] <= 0.1
         assert m["source"] in ("host_clock", "device_trace")

@@ -159,3 +159,40 @@ def test_a_conversation_ends_at_max_turns_and_a_new_one_takes_the_seat():
 def test_unknown_generator_is_an_error():
     with pytest.raises(ValueError):
         generators.build_plan({"generator": "no_such_kind"}, {**CTX, "seed": 1})
+
+
+@pytest.mark.parametrize("mix", MIXES)
+def test_a_traced_tail_leaves_the_plan_before_it_as_it_is(mix):
+    """``--trace 2`` traces a tail of the same traffic after the window:
+    the ladder, every actor there was and every turn due before the
+    window's end — arrivals, sizes, words, the ``check`` marks, and the
+    actor indices the sampling seeds come from — stay what they are."""
+    def rows(tail):
+        plan = generators.build_plan(
+            traffic(mix), {**CTX, "seed": 11, "tail_s": tail})
+        ladder = [(len(t.prompt_ids), t.max_tokens, tuple(t.prompt_ids))
+                  for rung in plan.ladder
+                  for t in (rung if isinstance(rung, list) else [rung])]
+        turns = []
+        for i, a in enumerate(plan.actors):
+            reply = None
+            for n in range(3):
+                t = a.next_turn(reply)
+                if t is None:
+                    break
+                turns.append((i, n, t.due, round(t.think, 9), t.max_tokens,
+                              t.greedy, t.check, t.tag, tuple(t.prompt_ids)))
+                reply = [0] * t.max_tokens
+        return ladder, turns, plan.ramp_s, plan.checked
+
+    plain, tailed = rows(0.0), rows(12.0)
+    assert tailed[0] == plain[0] and tailed[2:] == plain[2:]
+    before = [r for r in tailed[1] if r[2] is None or r[2] < CTX["seconds"]]
+    assert before == plain[1]
+    extra = [r for r in tailed[1] if r not in plain[1]]
+    if traffic(mix)["generator"] == "open_poisson":
+        assert extra and all(
+            CTX["seconds"] <= r[2] < CTX["seconds"] + 12.0
+            and r[7] == "tail" and not r[6] for r in extra)
+    else:       # a closed loop's actors simply go on
+        assert not extra
