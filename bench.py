@@ -1152,15 +1152,16 @@ def _kernel_parity_phase() -> dict:
         rng.permutation(pool_pages)[: b * np_].reshape(b, np_), jnp.int32)
     kg = pool_k[table].transpose(0, 2, 1, 3, 4).reshape(b, hkv, t, dh)
     vg = pool_v[table].transpose(0, 2, 1, 3, 4).reshape(b, hkv, t, dh)
-    got = flash_paged_decode_attention(qd, pool_k, pool_v, table,
-                                       seq_lens, scale)
+    got = flash_paged_decode_attention(qd, pool_k[None], pool_v[None], 0,
+                                       table, seq_lens, scale)
     want = A.decode_attention_ref(qd, kg, vg, seq_lens, scale)
     checks["paged_decode"] = err(got, want)
 
     k_i8, k_sc = quantize_kv(pool_k)
     v_i8, v_sc = quantize_kv(pool_v)
-    got = flash_paged_decode_attention(qd, k_i8, v_i8, table, seq_lens,
-                                       scale, k_scale=k_sc, v_scale=v_sc)
+    got = flash_paged_decode_attention(qd, k_i8[None], v_i8[None], 0, table,
+                                       seq_lens, scale, k_scale=k_sc[None],
+                                       v_scale=v_sc[None])
     ksg = k_sc[table].transpose(0, 2, 1, 3).reshape(b, hkv, t)
     vsg = v_sc[table].transpose(0, 2, 1, 3).reshape(b, hkv, t)
     want = A.decode_attention_q(qd, k_i8[table].transpose(0, 2, 1, 3, 4)

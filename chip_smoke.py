@@ -452,24 +452,27 @@ def kernel_parity_phase(sz: Sizes) -> None:
                                        sliding_window=win, kv_valid=valid)
         errs[name] = _max_err(got[:, :plen], want[:, :plen])
 
-    # one shared paged pool, bf16 and int8
+    # one shared paged pool of two layers, bf16 and int8: the kernels read
+    # layer 1 of the stack, the references the slice taken beforehand.
     pool_pages = b * np_ + 1
-    pool_k = jax.random.normal(ks[3], (pool_pages, hkv, page, dh), dt)
-    pool_v = jax.random.normal(ks[4], (pool_pages, hkv, page, dh), dt)
+    layer = 1
+    stack_k = jax.random.normal(ks[3], (2, pool_pages, hkv, page, dh), dt)
+    stack_v = jax.random.normal(ks[4], (2, pool_pages, hkv, page, dh), dt)
     rng = np.random.default_rng(3)
     table = jnp.asarray(rng.permutation(pool_pages - 1)[: b * np_]
                         .reshape(b, np_), jnp.int32)
-    k8, ksc = quantize_kv(pool_k)
-    v8, vsc = quantize_kv(pool_v)
-    pools = {"bf16": (pool_k, pool_v, None, None),
+    k8, ksc = quantize_kv(stack_k)
+    v8, vsc = quantize_kv(stack_v)
+    pools = {"bf16": (stack_k, stack_v, None, None),
              "int8": (k8, v8, ksc, vsc)}
 
-    def view(pool):  # [P, Hkv, page, Dh] -> [B, Hkv, ctx, Dh]
-        return pool[table].transpose(0, 2, 1, 3, 4).reshape(
+    def view(pool):  # [L, P, Hkv, page, Dh] -> [B, Hkv, ctx, Dh]
+        return pool[layer][table].transpose(0, 2, 1, 3, 4).reshape(
             b, hkv, ctx_len, dh)
 
-    def sview(sc):   # [P, Hkv, page] -> [B, Hkv, ctx]
-        return sc[table].transpose(0, 2, 1, 3).reshape(b, hkv, ctx_len)
+    def sview(sc):   # [L, P, Hkv, page] -> [B, Hkv, ctx]
+        return sc[layer][table].transpose(0, 2, 1, 3).reshape(
+            b, hkv, ctx_len)
 
     # paged decode: slots at mixed lengths, one full, one single-token.
     qd = jax.random.normal(ks[5], (b, h, dh), dt)
@@ -478,7 +481,7 @@ def kernel_parity_phase(sz: Sizes) -> None:
     for kv_name, (pk, pv, sk, sv) in pools.items():
         for wname, win in (("", 0), ("_window", window)):
             got = flash_paged_decode_attention(
-                qd, pk, pv, table, lens, scale, sliding_window=win,
+                qd, pk, pv, layer, table, lens, scale, sliding_window=win,
                 k_scale=sk, v_scale=sv)
             if sk is None:
                 want = A.decode_attention(qd, view(pk), view(pv), lens,
@@ -510,21 +513,23 @@ def kernel_parity_phase(sz: Sizes) -> None:
     for kv_name, (pk, pv, sk, sv) in pools.items():
         # The ref reads the chunk's self block from explicit operands:
         # carve them out of the pool so both paths see identical values.
-        ck = pk[cpages, :, cpos % page].astype(jnp.float32)
-        cv = pv[cpages, :, cpos % page].astype(jnp.float32)
+        at = (layer, cpages, slice(None), cpos % page)
+        ck = pk[at].astype(jnp.float32)
+        cv = pv[at].astype(jnp.float32)
         if sk is not None:
-            ck = ck * sk[cpages, :, cpos % page].astype(jnp.float32)[..., None]
-            cv = cv * sv[cpages, :, cpos % page].astype(jnp.float32)[..., None]
+            ck = ck * sk[at].astype(jnp.float32)[..., None]
+            cv = cv * sv[at].astype(jnp.float32)[..., None]
         ck = ck.astype(dt).transpose(1, 0, 2)[None]
         cv = cv.astype(dt).transpose(1, 0, 2)[None]
         for wname, win in (("", 0), ("_window", window)):
             want = ragged_paged_attention_ref(
-                qr, ck, cv, pk, pv, table, q_lens, kv_lens,
+                qr, ck, cv, pk, pv, layer, table, q_lens, kv_lens,
                 jnp.int32(chunk_slot), scale, sliding_window=win,
                 k_scale=sk, v_scale=sv)
             got = flash_ragged_paged_attention(
-                qr, pk, pv, table, q_lens, kv_lens, jnp.int32(chunk_slot),
-                scale, sliding_window=win, k_scale=sk, v_scale=sv)
+                qr, pk, pv, layer, table, q_lens, kv_lens,
+                jnp.int32(chunk_slot), scale, sliding_window=win,
+                k_scale=sk, v_scale=sv)
             errs[f"ragged_{kv_name}{wname}"] = _max_err(
                 got[jnp.asarray(live)], want[jnp.asarray(live)])
 

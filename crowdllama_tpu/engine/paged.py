@@ -13,11 +13,14 @@ paged attention as the north star).  Here KV lives in a pool of fixed
   dispatch (tiny transfer); pages are allocated at insert (prompt pages)
   and before each decode chunk (growth), freed at release.
 - decode attention: the fused Pallas kernel (ops/pallas/paged.py) reads
-  pages straight from the pool via the scalar-prefetched page table —
-  no virtual-contiguous gather, so paging buys capacity AND streams the
-  minimum bytes.  tp>1 meshes run it per-shard via shard_map (the pool
-  is tp-sharded over kv heads); CPU falls back to the jnp gather view
-  (exact, static-shaped, just more HBM traffic).
+  pages straight from the stacked pool via the scalar-prefetched page
+  table and a layer index — no virtual-contiguous gather and no layer
+  slice, so paging buys capacity AND streams the minimum bytes.  The
+  step bodies carry the stack through their layer loop and write each
+  layer's K/V into it in place (``_put_rows`` / ``_put_chunk``).  tp>1
+  meshes run the kernel per-shard via shard_map (the pool is tp-sharded
+  over kv heads); CPU falls back to the jnp gather view (exact,
+  static-shaped, just more HBM traffic).
 - int8 pools (``kv_dtype="int8"``): pages are int8 with per-(position,
   kv-head) scales; the kernel dequantizes in-flight (K on the score
   plane, V folded into probabilities), and suffix prefill dequantizes
@@ -69,6 +72,70 @@ from crowdllama_tpu.ops.quant import quantize_kv
 from crowdllama_tpu.ops.rope import rope_table
 
 log = logging.getLogger("crowdllama.engine.paged")
+
+
+def _put_rows(pool, rows, *, layer, pages, offsets):
+    """``pool[layer, pages[i], :, offsets[i]] = rows[i]`` for every row, as
+    one dynamic-update-slice per row.
+
+    pool ``[L, P, Hkv, page, Dh]`` (or the ``[L, P, Hkv, page]`` scales),
+    rows ``[N, Hkv, Dh]`` (``[N, Hkv]``).  Not ``pool.at[...].set``: the
+    TPU's scatter wants the pool in a layout with the kv-head dim next to
+    minor, the attention kernel reads it row-major, and XLA then converts
+    the WHOLE stack between the two every layer.  A dynamic-update-slice
+    takes the buffer in the layout it has and updates it in place."""
+    tail = (0,) * (pool.ndim - 4)
+    for i in range(rows.shape[0]):
+        pool = jax.lax.dynamic_update_slice(
+            pool, jnp.expand_dims(rows[i], (0, 1, 3)),
+            (layer, pages[i], 0, offsets[i], *tail))
+    return pool
+
+
+def _put_chunk(pool, rows, *, layer, page_row, start, valid, dump_page):
+    """Write a prefill chunk's rows into its slot's pages, a page at a time:
+    ``pool[layer, page_row[p // page], :, p % page] = rows[:, p - start]``
+    for ``start <= p < start + valid``; nothing else is touched.
+
+    rows ``[Hkv, C, Dh]`` (``[Hkv, C]`` for the scales), kv-head-major like
+    a page.  The chunk may start anywhere in a page, so it meets at most
+    ``ceil(C / page) + 1`` of them; each is read, merged under the row
+    mask and written back with one dynamic-update-slice (in place, in the
+    pool's own layout — see :func:`_put_rows`).  A page the chunk does not
+    reach goes to ``dump_page`` unchanged."""
+    hkv, page = pool.shape[2:4]
+    c = rows.shape[1]
+    tail = (0,) * (pool.ndim - 4)  # Dh, or nothing for the scales
+    tile = (1, 1, hkv, page, *pool.shape[4:])
+    padded = jnp.pad(rows, ((0, 0), (page, page), *((0, 0),) * len(tail)))
+    first = start // page
+    for j in range(-(-c // page) + 1):
+        col = first + j
+        pos = col * page + jnp.arange(page)
+        ok = (pos >= start) & (pos < start + valid)
+        src = jax.lax.dynamic_slice(
+            padded, (0, col * page - start + page, *tail), tile[2:])
+        pid = jnp.where(ok.any(),
+                        page_row[jnp.minimum(col, page_row.shape[0] - 1)],
+                        dump_page)
+        at = (layer, pid, 0, 0, *tail)
+        old = jax.lax.dynamic_slice(pool, at, tile)
+        mask = ok.reshape(1, 1, 1, page, *(1,) * len(tail))
+        pool = jax.lax.dynamic_update_slice(
+            pool, jnp.where(mask, src[None, None], old), at)
+    return pool
+
+
+def _write_kv(put, pk, pv, ksc, vsc, k, v):
+    """The four pools after ``put(pool, rows)`` has written one layer's
+    fresh K/V — quantized first, scales alongside, where the pools are
+    int8 (``ksc``/``vsc`` are None otherwise and stay None)."""
+    if ksc is None:
+        return (put(pk, k.astype(pk.dtype)), put(pv, v.astype(pv.dtype)),
+                None, None)
+    kq, k_sc = quantize_kv(k, scale_dtype=ksc.dtype)
+    vq, v_sc = quantize_kv(v, scale_dtype=vsc.dtype)
+    return put(pk, kq), put(pv, vq), put(ksc, k_sc), put(vsc, v_sc)
 
 
 class PagesExhausted(ValueError):
@@ -251,7 +318,15 @@ class PagedModelRunner(ModelRunner):
             raise PagesExhausted(
                 f"kv pool exhausted: need {n} pages, "
                 f"{len(self._free_pages)} free (pool={self.total_pages})")
-        return [self._free_pages.pop() for _ in range(n)]
+        pages = [self._free_pages.pop() for _ in range(n)]
+        # A recycled page starts its next life uncounted.  Growth and
+        # imported pages are never given a count (``_free`` reads a missing
+        # one as 1), so a stale 0 from the last life would go to -1 at
+        # release, make the page's next prompt look unreferenced while it
+        # is live, and leave it in the index at -1: never evictable.
+        for p in pages:
+            self._page_refs.pop(p, None)
+        return pages
 
     def _evict_cached(self, n: int) -> None:
         """Drop LRU prefix-cache pages no live slot references until ``n``
@@ -606,7 +681,13 @@ class PagedModelRunner(ModelRunner):
         """One paged decode step as a ``lax.scan`` body closure — shared
         verbatim by the per-step program (``_decode_paged_impl``) and the
         megastep (``_decode_mega_paged_impl``) so the two paths cannot
-        drift (byte-identity contract, docs/MEGASTEP.md)."""
+        drift (byte-identity contract, docs/MEGASTEP.md).
+
+        The layer loop inside carries ``(x, pool_k, pool_v, k_scale,
+        v_scale)`` with the pools at their full ``[L, P+1, Hkv, page, Dh]``
+        and scans ``(layer params, window, layer index)``: layer ``li``
+        writes its K/V into the stack at ``li`` and attends over the stack
+        at ``li``, so the donated pool is never copied or rebuilt."""
         cfg = self.cfg
         pg = self.page_size
         b = self.max_slots
@@ -616,6 +697,7 @@ class PagedModelRunner(ModelRunner):
         cos, sin = rope_table(cfg.max_context_length, dh, cfg.rope_theta,
                           scaling=cfg.rope_scaling)
         windows = T.layer_sliding_windows(cfg)
+        layer_idx = jnp.arange(cfg.num_layers, dtype=jnp.int32)
         view_len = self.max_pages_per_slot * pg
         slot_idx = jnp.arange(b)
         quant = self.kv_dtype == "int8"
@@ -640,22 +722,20 @@ class PagedModelRunner(ModelRunner):
                                  self.total_pages)  # [B]
             offset = positions % pg
 
-            def body(x, scanned):
-                lp, pk, pv, ksc, vsc, window = scanned
+            def body(carry, scanned):
+                # The stacked pools ride the layer loop as CARRY: an
+                # in-place update of a loop-carried, donated buffer.  (As
+                # scanned xs/ys XLA rebuilt the whole stack every step:
+                # ROADMAP S7.)
+                x, pk, pv, ksc, vsc = carry
+                lp, window, li = scanned
                 pool = {}
 
                 @jax.named_scope("kv_write")
                 def write(k, v):
-                    if quant:
-                        kq, k_sc = quantize_kv(k, scale_dtype=ksc.dtype)
-                        vq, v_sc = quantize_kv(v, scale_dtype=vsc.dtype)
-                        return (pk.at[cur_page, :, offset].set(kq),
-                                pv.at[cur_page, :, offset].set(vq),
-                                ksc.at[cur_page, :, offset].set(k_sc),
-                                vsc.at[cur_page, :, offset].set(v_sc))
-                    return (pk.at[cur_page, :, offset].set(k.astype(pk.dtype)),
-                            pv.at[cur_page, :, offset].set(v.astype(pv.dtype)),
-                            None, None)
+                    put = partial(_put_rows, layer=li, pages=cur_page,
+                                  offsets=offset)
+                    return _write_kv(put, pk, pv, ksc, vsc, k, v)
 
                 def attn_fn(q, k, v):
                     pk2, pv2, ks2, vs2 = write(k, v)
@@ -667,25 +747,25 @@ class PagedModelRunner(ModelRunner):
                     if use_kernel:
                         if sharded:
                             return flash_paged_decode_attention_tp(
-                                q, pk2, pv2, page_table, lens, scale,
+                                q, pk2, pv2, li, page_table, lens, scale,
                                 self.mesh, softcap=cfg.attn_logit_softcap,
                                 sliding_window=window,
                                 k_scale=ks2, v_scale=vs2)
                         return flash_paged_decode_attention(
-                            q, pk2, pv2, page_table, lens, scale,
+                            q, pk2, pv2, li, page_table, lens, scale,
                             softcap=cfg.attn_logit_softcap,
                             sliding_window=window,
                             k_scale=ks2, v_scale=vs2)
                     # Virtual-contiguous view of each slot's pages.
-                    kc = pk2[page_table].transpose(0, 2, 1, 3, 4).reshape(
-                        b, hkv, view_len, dh)
-                    vc = pv2[page_table].transpose(0, 2, 1, 3, 4).reshape(
-                        b, hkv, view_len, dh)
+                    kc = pk2[li, page_table].transpose(
+                        0, 2, 1, 3, 4).reshape(b, hkv, view_len, dh)
+                    vc = pv2[li, page_table].transpose(
+                        0, 2, 1, 3, 4).reshape(b, hkv, view_len, dh)
                     if quant:
-                        ksg = ks2[page_table].transpose(0, 2, 1, 3).reshape(
-                            b, hkv, view_len)
-                        vsg = vs2[page_table].transpose(0, 2, 1, 3).reshape(
-                            b, hkv, view_len)
+                        ksg = ks2[li, page_table].transpose(
+                            0, 2, 1, 3).reshape(b, hkv, view_len)
+                        vsg = vs2[li, page_table].transpose(
+                            0, 2, 1, 3).reshape(b, hkv, view_len)
                         return decode_attention_q(
                             q, kc, ksg, vc, vsg, lens, scale,
                             softcap=cfg.attn_logit_softcap,
@@ -696,11 +776,12 @@ class PagedModelRunner(ModelRunner):
 
                 x = T.decode_layer_body(lp, cfg, x, positions, cos, sin,
                                         attn_fn)
-                return x, (pool["pk"], pool["pv"], pool["ks"], pool["vs"])
+                return (x, pool["pk"], pool["pv"], pool["ks"],
+                        pool["vs"]), None
 
-            x, (pool_k, pool_v, k_scale, v_scale) = jax.lax.scan(
-                body, x, (params["layers"], st.pool_k, st.pool_v,
-                          st.k_scale, st.v_scale, windows))
+            (x, pool_k, pool_v, k_scale, v_scale), _ = jax.lax.scan(
+                body, (x, st.pool_k, st.pool_v, st.k_scale, st.v_scale),
+                (params["layers"], windows, layer_idx))
             logits = T._unembed(params, cfg, x)
             with jax.named_scope("sample"):
                 carry, sub = split_slot_keys(st.keys)
@@ -756,8 +837,9 @@ class PagedModelRunner(ModelRunner):
         slot (rows 0..B-1, exactly the plain decode step's math) plus one
         prefill chunk of up to C tokens for ``chunk_slot`` (rows B..,
         exactly the monolithic chunk's math with the slot's pages as
-        cached context).  KV for all rows scatters into the shared pool in
-        the same layer pass, and attention runs through
+        cached context).  KV for all rows is written into the shared,
+        loop-carried pool stack in the same layer pass (decode rows one by
+        one, the chunk a page at a time), and attention runs through
         :func:`ragged_paged_attention` with per-sequence (q_len, kv_len)
         metadata.  Returns ``(new_state, (decode tokens [B], chunk logits
         [V], has_chunk))``.
@@ -766,13 +848,12 @@ class PagedModelRunner(ModelRunner):
         pg = self.page_size
         b = self.max_slots
         dh = cfg.resolved_head_dim()
-        hkv = cfg.num_kv_heads
         scale = T.attn_scale(cfg)
         cos, sin = rope_table(cfg.max_context_length, dh, cfg.rope_theta,
                               scaling=cfg.rope_scaling)
         windows = T.layer_sliding_windows(cfg)
+        layer_idx = jnp.arange(cfg.num_layers, dtype=jnp.int32)
         slot_idx = jnp.arange(b)
-        quant = self.kv_dtype == "int8"
         use_pallas = self.attention_paths["ragged_step"] != "jnp"
 
         def step(st: PagedDecodeState, xs):
@@ -786,16 +867,12 @@ class PagedModelRunner(ModelRunner):
             # Decode rows of inactive slots (including the chunk's own
             # still-inactive decode lane) write to the dump page, exactly
             # like the plain decode step; chunk rows past the valid length
-            # dump too.
+            # are not written at all.
             cur_page = jnp.where(st.active,
                                  page_table[slot_idx, positions_dec // pg],
                                  self.total_pages)
-            crow_ok = jnp.arange(c) < valid
-            cpages = jnp.where(crow_ok,
-                               page_table[chunk_slot, cpos // pg],
-                               self.total_pages)
-            wpages = jnp.concatenate([cur_page, cpages])
-            woffs = jnp.concatenate([positions_dec % pg, cpos % pg])
+            dec_offs = positions_dec % pg
+            chunk_pages = page_table[chunk_slot]
             q_lens = jnp.concatenate([
                 jnp.where(st.active, 1, 0).astype(jnp.int32),
                 valid.astype(jnp.int32)[None]])
@@ -803,22 +880,25 @@ class PagedModelRunner(ModelRunner):
                 lens_dec.astype(jnp.int32),
                 (ctx_i + valid).astype(jnp.int32)[None]])
 
-            def body(x, scanned):
-                lp, pk, pv, ksc, vsc, window = scanned
+            def body(carry, scanned):
+                # Pools as carry, written and read at layer ``li`` — see
+                # ``_paged_step_body``.
+                x, pk, pv, ksc, vsc = carry
+                lp, window, li = scanned
                 pool = {}
 
                 @jax.named_scope("kv_write")
                 def write(k, v):
-                    if quant:
-                        kq, k_sc = quantize_kv(k, scale_dtype=ksc.dtype)
-                        vq, v_sc = quantize_kv(v, scale_dtype=vsc.dtype)
-                        return (pk.at[wpages, :, woffs].set(kq),
-                                pv.at[wpages, :, woffs].set(vq),
-                                ksc.at[wpages, :, woffs].set(k_sc),
-                                vsc.at[wpages, :, woffs].set(v_sc))
-                    return (pk.at[wpages, :, woffs].set(k.astype(pk.dtype)),
-                            pv.at[wpages, :, woffs].set(v.astype(pv.dtype)),
-                            None, None)
+                    def put(pool, rows):
+                        # [B + C, Hkv, ...]: decode rows, then the chunk's.
+                        pool = _put_rows(pool, rows[:b], layer=li,
+                                         pages=cur_page, offsets=dec_offs)
+                        return _put_chunk(
+                            pool, jnp.swapaxes(rows[b:], 0, 1), layer=li,
+                            page_row=chunk_pages, start=ctx_i,
+                            valid=valid, dump_page=self.total_pages)
+
+                    return _write_kv(put, pk, pv, ksc, vsc, k, v)
 
                 def attn_fn(q, k, v):
                     pk2, pv2, ks2, vs2 = write(k, v)
@@ -833,7 +913,7 @@ class PagedModelRunner(ModelRunner):
                     chunk_k = k[b:].transpose(1, 0, 2)[None]
                     chunk_v = v[b:].transpose(1, 0, 2)[None]
                     return ragged_paged_attention(
-                        q, chunk_k, chunk_v, pk2, pv2, page_table,
+                        q, chunk_k, chunk_v, pk2, pv2, li, page_table,
                         q_lens, kv_lens, chunk_slot, scale,
                         softcap=cfg.attn_logit_softcap,
                         sliding_window=window, k_scale=ks2, v_scale=vs2,
@@ -841,11 +921,12 @@ class PagedModelRunner(ModelRunner):
 
                 x = T.decode_layer_body(lp, cfg, x, positions, cos, sin,
                                         attn_fn)
-                return x, (pool["pk"], pool["pv"], pool["ks"], pool["vs"])
+                return (x, pool["pk"], pool["pv"], pool["ks"],
+                        pool["vs"]), None
 
-            x, (pool_k, pool_v, k_scale, v_scale) = jax.lax.scan(
-                body, x, (params["layers"], st.pool_k, st.pool_v,
-                          st.k_scale, st.v_scale, windows))
+            (x, pool_k, pool_v, k_scale, v_scale), _ = jax.lax.scan(
+                body, (x, st.pool_k, st.pool_v, st.k_scale, st.v_scale),
+                (params["layers"], windows, layer_idx))
             # Unembed the B decode rows + ONE chunk row (the last valid
             # one) — the rest of the chunk never needs logits.
             x_last = x[b + jnp.clip(valid - 1, 0, c - 1)]

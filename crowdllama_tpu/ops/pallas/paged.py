@@ -15,6 +15,13 @@ carries (m, l, acc) in VMEM scratch across the sequential innermost
 grid dimension.  HBM traffic is one read of the LIVE pages (dead pages
 are compute-skipped) and one [Hkv, G, Dh] output write per slot.
 
+Every kernel takes the WHOLE stacked pool ``[L, P, Hkv, page, Dh]`` and a
+layer index (one more scalar-prefetch operand; the index maps return
+``(layer, page, 0, 0, 0)``), never a layer's slice: the engine carries
+the stack through its layer loop and updates it in place, and a kernel
+that wanted ``pool[l]`` would make XLA cut 34 MB out of it per layer
+per step (ROADMAP S7).  The page DMA is the same either way.
+
 int8 pools: K/V tiles stay int8 through the DMA (the bandwidth-bound
 bytes) and dequantize on the fly — K scales on the [Hkv, G, page] score
 plane,
@@ -60,6 +67,51 @@ _VMEM_TILE_BUDGET = 8 * 1024 * 1024
 
 def _pairs_bytes(hkv: int, page: int, dh: int, itemsize: int) -> int:
     return 2 * hkv * page * dh * itemsize  # one page's K + V tiles
+
+
+def _page_stream(pool_k, pool_v, k_scale, v_scale, np_: int, page_of):
+    """BlockSpecs + operands that stream ONE layer's pages out of the
+    stacked pool, for a grid whose LAST dimension walks a page-table row.
+
+    ``page_of(grid_i, idx, *scalar_refs)`` names the pool page a grid row
+    reads at table column ``idx``; the layer index is the last scalar-
+    prefetch operand.  Pages are fetched in pairs per sequential grid step
+    when the VMEM budget allows (tiles are double-buffered) — the grid is
+    bubble-bound at serving shapes, so halving its length is nearly free
+    bandwidth.  The tail pair index clamps to the last page; its compute
+    is skipped by the kernels' length bound.  Returns ``(pairs, steps,
+    in_specs, operands)``."""
+    _, _, hkv, page, dh = pool_k.shape
+    pairs = 2 if (np_ >= 2 and 4 * _pairs_bytes(
+        hkv, page, dh, pool_k.dtype.itemsize) <= _VMEM_TILE_BUDGET) else 1
+
+    # Index maps receive (grid indices..., *scalar-prefetch refs).
+    def kv_map_at(j, tail):
+        def kv_map(gi, pi, *refs):
+            idx = jnp.minimum(pi * pairs + j, np_ - 1)
+            return (refs[-1][0], page_of(gi, idx, *refs), *tail)
+        return kv_map
+
+    in_specs, operands = [], []
+    for j in range(pairs):
+        in_specs += [pl.BlockSpec((None, None, hkv, page, dh),
+                                  kv_map_at(j, (0, 0, 0)))] * 2
+        operands += [pool_k, pool_v]
+    if k_scale is not None:
+        # Scales arrive as the [Hkv, page] plane of one page — the block's
+        # last two dims are the array's, which Mosaic accepts — and the
+        # kernels lift it to [Hkv, 1, page].  Reshaping the [L, P, Hkv,
+        # page] stack to a unit sublane dim out here instead would have XLA
+        # re-tile the whole stack for every layer.
+        for j in range(pairs):
+            in_specs += [pl.BlockSpec((None, None, hkv, page),
+                                      kv_map_at(j, (0, 0)))] * 2
+            operands += [k_scale, v_scale]
+    return pairs, -(-np_ // pairs), in_specs, operands
+
+
+def _layer_operand(layer) -> jnp.ndarray:
+    return jnp.asarray(layer, jnp.int32).reshape(1)
 
 
 def paged_pallas_refusal(page_size: int, head_dim: int,
@@ -122,6 +174,7 @@ def _decode_kernel(
     table_ref,    # [B, NP] int32 — page table
     seqlen_ref,   # [B] int32 — valid positions incl. the pending token
     window_ref,   # [1] int32 — sliding window (<=0 disables)
+    layer_ref,    # [1] int32 — pool layer (read by the index maps only)
     # operands: q, then PAIRS x (k, v), then PAIRS x (ks, vs) if quant;
     # output + scratch trail (pallas passes refs positionally).
     q_ref,        # [Hkv, G, Dh] — ALL kv heads of this slot
@@ -174,7 +227,7 @@ def _decode_kernel(
             if quant:
                 # int8 K: per-position scales act on the score plane, so
                 # no dequantized [page, Dh] tensor materializes.
-                logits = logits * scs[2 * j][...].astype(jnp.float32)
+                logits = logits * scs[2 * j][...].astype(jnp.float32)[:, None]
             logits = _softcap(logits, softcap)
 
             mask = kpos < seq_len
@@ -189,7 +242,7 @@ def _decode_kernel(
             pr = jnp.exp(logits - m_new) * mask.astype(jnp.float32)
             l_new = l_prev * alpha + jnp.sum(pr, axis=-1, keepdims=True)
             if quant:
-                pr = pr * scs[2 * j + 1][...].astype(jnp.float32)
+                pr = pr * scs[2 * j + 1][...].astype(jnp.float32)[:, None]
             pv = jax.lax.dot_general(
                 pr, v_tile, (((2,), (1,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32,
@@ -210,19 +263,21 @@ def _decode_kernel(
 
 def flash_paged_decode_attention(
     q: jnp.ndarray,           # [B, H, Dh]
-    pool_k: jnp.ndarray,      # [P, Hkv, page, Dh] (bf16 or int8)
+    pool_k: jnp.ndarray,      # [L, P, Hkv, page, Dh] (bf16 or int8)
     pool_v: jnp.ndarray,
+    layer: int | jnp.ndarray,  # scalar int32 — the layer whose pages to read
     page_table: jnp.ndarray,  # [B, NP] int32
     seq_lens: jnp.ndarray,    # [B] int32 (incl. the pending token)
     scale: float,
     softcap: float = 0.0,
     sliding_window: int | jnp.ndarray = 0,
-    k_scale: jnp.ndarray | None = None,  # [P, Hkv, page] int8 pools only
+    k_scale: jnp.ndarray | None = None,  # [L, P, Hkv, page] int8 pools only
     v_scale: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
-    """One cached decode step over the paged pool; output [B, H, Dh]."""
+    """One cached decode step over layer ``layer`` of the stacked paged
+    pool; output [B, H, Dh]."""
     b, h, dh = q.shape
-    _, hkv, page, _ = pool_k.shape
+    _, _, hkv, page, _ = pool_k.shape
     g = h // hkv
     np_ = page_table.shape[1]
     quant = k_scale is not None
@@ -232,44 +287,12 @@ def flash_paged_decode_attention(
     seq_lens = seq_lens.astype(jnp.int32)
     window = jnp.asarray(sliding_window, jnp.int32).reshape(1)
 
-    # Pages fetched per sequential grid step: pair pages when the VMEM
-    # budget allows (tiles are double-buffered) — the grid is bubble-
-    # bound at serving shapes, so halving its length is nearly free
-    # bandwidth.  The tail pair index clamps to the last page; its
-    # compute is skipped by the seq_len bound.
-    itemsize = pool_k.dtype.itemsize
-    pairs = 2 if (np_ >= 2 and 4 * _pairs_bytes(hkv, page, dh, itemsize)
-                  <= _VMEM_TILE_BUDGET) else 1
-    steps = -(-np_ // pairs)  # ceil
-
-    # Index maps receive (grid indices..., *scalar-prefetch refs).
-    def q_map(bi, pi, tr, sr, wr):
+    def q_map(bi, pi, *refs):
         return (bi, 0, 0, 0)
 
-    def kv_map_at(j):
-        def kv_map(bi, pi, tr, sr, wr):
-            idx = jnp.minimum(pi * pairs + j, np_ - 1)
-            return (tr[bi, idx], 0, 0, 0)
-        return kv_map
-
-    in_specs = [pl.BlockSpec((None, hkv, g, dh), q_map)]
-    operands = [qg]
-    for j in range(pairs):
-        in_specs += [pl.BlockSpec((None, hkv, page, dh), kv_map_at(j))] * 2
-        operands += [pool_k, pool_v]
-    if quant:
-        # Scales block to a [Hkv, 1, page] tile per grid step.  Mosaic
-        # requires the block's last-two dims to divide (8, 128) or equal
-        # the array dims, so the pool-shaped [P, Hkv, page] scales carry
-        # an explicit unit sublane dim ([P, Hkv, 1, page]) — a squeezed
-        # dim in second-to-last position fails to lower on real TPU
-        # (caught by the first on-chip compile, BENCH r4).
-        ks4 = k_scale.reshape(*k_scale.shape[:2], 1, page)
-        vs4 = v_scale.reshape(*v_scale.shape[:2], 1, page)
-        for j in range(pairs):
-            in_specs += [pl.BlockSpec((None, hkv, 1, page),
-                                      kv_map_at(j))] * 2
-            operands += [ks4, vs4]
+    pairs, steps, kv_specs, kv_operands = _page_stream(
+        pool_k, pool_v, k_scale, v_scale, np_,
+        lambda bi, idx, tr, *refs: tr[bi, idx])
 
     kernel = functools.partial(
         _decode_kernel,
@@ -277,9 +300,9 @@ def flash_paged_decode_attention(
         pairs=pairs, quant=quant,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(b, steps),
-        in_specs=in_specs,
+        in_specs=[pl.BlockSpec((None, hkv, g, dh), q_map), *kv_specs],
         out_specs=pl.BlockSpec((None, hkv, g, dh), q_map),
         scratch_shapes=[
             pltpu.VMEM((hkv, g, dh), jnp.float32),
@@ -293,7 +316,7 @@ def flash_paged_decode_attention(
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, dh), q.dtype),
         interpret=_interpret(),
         name="paged_decode_attention",
-    )(table, seq_lens, window, *operands)
+    )(table, seq_lens, window, _layer_operand(layer), qg, *kv_operands)
     return out.reshape(b, h, dh)
 
 
@@ -345,6 +368,7 @@ def _chunk_kernel(
     # scalar prefetch
     pages_ref,    # [NP] int32 — the chunk slot's page-table row
     info_ref,     # [3] int32 — (ctx, kv_len, window)
+    layer_ref,    # [1] int32 — pool layer (read by the index maps only)
     # operands: q, then PAIRS x (k, v), then PAIRS x (ks, vs) if quant
     q_ref,        # [Hkv, QB, G, Dh] — one query block of the chunk
     *refs,
@@ -404,7 +428,7 @@ def _chunk_kernel(
                 preferred_element_type=jnp.float32,
             ) * scale
             if quant:
-                logits = logits * scs[2 * j][...].astype(jnp.float32)
+                logits = logits * scs[2 * j][...].astype(jnp.float32)[:, None]
             logits = _softcap(logits, softcap)
 
             mask = (kpos < kv_len) & (kpos <= qpos)
@@ -419,7 +443,7 @@ def _chunk_kernel(
             pr = jnp.exp(logits - m_new) * mask.astype(jnp.float32)
             l_new = l_prev * alpha + jnp.sum(pr, axis=-1, keepdims=True)
             if quant:
-                pr = pr * scs[2 * j + 1][...].astype(jnp.float32)
+                pr = pr * scs[2 * j + 1][...].astype(jnp.float32)[:, None]
             pv = jax.lax.dot_general(
                 pr, v_tile, (((2,), (1,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32,
@@ -441,25 +465,27 @@ def _chunk_kernel(
 
 def flash_ragged_chunk_attention(
     q: jnp.ndarray,           # [C, H, Dh] — the chunk's query rows
-    pool_k: jnp.ndarray,      # [P, Hkv, page, Dh]
+    pool_k: jnp.ndarray,      # [L, P, Hkv, page, Dh]
     pool_v: jnp.ndarray,
+    layer: int | jnp.ndarray,  # scalar int32 — the layer whose pages to read
     pages: jnp.ndarray,       # [NP] int32 — the chunk slot's page row
     ctx_len: jnp.ndarray,     # scalar int32 — tokens already in the pool
     kv_len: jnp.ndarray,      # scalar int32 — ctx_len + valid chunk rows
     scale: float,
     softcap: float = 0.0,
     sliding_window: int | jnp.ndarray = 0,
-    k_scale: jnp.ndarray | None = None,
+    k_scale: jnp.ndarray | None = None,  # [L, P, Hkv, page]
     v_scale: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
-    """One prefill chunk's attention over its slot's paged KV.
+    """One prefill chunk's attention over its slot's paged KV in layer
+    ``layer`` of the stacked pool.
 
     The chunk's own K/V must already be scattered into the pool (the
     engine writes them in the same step); query row j attends kv
     positions < ctx_len + j + 1.  Rows past the valid chunk length
     produce garbage the caller drops.  Output [C, H, Dh]."""
     c, h, dh = q.shape
-    _, hkv, page, _ = pool_k.shape
+    _, _, hkv, page, _ = pool_k.shape
     g = h // hkv
     np_ = pages.shape[0]
     quant = k_scale is not None
@@ -479,32 +505,12 @@ def flash_ragged_chunk_attention(
     ])
     pages = pages.astype(jnp.int32)
 
-    itemsize = pool_k.dtype.itemsize
-    pairs = 2 if (np_ >= 2 and 4 * _pairs_bytes(hkv, page, dh, itemsize)
-                  <= _VMEM_TILE_BUDGET) else 1
-    steps = -(-np_ // pairs)
-
-    def q_map(qi, pi, pr, ir):
+    def q_map(qi, pi, *refs):
         return (0, qi, 0, 0)
 
-    def kv_map_at(j):
-        def kv_map(qi, pi, pr, ir):
-            idx = jnp.minimum(pi * pairs + j, np_ - 1)
-            return (pr[idx], 0, 0, 0)
-        return kv_map
-
-    in_specs = [pl.BlockSpec((hkv, qb, g, dh), q_map)]
-    operands = [qx]
-    for j in range(pairs):
-        in_specs += [pl.BlockSpec((None, hkv, page, dh), kv_map_at(j))] * 2
-        operands += [pool_k, pool_v]
-    if quant:
-        ks4 = k_scale.reshape(*k_scale.shape[:2], 1, page)
-        vs4 = v_scale.reshape(*v_scale.shape[:2], 1, page)
-        for j in range(pairs):
-            in_specs += [pl.BlockSpec((None, hkv, 1, page),
-                                      kv_map_at(j))] * 2
-            operands += [ks4, vs4]
+    pairs, steps, kv_specs, kv_operands = _page_stream(
+        pool_k, pool_v, k_scale, v_scale, np_,
+        lambda qi, idx, pr, *refs: pr[idx])
 
     kernel = functools.partial(
         _chunk_kernel,
@@ -512,9 +518,9 @@ def flash_ragged_chunk_attention(
         pairs=pairs, quant=quant,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(qblocks, steps),
-        in_specs=in_specs,
+        in_specs=[pl.BlockSpec((hkv, qb, g, dh), q_map), *kv_specs],
         out_specs=pl.BlockSpec((hkv, qb, g, dh), q_map),
         scratch_shapes=[
             pltpu.VMEM((hkv, qb * g, dh), jnp.float32),
@@ -528,7 +534,7 @@ def flash_ragged_chunk_attention(
         out_shape=jax.ShapeDtypeStruct((hkv, qblocks * qb, g, dh), q.dtype),
         interpret=_interpret(),
         name="ragged_chunk_attention",
-    )(pages, info, *operands)
+    )(pages, info, _layer_operand(layer), qx, *kv_operands)
     return out[:, :c].transpose(1, 0, 2, 3).reshape(c, h, dh)
 
 
@@ -536,8 +542,9 @@ def ragged_paged_attention_ref(
     q: jnp.ndarray,            # [B + C, H, Dh] — decode rows then chunk rows
     chunk_k: jnp.ndarray,      # [1, Hkv, C, Dh] — the chunk's fresh keys
     chunk_v: jnp.ndarray,      # [1, Hkv, C, Dh]
-    pool_k: jnp.ndarray,       # [P, Hkv, page, Dh]
+    pool_k: jnp.ndarray,       # [L, P, Hkv, page, Dh]
     pool_v: jnp.ndarray,
+    layer: int | jnp.ndarray,  # scalar int32 — the layer whose pages to read
     page_table: jnp.ndarray,   # [B, NP] int32
     q_lens: jnp.ndarray,       # [B + 1] int32 — per-sequence query lengths
     kv_lens: jnp.ndarray,      # [B + 1] int32 — incl. this step's tokens
@@ -550,7 +557,8 @@ def ragged_paged_attention_ref(
 ) -> jnp.ndarray:
     """Pure-JAX unified ragged batch attention (reference semantics).
 
-    One call covers B+1 ragged sequences over the same paged pool: B
+    One call covers B+1 ragged sequences over layer ``layer`` of the same
+    stacked paged pool (the gathers index ``pool[layer, pages]``): B
     decode sequences (q_len 0 or 1, rows 0..B-1) plus one prefill-chunk
     sequence (q_len = q_lens[B] <= C, rows B..).  Query i of sequence s
     attends kv positions < kv_lens[s] - q_lens[s] + i + 1.
@@ -569,19 +577,21 @@ def ragged_paged_attention_ref(
 
     b = page_table.shape[0]
     c = chunk_k.shape[2]
-    _, hkv, page, dh = pool_k.shape
+    _, _, hkv, page, dh = pool_k.shape
     np_ = page_table.shape[1]
     w = np_ * page
     quant = k_scale is not None
 
     # --- decode rows: identical to the plain paged decode fallback ---
-    view_k = pool_k[page_table].transpose(0, 2, 1, 3, 4).reshape(
+    view_k = pool_k[layer, page_table].transpose(0, 2, 1, 3, 4).reshape(
         b, hkv, w, dh)
-    view_v = pool_v[page_table].transpose(0, 2, 1, 3, 4).reshape(
+    view_v = pool_v[layer, page_table].transpose(0, 2, 1, 3, 4).reshape(
         b, hkv, w, dh)
     if quant:
-        vs_k = k_scale[page_table].transpose(0, 2, 1, 3).reshape(b, hkv, w)
-        vs_v = v_scale[page_table].transpose(0, 2, 1, 3).reshape(b, hkv, w)
+        vs_k = k_scale[layer, page_table].transpose(0, 2, 1, 3).reshape(
+            b, hkv, w)
+        vs_v = v_scale[layer, page_table].transpose(0, 2, 1, 3).reshape(
+            b, hkv, w)
         out_dec = decode_attention_q(
             q[:b], view_k, vs_k, view_v, vs_v, kv_lens[:b], scale,
             softcap=softcap, sliding_window=sliding_window)
@@ -592,15 +602,15 @@ def ragged_paged_attention_ref(
 
     # --- chunk rows: prefix pages as cached context + fresh self block ---
     ctx = kv_lens[b] - q_lens[b]
-    cpk = pool_k[page_table[chunk_slot]]
-    cpv = pool_v[page_table[chunk_slot]]
+    cpk = pool_k[layer, page_table[chunk_slot]]
+    cpv = pool_v[layer, page_table[chunk_slot]]
     ctx_k = cpk.transpose(1, 0, 2, 3).reshape(1, hkv, w, dh)
     ctx_v = cpv.transpose(1, 0, 2, 3).reshape(1, hkv, w, dh)
     if quant:
-        csk = k_scale[page_table[chunk_slot]].transpose(1, 0, 2).reshape(
-            1, hkv, w, 1)
-        csv = v_scale[page_table[chunk_slot]].transpose(1, 0, 2).reshape(
-            1, hkv, w, 1)
+        csk = k_scale[layer, page_table[chunk_slot]].transpose(
+            1, 0, 2).reshape(1, hkv, w, 1)
+        csv = v_scale[layer, page_table[chunk_slot]].transpose(
+            1, 0, 2).reshape(1, hkv, w, 1)
         ctx_k = ctx_k.astype(jnp.float32) * csk.astype(jnp.float32)
         ctx_v = ctx_v.astype(jnp.float32) * csv.astype(jnp.float32)
     kvpos = jnp.arange(w)[None, :]
@@ -620,6 +630,7 @@ def _ragged_v2_kernel(
     table_ref,    # [NB, NP] int32 — page-table row per query block
     info_ref,     # [NB, 3] int32 — (q_start, kv_len, q_valid) per block
     window_ref,   # [1] int32 — sliding window (<=0 disables)
+    layer_ref,    # [1] int32 — pool layer (read by the index maps only)
     # operands: q, then PAIRS x (k, v), then PAIRS x (ks, vs) if quant
     q_ref,        # [Hkv, QB, G, Dh] — one head-packed query block
     *refs,
@@ -696,7 +707,7 @@ def _ragged_v2_kernel(
                 preferred_element_type=jnp.float32,
             ) * scale
             if quant:
-                logits = logits * scs[2 * j][...].astype(jnp.float32)
+                logits = logits * scs[2 * j][...].astype(jnp.float32)[:, None]
             logits = _softcap(logits, softcap)
 
             mask = row_ok & (kpos < kv_len) & (kpos <= qpos)
@@ -711,7 +722,7 @@ def _ragged_v2_kernel(
             pr = jnp.exp(logits - m_new) * mask.astype(jnp.float32)
             l_new = l_prev * alpha + jnp.sum(pr, axis=-1, keepdims=True)
             if quant:
-                pr = pr * scs[2 * j + 1][...].astype(jnp.float32)
+                pr = pr * scs[2 * j + 1][...].astype(jnp.float32)[:, None]
             pv = jax.lax.dot_general(
                 pr, v_tile, (((2,), (1,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32,
@@ -733,8 +744,9 @@ def _ragged_v2_kernel(
 
 def flash_ragged_paged_attention(
     q: jnp.ndarray,            # [B + C, H, Dh] — decode rows then chunk rows
-    pool_k: jnp.ndarray,       # [P, Hkv, page, Dh]
+    pool_k: jnp.ndarray,       # [L, P, Hkv, page, Dh]
     pool_v: jnp.ndarray,
+    layer: int | jnp.ndarray,  # scalar int32 — the layer whose pages to read
     page_table: jnp.ndarray,   # [B, NP] int32
     q_lens: jnp.ndarray,       # [B + 1] int32
     kv_lens: jnp.ndarray,      # [B + 1] int32
@@ -753,10 +765,11 @@ def flash_ragged_paged_attention(
     ceil(C/QB)`` uniform head-packed query blocks whose behavior is
     driven entirely by a scalar-prefetched ``(q_start, kv_len, q_valid)``
     row and a per-block page-table row (decode block n gets slot n's
-    row; every chunk block gets ``chunk_slot``'s).  The chunk's fresh KV
-    must already be scattered into the pool.  Output [B + C, H, Dh]."""
+    row; every chunk block gets ``chunk_slot``'s).  Pages come from layer
+    ``layer`` of the stacked pool; the chunk's fresh KV must already be
+    scattered into it.  Output [B + C, H, Dh]."""
     bc, h, dh = q.shape
-    _, hkv, page, _ = pool_k.shape
+    _, _, hkv, page, _ = pool_k.shape
     g = h // hkv
     b = page_table.shape[0]
     c = bc - b
@@ -792,32 +805,12 @@ def flash_ragged_paged_attention(
         [q_start, kv_len_blk, q_valid], axis=1).astype(jnp.int32)
     window = jnp.asarray(sliding_window, jnp.int32).reshape(1)
 
-    itemsize = pool_k.dtype.itemsize
-    pairs = 2 if (np_ >= 2 and 4 * _pairs_bytes(hkv, page, dh, itemsize)
-                  <= _VMEM_TILE_BUDGET) else 1
-    steps = -(-np_ // pairs)
-
-    def q_map(ni, pi, tr, ir, wr):
+    def q_map(ni, pi, *refs):
         return (ni, 0, 0, 0, 0)
 
-    def kv_map_at(j):
-        def kv_map(ni, pi, tr, ir, wr):
-            idx = jnp.minimum(pi * pairs + j, np_ - 1)
-            return (tr[ni, idx], 0, 0, 0)
-        return kv_map
-
-    in_specs = [pl.BlockSpec((None, hkv, qb, g, dh), q_map)]
-    operands = [qx]
-    for j in range(pairs):
-        in_specs += [pl.BlockSpec((None, hkv, page, dh), kv_map_at(j))] * 2
-        operands += [pool_k, pool_v]
-    if quant:
-        ks4 = k_scale.reshape(*k_scale.shape[:2], 1, page)
-        vs4 = v_scale.reshape(*v_scale.shape[:2], 1, page)
-        for j in range(pairs):
-            in_specs += [pl.BlockSpec((None, hkv, 1, page),
-                                      kv_map_at(j))] * 2
-            operands += [ks4, vs4]
+    pairs, steps, kv_specs, kv_operands = _page_stream(
+        pool_k, pool_v, k_scale, v_scale, np_,
+        lambda ni, idx, tr, *refs: tr[ni, idx])
 
     kernel = functools.partial(
         _ragged_v2_kernel,
@@ -825,9 +818,9 @@ def flash_ragged_paged_attention(
         pairs=pairs, quant=quant,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(nb, steps),
-        in_specs=in_specs,
+        in_specs=[pl.BlockSpec((None, hkv, qb, g, dh), q_map), *kv_specs],
         out_specs=pl.BlockSpec((None, hkv, qb, g, dh), q_map),
         scratch_shapes=[
             pltpu.VMEM((hkv, qb * g, dh), jnp.float32),
@@ -841,7 +834,7 @@ def flash_ragged_paged_attention(
         out_shape=jax.ShapeDtypeStruct((nb, hkv, qb, g, dh), q.dtype),
         interpret=_interpret(),
         name="ragged_paged_attention",
-    )(blk_table, blk_info, window, *operands)
+    )(blk_table, blk_info, window, _layer_operand(layer), qx, *kv_operands)
     out_dec = out[:b, :, 0].reshape(b, h, dh)
     out_chunk = out[b:].transpose(1, 0, 2, 3, 4).reshape(
         hkv, jblocks * qb, g, dh)[:, :c].transpose(1, 0, 2, 3).reshape(
@@ -853,8 +846,9 @@ def ragged_paged_attention(
     q: jnp.ndarray,            # [B + C, H, Dh]
     chunk_k: jnp.ndarray,      # [1, Hkv, C, Dh]
     chunk_v: jnp.ndarray,
-    pool_k: jnp.ndarray,
+    pool_k: jnp.ndarray,       # [L, P, Hkv, page, Dh]
     pool_v: jnp.ndarray,
+    layer: int | jnp.ndarray,  # scalar int32
     page_table: jnp.ndarray,   # [B, NP] int32
     q_lens: jnp.ndarray,       # [B + 1] int32
     kv_lens: jnp.ndarray,      # [B + 1] int32
@@ -866,7 +860,8 @@ def ragged_paged_attention(
     v_scale: jnp.ndarray | None = None,
     use_pallas: bool = False,
 ) -> jnp.ndarray:
-    """Unified ragged batch attention over the paged pool.
+    """Unified ragged batch attention over layer ``layer`` of the stacked
+    paged pool.
 
     ``use_pallas`` (a static flag the runner resolves via
     :func:`ragged_pallas_supported`) routes the whole mixed batch
@@ -880,19 +875,20 @@ def ragged_paged_attention(
     path / TP wrapper and as the per-population building blocks."""
     if not use_pallas:
         return ragged_paged_attention_ref(
-            q, chunk_k, chunk_v, pool_k, pool_v, page_table, q_lens,
+            q, chunk_k, chunk_v, pool_k, pool_v, layer, page_table, q_lens,
             kv_lens, chunk_slot, scale, softcap=softcap,
             sliding_window=sliding_window, k_scale=k_scale, v_scale=v_scale)
     return flash_ragged_paged_attention(
-        q, pool_k, pool_v, page_table, q_lens, kv_lens, chunk_slot,
+        q, pool_k, pool_v, layer, page_table, q_lens, kv_lens, chunk_slot,
         scale, softcap=softcap, sliding_window=sliding_window,
         k_scale=k_scale, v_scale=v_scale)
 
 
 def flash_paged_decode_attention_tp(
     q: jnp.ndarray,           # [B, H, Dh] — heads tp-sharded (kv-major)
-    pool_k: jnp.ndarray,      # [P, Hkv, page, Dh] — kv heads tp-sharded
+    pool_k: jnp.ndarray,      # [L, P, Hkv, page, Dh] — kv heads tp-sharded
     pool_v: jnp.ndarray,
+    layer: int | jnp.ndarray,  # scalar int32 (replicated)
     page_table: jnp.ndarray,  # [B, NP] int32 (replicated)
     seq_lens: jnp.ndarray,    # [B] int32 (replicated)
     scale: float,
@@ -920,19 +916,20 @@ def flash_paged_decode_attention_tp(
 
     window = jnp.asarray(sliding_window, jnp.int32).reshape(1)
     q_spec = P(None, AXIS_TP, None)
-    pool_spec = P(None, AXIS_TP, None, None)
-    sc_spec = P(None, AXIS_TP, None)
+    pool_spec = P(None, None, AXIS_TP, None, None)
+    sc_spec = P(None, None, AXIS_TP, None)
     rep = P(None)
 
-    args = (q, pool_k, pool_v, page_table, seq_lens, window)
-    in_specs = (q_spec, pool_spec, pool_spec, rep, rep, rep)
+    args = (q, pool_k, pool_v, _layer_operand(layer), page_table, seq_lens,
+            window)
+    in_specs = (q_spec, pool_spec, pool_spec, rep, rep, rep, rep)
     if k_scale is not None:
         args += (k_scale, v_scale)
         in_specs += (sc_spec, sc_spec)
 
-    def local(q, pk, pv, tbl, lens, win, *scales):
+    def local(q, pk, pv, lyr, tbl, lens, win, *scales):
         return flash_paged_decode_attention(
-            q, pk, pv, tbl, lens, scale, softcap=softcap,
+            q, pk, pv, lyr, tbl, lens, scale, softcap=softcap,
             sliding_window=win,
             k_scale=scales[0] if scales else None,
             v_scale=scales[1] if scales else None)

@@ -258,7 +258,8 @@ def test_paged_kernel_odd_page_count_tail(monkeypatch):
     assert 4 * _pairs_bytes(HKV, PAGE, DH, 4) <= _VMEM_TILE_BUDGET
     table = jnp.asarray([[0, 1, 2], [3, 4, 5]], jnp.int32)
     lens = jnp.asarray([70, 95], jnp.int32)  # partial last pages
-    out = flash_paged_decode_attention(q, pk, pv, table, lens, DH ** -0.5)
+    out = flash_paged_decode_attention(q, pk[None], pv[None], 0, table,
+                                       lens, DH ** -0.5)
     kc = pk[table].transpose(0, 2, 1, 3, 4).reshape(B, HKV, NP_ * PAGE, DH)
     vc = pv[table].transpose(0, 2, 1, 3, 4).reshape(B, HKV, NP_ * PAGE, DH)
     ref = decode_attention(q, kc, vc, lens, DH ** -0.5)
@@ -414,3 +415,101 @@ def test_paged_chunked_admission_seeds_from_prefix_cache():
         tB, state = pr.decode_steps(state, 5)
         t2, s2 = pr2.decode_steps(s2, 5)
         assert tB[:, 1].tolist() == t2[:, 1].tolist()
+
+
+@pytest.mark.parametrize("scales", [False, True])
+@pytest.mark.parametrize("start,valid", [
+    (0, 64),     # page-aligned, every row real
+    (32, 64),    # aligned start on the second page
+    (40, 64),    # starts mid-page: the chunk meets three pages
+    (40, 23),    # a short tail: rows past `valid` are not written
+    (96, 20),    # runs into the slot's last page
+    (64, 0),     # no chunk this step
+])
+def test_kv_write_helpers_match_the_scatter(start, valid, scales):
+    """The step bodies write KV with dynamic-update-slices (``_put_rows``
+    per decode row, ``_put_chunk`` per page of the prefill chunk) so the
+    stacked pool is updated in place in its own layout.  Both are, on the
+    positions that hold real tokens, exactly ``pool.at[layer, page, :,
+    offset].set(row)`` — and touch no other position of a real page."""
+    import jax.numpy as jnp
+
+    from crowdllama_tpu.engine.paged import _put_chunk, _put_rows
+
+    layers, pages, hkv, page, dh, c = 3, 6, 2, 32, 8, 64
+    dump = pages  # the reserved extra page
+    shape = (layers, pages + 1, hkv, page) + (() if scales else (dh,))
+    k0, k1, k2 = jax.random.split(jax.random.PRNGKey(start + valid), 3)
+    pool = jax.random.normal(k0, shape)
+    page_row = jnp.asarray([4, 1, 3, 5], jnp.int32)
+    layer = jnp.int32(1)
+
+    # chunk rows [C, Hkv, ...] -> kv-head-major like a page
+    rows = jax.random.normal(k1, (c, hkv) + shape[4:])
+    got = jax.jit(_put_chunk, static_argnames=("dump_page",))(
+        pool, jnp.swapaxes(rows, 0, 1), layer=layer, page_row=page_row,
+        start=jnp.int32(start), valid=jnp.int32(valid), dump_page=dump)
+    pos = start + np.arange(valid)
+    want = pool.at[1, page_row[pos // page], :, pos % page].set(rows[:valid])
+    np.testing.assert_array_equal(np.asarray(got)[:, :pages],
+                                  np.asarray(want)[:, :pages])
+
+    # decode rows: two live slots and one routed to the dump page
+    drows = jax.random.normal(k2, (3, hkv) + shape[4:])
+    dpages = jnp.asarray([2, dump, 0], jnp.int32)
+    doffs = jnp.asarray([5, 0, 31], jnp.int32)
+    got = jax.jit(_put_rows)(pool, drows, layer=layer, pages=dpages,
+                             offsets=doffs)
+    want = pool.at[1, dpages, :, doffs].set(drows)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _page_partition(runner):
+    """(free, evictable, live) page sets; they must partition the pool."""
+    free = set(runner._free_pages)
+    assert len(free) == len(runner._free_pages), "a page is free twice"
+    live = {p for pages in runner._slot_pages.values() for p in pages}
+    evictable = {p for p in runner._page_key
+                 if runner._page_refs.get(p, 0) == 0} - live
+    return free, evictable, live
+
+
+def test_pool_survives_hundreds_of_unshared_requests():
+    """free + evictable + live = total, through a few hundred admissions
+    and releases of prompts that share nothing (the chip benchmark's
+    decode_sat: one full prompt page that gets indexed, then growth pages).
+    Recycled pages used to keep the refcount of their last life; after
+    ~150 requests on a 128-page pool every page sat in the prefix index at
+    -1, unevictable, and admission failed with ``kv pool exhausted``."""
+    cfg = get_config("tiny-test", max_context_length=128)
+    pr = PagedModelRunner(cfg, max_slots=2, max_seq=128, page_size=32,
+                          mesh_spec="1", seed=0)
+    assert pr.total_pages == 8
+    state = pr.init_state()
+    key = jax.random.PRNGKey(0)
+    rng = np.random.default_rng(0)
+    held: dict[int, int] = {}  # slot -> decode steps still to run
+    admitted = 0
+    while admitted < 300 or held:
+        for slot in range(pr.max_slots):
+            if slot not in held and admitted < 300:
+                prompt = rng.integers(1, 200, 32).tolist()  # one full page
+                tok, ks, vs, plen = pr.prefill(prompt, 0.0, 1.0, key,
+                                               state=state)
+                state = pr.insert(state, slot, ks, vs, plen, tok, 0.0, 1.0,
+                                  prompt_tokens=prompt)
+                held[slot] = 5  # x 8 steps: grows to 3 pages
+                admitted += 1
+        _, state = pr.decode_steps(state, 8)
+        for slot in list(held):
+            held[slot] -= 1
+            if not held[slot]:
+                state = pr.release(state, slot)
+                del held[slot]
+        free, evictable, live = _page_partition(pr)
+        assert not (free & live) and not (free & evictable)
+        assert len(free) + len(evictable) + len(live) == pr.total_pages, (
+            admitted, sorted(free), sorted(evictable), sorted(live),
+            dict(pr._page_refs))
+    assert all(v >= 0 for v in pr._page_refs.values()), pr._page_refs
+    _assert_all_pages_accounted(pr)

@@ -165,11 +165,11 @@ def test_ragged_v2_matches_reference(softcap, window):
     scale = dh ** -0.5
 
     ref = ragged_paged_attention_ref(
-        q, chunk_k, chunk_v, pool_k, pool_v, page_table, q_lens, kv_lens,
-        jnp.int32(chunk_slot), scale, softcap=softcap,
+        q, chunk_k, chunk_v, pool_k[None], pool_v[None], 0, page_table,
+        q_lens, kv_lens, jnp.int32(chunk_slot), scale, softcap=softcap,
         sliding_window=window)
     got = flash_ragged_paged_attention(
-        q, pool_k, pool_v, page_table, q_lens, kv_lens,
+        q, pool_k[None], pool_v[None], 0, page_table, q_lens, kv_lens,
         jnp.int32(chunk_slot), scale, softcap=softcap,
         sliding_window=window)
     # Compare rows that carry real queries: active decode rows + the
@@ -180,6 +180,83 @@ def test_ragged_v2_matches_reference(softcap, window):
     # The kernel's dead rows are zeros, not NaN (q_valid=0 skips compute).
     assert np.isfinite(np.asarray(got)).all()
     np.testing.assert_array_equal(np.asarray(got)[1], 0.0)
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+@pytest.mark.parametrize("kernel", ["decode", "ragged_v2", "chunk",
+                                    "decode_tp"])
+def test_stacked_pool_kernel_reads_its_layer(kernel, kv):
+    """Every paged kernel takes the whole ``[L, P, Hkv, page, Dh]`` pool and
+    a (traced) layer index: its answer at layer ``l`` is bit for bit the
+    answer of the same kernel on ``pool[l]`` cut out beforehand — and not
+    that of another layer."""
+    from crowdllama_tpu.ops.pallas import paged as pp
+    from crowdllama_tpu.ops.quant import quantize_kv
+    from crowdllama_tpu.parallel.mesh import build_mesh
+
+    layers, b, h, hkv, dh, page, np_ = 3, 2, 4, 2, 16, 32, 3
+    c, ctx = 40, 16
+    pool_pages = b * np_ + 2
+    ks = jax.random.split(jax.random.PRNGKey(11), 4)
+    dt = jnp.bfloat16
+    pool_k = jax.random.normal(ks[0], (layers, pool_pages, hkv, page, dh), dt)
+    pool_v = jax.random.normal(ks[1], (layers, pool_pages, hkv, page, dh), dt)
+    k_sc = v_sc = None
+    if kv == "int8":
+        pool_k, k_sc = quantize_kv(pool_k)
+        pool_v, v_sc = quantize_kv(pool_v)
+    table = jnp.asarray([[1, 2, 3], [4, 5, 6]], jnp.int32)
+    lens = jnp.asarray([70, 33], jnp.int32)
+    scale = dh ** -0.5
+
+    if kernel in ("decode", "decode_tp"):
+        q = jax.random.normal(ks[2], (b, h, dh), dt)
+        if kernel == "decode":
+            def run(pk, pv, sk, sv, layer):
+                return pp.flash_paged_decode_attention(
+                    q, pk, pv, layer, table, lens, scale, sliding_window=40,
+                    k_scale=sk, v_scale=sv)
+        else:
+            mesh = build_mesh("2")  # tp=2 over the two kv heads
+
+            def run(pk, pv, sk, sv, layer):
+                return pp.flash_paged_decode_attention_tp(
+                    q, pk, pv, layer, table, lens, scale, mesh,
+                    sliding_window=40, k_scale=sk, v_scale=sv)
+    elif kernel == "ragged_v2":
+        q = jax.random.normal(ks[2], (b + c, h, dh), dt)
+        q_lens = jnp.asarray([1, 0, c], jnp.int32)
+        kv_lens = jnp.asarray([70, 0, ctx + c], jnp.int32)
+
+        def run(pk, pv, sk, sv, layer):
+            return pp.flash_ragged_paged_attention(
+                q, pk, pv, layer, table, q_lens, kv_lens, jnp.int32(1),
+                scale, sliding_window=40, k_scale=sk, v_scale=sv)
+    else:
+        q = jax.random.normal(ks[2], (c, h, dh), dt)
+
+        def run(pk, pv, sk, sv, layer):
+            return pp.flash_ragged_chunk_attention(
+                q, pk, pv, layer, table[1], jnp.int32(ctx),
+                jnp.int32(ctx + c), scale, sliding_window=40,
+                k_scale=sk, v_scale=sv)
+
+    def cut(a, layer):
+        return None if a is None else a[layer][None]
+
+    stacked = jax.jit(run)
+    outs = []
+    for layer in range(layers):
+        got = np.asarray(stacked(pool_k, pool_v, k_sc, v_sc,
+                                 jnp.int32(layer)), np.float32)
+        want = np.asarray(run(cut(pool_k, layer), cut(pool_v, layer),
+                              cut(k_sc, layer), cut(v_sc, layer), 0),
+                          np.float32)
+        assert np.isfinite(got).all()
+        np.testing.assert_array_equal(got, want)
+        outs.append(got)
+    assert not np.array_equal(outs[0], outs[1])
+    assert not np.array_equal(outs[1], outs[2])
 
 
 def test_decode_bf16():

@@ -1,4 +1,5 @@
-"""Deviceless TPU compiles of the main-path kernels at Mistral-7B widths.
+"""Deviceless TPU compiles of the main-path kernels, and of the runner's
+decode and ragged step programs, at Mistral-7B widths.
 
 The TPU's compiler is installed here and compiles for a chip that is
 described, not attached (``v5e:2x2``): what it refuses — a slice not aligned
@@ -27,10 +28,12 @@ from crowdllama_tpu.ops.pallas.paged import (
 from crowdllama_tpu.parallel.mesh import AXIS_TP
 
 # mistral-7b (models/config.py) under the serving defaults: page 128,
-# 8 slots, context 2048 (16 pages per slot), ragged chunk 512.
+# 8 slots, context 2048 (16 pages per slot), ragged chunk 512.  The pool is
+# the engine's stack of layers; a few are enough to see what a layer costs.
 H, HKV, DH = 32, 8, 128
 PAGE, SLOTS, PAGES_PER_SLOT = 128, 8, 16
 POOL_PAGES = SLOTS * PAGES_PER_SLOT + 1
+LAYERS = 3
 PREFILL_T, CHUNK = 2048, 512
 SCALE = DH ** -0.5
 WINDOW = 4096
@@ -75,10 +78,10 @@ def _sds(shape, dtype, sharding):
 def _pool(kv, sharding, scale_sharding=None):
     """(pool_k, pool_v, k_scale, v_scale) shapes for a bf16 or int8 pool."""
     dtype = jnp.int8 if kv == "int8" else jnp.bfloat16
-    pool = _sds((POOL_PAGES, HKV, PAGE, DH), dtype, sharding)
+    pool = _sds((LAYERS, POOL_PAGES, HKV, PAGE, DH), dtype, sharding)
     if kv != "int8":
         return pool, pool, None, None
-    sc = _sds((POOL_PAGES, HKV, PAGE), jnp.bfloat16,
+    sc = _sds((LAYERS, POOL_PAGES, HKV, PAGE), jnp.bfloat16,
               scale_sharding or sharding)
     return pool, pool, sc, sc
 
@@ -106,13 +109,15 @@ def test_paged_decode_kernel_compiles(one_chip, kv):
     pk, pv, ks, vs = _pool(kv, one_chip)
     table = _sds((SLOTS, PAGES_PER_SLOT), jnp.int32, one_chip)
     lens = _sds((SLOTS,), jnp.int32, one_chip)
+    layer = _sds((), jnp.int32, one_chip)
 
-    def f(q, pk, pv, table, lens, ks, vs):
+    def f(q, pk, pv, layer, table, lens, ks, vs):
         return flash_paged_decode_attention(
-            q, pk, pv, table, lens, SCALE, sliding_window=WINDOW,
+            q, pk, pv, layer, table, lens, SCALE, sliding_window=WINDOW,
             k_scale=ks, v_scale=vs)
 
-    _assert_kernel(jax.jit(f).lower(q, pk, pv, table, lens, ks, vs).compile())
+    _assert_kernel(jax.jit(f).lower(
+        q, pk, pv, layer, table, lens, ks, vs).compile())
 
 
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
@@ -123,13 +128,13 @@ def test_ragged_v2_kernel_compiles(one_chip, kv):
     lens = _sds((SLOTS + 1,), jnp.int32, one_chip)
     slot = _sds((), jnp.int32, one_chip)
 
-    def f(q, pk, pv, table, q_lens, kv_lens, slot, ks, vs):
+    def f(q, pk, pv, layer, table, q_lens, kv_lens, slot, ks, vs):
         return flash_ragged_paged_attention(
-            q, pk, pv, table, q_lens, kv_lens, slot, SCALE,
+            q, pk, pv, layer, table, q_lens, kv_lens, slot, SCALE,
             sliding_window=WINDOW, k_scale=ks, v_scale=vs)
 
     _assert_kernel(jax.jit(f).lower(
-        q, pk, pv, table, lens, lens, slot, ks, vs).compile())
+        q, pk, pv, slot, table, lens, lens, slot, ks, vs).compile())
 
 
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
@@ -141,22 +146,115 @@ def test_tp4_wrapped_decode_kernel_compiles(topo, kv):
     heads = NamedSharding(mesh, P(None, AXIS_TP, None))
     q = _sds((SLOTS, H, DH), jnp.bfloat16, heads)
     pk, pv, ks, vs = _pool(
-        kv, NamedSharding(mesh, P(None, AXIS_TP, None, None)), heads)
+        kv, NamedSharding(mesh, P(None, None, AXIS_TP, None, None)),
+        NamedSharding(mesh, P(None, None, AXIS_TP, None)))
     table = _sds((SLOTS, PAGES_PER_SLOT), jnp.int32, rep)
     lens = _sds((SLOTS,), jnp.int32, rep)
+    layer = _sds((), jnp.int32, rep)
 
-    def f(q, pk, pv, table, lens, ks, vs):
+    def f(q, pk, pv, layer, table, lens, ks, vs):
         return flash_paged_decode_attention_tp(
-            q, pk, pv, table, lens, SCALE, mesh, sliding_window=WINDOW,
-            k_scale=ks, v_scale=vs)
+            q, pk, pv, layer, table, lens, SCALE, mesh,
+            sliding_window=WINDOW, k_scale=ks, v_scale=vs)
 
-    compiled = jax.jit(f).lower(q, pk, pv, table, lens, ks, vs).compile()
+    compiled = jax.jit(f).lower(
+        q, pk, pv, layer, table, lens, ks, vs).compile()
     _assert_kernel(compiled)
     text = compiled.as_text()
     for op in ("all-gather", "all-reduce", "all-to-all"):
         assert f" {op}(" not in text, f"unexpected {op} in the tp kernel"
     # Per device: a quarter of the pool (kv heads are the sharded dim).
     ma = compiled.memory_analysis()
-    per_dev_pool = 2 * POOL_PAGES * (HKV // 4) * PAGE * DH * (
+    per_dev_pool = 2 * LAYERS * POOL_PAGES * (HKV // 4) * PAGE * DH * (
         1 if kv == "int8" else 2)
     assert ma.argument_size_in_bytes < 1.5 * per_dev_pool
+
+
+# ------------------------------------------------- the runner's own programs
+
+
+@pytest.fixture
+def mistral_runner(one_chip, monkeypatch):
+    """``(kv) -> (runner, params, state, page table)``: a PagedModelRunner
+    at Mistral-7B widths with LAYERS layers of int8 weights, as the chip
+    benchmark serves it, built from shapes alone — nothing is allocated —
+    and the shapes of its arguments on the described chip."""
+    from crowdllama_tpu.engine import runner as runner_mod
+    from crowdllama_tpu.engine.paged import PagedModelRunner
+    from crowdllama_tpu.models.config import get_config
+    from crowdllama_tpu.ops.quant import random_quantized_params
+
+    # The runner would place real parameters on the CPU's devices and, on
+    # this backend, gate its kernels off: steer both here, in the test.
+    monkeypatch.setattr(runner_mod, "shard_params", lambda p, cfg, mesh: p)
+
+    def build(kv):
+        cfg = get_config("mistral-7b", num_layers=LAYERS,
+                         max_context_length=PREFILL_T)
+        shapes = jax.eval_shape(lambda: random_quantized_params(
+            cfg, jax.random.PRNGKey(0), dtype=jnp.bfloat16))
+        r = PagedModelRunner(cfg, params=shapes, mesh_spec="1x1",
+                             max_slots=SLOTS, max_seq=PREFILL_T,
+                             page_size=PAGE, kv_dtype=kv)
+        r.attention_paths = {**r.attention_paths, "decode": "pallas",
+                             "ragged_step": "pallas"}
+
+        def on_chip(tree):
+            return jax.tree_util.tree_map(
+                lambda a: _sds(a.shape, a.dtype, one_chip), tree)
+
+        table = _sds((SLOTS, PAGES_PER_SLOT), jnp.int32, one_chip)
+        return r, on_chip(shapes), on_chip(jax.eval_shape(r.init_state)), table
+
+    return build
+
+
+def _assert_pool_stays_in_place(compiled, kv):
+    """The step updates the donated pool where it lies: no temporary as
+    large as ONE layer's K+V slice, no ``copy`` whose result has the shape
+    of the pool, of a layer's slice of it or of the scales, and one
+    attention kernel per layer per step (the benchmark's readers divide
+    the traced custom calls by the layers to count steps; the layer and
+    step loops are rolled, so that is one call site in the text)."""
+    itemsize = 1 if kv == "int8" else 2
+    layer_kv = 2 * POOL_PAGES * HKV * PAGE * DH * itemsize
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes < layer_kv, (
+        ma.temp_size_in_bytes, layer_kv)
+    # Every donated byte is handed back as the new state.
+    pool = LAYERS * layer_kv + (
+        2 * LAYERS * POOL_PAGES * HKV * PAGE * 2 if kv == "int8" else 0)
+    assert ma.alias_size_in_bytes >= pool
+    text = compiled.as_text()
+    pool_dims = f"{POOL_PAGES},{HKV},{PAGE}"
+    for line in text.splitlines():
+        head = line.split(" copy(")[0] if " copy(" in line else ""
+        assert pool_dims not in head, f"pool-shaped copy: {line.strip()[:200]}"
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+@pytest.mark.parametrize("steps", [1, 8])
+def test_decode_program_keeps_the_pool_in_place(mistral_runner, kv, steps):
+    r, params, state, table = mistral_runner(kv)
+    compiled = jax.jit(
+        r._decode_paged_impl, donate_argnums=(1,), static_argnums=(3,)
+    ).lower(params, state, table, steps).compile()
+    assert "jit__decode_paged_impl" in compiled.as_text()[:200]
+    _assert_pool_stays_in_place(compiled, kv)
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_ragged_step_program_keeps_the_pool_in_place(mistral_runner, one_chip,
+                                                     kv):
+    r, params, state, table = mistral_runner(kv)
+    assert r.ragged_chunk == CHUNK
+
+    def i32(*shape):
+        return _sds(shape, jnp.int32, one_chip)
+
+    compiled = jax.jit(
+        r._ragged_step_impl, donate_argnums=(1,), static_argnums=(7,)
+    ).lower(params, state, table, i32(1, CHUNK), i32(1), i32(), i32(),
+            1).compile()
+    _assert_pool_stays_in_place(compiled, kv)
