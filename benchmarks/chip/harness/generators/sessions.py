@@ -89,12 +89,27 @@ def plan(p: dict, ctx: dict) -> Plan:
     actors = [_Seat(j, p, ctx, systems, first[j],
                     1 if j in checked else None) for j in range(seats)]
     # ladder: a cold conversation's first turn (ragged prefill), the same
-    # again (prefix hit), and a follow-up turn (suffix prefill)
+    # again (prefix hit), and a follow-up turn (suffix prefill); then the
+    # deepest seat's first turn (its system prompt a hit, a long suffix:
+    # the widest page table the traffic reaches, alone on the chip) and,
+    # sent together, a follow-up to it with first turns of other
+    # conversations behind it (admissions while the long context decodes).
+    # Without the last two a run on an empty compile cache compiled the
+    # wide ragged-step programs inside its window (PERF.md, PR 26).
     lrng = rng_for(ctx["seed"], 3)
-    cold = systems[0] + random_ids(lrng, p["user_tokens"], ctx["vocab_size"])
-    more = cold + random_ids(lrng, p["user_tokens"] + p["reply_tokens"],
-                             ctx["vocab_size"])
-    ladder = [Turn(prompt_ids=ids, max_tokens=16, greedy=True, tag="ladder")
-              for ids in (cold, cold, more)]
+    words = lambda n: random_ids(lrng, n, ctx["vocab_size"])
+    per_turn = p["user_tokens"] + p["reply_tokens"]
+    cold = systems[0] + words(p["user_tokens"])
+    more = cold + words(per_turn)
+    room = ctx["context"] - 1 - p["reply_tokens"] - p["user_tokens"]
+    depth = min(p["max_turns"] - 1,
+                (room - p["system_tokens"] - per_turn) // per_turn)
+    deep = systems[-1] + words(per_turn * depth + p["user_tokens"])
+    rung = lambda ids, n=16: Turn(prompt_ids=ids, max_tokens=n, greedy=True,
+                                  tag="ladder")
+    ladder = [rung(cold), rung(cold), rung(more), rung(deep),
+              [rung(deep + words(per_turn), p["reply_tokens"])]
+              + [rung(systems[k % len(systems)] + words(p["user_tokens"]))
+                 for k in range(3)]]
     return Plan(ladder=ladder, actors=actors, ramp_s=p["ramp_s"],
                 checked=len(checked))

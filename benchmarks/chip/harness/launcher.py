@@ -24,7 +24,15 @@ RUN_DIR = CHIP_DIR / "_run"                             # git-ignored
 
 
 class BenchFailure(Exception):
-    """The run cannot give a result; the command exits non-zero."""
+    """The run cannot give a result; the command exits non-zero.  ``child``
+    names the process that died or answered wrongly and ``log`` its log
+    file, where there is one: run.py keeps the log's last lines in the
+    run's ``failure.json``."""
+
+    def __init__(self, message: str, *, child: str | None = None,
+                 log: Path | None = None) -> None:
+        super().__init__(message)
+        self.child, self.log = child, log
 
 
 def cache_dir() -> str:
@@ -106,6 +114,7 @@ class Nodes:
         self.out = out_dir
         self.procs: list[tuple[str, subprocess.Popen, Path]] = []
         self.ports: dict[str, int] = {}
+        self.died: list[tuple[str, int, Path]] = []   # see stop()
         self.t_start = time.monotonic()
 
     def _start(self, name: str, argv: list[str],
@@ -161,8 +170,8 @@ class Nodes:
         for name, proc, log in self.procs:
             if proc.poll() is not None:
                 raise BenchFailure(
-                    f"{name} exited with code {proc.returncode}:\n"
-                    + tail(log))
+                    f"{name} exited with code {proc.returncode}",
+                    child=name, log=log)
 
     def wait_ready(self, timeout: float) -> dict:
         """Until the gateway's /api/health shows the worker."""
@@ -170,8 +179,8 @@ class Nodes:
             self.assert_alive()
             if time.monotonic() - self.t_start > timeout:
                 raise BenchFailure(
-                    f"no worker behind the gateway after {timeout:.0f}s; "
-                    f"worker log:\n" + tail(self.out / "worker.log"))
+                    f"no worker behind the gateway after {timeout:.0f}s",
+                    child="worker", log=self.out / "worker.log")
             try:
                 health = json.loads(
                     http_get(self.ports["gateway"], "/api/health"))
@@ -182,6 +191,11 @@ class Nodes:
             time.sleep(0.5)
 
     def stop(self) -> None:
+        """Terminate and wait for every child; ``died`` keeps (name, exit
+        code, log) of those that had ended by themselves before."""
+        self.died += [(name, proc.returncode, log)
+                      for name, proc, log in self.procs
+                      if proc.poll() is not None]
         for _, proc, _ in reversed(self.procs):
             if proc.poll() is None:
                 proc.send_signal(signal.SIGTERM)

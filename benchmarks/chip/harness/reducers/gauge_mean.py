@@ -1,5 +1,6 @@
-"""The mean of a worker gauge over the samples taken at 2 Hz inside the
-window."""
+"""The mean of a worker gauge over the samples taken at 2 Hz: ``--trace 2``
+inside the traced seconds and no others (harness/profiler.py), ``--trace 1``
+through the window."""
 
 from . import samples
 

@@ -1,15 +1,19 @@
 """Device time of one decode step, in ms: the summed device time of the
 traced programs matching ``program`` over the decode steps they ran.  Steps
-are counted, not assumed: ``step_op`` matches an op that runs exactly once
-per layer per step (the paged decode attention kernel), so steps = its
-count / the configuration's layers — right whatever mix of 8-step and
-1-step dispatches the scheduler made."""
+are counted, not assumed: ``step_op`` matches, by the kernel's own name, an
+op that runs exactly once per layer per step (the paged decode attention
+kernel, ``%paged_decode_attention``: TRACING.md has the convention), counted
+inside those programs alone, so steps = its count / the configuration's
+layers — right whatever mix of 8-step and 1-step dispatches the scheduler
+made, however many other custom calls a layer has, and whatever prefills
+ran beside them."""
 
 from .. import trace_reduce
 
 
 def steps_traced(s: dict, run) -> float:
-    _, calls = trace_reduce.op_time(run.profile, s["step_op"])
+    _, calls = trace_reduce.op_time(run.profile, s["step_op"],
+                                    s.get("program"))
     return calls / run.profile["devices"] / run.config["num_hidden_layers"]
 
 

@@ -12,6 +12,10 @@ doing during the device's idle gaps).
 * time per op is SELF time: an op that encloses others on its line (a
   ``while`` round its body, a fusion round its parts) is charged only what
   its children leave, so the times of all ops add up to busy.
+* every op is also charged to the program (the event of the modules line)
+  inside which it started, so that a reader can ask for a kernel's time in
+  the decode program alone: a prefill runs the same expert matmuls, and
+  its calls are not a decode step's.
 * an idle gap is labelled with the host event that covers most of it, or
   ``host: no runtime call`` when none does — Python, the scheduler, a wait
   for a request.
@@ -28,6 +32,7 @@ import json
 import os
 import re
 import sys
+from bisect import bisect_right
 from collections import defaultdict
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
@@ -127,7 +132,8 @@ def reduce(path: str) -> dict:
     window = (hi - lo) / 1e9
     host_events.sort()
 
-    busy_s, ops, programs = [], defaultdict(lambda: [0, 0.0, 0.0]), {}
+    # op -> [calls, self ns->s, total s, {program: [calls, self s]}]
+    busy_s, ops, programs = [], defaultdict(lambda: [0, 0.0, 0.0, {}]), {}
     gaps: list[tuple[float, float]] = []
     for name, lines in device_planes:
         if OPS_LINE not in lines:
@@ -136,11 +142,18 @@ def reduce(path: str) -> dict:
         ev = lines[OPS_LINE]
         merged = union([(a, b) for a, b, _ in ev])
         busy_s.append(sum(b - a for a, b in merged) / 1e9)
+        mods = sorted(lines.get(MODULES_LINE, []))
+        mod_starts = [m[0] for m in mods]
         for (a, b, op), s in zip(ev, self_times(ev)):
             rec = ops[op]
             rec[0] += 1
             rec[1] += s / 1e9
             rec[2] += (b - a) / 1e9
+            i = bisect_right(mod_starts, a) - 1
+            prog = mods[i][2] if i >= 0 and a < mods[i][1] else ""
+            per = rec[3].setdefault(prog, [0, 0.0])
+            per[0] += 1
+            per[1] += s / 1e9
         edges = [lo, *[x for ab in merged for x in ab], hi]
         gaps.extend((edges[i], edges[i + 1])
                     for i in range(0, len(edges), 2)
@@ -172,7 +185,8 @@ def reduce(path: str) -> dict:
         "busy_s": sum(busy_s) / n_dev,
         "busy_s_per_device": busy_s,
         "devices": n_dev,
-        "ops": {k: {"count": v[0], "self_s": v[1], "total_s": v[2]}
+        "ops": {k: {"count": v[0], "self_s": v[1], "total_s": v[2],
+                    "in_program": v[3]}
                 for k, v in top_ops},
         "programs": programs,
         "breakdown": {
@@ -184,11 +198,19 @@ def reduce(path: str) -> dict:
     }
 
 
-def op_time(red: dict, pattern: str, field: str = "self_s") -> tuple[float, int]:
-    """Summed time and count of the ops whose name matches ``pattern``."""
+def op_time(red: dict, pattern: str, program: str | None = None
+            ) -> tuple[float, int]:
+    """Summed self time and count of the ops whose name matches
+    ``pattern`` — with ``program``, of their calls inside the programs
+    whose name matches that."""
     rx = re.compile(pattern)
     hit = [v for k, v in red["ops"].items() if rx.search(k)]
-    return sum(v[field] for v in hit), sum(v["count"] for v in hit)
+    if program is None:
+        return sum(v["self_s"] for v in hit), sum(v["count"] for v in hit)
+    px = re.compile(program)
+    per = [cs for v in hit for prog, cs in v["in_program"].items()
+           if px.search(prog)]
+    return sum(s for _, s in per), sum(n for n, _ in per)
 
 
 def program_durations(red: dict, pattern: str) -> list[float]:

@@ -13,11 +13,17 @@ end-to-end metrics; ``--trace 1``: its per-layer metrics, from spans,
 counters and a device trace of a few seconds of the window; ``--trace 2``:
 both — a ``--trace 0`` run up to the moment the window closes, then a few
 traced seconds of the same traffic, one last line with the end-to-end
-metrics of the window and the per-layer metrics side by side).
+metrics of the window and the per-layer metrics side by side).  A run that
+cannot give a result exits 1, prints no result, says why on standard error
+in one line that starts ``benchmark failed:`` and leaves ``failure.json``
+(the message, the children that had exited, the last lines of their logs)
+in its directory under ``_run/out/``.
 
     --rehearse      the same flow at tiny size on the CPU (Pallas in
-                    interpret mode); prints "device" as the CPU and never a
-                    device metric
+                    interpret mode), on the rehearsal configuration the
+                    cell's configuration names (``bench.rehearsal``, else
+                    rehearsal-tiny-mistral); prints "device" as the CPU and
+                    never a device metric
     --sweep R1,R2   open-loop cells: one set-up, one window per rate, a
                     table instead of a result (how the knee was found)
 
@@ -41,12 +47,14 @@ import shutil  # noqa: E402
 import signal  # noqa: E402
 import subprocess  # noqa: E402
 import sys  # noqa: E402
+import traceback  # noqa: E402
 from pathlib import Path  # noqa: E402
 
 CHIP_DIR = Path(__file__).resolve().parent
 sys.path.insert(0, str(CHIP_DIR))
 
-from harness import generators, launcher, metrics, reducers  # noqa: E402
+from harness import (costs, generators, launcher, metrics,  # noqa: E402
+                     profiler, reducers, reference)
 from harness.launcher import BenchFailure  # noqa: E402
 from harness.loadgen import LoadGen  # noqa: E402
 
@@ -79,9 +87,39 @@ def load_cell(name: str, rehearse: bool) -> tuple[dict, dict, dict, dict]:
     config = load_json(ROOT / cfg_entry["file"])
     traffic = load_json(CHIP_DIR / "traffic" / f"{cell['traffic']}.json")
     if rehearse:
-        config = load_json(CHIP_DIR / "configs" / f"{REHEARSAL_CONFIG}.json")
+        tiny = config["bench"].get("rehearsal", REHEARSAL_CONFIG)
+        path = CHIP_DIR / "configs" / f"{tiny}.json"
+        if not path.exists():
+            raise BenchFailure(
+                f"configuration {cell['config']!r} names the rehearsal "
+                f"configuration {tiny!r}: no file {path}")
+        config = load_json(path)
         traffic.update(traffic.get("rehearsal") or {})
+    check_pieces(config)
     return bench, cell, config, traffic
+
+
+def check_pieces(config: dict) -> None:
+    """A configuration's reference, its limits and its cost module, found
+    and tried before anything starts: a family that lacks one fails here in
+    a second, with the file to add, not after minutes of set-up — and never
+    by being counted as another family."""
+    b = config["bench"]
+    ref = reference.module_file(b["reference"])
+    if not ref.exists():
+        raise BenchFailure(f"configuration {b['name']!r} names the "
+                           f"reference {b['reference']!r}: no file {ref}")
+    try:
+        tol = reference.limits(b["reference"])
+        mod = costs.module_for(config)
+        need = mod.decode_step_bytes(config, b["slots"],
+                                     b["slots"] * b["context"] / 2)
+    except (FileNotFoundError, costs.CostsMisread) as e:
+        raise BenchFailure(str(e)) from e
+    say(f"info: pieces: reference {b['reference']} (limits "
+        f"{ {k: tol[k] for k in reference.LIMITS} }), costs "
+        f"{mod.__name__.rpartition('.')[2]}: a step of {b['slots']} tokens "
+        f"at half the context reads at least {need / 1e9:.3f} GB")
 
 
 def cell_metrics(bench: dict, group: str, cell: str) -> list[dict]:
@@ -161,18 +199,23 @@ class Run:
         return await asyncio.get_running_loop().run_in_executor(
             None, self.scrape, node, path)
 
-    async def profile_for(self, length: float) -> dict:
+    async def profile_for(self, length: float, gauges: list | None = None
+                          ) -> dict:
         """Trace the worker's chip for ``length`` seconds through its own
-        control; the answer to "stop" (artifact directory, host clocks)."""
+        control; the answer to "stop" (artifact directory, host clocks).
+        ``gauges`` takes the worker's /metrics sampled over exactly the
+        traced seconds (harness/profiler.py)."""
         def post(action: str) -> dict:
             return json.loads(launcher.http_request(
                 "POST", self.nodes.ports["metrics"],
                 f"/debug/profile/{action}", timeout=120))
 
-        loop = asyncio.get_running_loop()
-        await loop.run_in_executor(None, post, "start")
-        await asyncio.sleep(length)
-        return await loop.run_in_executor(None, post, "stop")
+        stopped, samples = await profiler.profile_for(
+            post, length,
+            None if gauges is None else lambda: self.ascrape("metrics"))
+        if gauges is not None:
+            gauges.extend(text for _, _, text in samples)
+        return stopped
 
     async def measure(self, traffic: dict, seconds: float) -> reducers.RunData:
         """One timeline (ladder, ramp, window, [traced tail,] drain) against
@@ -207,11 +250,12 @@ class Run:
             # ones: made once and thrown away, it falls into no number
             first = await self.profile_for(0.0)
             shutil.rmtree(first["artifact"], ignore_errors=True)
-            sampler = asyncio.create_task(sample_gauges())
-            try:
-                self.profile = await self.profile_for(trace_len)
-            finally:
-                sampler.cancel()
+            self.profile = await self.profile_for(trace_len,
+                                                  run.gauge_samples)
+            say(f"info: gauges: {len(run.gauge_samples)} samples inside the "
+                f"{trace_len:.1f}s traced; the profiler's stop answered "
+                f"{self.profile.get('written_monotonic', 0.0) - self.profile['stopped_monotonic']:.1f}s "
+                f"after it was asked")
             # the worker's monotonic clock is this machine's, as gen.t0 is
             self.traced_at = (self.profile["started_monotonic"] - gen.t0,
                               self.profile["stopped_monotonic"] - gen.t0)
@@ -291,11 +335,10 @@ class Run:
                 cwd=CHIP_DIR, env=env, stdout=f, stderr=subprocess.STDOUT,
                 timeout=900).returncode
         if rc != 0:
-            raise BenchFailure(f"reference check exited {rc}:\n"
-                               + launcher.tail(log))
+            raise BenchFailure(f"reference check exited {rc}",
+                               child="reference check", log=log)
         res = load_json(self.out / "check_output.json")
-        tol = load_json(CHIP_DIR / "harness" / "reference" / "tolerance.json"
-                        )[self.config["bench"]["reference"]]
+        tol = reference.limits(self.config["bench"]["reference"])
         ok = (not problems and res["max_deficit"] <= tol["max_deficit"]
               and res["mean_deficit"] <= tol["mean_deficit"])
         say("info: reference check: " + json.dumps(
@@ -323,8 +366,9 @@ class Run:
                     "no device metric is printed")
                 shutil.rmtree(pdir, ignore_errors=True)
                 return None
-            raise BenchFailure("trace reduction failed:\n" + launcher.tail(
-                self.out / "trace_reduce.log"))
+            raise BenchFailure(f"trace reduction exited {rc}",
+                               child="trace reduction",
+                               log=self.out / "trace_reduce.log")
         if not os.environ.get("BENCH_KEEP_TRACE"):
             shutil.rmtree(pdir, ignore_errors=True)
         return load_json(outp)
@@ -447,6 +491,47 @@ async def sweep(r: Run, rates: list[float], seconds: float) -> None:
         await asyncio.sleep(2.0)
 
 
+def report_failure(e: Exception, args, run: "Run | None") -> None:
+    """Why the run gave no result, where the record can keep it: one line
+    on standard error that starts ``benchmark failed:`` and, once the run
+    has a directory, ``failure.json`` in it with the last lines of the
+    logs of the child the failure names and of every child that had
+    exited."""
+    log = getattr(e, "log", None)
+    exited = run.nodes.died if run else []
+    rec = {
+        "message": str(e), "kind": type(e).__name__,
+        "workload": args.workload, "seed": args.seed, "trace": args.trace,
+        "rehearse": args.rehearse,
+        "after_s": round(time.monotonic() - T_PROCESS_START, 1),
+        "child": getattr(e, "child", None),
+        "log": str(log) if log else None,
+        "log_tail": launcher.tail(log) if log else "",
+        "children_exited": [
+            {"name": n, "returncode": rc, "log_tail": launcher.tail(lg)}
+            for n, rc, lg in exited],
+        "traceback": "" if isinstance(e, BenchFailure)
+        else traceback.format_exc(),
+    }
+    parts = [f"{rec['kind']}: {rec['message']}"]
+    if exited:
+        parts.append("children that had exited: " + ", ".join(
+            f"{n} (code {rc})" for n, rc, _ in exited))
+    last = [ln for ln in (rec["log_tail"] or "\n".join(
+        c["log_tail"] for c in rec["children_exited"])).splitlines()
+        if ln.strip()][-3:]
+    if last:
+        parts.append("last log lines: " + " / ".join(last))
+    if run:
+        path = run.out / "failure.json"
+        path.write_text(json.dumps(rec, indent=1))
+        parts.append(f"see {path.relative_to(ROOT)}")
+    if rec["traceback"]:        # not a failure the harness foresaw
+        print(rec["traceback"], file=sys.stderr, end="")
+    line = "; ".join(parts).replace("\r", " ").replace("\n", " / ")
+    print("benchmark failed: " + line[:4000], file=sys.stderr, flush=True)
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -460,6 +545,7 @@ def main() -> int:
     args = ap.parse_args()
     # a terminated run still stops its children (the finally below)
     signal.signal(signal.SIGTERM, lambda *_: sys.exit(143))
+    r: Run | None = None
     try:
         bench, cell, config, traffic = load_cell(args.workload, args.rehearse)
         seconds = args.seconds or float(bench["run_seconds"])
@@ -515,8 +601,8 @@ def main() -> int:
         (r.out / "result.json").write_text(json.dumps(line, indent=1))
         print(json.dumps(line), flush=True)
         return 0
-    except (BenchFailure, metrics.TooFewSamples) as e:
-        print(f"benchmark failed: {e}", file=sys.stderr, flush=True)
+    except Exception as e:      # the command's boundary: say why, exit 1
+        report_failure(e, args, r)
         return 1
 
 

@@ -156,6 +156,31 @@ def test_a_conversation_ends_at_max_turns_and_a_new_one_takes_the_seat():
     assert max(lens) + p["reply_tokens"] <= CTX["context"]
 
 
+@pytest.mark.parametrize("sizes", ["as_served", "rehearsal"])
+def test_the_sessions_ladder_reaches_the_widest_context_the_seats_reach(sizes):
+    """Alone first, then with admissions behind it: the page-table widths
+    of the ramp's deepest turns are compiled before the ramp (PR 26: on an
+    empty compile cache they compiled inside the window)."""
+    p = traffic("sessions_shared")
+    ctx = {**CTX, "seed": 9}
+    if sizes == "rehearsal":
+        p, ctx = {**p, **p["rehearsal"]}, {**ctx, "context": 256,
+                                           "vocab_size": 512, "slots": 4}
+    plan = generators.build_plan(p, ctx)
+    seat, reply, deepest = plan.actors[0], None, 0   # a whole conversation
+    for _ in range(p["max_turns"]):
+        deepest = max(deepest, len(seat.next_turn(reply).prompt_ids))
+        reply = [1] * p["reply_tokens"]
+    *singles, burst = plan.ladder
+    assert not any(isinstance(r, list) for r in singles)
+    assert max(len(t.prompt_ids) for t in singles) == deepest
+    assert len(burst[0].prompt_ids) > deepest and len(burst) == 4
+    assert burst[0].prompt_ids[:deepest] == singles[-1].prompt_ids
+    for t in [*singles, *burst]:
+        assert len(t.prompt_ids) + t.max_tokens <= ctx["context"]
+        assert t.greedy and not t.check and t.tag == "ladder"
+
+
 def test_unknown_generator_is_an_error():
     with pytest.raises(ValueError):
         generators.build_plan({"generator": "no_such_kind"}, {**CTX, "seed": 1})

@@ -3,25 +3,21 @@ CLI's main, the load generator, the scrapes, the reference check in its own
 process — so that a later PR cannot break the harness unseen."""
 
 import json
-import os
 import subprocess
 import sys
 
-from conftest import CHIP_DIR
+from conftest import CHIP_DIR, cpu_env
 
 
 import pytest
 
 
-def rehearse(cell: str, trace: int) -> tuple[dict, str]:
-    env = {k: v for k, v in os.environ.items()
-           if k not in ("XLA_FLAGS",)}      # one CPU device, as a worker has
-    env["JAX_PLATFORMS"] = "cpu"
+def rehearse(cell: str, trace: int, chip_dir=CHIP_DIR) -> tuple[dict, str]:
     p = subprocess.run(
-        [sys.executable, str(CHIP_DIR / "run.py"), "--rehearse",
+        [sys.executable, str(chip_dir / "run.py"), "--rehearse",
          "--workload", cell, "--seed", "2147483659",
          "--seconds", "5", "--trace", str(trace)],
-        capture_output=True, text=True, timeout=400, env=env)
+        capture_output=True, text=True, timeout=400, env=cpu_env())
     assert p.returncode == 0, p.stdout[-3000:] + p.stderr[-3000:]
     last = p.stdout.strip().splitlines()[-1]
     assert sum(ln.startswith("{") for ln in p.stdout.splitlines()) == 1
