@@ -610,10 +610,13 @@ class JaxEngine(Engine):
             return runner
 
         self._runner = await loop.run_in_executor(None, _build)
+        t_w = time.monotonic()
         if self.config.warmup:
-            t_w = time.monotonic()
             await loop.run_in_executor(None, self._warmup)
-            ENGINE_TELEMETRY.startup_set("warmup", time.monotonic() - t_w)
+        # set by every start: the gauges are the newest engine's, and an
+        # engine that did not warm up did so in 0 s
+        ENGINE_TELEMETRY.startup_set(
+            "warmup", time.monotonic() - t_w if self.config.warmup else 0.0)
         self.scheduler = Scheduler(
             self._runner,
             decode_chunk=self.config.decode_chunk,

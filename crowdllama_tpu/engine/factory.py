@@ -16,6 +16,18 @@ from __future__ import annotations
 def build_runner(config, plan, cfg, params):
     """Instantiate the runner ``plan`` names (unwrapped — the engine adds
     the ReplicatedRunner proxy on the leader itself)."""
+    if cfg.is_hybrid:
+        # Mamba layers beside attention layers (engine/hybrid.py): two
+        # kinds of state, kept by the paged runner's subclass alone, and
+        # no rollback for speculation.
+        from crowdllama_tpu.engine.hybrid import refuse_speculation
+
+        if plan.spec:
+            refuse_speculation(cfg, f"{plan.spec} speculation")
+        if plan.kv_layout != "paged":
+            raise ValueError(
+                f"{cfg.name!r} has layers of several kinds and is served "
+                f"on the paged layout only, not {plan.kv_layout!r}")
     kwargs = dict(
         params=params,
         mesh_spec=config.mesh_shape,
@@ -29,6 +41,10 @@ def build_runner(config, plan, cfg, params):
             prefix_cache=config.kv_prefix_cache,
             kv_dtype=plan.kv_dtype,
             step_token_budget=config.step_token_budget)
+        if cfg.is_hybrid:
+            from crowdllama_tpu.engine.hybrid import HybridPagedModelRunner
+
+            return HybridPagedModelRunner(cfg, **kwargs)
         if plan.runner == "DraftSpecPagedModelRunner":
             from dataclasses import replace as _replace
 

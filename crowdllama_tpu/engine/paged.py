@@ -40,7 +40,7 @@ from __future__ import annotations
 
 import logging
 import math
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from functools import partial
 
 import jax
@@ -166,6 +166,15 @@ class PagedDecodeState:
     # model's own contiguous KV cache [Ld, B, Hkvd, S, Dhd].
     draft_k: jnp.ndarray | None = None
     draft_v: jnp.ndarray | None = None
+    # Models with Mamba layers only (engine/hybrid.py): each slot's
+    # recurrent state, per Mamba layer — the state-space state [L_M, B, H,
+    # P, N] float32 and the convolution's last K-1 inputs [L_M, B,
+    # conv_dim, K-1] — beside pools that cover the attention layers alone;
+    # and the expert layers' [held, left out] assignment counts since the
+    # scheduler last took them.
+    ssm: jnp.ndarray | None = None
+    conv: jnp.ndarray | None = None
+    moe_rows: jnp.ndarray | None = None
 
 
 jax.tree_util.register_dataclass(
@@ -173,7 +182,7 @@ jax.tree_util.register_dataclass(
     data_fields=["pool_k", "pool_v", "seq_lens", "tokens", "active",
                  "temperature", "top_p", "top_k", "repeat_penalty",
                  "recent", "keys", "k_scale", "v_scale", "hist",
-                 "draft_k", "draft_v"],
+                 "draft_k", "draft_v", "ssm", "conv", "moe_rows"],
     meta_fields=[],
 )
 
@@ -405,9 +414,20 @@ class PagedModelRunner(ModelRunner):
             kp.astype(state.pool_k.dtype))
         pool_v = state.pool_v.at[:, page_idx].set(
             vp.astype(state.pool_v.dtype))
-        return PagedDecodeState(
-            pool_k=pool_k, pool_v=pool_v,
-            k_scale=k_scale, v_scale=v_scale,
+        return self._activated(
+            replace(state, pool_k=pool_k, pool_v=pool_v,
+                    k_scale=k_scale, v_scale=v_scale),
+            slot, plen, first_token, temperature, top_p, top_k,
+            repeat_penalty, recent_row, slot_key)
+
+    @staticmethod
+    def _activated(state: PagedDecodeState, slot, plen, first_token,
+                   temperature, top_p, top_k, repeat_penalty, recent_row,
+                   slot_key) -> PagedDecodeState:
+        """``state`` with ``slot`` live: its KV (and whatever else the
+        model carries per slot) is already in place."""
+        return replace(
+            state,
             seq_lens=state.seq_lens.at[slot].set(plen),
             tokens=state.tokens.at[slot].set(first_token),
             active=state.active.at[slot].set(True),
@@ -416,22 +436,13 @@ class PagedModelRunner(ModelRunner):
             top_k=state.top_k.at[slot].set(top_k),
             repeat_penalty=state.repeat_penalty.at[slot].set(repeat_penalty),
             recent=state.recent.at[slot].set(recent_row),
-            keys=state.keys.at[slot].set(slot_key),
-            hist=state.hist, draft_k=state.draft_k, draft_v=state.draft_v,
-        )
+            keys=state.keys.at[slot].set(slot_key))
 
     def _release_paged_impl(self, state: PagedDecodeState, slot):
-        return PagedDecodeState(
-            pool_k=state.pool_k, pool_v=state.pool_v,
-            k_scale=state.k_scale, v_scale=state.v_scale,
-            seq_lens=state.seq_lens.at[slot].set(0),
-            tokens=state.tokens.at[slot].set(0),
-            active=state.active.at[slot].set(False),
-            temperature=state.temperature, top_p=state.top_p,
-            top_k=state.top_k, repeat_penalty=state.repeat_penalty,
-            recent=state.recent, keys=state.keys, hist=state.hist,
-            draft_k=state.draft_k, draft_v=state.draft_v,
-        )
+        return replace(state,
+                       seq_lens=state.seq_lens.at[slot].set(0),
+                       tokens=state.tokens.at[slot].set(0),
+                       active=state.active.at[slot].set(False))
 
     def _prefill_ctx_impl(self, params, tokens, slen, ctx_len, pool_k, pool_v,
                           k_scale, v_scale, pages, temperature, top_p, top_k,
@@ -444,7 +455,7 @@ class PagedModelRunner(ModelRunner):
         """
         cfg = self.cfg
         pg = self.page_size
-        l, hkv, dh = cfg.num_layers, cfg.num_kv_heads, cfg.resolved_head_dim()
+        l, hkv, dh = self.kv_layers, cfg.num_kv_heads, cfg.resolved_head_dim()
         t = tokens.shape[1]
         c = pages.shape[0] * pg
         # [L, n, Hkv, pg, Dh] -> [L, 1, Hkv, n*pg, Dh] virtual-contiguous ctx
@@ -543,7 +554,7 @@ class PagedModelRunner(ModelRunner):
                   ctx_v):
         """Copy pool pages into a prefill job's context accumulators
         ([L, n, Hkv, pg, Dh] gather → [L, 1, Hkv, n*pg, Dh] prefix)."""
-        l, hkv, dh = (self.cfg.num_layers, self.cfg.num_kv_heads,
+        l, hkv, dh = (self.kv_layers, self.cfg.num_kv_heads,
                       self.cfg.resolved_head_dim())
         c = pages.shape[0] * self.page_size
         ck, cv = pool_k[:, pages], pool_v[:, pages]
@@ -677,27 +688,76 @@ class PagedModelRunner(ModelRunner):
         self._pending_match = (keys, matched)
         return int(tok), ks, vs, plen
 
+    def _decode_layers(self, params, x, positions, pools, attend, st,
+                       live, chunk=None):
+        """The layer loop of a decode or ragged step over rows ``x [N, D]``:
+        a ``lax.scan`` over the stacked layers that carries ``(x, pool_k,
+        pool_v, k_scale, v_scale)`` with the pools at their full ``[L, P+1,
+        Hkv, page, Dh]`` and scans ``(layer params, window, layer index)``.
+        ``attend(pools, window, li)`` gives layer ``li``'s ``attn_fn`` —
+        it writes the rows' K/V into the stack at ``li`` and attends over
+        the stack at ``li`` — and where the pools are afterwards, so the
+        donated pool is never copied or rebuilt.  (As scanned xs/ys XLA
+        rebuilt the whole stack every step: ROADMAP S7.)
+
+        Returns (x, pools, the state fields the layers changed besides the
+        pools: none here).  ``st``, ``live`` (which rows are real tokens)
+        and ``chunk`` (the ragged step's ``(slot, valid rows)``) are for a
+        model that carries more per slot than KV (engine/hybrid.py)."""
+        cfg = self.cfg
+        cos, sin = rope_table(cfg.max_context_length,
+                              cfg.resolved_head_dim(), cfg.rope_theta,
+                              scaling=cfg.rope_scaling)
+
+        def body(carry, scanned):
+            x, *pools = carry
+            lp, window, li = scanned
+            attn_fn, after = attend(tuple(pools), window, li)
+            x = T.decode_layer_body(lp, cfg, x, positions, cos, sin, attn_fn)
+            return (x, *after["pools"]), None
+
+        (x, *pools), _ = jax.lax.scan(
+            body, (x, *pools),
+            (params["layers"], T.layer_sliding_windows(cfg),
+             jnp.arange(cfg.num_layers, dtype=jnp.int32)))
+        return x, tuple(pools), {}
+
+    def _sampled(self, st: PagedDecodeState, logits, pools, changed):
+        """The state after a step whose decode rows gave ``logits [B, V]``:
+        one token sampled per slot, the repeat ring and the PRNG carries
+        advanced, the pools (and ``changed``) as the layers left them.
+        Returns (new state, next tokens)."""
+        with jax.named_scope("sample"):
+            carry, sub = split_slot_keys(st.keys)
+            logits = apply_repeat_penalty(logits, st.recent,
+                                          st.repeat_penalty)
+            next_tokens = sample_tokens_slots(
+                logits, st.temperature, st.top_p, sub, top_k=st.top_k)
+            next_tokens = jnp.where(st.active, next_tokens, 0)
+        bidx2 = jnp.arange(st.recent.shape[0])
+        cursor = (st.seq_lens + 1) % REPEAT_LAST_N
+        recent = st.recent.at[bidx2, cursor].set(
+            jnp.where(st.active, next_tokens, st.recent[bidx2, cursor]))
+        pool_k, pool_v, k_scale, v_scale = pools
+        return replace(
+            st, pool_k=pool_k, pool_v=pool_v, k_scale=k_scale,
+            v_scale=v_scale,
+            seq_lens=jnp.where(st.active, st.seq_lens + 1, st.seq_lens),
+            tokens=next_tokens, recent=recent, keys=carry,
+            **changed), next_tokens
+
     def _paged_step_body(self, params, page_table):
         """One paged decode step as a ``lax.scan`` body closure — shared
         verbatim by the per-step program (``_decode_paged_impl``) and the
         megastep (``_decode_mega_paged_impl``) so the two paths cannot
-        drift (byte-identity contract, docs/MEGASTEP.md).
-
-        The layer loop inside carries ``(x, pool_k, pool_v, k_scale,
-        v_scale)`` with the pools at their full ``[L, P+1, Hkv, page, Dh]``
-        and scans ``(layer params, window, layer index)``: layer ``li``
-        writes its K/V into the stack at ``li`` and attends over the stack
-        at ``li``, so the donated pool is never copied or rebuilt."""
+        drift (byte-identity contract, docs/MEGASTEP.md).  The layer loop
+        is :meth:`_decode_layers`."""
         cfg = self.cfg
         pg = self.page_size
         b = self.max_slots
         dh = cfg.resolved_head_dim()
         hkv = cfg.num_kv_heads
         scale = T.attn_scale(cfg)
-        cos, sin = rope_table(cfg.max_context_length, dh, cfg.rope_theta,
-                          scaling=cfg.rope_scaling)
-        windows = T.layer_sliding_windows(cfg)
-        layer_idx = jnp.arange(cfg.num_layers, dtype=jnp.int32)
         view_len = self.max_pages_per_slot * pg
         slot_idx = jnp.arange(b)
         quant = self.kv_dtype == "int8"
@@ -722,14 +782,9 @@ class PagedModelRunner(ModelRunner):
                                  self.total_pages)  # [B]
             offset = positions % pg
 
-            def body(carry, scanned):
-                # The stacked pools ride the layer loop as CARRY: an
-                # in-place update of a loop-carried, donated buffer.  (As
-                # scanned xs/ys XLA rebuilt the whole stack every step:
-                # ROADMAP S7.)
-                x, pk, pv, ksc, vsc = carry
-                lp, window, li = scanned
-                pool = {}
+            def attend(pools, window, li):
+                pk, pv, ksc, vsc = pools
+                after = {}
 
                 @jax.named_scope("kv_write")
                 def write(k, v):
@@ -738,9 +793,8 @@ class PagedModelRunner(ModelRunner):
                     return _write_kv(put, pk, pv, ksc, vsc, k, v)
 
                 def attn_fn(q, k, v):
-                    pk2, pv2, ks2, vs2 = write(k, v)
-                    pool.update(pk=pk2, pv=pv2, ks=ks2, vs=vs2)
-                    return read(q, pk2, pv2, ks2, vs2)
+                    after["pools"] = write(k, v)
+                    return read(q, *after["pools"])
 
                 @jax.named_scope("attention")
                 def read(q, pk2, pv2, ks2, vs2):
@@ -774,38 +828,14 @@ class PagedModelRunner(ModelRunner):
                                             softcap=cfg.attn_logit_softcap,
                                             sliding_window=window)
 
-                x = T.decode_layer_body(lp, cfg, x, positions, cos, sin,
-                                        attn_fn)
-                return (x, pool["pk"], pool["pv"], pool["ks"],
-                        pool["vs"]), None
+                return attn_fn, after
 
-            (x, pool_k, pool_v, k_scale, v_scale), _ = jax.lax.scan(
-                body, (x, st.pool_k, st.pool_v, st.k_scale, st.v_scale),
-                (params["layers"], windows, layer_idx))
+            x, pools, changed = self._decode_layers(
+                params, x, positions,
+                (st.pool_k, st.pool_v, st.k_scale, st.v_scale), attend, st,
+                st.active)
             logits = T._unembed(params, cfg, x)
-            with jax.named_scope("sample"):
-                carry, sub = split_slot_keys(st.keys)
-                logits = apply_repeat_penalty(logits, st.recent,
-                                              st.repeat_penalty)
-                next_tokens = sample_tokens_slots(
-                    logits, st.temperature, st.top_p, sub, top_k=st.top_k)
-                next_tokens = jnp.where(st.active, next_tokens, 0)
-            bidx2 = jnp.arange(st.recent.shape[0])
-            cursor = (st.seq_lens + 1) % REPEAT_LAST_N
-            recent = st.recent.at[bidx2, cursor].set(
-                jnp.where(st.active, next_tokens,
-                          st.recent[bidx2, cursor]))
-            new_state = PagedDecodeState(
-                pool_k=pool_k, pool_v=pool_v,
-                k_scale=k_scale, v_scale=v_scale,
-                seq_lens=jnp.where(st.active, st.seq_lens + 1, st.seq_lens),
-                tokens=next_tokens, active=st.active,
-                temperature=st.temperature, top_p=st.top_p,
-                top_k=st.top_k, repeat_penalty=st.repeat_penalty,
-                recent=recent, keys=carry, hist=st.hist,
-                draft_k=st.draft_k, draft_v=st.draft_v,
-            )
-            return new_state, next_tokens
+            return self._sampled(st, logits, pools, changed)
 
         return step
 
@@ -847,12 +877,7 @@ class PagedModelRunner(ModelRunner):
         cfg = self.cfg
         pg = self.page_size
         b = self.max_slots
-        dh = cfg.resolved_head_dim()
         scale = T.attn_scale(cfg)
-        cos, sin = rope_table(cfg.max_context_length, dh, cfg.rope_theta,
-                              scaling=cfg.rope_scaling)
-        windows = T.layer_sliding_windows(cfg)
-        layer_idx = jnp.arange(cfg.num_layers, dtype=jnp.int32)
         slot_idx = jnp.arange(b)
         use_pallas = self.attention_paths["ragged_step"] != "jnp"
 
@@ -880,12 +905,9 @@ class PagedModelRunner(ModelRunner):
                 lens_dec.astype(jnp.int32),
                 (ctx_i + valid).astype(jnp.int32)[None]])
 
-            def body(carry, scanned):
-                # Pools as carry, written and read at layer ``li`` — see
-                # ``_paged_step_body``.
-                x, pk, pv, ksc, vsc = carry
-                lp, window, li = scanned
-                pool = {}
+            def attend(pools, window, li):
+                pk, pv, ksc, vsc = pools
+                after = {}
 
                 @jax.named_scope("kv_write")
                 def write(k, v):
@@ -901,9 +923,8 @@ class PagedModelRunner(ModelRunner):
                     return _write_kv(put, pk, pv, ksc, vsc, k, v)
 
                 def attn_fn(q, k, v):
-                    pk2, pv2, ks2, vs2 = write(k, v)
-                    pool.update(pk=pk2, pv=pv2, ks=ks2, vs=vs2)
-                    return read(q, k, v, pk2, pv2, ks2, vs2)
+                    after["pools"] = write(k, v)
+                    return read(q, k, v, *after["pools"])
 
                 @jax.named_scope("attention")
                 def read(q, k, v, pk2, pv2, ks2, vs2):
@@ -919,44 +940,21 @@ class PagedModelRunner(ModelRunner):
                         sliding_window=window, k_scale=ks2, v_scale=vs2,
                         use_pallas=use_pallas)
 
-                x = T.decode_layer_body(lp, cfg, x, positions, cos, sin,
-                                        attn_fn)
-                return (x, pool["pk"], pool["pv"], pool["ks"],
-                        pool["vs"]), None
+                return attn_fn, after
 
-            (x, pool_k, pool_v, k_scale, v_scale), _ = jax.lax.scan(
-                body, (x, st.pool_k, st.pool_v, st.k_scale, st.v_scale),
-                (params["layers"], windows, layer_idx))
+            live = jnp.concatenate([st.active, jnp.arange(c) < valid])
+            x, pools, changed = self._decode_layers(
+                params, x, positions,
+                (st.pool_k, st.pool_v, st.k_scale, st.v_scale), attend, st,
+                live, chunk=(chunk_slot, valid))
             # Unembed the B decode rows + ONE chunk row (the last valid
             # one) — the rest of the chunk never needs logits.
             x_last = x[b + jnp.clip(valid - 1, 0, c - 1)]
             logits = T._unembed(params, cfg,
                                 jnp.concatenate([x[:b], x_last[None]]))
-            chunk_logits = logits[b]
-            with jax.named_scope("sample"):
-                carry, sub = split_slot_keys(st.keys)
-                dec_logits = apply_repeat_penalty(logits[:b], st.recent,
-                                                  st.repeat_penalty)
-                next_tokens = sample_tokens_slots(
-                    dec_logits, st.temperature, st.top_p, sub,
-                    top_k=st.top_k)
-                next_tokens = jnp.where(st.active, next_tokens, 0)
-            bidx2 = jnp.arange(st.recent.shape[0])
-            cursor = (st.seq_lens + 1) % REPEAT_LAST_N
-            recent = st.recent.at[bidx2, cursor].set(
-                jnp.where(st.active, next_tokens,
-                          st.recent[bidx2, cursor]))
-            new_state = PagedDecodeState(
-                pool_k=pool_k, pool_v=pool_v,
-                k_scale=k_scale, v_scale=v_scale,
-                seq_lens=jnp.where(st.active, st.seq_lens + 1, st.seq_lens),
-                tokens=next_tokens, active=st.active,
-                temperature=st.temperature, top_p=st.top_p,
-                top_k=st.top_k, repeat_penalty=st.repeat_penalty,
-                recent=recent, keys=carry, hist=st.hist,
-                draft_k=st.draft_k, draft_v=st.draft_v,
-            )
-            return new_state, (next_tokens, chunk_logits, valid > 0)
+            new_state, next_tokens = self._sampled(st, logits[:b], pools,
+                                                   changed)
+            return new_state, (next_tokens, logits[b], valid > 0)
 
         return step
 
@@ -1003,7 +1001,7 @@ class PagedModelRunner(ModelRunner):
         from crowdllama_tpu.parallel.mesh import AXIS_TP
         from crowdllama_tpu.parallel.sharding import filter_spec
 
-        l = self.cfg.num_layers
+        l = self.kv_layers
         hkv, dh = self.cfg.num_kv_heads, self.cfg.resolved_head_dim()
         # +1: reserved dump page absorbing inactive slots' decode writes.
         shape = (l, self.total_pages + 1, hkv, self.page_size, dh)
@@ -1424,20 +1422,9 @@ class PagedModelRunner(ModelRunner):
                          repeat_penalty, recent_row, slot_key):
         """Flip a ragged-prefilled slot live: the KV is already in its
         pages, so this is _insert_paged minus the pool scatter."""
-        return PagedDecodeState(
-            pool_k=state.pool_k, pool_v=state.pool_v,
-            k_scale=state.k_scale, v_scale=state.v_scale,
-            seq_lens=state.seq_lens.at[slot].set(plen),
-            tokens=state.tokens.at[slot].set(first_token),
-            active=state.active.at[slot].set(True),
-            temperature=state.temperature.at[slot].set(temperature),
-            top_p=state.top_p.at[slot].set(top_p),
-            top_k=state.top_k.at[slot].set(top_k),
-            repeat_penalty=state.repeat_penalty.at[slot].set(repeat_penalty),
-            recent=state.recent.at[slot].set(recent_row),
-            keys=state.keys.at[slot].set(slot_key),
-            hist=state.hist, draft_k=state.draft_k, draft_v=state.draft_v,
-        )
+        return self._activated(state, slot, plen, first_token, temperature,
+                               top_p, top_k, repeat_penalty, recent_row,
+                               slot_key)
 
     def ragged_finish(self, state: PagedDecodeState, job: "RaggedPrefillJob",
                       temperature: float, top_p: float, key,
@@ -1572,16 +1559,8 @@ class PagedModelRunner(ModelRunner):
         if self.kv_dtype == "int8":
             k_scale = k_scale.at[:, page_idx].set(ksp)
             v_scale = v_scale.at[:, page_idx].set(vsp)
-        return PagedDecodeState(
-            pool_k=pool_k, pool_v=pool_v,
-            k_scale=k_scale, v_scale=v_scale,
-            seq_lens=state.seq_lens, tokens=state.tokens,
-            active=state.active, temperature=state.temperature,
-            top_p=state.top_p, top_k=state.top_k,
-            repeat_penalty=state.repeat_penalty, recent=state.recent,
-            keys=state.keys, hist=state.hist,
-            draft_k=state.draft_k, draft_v=state.draft_v,
-        )
+        return replace(state, pool_k=pool_k, pool_v=pool_v,
+                       k_scale=k_scale, v_scale=v_scale)
 
     def import_pages(self, state: PagedDecodeState,
                      payload: dict) -> tuple[PagedDecodeState, int]:
@@ -1609,7 +1588,7 @@ class PagedModelRunner(ModelRunner):
         if skip >= n:
             return state, 0
         cfg = self.cfg
-        l, hkv, dh = (cfg.num_layers, cfg.num_kv_heads,
+        l, hkv, dh = (self.kv_layers, cfg.num_kv_heads,
                       cfg.resolved_head_dim())
         pg = self.page_size
         quant = self.kv_dtype == "int8"

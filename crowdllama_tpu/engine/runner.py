@@ -118,6 +118,9 @@ class ModelRunner:
     # replay frames (parallel/replicated.py) opt out explicitly; sharded
     # multi-process runners lack the attribute (getattr default False).
     supports_megastep = True
+    #: a model whose layers differ in kind keeps state this runner has no
+    #: place for; engine/hybrid.py's runner says True
+    serves_hybrid = False
 
     def __init__(
         self,
@@ -131,6 +134,11 @@ class ModelRunner:
         seed: int = 0,
         kv_dtype: str = "bf16",  # "bf16" | "int8" (quantized KV cache)
     ):
+        if cfg.is_hybrid and not self.serves_hybrid:
+            raise ValueError(
+                f"{type(self).__name__} cannot serve {cfg.name!r}: its Mamba "
+                f"layers' per-slot state lives in the paged runner of "
+                f"engine/hybrid.py alone")
         self.cfg = cfg
         self.max_slots = max_slots
         self.max_seq = max_seq or cfg.max_context_length
@@ -208,6 +216,12 @@ class ModelRunner:
         self._release = jax.jit(self._release_impl, donate_argnums=(0,))
         self._announce_attention_paths()
 
+    @property
+    def kv_layers(self) -> int:
+        """Layers that keep KV: all of them, but for a model whose layers
+        differ in kind (engine/hybrid.py)."""
+        return self.cfg.layers_of("*")
+
     # ------------------------------------------------------- attention paths
 
     def _attention_refusals(self) -> dict[str, str]:
@@ -256,21 +270,24 @@ class ModelRunner:
         # attention (clamped positions would otherwise pass the causal mask).
         positions = jnp.minimum(jnp.arange(t)[None, :], plen - 1)
         kv_valid = (jnp.arange(t) < plen)[None, :]
-        if self.pp > 1:
-            logits, ks, vs = pp_prefill(params, self.cfg, tokens, positions,
-                                        self.mesh, kv_valid=kv_valid)
-        else:
-            logits, ks, vs = T.prefill(params, self.cfg, tokens, positions,
-                                       kv_valid=kv_valid,
-                                       sp_mesh=self._sp_mesh,
-                                       sp_batch_axis=None,
-                                       n_shards=self.mesh.size)
+        logits, ks, vs = self._prefill_forward(params, tokens, positions,
+                                               kv_valid)
         last = apply_repeat_penalty(
             logits[0, plen - 1][None, :], recent_row[None],
             repeat_penalty[None])  # [1, V]
         tok = sample_tokens(last, temperature[None], top_p[None],
                             key, top_k=top_k[None])[0]
         return tok, ks, vs
+
+    def _prefill_forward(self, params, tokens, positions, kv_valid):
+        """(logits [1, T, V], ks, vs): the forward pass of a whole prompt
+        and what it leaves for ``insert`` to place."""
+        if self.pp > 1:
+            return pp_prefill(params, self.cfg, tokens, positions,
+                              self.mesh, kv_valid=kv_valid)
+        return T.prefill(params, self.cfg, tokens, positions,
+                         kv_valid=kv_valid, sp_mesh=self._sp_mesh,
+                         sp_batch_axis=None, n_shards=self.mesh.size)
 
     def _insert_impl(self, state: DecodeState, slot, ks, vs, plen, first_token,
                      temperature, top_p, top_k, repeat_penalty, recent_row,
@@ -470,7 +487,7 @@ class ModelRunner:
             raise ValueError(
                 f"prompt of {len(prompt_ids)} tokens exceeds max context "
                 f"{self.max_seq}")
-        l, hkv, dh = (self.cfg.num_layers, self.cfg.num_kv_heads,
+        l, hkv, dh = (self.kv_layers, self.cfg.num_kv_heads,
                       self.cfg.resolved_head_dim())
         # Accumulators sized to the PROMPT's bucket, not max_seq: a 600-token
         # prompt on a 32k-context model must not allocate (or attend over)
@@ -636,19 +653,21 @@ class ModelRunner:
         t = tokens.shape[1]
         positions = jnp.minimum(jnp.arange(t)[None, :], plens[:, None] - 1)
         kv_valid = jnp.arange(t)[None, :] < plens[:, None]  # [B, T]
-        if self.pp > 1:
-            h = pp_hidden_states(params, self.cfg, tokens, positions,
-                                 self.mesh, kv_valid=kv_valid)  # [B, T, D]
-        else:
-            h = T.hidden_states(params, self.cfg, tokens, positions,
-                                kv_valid=kv_valid,
-                                sp_mesh=self._sp_mesh,
-                                n_shards=self.mesh.size)  # [B, T, D]
+        h = self._hidden_states(params, tokens, positions, kv_valid)
         mask = kv_valid[..., None].astype(jnp.float32)  # [B, T, 1]
         pooled = jnp.sum(h.astype(jnp.float32) * mask, axis=1) / jnp.maximum(
             jnp.sum(mask, axis=1), 1.0)
         return pooled / jnp.maximum(
             jnp.linalg.norm(pooled, axis=-1, keepdims=True), 1e-9)
+
+    def _hidden_states(self, params, tokens, positions, kv_valid):
+        """Final-norm hidden states [B, T, D] of padded prompts."""
+        if self.pp > 1:
+            return pp_hidden_states(params, self.cfg, tokens, positions,
+                                    self.mesh, kv_valid=kv_valid)
+        return T.hidden_states(params, self.cfg, tokens, positions,
+                               kv_valid=kv_valid, sp_mesh=self._sp_mesh,
+                               n_shards=self.mesh.size)
 
     def insert(self, state: DecodeState, slot: int, ks, vs, plen: int,
                first_token: int, temperature: float, top_p: float,

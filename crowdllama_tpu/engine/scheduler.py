@@ -171,6 +171,10 @@ class _InFlightChunk:
     # credits this flight consumed — retire answers each with a _VERIFY
     # payload carrying the tokens that slot emitted in the flight.
     verify_meta: list | None = None
+    # Counters the flight's programs kept on the device (a runner's
+    # ``flight_counters``; engine/hybrid.py: the expert layers' [held,
+    # left out] assignments), read back in the same transfer as the tokens.
+    counters_dev: object = None
 
 
 class Scheduler:
@@ -777,6 +781,10 @@ class Scheduler:
         with jax.profiler.TraceAnnotation(SCHED_EMIT, first_token=1,
                                           exec_us=exec_us):
             self._emit(req, first, info)
+
+    def _flight_counters(self):
+        take = getattr(self.runner, "flight_counters", None)
+        return take() if take is not None else None
 
     def _prefix_mark(self) -> tuple[int, int]:
         r = self.runner
@@ -1404,7 +1412,8 @@ class Scheduler:
                     dispatched = _InFlightChunk(
                         tokens_dev=tokens_dev, snapshot=list(self.slots),
                         dispatched_at=time.monotonic(),
-                        ragged_steps=n_chunks, done_dev=rdone_dev)
+                        ragged_steps=n_chunks, done_dev=rdone_dev,
+                        counters_dev=self._flight_counters())
                     if not req.exec_start_at:
                         req.exec_start_at = dispatched.dispatched_at
                     if job.finished:
@@ -1458,7 +1467,8 @@ class Scheduler:
                 self.host_dispatches += 1
                 dispatched = _InFlightChunk(
                     tokens_dev=tokens_dev, snapshot=list(self.slots),
-                    dispatched_at=time.monotonic(), done_dev=done_dev)
+                    dispatched_at=time.monotonic(), done_dev=done_dev,
+                    counters_dev=self._flight_counters())
 
         # Advance an in-progress LEGACY chunked admission by ONE prefill
         # chunk (ragged jobs already advanced inside the dispatch above).
@@ -1631,11 +1641,15 @@ class Scheduler:
 
         def readback():
             with jax.profiler.TraceAnnotation(SCHED_READBACK, dispatch=cls):
-                tokens, done = jax.device_get((fl.tokens_dev, fl.done_dev))
+                tokens, done, counters = jax.device_get(
+                    (fl.tokens_dev, fl.done_dev, fl.counters_dev))
                 # [K,B] (or packed [K,2+J,B]) on the host
-                return np.asarray(tokens), done
+                return np.asarray(tokens), done, counters
 
-        tokens, done = await loop.run_in_executor(self._exec, readback)
+        tokens, done, counters = await loop.run_in_executor(self._exec,
+                                                            readback)
+        if counters is not None:
+            ENGINE_TELEMETRY.moe_assignments_inc(*counters)
         now = time.monotonic()
         with jax.profiler.TraceAnnotation(SCHED_EMIT, dispatch=cls):
             emitted, dt = self._account_and_emit(fl, cls, tokens, done, now)

@@ -323,6 +323,9 @@ class SpecPagedModelRunner(_AdaptiveDraftLen, PagedModelRunner):
     supports_remote_draft = True
 
     def __init__(self, cfg, *args, draft_len: int = 4, **kwargs):
+        from crowdllama_tpu.engine.hybrid import refuse_speculation
+
+        refuse_speculation(cfg, type(self).__name__)
         super().__init__(cfg, *args, **kwargs)
         self.draft_len = max(1, draft_len)
         self._spec_plens = np.zeros((self.max_slots,), np.int32)

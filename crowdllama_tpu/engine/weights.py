@@ -273,16 +273,44 @@ def resolve_model_config(name: str, model_path: str = "",
     return _replace(cfg, **overrides) if overrides else cfg
 
 
+#: config.json ``model_type`` -> family; a type that is not here is an error
+_FAMILIES = {"llama": "llama", "mistral": "mistral", "mixtral": "mixtral",
+             "gemma2": "gemma2", "qwen2": "qwen2", "qwen3": "qwen3",
+             "qwen3_moe": "qwen3", "nemotron_h": "nemotron_h"}
+#: keys that say the block is not the one the dense families share: reading
+#: past them would serve another model under this one's name
+_FOREIGN_KEYS = ("hybrid_override_pattern", "n_routed_experts",
+                 "n_shared_experts", "ssm_state_size")
+_FOREIGN_PREFIXES = ("mamba_", "moe_")
+
+
 def config_from_hf_dir(path: str | Path) -> ModelConfig:
     """Derive a ModelConfig from a checkpoint's config.json (for models not
-    in the registry)."""
+    in the registry).  A ``model_type`` this program has no family for, or
+    a key that only another kind of block has, is an error that names it —
+    never a reading as a llama."""
     d = json.loads((Path(path) / "config.json").read_text())
     arch = (d.get("architectures") or [""])[0].lower()
-    family = ("gemma2" if "gemma2" in arch
-              else "mixtral" if "mixtral" in arch
-              else "mistral" if "mistral" in arch
-              else "qwen3" if "qwen3" in arch
-              else "qwen2" if "qwen2" in arch else "llama")
+    kind = d.get("model_type")
+    if kind is not None and kind not in _FAMILIES:
+        raise ValueError(
+            f"{path}/config.json: model_type {kind!r} is not a family this "
+            f"program serves ({sorted(set(_FAMILIES))})")
+    family = _FAMILIES.get(kind) or (
+        "gemma2" if "gemma2" in arch
+        else "mixtral" if "mixtral" in arch
+        else "mistral" if "mistral" in arch
+        else "qwen3" if "qwen3" in arch
+        else "qwen2" if "qwen2" in arch
+        else "nemotron_h" if "nemotronh" in arch else "llama")
+    if family == "nemotron_h":
+        return _nemotron_h_config(d)
+    odd = sorted(k for k, v in d.items() if v not in (None, 0, False)
+                 and (k in _FOREIGN_KEYS or k.startswith(_FOREIGN_PREFIXES)))
+    if odd:
+        raise ValueError(
+            f"{path}/config.json: family {family!r} has no use for "
+            f"{', '.join(odd)}; serving it as a {family} would drop them")
     return ModelConfig(
         name=d.get("_name_or_path", "hf-model"),
         family=family,
@@ -313,4 +341,55 @@ def config_from_hf_dir(path: str | Path) -> ModelConfig:
         num_experts_per_tok=d.get("num_experts_per_tok", 2),
         attn_qkv_bias=family == "qwen2" or bool(d.get("attention_bias")),
         qk_norm=family == "qwen3",
+    )
+
+
+
+def _nemotron_h_config(d: dict) -> ModelConfig:
+    """``model_type: nemotron_h``.  ``n_routed_experts`` counts the experts
+    held HERE; where that is a share, ``n_routed_experts_published`` gives
+    the router's width and ``expert_parallel_rank`` which share (the
+    benchmark's cut states both: benchmarks/chip/configs).  The config's
+    ``rope_theta`` and ``partial_rotary_factor`` have no reader: the
+    attention of this family does not rotate."""
+    pattern = d["hybrid_override_pattern"]
+    if len(pattern) != d["num_hidden_layers"]:
+        raise ValueError(
+            f"hybrid_override_pattern has {len(pattern)} layers, "
+            f"num_hidden_layers says {d['num_hidden_layers']}")
+    served = {"mlp_hidden_act": "relu2", "mamba_hidden_act": "silu",
+              "n_group": 1, "topk_group": 1, "n_shared_experts": 1,
+              "use_conv_bias": True, "use_bias": False, "mlp_bias": False,
+              "mamba_proj_bias": False, "attention_bias": False}
+    odd = {k: d[k] for k, v in served.items() if d.get(k, v) != v}
+    if odd:
+        raise ValueError(f"nemotron_h is served with {served} only; this "
+                         f"config.json says {odd}")
+    d_inner = d["mamba_num_heads"] * d["mamba_head_dim"]
+    if d_inner != d.get("expand", 2) * d["hidden_size"]:
+        raise ValueError(f"mamba heads give d_inner {d_inner}, expand says "
+                         f"{d.get('expand', 2) * d['hidden_size']}")
+    held = d["n_routed_experts"]
+    return ModelConfig(
+        name=d.get("_name_or_path", "hf-model"), family="nemotron_h",
+        vocab_size=d["vocab_size"], hidden_size=d["hidden_size"],
+        intermediate_size=d["intermediate_size"],
+        num_layers=d["num_hidden_layers"],
+        num_heads=d["num_attention_heads"],
+        num_kv_heads=d["num_key_value_heads"], head_dim=d.get("head_dim", 0),
+        rms_norm_eps=d.get("layer_norm_epsilon", d.get("norm_eps", 1e-5)),
+        tie_word_embeddings=d.get("tie_word_embeddings", False),
+        max_context_length=d.get("max_position_embeddings", 4096),
+        layer_pattern=pattern, ssm_heads=d["mamba_num_heads"],
+        ssm_head_dim=d["mamba_head_dim"], ssm_groups=d["n_groups"],
+        ssm_state=d["ssm_state_size"], ssm_conv_kernel=d["conv_kernel"],
+        ssm_chunk=d.get("chunk_size", 128),
+        num_experts=d.get("n_routed_experts_published", held),
+        experts_held=held, expert_rank=d.get("expert_parallel_rank", 0),
+        num_experts_per_tok=d["num_experts_per_tok"],
+        moe_intermediate_size=d["moe_intermediate_size"],
+        moe_latent_size=d["moe_latent_size"],
+        moe_shared_intermediate_size=d["moe_shared_expert_intermediate_size"],
+        moe_routed_scaling=float(d.get("routed_scaling_factor", 1.0)),
+        moe_norm_topk=bool(d.get("norm_topk_prob", True)),
     )

@@ -37,7 +37,9 @@ class RopeScaling:
 @dataclass(frozen=True)
 class ModelConfig:
     name: str = "custom"
-    family: str = "llama"  # "llama" | "mistral" | "gemma2" | "mixtral" | "qwen2" | "qwen3"
+    # "llama" | "mistral" | "gemma2" | "mixtral" | "qwen2" | "qwen3" |
+    # "nemotron_h" (layers of three kinds: models/hybrid.py)
+    family: str = "llama"
     vocab_size: int = 32000
     hidden_size: int = 2048
     intermediate_size: int = 5632
@@ -73,17 +75,63 @@ class ModelConfig:
     # exact); "dense": compute-all-experts reference semantics.
     moe_dispatch: str = "sorted"
 
+    # Hybrid specifics (family="nemotron_h", models/hybrid.py).  Every
+    # layer is ONE mixer, its kind a character of ``layer_pattern``: "M"
+    # Mamba-2, "E" latent mixture of experts, "*" attention (no rotary).
+    # ``num_experts`` is the router's width; this worker holds
+    # ``experts_held`` of them (0 = all), those of ``expert_rank``.
+    layer_pattern: str = ""
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1
+    ssm_state: int = 0
+    ssm_conv_kernel: int = 4
+    ssm_chunk: int = 128
+    moe_intermediate_size: int = 0
+    moe_latent_size: int = 0
+    moe_shared_intermediate_size: int = 0
+    moe_routed_scaling: float = 1.0
+    moe_norm_topk: bool = True
+    experts_held: int = 0
+    expert_rank: int = 0
+
     def __post_init__(self) -> None:
         if self.moe_dispatch not in ("sorted", "dense"):
             raise ValueError(
                 f"moe_dispatch must be 'sorted' or 'dense', "
                 f"got {self.moe_dispatch!r}")
+        if self.layer_pattern:
+            odd = set(self.layer_pattern) - set("ME*")
+            if odd or len(self.layer_pattern) != self.num_layers:
+                raise ValueError(
+                    f"layer_pattern {self.layer_pattern!r} must give one of "
+                    f"M, E, * for each of the {self.num_layers} layers")
+            held = self.experts_held or self.num_experts
+            if held * (self.expert_rank + 1) > self.num_experts:
+                raise ValueError(
+                    f"rank {self.expert_rank} of shares of {held} experts "
+                    f"lies outside the router's {self.num_experts}")
+
+    @property
+    def is_hybrid(self) -> bool:
+        return bool(self.layer_pattern)
+
+    def layers_of(self, kind: str) -> int:
+        """How many layers are of ``kind`` ("M", "E" or "*"); every layer of
+        a model without a pattern is an attention layer."""
+        if not self.layer_pattern:
+            return self.num_layers if kind == "*" else 0
+        return self.layer_pattern.count(kind)
 
     def resolved_head_dim(self) -> int:
         return self.head_dim or self.hidden_size // self.num_heads
 
     def param_count(self) -> int:
         """Total parameters (matches models.transformer.init_params)."""
+        if self.is_hybrid:
+            from crowdllama_tpu.models import hybrid
+
+            return hybrid.param_count(self)
         d, f, v = self.hidden_size, self.intermediate_size, self.vocab_size
         dh = self.resolved_head_dim()
         attn = d * self.num_heads * dh + 2 * d * self.num_kv_heads * dh \
@@ -158,6 +206,20 @@ TINY_TEST_MISTRAL = _register(ModelConfig(
     name="tiny-test-mistral", family="mistral", vocab_size=512,
     hidden_size=64, intermediate_size=128, num_layers=2, num_heads=4,
     num_kv_heads=2, sliding_window=16, rms_norm_eps=1e-6,
+    max_context_length=256,
+))
+
+# All three kinds of layer, 16 experts behind the router of which this
+# worker holds 8 (rank 0 of 2), a latent width, more than one SSD chunk in
+# the smallest prefill bucket.
+TINY_TEST_NEMOTRON_H = _register(ModelConfig(
+    name="tiny-test-nemotron-h", family="nemotron_h", vocab_size=512,
+    hidden_size=64, intermediate_size=48, num_layers=5, num_heads=4,
+    num_kv_heads=2, head_dim=16, layer_pattern="MEM*E", ssm_heads=8,
+    ssm_head_dim=16, ssm_groups=2, ssm_state=16, ssm_conv_kernel=4,
+    ssm_chunk=8, num_experts=16, num_experts_per_tok=4, experts_held=8,
+    moe_intermediate_size=48, moe_latent_size=32,
+    moe_shared_intermediate_size=96, moe_routed_scaling=2.5,
     max_context_length=256,
 ))
 

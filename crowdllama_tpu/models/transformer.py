@@ -42,6 +42,10 @@ Params = dict[str, Any]
 
 def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
     """Random-init a parameter pytree (layers stacked on axis 0)."""
+    if cfg.is_hybrid:  # a list of layers per kind: models/hybrid.py
+        from crowdllama_tpu.models import hybrid
+
+        return hybrid.init_params(cfg, key, dtype)
     dh = cfg.resolved_head_dim()
     d, f, v = cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size
     h, hkv, nl = cfg.num_heads, cfg.num_kv_heads, cfg.num_layers

@@ -418,6 +418,15 @@ class EngineTelemetry:
         # retired flight: seconds / steps is the wall time of one step.
         self._flight_seconds = {cls: 0.0 for cls in FLIGHT_CLASSES}
         self._flight_steps = {cls: 0 for cls in FLIGHT_CLASSES}
+        # An expert layer that holds a share of its experts (models/
+        # hybrid.py): token-expert rows it computed ("yes") and rows it left
+        # to the ranks that hold their expert ("no"), counted on the device
+        # and read back with each flight's tokens.
+        self._moe_assignments = {"yes": 0, "no": 0}
+        # Bytes of each kind of per-request state the newest runner's
+        # init_state allocated (engine/hybrid.py: KV pool, state-space
+        # state, convolution tail).
+        self._state_bytes: dict[str, int] = {}
         # Seconds each phase of the start took ("ready": from the import
         # of this module, which the CLI does first, to the engine serving).
         self.t_import = time.monotonic()
@@ -500,6 +509,15 @@ class EngineTelemetry:
             self._prefix["tokens_reused"] += max(0, int(tokens_reused))
             self._prefix["hits"] += max(0, int(hits))
 
+    def moe_assignments_inc(self, held: int, left_out: int) -> None:
+        with self._lock:
+            self._moe_assignments["yes"] += max(0, int(held))
+            self._moe_assignments["no"] += max(0, int(left_out))
+
+    def state_bytes_set(self, by_kind: dict[str, int]) -> None:
+        with self._lock:
+            self._state_bytes = {k: int(v) for k, v in by_kind.items()}
+
     def startup_set(self, phase: str, seconds: float) -> None:
         with self._lock:
             self._startup[phase] = max(0.0, float(seconds))
@@ -533,6 +551,8 @@ class EngineTelemetry:
             flight_seconds = dict(self._flight_seconds)
             flight_steps = dict(self._flight_steps)
             startup = dict(self._startup)
+            moe = dict(self._moe_assignments)
+            state_bytes = sorted(self._state_bytes.items())
         out.append("# TYPE crowdllama_engine_attention_path gauge")
         if not attention:
             out.append('crowdllama_engine_attention_path{program="none",'
@@ -578,6 +598,15 @@ class EngineTelemetry:
         for cls in FLIGHT_CLASSES:
             out.append(f'crowdllama_engine_flight_steps_total{{'
                        f'dispatch="{cls}"}} {flight_steps[cls]}')
+        out.append("# TYPE crowdllama_moe_assignments_total counter")
+        for held, n in moe.items():
+            out.append(f'crowdllama_moe_assignments_total{{held="{held}"}} '
+                       f'{n}')
+        out.append("# TYPE crowdllama_engine_state_bytes gauge")
+        if not state_bytes:
+            out.append('crowdllama_engine_state_bytes{kind="none"} 0')
+        for kind, n in state_bytes:
+            out.append(f'crowdllama_engine_state_bytes{{kind="{kind}"}} {n}')
         out.append("# TYPE crowdllama_startup_seconds gauge")
         for phase in STARTUP_PHASES:
             out.append(f'crowdllama_startup_seconds{{phase="{phase}"}} '

@@ -30,7 +30,11 @@ Params = dict[str, Any]
 # Weight names that carry the bulk of the bytes and tolerate int8: every
 # large matmul.  Norm gains, the MoE router (tiny, routing-critical), and the
 # embedding table (gather + tied-unembed accuracy) stay in bf16.
-QUANT_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+QUANT_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
+              # models/hybrid.py: Mamba projections, latent projections,
+              # the held expert banks, the shared expert
+              "w_in", "w_out", "w_lat_down", "w_lat_up", "w1", "w2",
+              "ws1", "ws2")
 
 
 @jax.tree_util.register_dataclass
@@ -168,13 +172,17 @@ def quantize_params(params: Params, extra_keys: tuple[str, ...] = ("lm_head",),
         raise ValueError(f"unknown quantization mode {mode!r}")
     qfn = quantize_weight if mode == "int8" else quantize_weight_int4
 
+    def _stack(layers):
+        # a hybrid model's layers are a list of layers per kind of layer
+        if isinstance(layers, list):
+            return [_stack(layer) for layer in layers]
+        return {k: _stack(v) if isinstance(v, (dict, list))
+                else qfn(v) if k in QUANT_KEYS else v
+                for k, v in layers.items()}
+
     def _quantize(p: Params) -> Params:
         out = dict(p)
-        layers = dict(p["layers"])
-        for k in QUANT_KEYS:
-            if k in layers:
-                layers[k] = qfn(layers[k])
-        out["layers"] = layers
+        out["layers"] = _stack(p["layers"])
         for k in extra_keys:
             if k in out:
                 out[k] = qfn(out[k])
@@ -210,13 +218,14 @@ def random_quantized_params(cfg, key: jax.Array, dtype=jnp.bfloat16,
     are a function of ``key`` alone.
     """
     from crowdllama_tpu.models import transformer as T
+    from crowdllama_tpu.models.hybrid import special_leaf
 
     shapes = jax.eval_shape(lambda k: T.init_params(cfg, k, dtype), key)
     flat, treedef = jax.tree_util.tree_flatten_with_path(shapes)
     leaf_keys = jax.random.split(key, len(flat))
 
     norm_names = ("ln1", "ln2", "post_ln1", "post_ln2", "q_norm", "k_norm",
-                  "final_norm")
+                  "final_norm", "norm", "gate_norm")
 
     def build(path, sds, k):
         name = path[-1].key
@@ -238,6 +247,9 @@ def random_quantized_params(cfg, key: jax.Array, dtype=jnp.bfloat16,
             return jnp.ones(sds.shape, sds.dtype)
         if name in ("bq", "bk", "bv"):  # qkv biases init to zero
             return jnp.zeros(sds.shape, sds.dtype)
+        special = special_leaf(name, sds.shape, k, sds.dtype)
+        if special is not None:  # state-space constants, correction bias
+            return special
         if sds.ndim >= 2:  # embeddings / router / any remaining dense weight
             fan = sds.shape[-2]
             return (jax.random.normal(k, sds.shape, jnp.float32)

@@ -32,6 +32,13 @@ Params = dict[str, Any]
 
 def param_pspecs(cfg: ModelConfig) -> Params:
     """PartitionSpec pytree mirroring models.transformer.init_params."""
+    if cfg.is_hybrid:
+        # One device (engine/hybrid.py refuses a larger mesh): replicated.
+        from crowdllama_tpu.models import transformer as T
+
+        shapes = jax.eval_shape(
+            lambda: T.init_params(cfg, jax.random.PRNGKey(0)))
+        return jax.tree_util.tree_map(lambda _: P(), shapes)
     layers: Params = {
         "ln1": P(AXIS_PP, None),
         "ln2": P(AXIS_PP, None),

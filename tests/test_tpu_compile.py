@@ -258,3 +258,105 @@ def test_ragged_step_program_keeps_the_pool_in_place(mistral_runner, one_chip,
     ).lower(params, state, table, i32(1, CHUNK), i32(1), i32(), i32(),
             1).compile()
     _assert_pool_stays_in_place(compiled, kv)
+
+
+# ------------- a model whose layers differ in kind, at the benchmark's cut
+
+
+@pytest.fixture
+def nemotron_runner(one_chip, monkeypatch, tmp_path):
+    """``() -> (runner, params, state, page table)``: the hybrid runner at
+    the widths, depth and share of ``nemotron-3-super-p1-ep4-int8`` (the
+    benchmark's configuration file, read as the worker reads it), int8,
+    32 slots, built from shapes alone."""
+    import json
+    from pathlib import Path
+
+    from crowdllama_tpu.engine import runner as runner_mod
+    from crowdllama_tpu.engine.hybrid import HybridPagedModelRunner
+    from crowdllama_tpu.engine.weights import resolve_model_config
+    from crowdllama_tpu.ops.quant import random_quantized_params
+
+    monkeypatch.setattr(runner_mod, "shard_params", lambda p, cfg, mesh: p)
+    doc = json.loads((Path(__file__).resolve().parents[1] / "benchmarks"
+                      / "chip" / "configs"
+                      / "nemotron-3-super-p1-ep4-int8.json").read_text())
+    slots = doc["bench"]["slots"]
+    (tmp_path / "config.json").write_text(json.dumps(
+        {k: v for k, v in doc.items() if k != "bench"}))
+
+    def build():
+        cfg = resolve_model_config(doc["bench"]["name"], str(tmp_path),
+                                   max_context_length=PREFILL_T)
+        shapes = jax.eval_shape(lambda: random_quantized_params(
+            cfg, jax.random.PRNGKey(0), dtype=jnp.bfloat16))
+        r = HybridPagedModelRunner(cfg, params=shapes, mesh_spec="1x1",
+                                   max_slots=slots, max_seq=PREFILL_T,
+                                   page_size=PAGE)
+        r.attention_paths = {**r.attention_paths, "decode": "pallas",
+                             "ragged_step": "pallas"}
+
+        def on_chip(tree):
+            return jax.tree_util.tree_map(
+                lambda a: _sds(a.shape, a.dtype, one_chip), tree)
+
+        table = _sds((slots, PAGES_PER_SLOT), jnp.int32, one_chip)
+        return r, on_chip(shapes), on_chip(jax.eval_shape(r.init_state)), table
+
+    return build
+
+
+def _assert_both_states_stay_in_place(compiled, r, state,
+                                      decode_kernel=True):
+    """Every byte of the donated state — the attention layer's pools, the
+    Mamba layers' state and tail — is handed back where it lay; the
+    temporaries hold ONE dequantized bf16 expert bank and small change
+    (no second copy of a layer's weights: the layers are a list, and each
+    layer's weights are tied to its rows so that the step loop cannot hoist
+    their dequantized copies); no ``copy`` has the state's shape; the
+    decode attention kernel has one call site (one attention layer)."""
+    cfg = r.cfg
+    bank = 2 * (cfg.experts_held * cfg.moe_latent_size
+                * cfg.moe_intermediate_size)              # bf16
+    kept = sum(a.size * a.dtype.itemsize for a in (
+        state.pool_k, state.pool_v, state.ssm, state.conv))
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= kept, (ma.alias_size_in_bytes, kept)
+    assert ma.temp_size_in_bytes < 1.25 * bank, (ma.temp_size_in_bytes, bank)
+    text = compiled.as_text()
+    state_dims = ",".join(map(str, state.ssm.shape[1:]))
+    for line in text.splitlines():
+        head = line.split(" copy(")[0] if " copy(" in line else ""
+        assert state_dims not in head, f"state-shaped copy: {line.strip()[:200]}"
+    if decode_kernel:
+        assert len([ln for ln in text.splitlines()
+                    if " custom-call(" in ln and "%paged_decode_attention"
+                    in ln.split(" = ")[0]]) == 1
+
+
+@pytest.mark.parametrize("steps", [1, 8])
+def test_hybrid_decode_program_keeps_both_states_in_place(nemotron_runner,
+                                                          steps):
+    r, params, state, table = nemotron_runner()
+    assert state.pool_k.shape[0] == 1 and state.ssm.shape == (
+        5, 32, 128, 64, 128) and state.conv.shape == (5, 32, 10240, 3)
+    compiled = jax.jit(
+        r._decode_paged_impl, donate_argnums=(1,), static_argnums=(3,)
+    ).lower(params, state, table, steps).compile()
+    _assert_both_states_stay_in_place(compiled, r, state)
+
+
+def test_hybrid_ragged_step_program_keeps_both_states_in_place(
+        nemotron_runner, one_chip):
+    r, params, state, table = nemotron_runner()
+    assert r.ragged_chunk == CHUNK
+
+    def i32(*shape):
+        return _sds(shape, jnp.int32, one_chip)
+
+    compiled = jax.jit(
+        r._ragged_step_impl, donate_argnums=(1,), static_argnums=(7,)
+    ).lower(params, state, table, i32(1, CHUNK), i32(1), i32(), i32(),
+            1).compile()
+    _assert_both_states_stay_in_place(compiled, r, state,
+                                      decode_kernel=False)
