@@ -610,6 +610,8 @@ class JaxEngine(Engine):
             return runner
 
         self._runner = await loop.run_in_executor(None, _build)
+        ENGINE_TELEMETRY.moe_matmul_path_set(
+            getattr(self._runner, "moe_matmul_path", ""))
         t_w = time.monotonic()
         if self.config.warmup:
             await loop.run_in_executor(None, self._warmup)

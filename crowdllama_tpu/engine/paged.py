@@ -68,7 +68,7 @@ from crowdllama_tpu.ops.pallas.paged import (
     ragged_paged_attention,
     ragged_pallas_refusal,
 )
-from crowdllama_tpu.ops.quant import quantize_kv
+from crowdllama_tpu.ops.quant import quantize_kv, ride_banks
 from crowdllama_tpu.ops.rope import rope_table
 
 log = logging.getLogger("crowdllama.engine.paged")
@@ -709,16 +709,20 @@ class PagedModelRunner(ModelRunner):
                               cfg.resolved_head_dim(), cfg.rope_theta,
                               scaling=cfg.rope_scaling)
 
+        # int8 expert banks ride the loop whole, as the pools do
+        layers, bind = ride_banks(params["layers"])
+
         def body(carry, scanned):
             x, *pools = carry
             lp, window, li = scanned
             attn_fn, after = attend(tuple(pools), window, li)
-            x = T.decode_layer_body(lp, cfg, x, positions, cos, sin, attn_fn)
+            x = T.decode_layer_body(bind(lp), cfg, x, positions, cos, sin,
+                                    attn_fn)
             return (x, *after["pools"]), None
 
         (x, *pools), _ = jax.lax.scan(
             body, (x, *pools),
-            (params["layers"], T.layer_sliding_windows(cfg),
+            (layers, T.layer_sliding_windows(cfg),
              jnp.arange(cfg.num_layers, dtype=jnp.int32)))
         return x, tuple(pools), {}
 

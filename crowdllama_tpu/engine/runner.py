@@ -215,6 +215,7 @@ class ModelRunner:
         self._insert = jax.jit(self._insert_impl, donate_argnums=(0,))
         self._release = jax.jit(self._release_impl, donate_argnums=(0,))
         self._announce_attention_paths()
+        self._announce_moe_matmul_path()
 
     @property
     def kv_layers(self) -> int:
@@ -259,6 +260,24 @@ class ModelRunner:
                 if why:
                     log.warning("%s attention runs the jnp path on this "
                                 "TPU, not the Pallas kernel: %s", prog, why)
+
+    def _announce_moe_matmul_path(self) -> None:
+        """Which path the expert layers' grouped matmuls take in every
+        program this runner builds (ops/quant.py ``qragged_dot`` decides
+        from the placed bank, and logs why a bank is dequantized; "" for a
+        model that has none), for ``crowdllama_moe_matmul_path``."""
+        from crowdllama_tpu.ops.quant import ragged_dot_path
+
+        cfg, layers = self.cfg, self.params["layers"]
+        self.moe_matmul_path = ""
+        if cfg.is_hybrid:
+            bank = layers["moe"][0]["w1"]
+        elif cfg.is_moe and cfg.moe_dispatch == "sorted":
+            bank = layers["w_gate"]
+        else:
+            return
+        self.moe_matmul_path, _ = ragged_dot_path(bank)
+        log.info("expert matmul path: %s", self.moe_matmul_path)
 
     # ------------------------------------------------------------- programs
 

@@ -20,7 +20,12 @@ import jax
 import jax.numpy as jnp
 
 from crowdllama_tpu.models.config import ModelConfig
-from crowdllama_tpu.ops.quant import qeinsum, qragged_dot, quantize_kv
+from crowdllama_tpu.ops.quant import (
+    qeinsum,
+    qragged_dot,
+    quantize_kv,
+    ride_banks,
+)
 from crowdllama_tpu.ops.attention import (
     decode_attention,
     decode_attention_q,
@@ -260,12 +265,14 @@ def scan_prefill_layers(
     cos, sin = rope_table(cfg.max_context_length, dh, cfg.rope_theta,
                           scaling=cfg.rope_scaling)
     b, t = x.shape[0], x.shape[1]
+    layers, bind = ride_banks(layers)  # int8 expert banks ride whole
 
     def body(x, scanned):
         if has_ctx:
             lp, ck, cv, window = scanned
         else:
             lp, window = scanned
+        lp = bind(lp)
         with jax.named_scope("attn_proj"):
             h = rms_norm(x, lp["ln1"], cfg.rms_norm_eps,
                          plus_one=cfg.family == "gemma2")
@@ -474,6 +481,7 @@ def scan_decode_layers(
                           scaling=cfg.rope_scaling)
     b = x.shape[0]
     slot_idx = jnp.arange(b)
+    layers, bind = ride_banks(layers)  # int8 expert banks ride whole
 
     def body(x, scanned):
         if quantized:
@@ -481,6 +489,7 @@ def scan_decode_layers(
         else:
             lp, kc, vc, window = scanned  # kc/vc: [B, Hkv, S, Dh]
             ks = vs = None
+        lp = bind(lp)
         cache = {}
 
         def attn_fn(q, k, v):

@@ -436,6 +436,10 @@ class EngineTelemetry:
         # (set at runner build; a refused kernel must be visible to a
         # scrape, not just to whoever reads the worker's log).
         self._attention_paths: dict[str, str] = {}
+        # "int8_kernel" | "dequant_ragged_dot" | "ragged_dot": how the
+        # newest engine's expert layers multiply their banks (ops/quant.py
+        # qragged_dot; set at JaxEngine.start, "" without expert layers).
+        self._moe_matmul_path = ""
         # Unified ragged batch (docs/RAGGED_BATCH.md): wall time per
         # prefill chunk carried inside a decode dispatch.  Engine-plane
         # like the compile histogram (the scheduler's dispatch loop
@@ -481,6 +485,10 @@ class EngineTelemetry:
     def attention_paths_set(self, paths: dict[str, str]) -> None:
         with self._lock:
             self._attention_paths = dict(paths)
+
+    def moe_matmul_path_set(self, path: str) -> None:
+        with self._lock:
+            self._moe_matmul_path = path
 
     def padding_inc(self, useful: int, waste: int) -> None:
         """Account one padded dispatch: ``useful`` real tokens rode it,
@@ -547,6 +555,7 @@ class EngineTelemetry:
             padding = dict(self._padding)
             cache_hits = sorted(self._cache_hits.items())
             attention = sorted(self._attention_paths.items())
+            moe_path = self._moe_matmul_path
             prefix = dict(self._prefix)
             flight_seconds = dict(self._flight_seconds)
             flight_steps = dict(self._flight_steps)
@@ -560,6 +569,9 @@ class EngineTelemetry:
         for program, path in attention:
             out.append(f'crowdllama_engine_attention_path{{'
                        f'program="{program}",path="{path}"}} 1')
+        out.append("# TYPE crowdllama_moe_matmul_path gauge")
+        out.append(f'crowdllama_moe_matmul_path{{path="{moe_path or "none"}"'
+                   f'}} {1 if moe_path else 0}')
         out.append("# TYPE crowdllama_xla_compiles_total counter")
         if not compiles:
             out.append('crowdllama_xla_compiles_total{program="none",'

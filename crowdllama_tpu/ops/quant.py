@@ -2,10 +2,17 @@
 
 Decode is HBM-bandwidth-bound (SURVEY §6; ROADMAP S1): every
 step streams the full parameter set.  Storing matmul weights as int8 with a
-per-output-channel bf16 scale halves the dominant traffic; the dequantize
-(convert + broadcast multiply) fuses into the matmul operand read, so the
-MXU still sees bf16 inputs.  (Device numbers for this: not measured in
-this round.)
+per-output-channel bf16 scale halves the dominant traffic, and the MXU
+still sees bf16 inputs.  Where the dequantize (convert + broadcast
+multiply) happens depends on the consumer.  A dense ``qeinsum`` is an XLA
+dot, and XLA fuses the dequantize into its operand read: Mistral-7B's
+three MLP matmuls stream their int8 weights at 730 GB/s, 89% of a v5e's
+peak (PERF.md §5).  ``lax.ragged_dot`` is a custom call that nothing
+fuses into: an expert bank dequantized for it was written whole to HBM as
+bf16 and read again, five times the bytes, 87% of a Mixtral decode step
+(PERF_LEDGER.jsonl, PR 27).  So an int8 bank goes to a grouped matmul
+that converts in VMEM (ops/pallas/moe.py); :func:`qragged_dot` says which
+input takes which path.
 
 Int8×int8 MXU matmuls (dynamic activation quantization) were measured
 SLOWER at serving batch sizes (B=8: 6.5 ms/step) — the per-step activation
@@ -18,14 +25,16 @@ quantizes offline in formats the swarm layer never sees).
 
 from __future__ import annotations
 
+import logging
 import math
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 
 Params = dict[str, Any]
+log = logging.getLogger(__name__)
 
 # Weight names that carry the bulk of the bytes and tolerate int8: every
 # large matmul.  Norm gains, the MoE router (tiny, routing-critical), and the
@@ -45,10 +54,19 @@ class QTensor:
     ``q`` keeps the source shape [..., d_in, d_out]; ``s`` is [..., d_out].
     A pytree node, so it flows through jit / scan / device_put like the
     plain array it replaces.
+
+    Placement metadata lives on the weight: ``mesh_devices`` is the size of
+    the mesh ``parallel/sharding.py`` ``shard_params`` placed it on (the
+    one place a placed ``QTensor`` is built), which a traced program cannot
+    read off its arguments and a ``pallas_call`` has to know (GSPMD does
+    not partition one).  It is static, so ``tree_map``, ``jit`` and a
+    ``scan`` slice keep it; a ``QTensor(q=.., s=..)`` built by hand from a
+    placed one's arrays starts at 1 again and must pass it on.
     """
 
     q: jnp.ndarray
     s: jnp.ndarray
+    mesh_devices: int = field(default=1, metadata=dict(static=True))
 
     @property
     def shape(self):
@@ -125,9 +143,25 @@ def quantize_weight_int4(w: jnp.ndarray, group: int = GROUP,
                     s=s.squeeze(-2).astype(scale_dtype))
 
 
+@jax.tree_util.register_dataclass
+@dataclass
+class LayerOf:
+    """Layer ``layer`` of a stacked expert bank (``stack.q`` ``[L, E, d_in,
+    d_out]``) that rides a layer loop whole (:func:`ride_banks`).  A weight
+    like the others: :func:`dequant` slices the layer, and with it
+    :func:`qeinsum` and :func:`qragged_dot`'s fallback."""
+
+    stack: QTensor
+    layer: jnp.ndarray  # int32 scalar
+
+
 def dequant(t) -> jnp.ndarray:
-    """QTensor/QTensor4 → bf16 weight (XLA fuses convert+scale into the
-    consumer matmul's operand read); plain arrays pass through."""
+    """QTensor/QTensor4 → bf16 weight; plain arrays pass through.  XLA
+    fuses the convert+scale into the operand read of a consumer that is
+    its own dot (``qeinsum``); a consumer that is a custom call gets the
+    bf16 weight materialized in HBM (see the module docstring)."""
+    if isinstance(t, LayerOf):  # the slice the layer loop would have made
+        t = jax.tree_util.tree_map(lambda a: a[t.layer], t.stack)
     if isinstance(t, QTensor):
         return t.q.astype(t.s.dtype) * t.s[..., None, :]
     if isinstance(t, QTensor4):
@@ -151,13 +185,91 @@ def qeinsum(subscript: str, x: jnp.ndarray, w, dtype=None) -> jnp.ndarray:
     return jnp.einsum(subscript, x, wd)
 
 
+def ragged_dot_path(w) -> tuple[str, str]:
+    """(path, why not the one before it) of :func:`qragged_dot` for a bank
+    ``w``, from its type, the backend, the mesh it was placed on and its
+    shape — the names ``crowdllama_moe_matmul_path`` exports."""
+    from crowdllama_tpu.ops.pallas.moe import grouped_matmul_refusal
+
+    if isinstance(w, LayerOf):
+        w = w.stack
+    if isinstance(w, QTensor):
+        why = grouped_matmul_refusal(w.q.shape, w.mesh_devices)
+        return ("dequant_ragged_dot" if why else "int8_kernel"), why
+    if isinstance(w, QTensor4):
+        return "dequant_ragged_dot", (
+            "int4 scales are group-wise along d_in and cannot multiply the "
+            "product")
+    return "ragged_dot", "the bank is not quantized"
+
+
+_logged_fallbacks: set[str] = set()
+
+
 def qragged_dot(xs: jnp.ndarray, w, group_sizes: jnp.ndarray) -> jnp.ndarray:
-    """``lax.ragged_dot`` against a possibly-quantized expert bank
-    ([E, d_in, d_out])."""
+    """``lax.ragged_dot(xs, w, group_sizes)`` against a possibly-quantized
+    expert bank ([E, d_in, d_out], or a :class:`LayerOf` a stacked one).
+
+    - ``QTensor`` (int8, per-output-channel scales) on one TPU device (or
+      in forced interpret mode) with dims that are multiples of 128: the
+      Pallas ``moe_grouped_matmul`` reads the bank as int8, converts in
+      VMEM and scales the float32 product — no bf16 bank in HBM;
+    - ``QTensor`` elsewhere (CPU, a mesh of several devices, odd dims) and
+      ``QTensor4`` (its scales vary along d_in): ``dequant`` then
+      ``lax.ragged_dot``, the reason logged once (a WARNING on a TPU);
+    - a plain array: ``lax.ragged_dot``.
+    """
+    path, why = ragged_dot_path(w)
+    if path == "int8_kernel":
+        from crowdllama_tpu.ops.pallas.moe import moe_grouped_matmul
+
+        if isinstance(w, LayerOf):
+            return moe_grouped_matmul(xs, w.stack.q, w.stack.s, group_sizes,
+                                      w.layer)
+        return moe_grouped_matmul(xs, w.q, w.s, group_sizes)
+    if path == "dequant_ragged_dot" and why not in _logged_fallbacks:
+        _logged_fallbacks.add(why)
+        log.log(logging.WARNING if jax.default_backend() == "tpu"
+                else logging.INFO,
+                "expert banks are dequantized to bf16 in HBM for "
+                "lax.ragged_dot, not read as int8 by the grouped-matmul "
+                "kernel: %s", why)
     with jax.named_scope("bank_dequant"):
         bank = dequant(w)
     with jax.named_scope("ragged_dot"):
         return jax.lax.ragged_dot(xs, bank, group_sizes)
+
+
+# the scanned entry that stands in for the banks that ride
+_LAYER_INDEX = "_layer_index"
+
+
+def ride_banks(layers: Params):
+    """For a ``lax.scan`` over stacked layer params: ``(what to scan,
+    bind)`` with ``bind(lp)`` the layer's params inside the body.  The
+    int8 expert banks the kernel will read (``[L, E, d_in, d_out]``) are
+    taken out of the scan and ride it whole, each layer seeing a
+    :class:`LayerOf` (the layer's index is scanned in their place): the
+    kernel's index map picks the layer, where a scanned slice handed to a
+    custom call is a 470 MB copy a matrix.  A consumer that is no kernel
+    (``moe_dispatch="dense"``: ``qeinsum``) slices the layer in
+    :func:`dequant`, as the scan would have.  Without such a bank the
+    layers come back as they are and the program is the one it was."""
+    riding = {k: w for k, w in layers.items()
+              if isinstance(w, QTensor) and w.q.ndim == 4
+              and ragged_dot_path(w)[0] == "int8_kernel"}
+    if not riding:
+        return layers, lambda lp: lp
+    n_layers = next(iter(riding.values())).q.shape[0]
+    scanned = {k: v for k, v in layers.items() if k not in riding}
+    scanned[_LAYER_INDEX] = jnp.arange(n_layers, dtype=jnp.int32)
+
+    def bind(lp: Params) -> Params:
+        lp = dict(lp)
+        li = lp.pop(_LAYER_INDEX)
+        return {**lp, **{k: LayerOf(w, li) for k, w in riding.items()}}
+
+    return scanned, bind
 
 
 def quantize_params(params: Params, extra_keys: tuple[str, ...] = ("lm_head",),

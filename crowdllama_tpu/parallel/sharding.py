@@ -114,6 +114,7 @@ def shard_params(params: Params, cfg: ModelConfig, mesh: Mesh) -> Params:
                 s=jax.device_put(
                     a.s, NamedSharding(mesh, filter_spec(
                         drop_input_axis_spec(s, a.q.ndim), mesh))),
+                mesh_devices=mesh.size,
             )
         if isinstance(a, QTensor4):
             # Group scales keep the weight's rank (input dim → group dim),

@@ -1,5 +1,6 @@
 """Deviceless TPU compiles of the main-path kernels, and of the runner's
-decode and ragged step programs, at Mistral-7B widths.
+decode and ragged step programs, at Mistral-7B widths — and, for the
+expert layers, at Mixtral-8x7B's and the Nemotron configuration's.
 
 The TPU's compiler is installed here and compiles for a chip that is
 described, not attached (``v5e:2x2``): what it refuses — a slice not aligned
@@ -69,6 +70,16 @@ def _no_persistent_cache():
     yield
     jax.config.update("jax_enable_compilation_cache", True)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def expert_kernel(monkeypatch):
+    """The expert banks take the grouped-matmul kernel, as on the chip:
+    here the backend is the CPU and the gate would refuse it."""
+    from crowdllama_tpu.ops.pallas import moe
+
+    monkeypatch.setattr(moe, "grouped_matmul_refusal",
+                        lambda q_shape, n_devices=1: "")
 
 
 def _sds(shape, dtype, sharding):
@@ -170,6 +181,33 @@ def test_tp4_wrapped_decode_kernel_compiles(topo, kv):
     assert ma.argument_size_in_bytes < 1.5 * per_dev_pool
 
 
+@pytest.mark.parametrize("shape", [
+    (32, 8, 4096, 14336, 4), (32, 8, 14336, 4096, 4),     # Mixtral decode
+    (256, 8, 4096, 14336, 4), (3072, 8, 14336, 4096, 4),  # and prefills
+    (704, 128, 1024, 2688, None), (704, 128, 2688, 1024, None),  # Nemotron
+    (2816, 128, 1024, 2688, None), (2816, 128, 2688, 1024, None),
+], ids=lambda s: "x".join(map(str, s)))
+def test_moe_grouped_matmul_compiles(one_chip, shape):
+    """The int8 grouped matmul at the cells' row counts and widths, with
+    the tiles it chooses: stacked leaf + layer index (Mixtral) or one
+    layer's bank (Nemotron)."""
+    from crowdllama_tpu.ops.pallas.moe import moe_grouped_matmul
+
+    m, e, d_in, d_out, layers = shape
+    lead = () if layers is None else (layers,)
+    args = [_sds((m, d_in), jnp.bfloat16, one_chip),
+            _sds((*lead, e, d_in, d_out), jnp.int8, one_chip),
+            _sds((*lead, e, d_out), jnp.bfloat16, one_chip),
+            _sds((e,), jnp.int32, one_chip)]
+    if layers is not None:
+        args.append(_sds((), jnp.int32, one_chip))
+    compiled = jax.jit(moe_grouped_matmul).lower(*args).compile()
+    _assert_kernel(compiled)
+    # nothing bank-sized beside the kernel: metadata, padding, the mask
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * m * d_out + (
+        1 << 20)
+
+
 # ------------------------------------------------- the runner's own programs
 
 
@@ -260,11 +298,144 @@ def test_ragged_step_program_keeps_the_pool_in_place(mistral_runner, one_chip,
     _assert_pool_stays_in_place(compiled, kv)
 
 
+@pytest.mark.parametrize("program", ["decode", "ragged_step", "prefill"])
+def test_dense_programs_are_what_they_were_without_riding_banks(
+        mistral_runner, one_chip, monkeypatch, program):
+    """``ride_banks`` stands in every layer loop, and a model without a
+    kernel-bound int8 expert bank must not see it: the layers come back as
+    they are (no ``LayerOf``, no scanned layer index beside them), and the
+    lowered program is, to the byte, the one a loop without it lowers."""
+    from crowdllama_tpu.engine import paged
+    from crowdllama_tpu.engine.runner import REPEAT_LAST_N
+    from crowdllama_tpu.models import transformer
+    from crowdllama_tpu.ops.quant import ride_banks
+
+    r, params, state, table = mistral_runner("bf16")
+    layers, bind = ride_banks(params["layers"])
+    assert layers is params["layers"] and bind(layers) is layers
+
+    def i32(*shape):
+        return _sds(shape, jnp.int32, one_chip)
+
+    def f32():
+        return _sds((), jnp.float32, one_chip)
+
+    def lowered() -> str:
+        jax.clear_caches()  # a bound method's trace is cached by equality
+        if program == "decode":
+            return jax.jit(
+                r._decode_paged_impl, donate_argnums=(1,), static_argnums=(3,)
+            ).lower(params, state, table, 1).as_text()
+        if program == "ragged_step":
+            return jax.jit(
+                r._ragged_step_impl, donate_argnums=(1,), static_argnums=(7,)
+            ).lower(params, state, table, i32(1, CHUNK), i32(1), i32(), i32(),
+                    1).as_text()
+        key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+        return jax.jit(r._prefill_impl).lower(
+            params, i32(1, r.buckets[0]), i32(), f32(), f32(), i32(), f32(),
+            i32(REPEAT_LAST_N), _sds(key.shape, key.dtype, one_chip)
+        ).as_text()
+
+    with_it = lowered()
+    for mod in (paged, transformer):
+        monkeypatch.setattr(mod, "ride_banks",
+                            lambda layers: (layers, lambda lp: lp))
+    assert lowered() == with_it
+
+
+# ------------------------ the expert layer, at Mixtral-8x7B widths (4 layers)
+
+MOE_SLOTS, MOE_LAYERS = 16, 4
+
+
+@pytest.fixture
+def mixtral_runner(one_chip, monkeypatch, expert_kernel):
+    """``() -> (runner, params, state, page table)`` at the widths, depth
+    and slots of ``mixtral-8x7b-d4-int8``, int8, from shapes alone."""
+    from crowdllama_tpu.engine import runner as runner_mod
+    from crowdllama_tpu.engine.paged import PagedModelRunner
+    from crowdllama_tpu.models.config import get_config
+    from crowdllama_tpu.ops.quant import random_quantized_params
+
+    monkeypatch.setattr(runner_mod, "shard_params", lambda p, cfg, mesh: p)
+
+    def build():
+        cfg = get_config("mixtral-8x7b", num_layers=MOE_LAYERS,
+                         max_context_length=PREFILL_T)
+        shapes = jax.eval_shape(lambda: random_quantized_params(
+            cfg, jax.random.PRNGKey(0), dtype=jnp.bfloat16))
+        r = PagedModelRunner(cfg, params=shapes, mesh_spec="1x1",
+                             max_slots=MOE_SLOTS, max_seq=PREFILL_T,
+                             page_size=PAGE)
+        assert r.moe_matmul_path == "int8_kernel"
+        r.attention_paths = {**r.attention_paths, "decode": "pallas",
+                             "ragged_step": "pallas"}
+
+        def on_chip(tree):
+            return jax.tree_util.tree_map(
+                lambda a: _sds(a.shape, a.dtype, one_chip), tree)
+
+        table = _sds((MOE_SLOTS, PAGES_PER_SLOT), jnp.int32, one_chip)
+        return r, on_chip(shapes), on_chip(jax.eval_shape(r.init_state)), table
+
+    return build
+
+
+def _assert_banks_are_read_in_place(compiled, state):
+    """The scan body calls the grouped-matmul kernel three times (gate, up,
+    down; layer and step loops are rolled) on the STACKED int8 leaves: no
+    buffer, fusion or ``copy`` of one layer's bank exists, int8 or bf16, and
+    the temporaries are far below one bank; the pools still stay in place."""
+    import re
+
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines() if " custom-call(" in ln
+             and "%moe_grouped_matmul" in ln.split(" = ")[0]]
+    assert len(calls) == 3, calls
+    bank = re.compile(r"= (s8|bf16)\[(1,)?8,(4096,14336|14336,4096)\]")
+    for line in text.splitlines():
+        assert not bank.search(line), f"a layer's bank: {line.strip()[:200]}"
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes < 8 * 4096 * 14336 // 4, ma.temp_size_in_bytes
+    pools = sum(a.size * a.dtype.itemsize
+                for a in (state.pool_k, state.pool_v))
+    assert ma.alias_size_in_bytes >= pools
+    pool_dims = ",".join(map(str, state.pool_k.shape[1:4]))
+    for line in text.splitlines():
+        head = line.split(" copy(")[0] if " copy(" in line else ""
+        assert pool_dims not in head, f"pool-shaped copy: {line.strip()[:200]}"
+
+
+@pytest.mark.parametrize("steps", [1, 8])
+def test_moe_decode_program_reads_the_int8_banks_in_place(mixtral_runner,
+                                                          steps):
+    r, params, state, table = mixtral_runner()
+    compiled = jax.jit(
+        r._decode_paged_impl, donate_argnums=(1,), static_argnums=(3,)
+    ).lower(params, state, table, steps).compile()
+    _assert_banks_are_read_in_place(compiled, state)
+
+
+def test_moe_ragged_step_program_reads_the_int8_banks_in_place(
+        mixtral_runner, one_chip):
+    r, params, state, table = mixtral_runner()
+
+    def i32(*shape):
+        return _sds(shape, jnp.int32, one_chip)
+
+    compiled = jax.jit(
+        r._ragged_step_impl, donate_argnums=(1,), static_argnums=(7,)
+    ).lower(params, state, table, i32(1, r.ragged_chunk), i32(1), i32(),
+            i32(), 1).compile()
+    _assert_banks_are_read_in_place(compiled, state)
+
+
 # ------------- a model whose layers differ in kind, at the benchmark's cut
 
 
 @pytest.fixture
-def nemotron_runner(one_chip, monkeypatch, tmp_path):
+def nemotron_runner(one_chip, monkeypatch, tmp_path, expert_kernel):
     """``() -> (runner, params, state, page table)``: the hybrid runner at
     the widths, depth and share of ``nemotron-3-super-p1-ep4-int8`` (the
     benchmark's configuration file, read as the worker reads it), int8,
@@ -309,12 +480,14 @@ def nemotron_runner(one_chip, monkeypatch, tmp_path):
 def _assert_both_states_stay_in_place(compiled, r, state,
                                       decode_kernel=True):
     """Every byte of the donated state — the attention layer's pools, the
-    Mamba layers' state and tail — is handed back where it lay; the
-    temporaries hold ONE dequantized bf16 expert bank and small change
-    (no second copy of a layer's weights: the layers are a list, and each
-    layer's weights are tied to its rows so that the step loop cannot hoist
-    their dequantized copies); no ``copy`` has the state's shape; the
-    decode attention kernel has one call site (one attention layer)."""
+    Mamba layers' state and tail — is handed back where it lay; the expert
+    banks go to the grouped-matmul kernel as int8 (ten call sites: five
+    expert layers, two matrices), so NO dequantized bank is among the
+    temporaries: a decode step's are under a sixteenth of one bf16 bank
+    (28 MB of 705), and a ragged step's are its 11,968 expert rows'
+    activations (342 MB), under half of one; no ``copy`` has the state's
+    shape; the decode attention kernel has one call site (one attention
+    layer)."""
     cfg = r.cfg
     bank = 2 * (cfg.experts_held * cfg.moe_latent_size
                 * cfg.moe_intermediate_size)              # bf16
@@ -322,12 +495,23 @@ def _assert_both_states_stay_in_place(compiled, r, state,
         state.pool_k, state.pool_v, state.ssm, state.conv))
     ma = compiled.memory_analysis()
     assert ma.alias_size_in_bytes >= kept, (ma.alias_size_in_bytes, kept)
-    assert ma.temp_size_in_bytes < 1.25 * bank, (ma.temp_size_in_bytes, bank)
+    limit = bank // 16 if decode_kernel else bank // 2
+    assert ma.temp_size_in_bytes < limit, (ma.temp_size_in_bytes, bank)
     text = compiled.as_text()
+    assert len([ln for ln in text.splitlines()
+                if " custom-call(" in ln and "%moe_grouped_matmul"
+                in ln.split(" = ")[0]]) == 10
     state_dims = ",".join(map(str, state.ssm.shape[1:]))
+    bank_dims = (f"[{cfg.experts_held},{cfg.moe_latent_size},"
+                 f"{cfg.moe_intermediate_size}]",
+                 f"[{cfg.experts_held},{cfg.moe_intermediate_size},"
+                 f"{cfg.moe_latent_size}]")
     for line in text.splitlines():
         head = line.split(" copy(")[0] if " copy(" in line else ""
         assert state_dims not in head, f"state-shaped copy: {line.strip()[:200]}"
+        if " fusion(" in line or " copy(" in line:
+            assert not any(f"= bf16{d}" in line or f"= s8{d}" in line
+                           for d in bank_dims), line.strip()[:200]
     if decode_kernel:
         assert len([ln for ln in text.splitlines()
                     if " custom-call(" in ln and "%paged_decode_attention"
@@ -360,3 +544,29 @@ def test_hybrid_ragged_step_program_keeps_both_states_in_place(
             1).compile()
     _assert_both_states_stay_in_place(compiled, r, state,
                                       decode_kernel=False)
+
+
+
+def test_hybrid_decode_step_lowers_one_kernel_body_per_shape(nemotron_runner):
+    """The layers are a Python list, so a step program holds ten call sites
+    of the grouped matmul, and every warm-up program is traced and lowered
+    anew at every start, compile cache or not: the kernel's entry is its
+    own ``jit``, so the ten sites CALL two lowered bodies — one a distinct
+    (rows, ``d_in``, ``d_out``) — where ten were 8 s of a warm start
+    (PERF.md §6, PR 29).  XLA inlines them: the compiled step above holds
+    ten ``%moe_grouped_matmul`` custom calls all the same."""
+    import re
+
+    r, params, state, table = nemotron_runner()
+    text = jax.jit(
+        r._decode_paged_impl, donate_argnums=(1,), static_argnums=(3,)
+    ).lower(params, state, table, 2).as_text()
+    bodies = re.findall(
+        r"func\.func private @(_grouped_matmul\w*)\(%arg0: tensor<(\d+)x(\d+)"
+        r"xbf16>, %arg1: tensor<\d+x\d+x(\d+)xi8>", text)
+    assert sorted((int(m), int(k), int(n)) for _, m, k, n in bodies) == [
+        (704, 1024, 2688), (704, 2688, 1024)]
+    for name, *_ in bodies:
+        assert len(re.findall(rf"call @{name}\(", text)) == 5
+    # two grouped-matmul kernels and the attention layer's
+    assert text.count("stablehlo.custom_call @tpu_custom_call") == 3
