@@ -5,9 +5,7 @@ PY ?= python
 PYTEST = env JAX_PLATFORMS=cpu $(PY) -m pytest -p no:cacheprovider
 
 .PHONY: test tier1 lint chaos chaos-multi-gateway chaos-soak \
-	distill-smoke bench-kv bench-mixed bench-megastep bench-fused \
-	bench-autopilot bench-swarm bench-spec-rtt trace-demo obs-demo \
-	chip-smoke
+	distill-smoke trace-demo obs-demo chip-smoke
 
 # Full suite (slow soaks included).  Runs lint + the chaos matrix FIRST:
 # swarmlint finishes in seconds and the fault-injection scenarios are the
@@ -57,7 +55,8 @@ chaos-multi-gateway:
 # byte-identical to its fault-free control with exactly one clean
 # terminal, stalled streams must recover within the stall budget +
 # failover slack, and hedge_launched == hedge_won + hedge_cancelled.
-# Deterministic schedule, < 120 s; artifact under benchmarks/results/.
+# Deterministic schedule, < 120 s; the report goes to a temporary
+# directory (--out-dir places it), its path on the last line printed.
 chaos-soak:
 	env JAX_PLATFORMS=cpu $(PY) -m crowdllama_tpu.testing.soak \
 		--seed 42 --streams 200
@@ -80,53 +79,3 @@ trace-demo:
 # `crowdllama-tpu top` table plus a /metrics/cluster excerpt.
 obs-demo:
 	env JAX_PLATFORMS=cpu PYTHONPATH=. $(PY) examples/obs_demo.py
-
-# KV-shipping benchmark (docs/KV_TRANSFER.md): fetch-vs-recompute TTFT
-# over real p2p streams with an injected-RTT sweep; writes the artifact
-# under benchmarks/results/.
-bench-kv:
-	env JAX_PLATFORMS=cpu CROWDLLAMA_BENCH_PHASES=kv_transfer $(PY) bench.py
-
-# Gateway-drafted speculative pipeline vs worker-paced stop-and-wait vs
-# plain streaming across injected swarm RTT (docs/SPECULATIVE.md).
-bench-spec-rtt:
-	env JAX_PLATFORMS=cpu CROWDLLAMA_BENCH_PHASES=spec_rtt $(PY) bench.py
-
-# Unified-ragged-batch benchmark (docs/RAGGED_BATCH.md): decode-step p95
-# while a long prefill chunks through the same jitted step (swept over
-# step_token_budget, with the retired alternating loop as the control),
-# plus a 32k-token prefill the monolithic one-shot path could not fit.
-bench-mixed:
-	env JAX_PLATFORMS=cpu CROWDLLAMA_BENCH_PHASES=mixed_batch,ctx32k \
-		$(PY) bench.py
-
-# Kernel-looped decode megastep (docs/MEGASTEP.md): decode steps/sec and
-# host dispatches per token, swept over K in {1,2,4,8} against the
-# per-step dispatch+readback control.
-bench-megastep:
-	env JAX_PLATFORMS=cpu CROWDLLAMA_BENCH_PHASES=decode_megastep \
-		$(PY) bench.py
-
-# Fused ragged megastep (docs/MEGASTEP.md "Fused ragged megastep"): the
-# mixed-batch phase's fused-vs-gated arms (decode-step p95 during a long
-# prefill, tokens per dispatch, host-gap share) plus the megastep K
-# sweep — the two phases that price megastep x ragged fusion.
-bench-fused:
-	env JAX_PLATFORMS=cpu \
-		CROWDLLAMA_BENCH_PHASES=mixed_batch,decode_megastep \
-		$(PY) bench.py
-
-# Closed-loop performance autopilot (docs/AUTOTUNE.md): three scenario
-# shapes under grid-search-best static dials vs the autotuner walking
-# from defaults — steps/sec ratio, moves-to-converge, dial trajectory
-# (artifact: benchmarks/results/AUTOTUNE_cpu_*.json).
-# Native data-plane arms (docs/NATIVE.md): the swarm_scaling phase run
-# twice — native fast path vs CROWDLLAMA_NO_NATIVE=1 — one subprocess
-# per arm; writes benchmarks/results/SWARM_SCALING_cpu_<date>.json with
-# req/s, cpu_us_per_request, loop lag, and the serde+aead share per arm.
-bench-swarm:
-	env JAX_PLATFORMS=cpu $(PY) benchmarks/swarm_scaling.py --arms
-
-bench-autopilot:
-	env JAX_PLATFORMS=cpu CROWDLLAMA_BENCH_PHASES=autopilot \
-		$(PY) bench.py

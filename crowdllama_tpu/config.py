@@ -126,7 +126,7 @@ class Configuration:
     # max_batch_slots) prompt tokens over the same paged pool.  0 = auto
     # (runner prefill_chunk + max_batch_slots: a full 512-token chunk
     # rides every step).  ragged_prefill=False keeps the legacy
-    # alternating chunked-prefill dispatch (the bench.py mixed_batch A/B).
+    # alternating chunked-prefill dispatch.
     step_token_budget: int = 0
     ragged_prefill: bool = True
     # Kernel-looped decode megastep (docs/MEGASTEP.md): K full decode
@@ -208,7 +208,7 @@ class Configuration:
     kv_ship: bool = False
     # Don't bother fetching when fewer than this many prefix tokens are
     # missing locally — below break-even the round trip costs more than
-    # the recompute it saves (benchmarks/kv_transfer.py measures it).
+    # the recompute it saves (no chip measurement of where that is).
     kv_ship_min_tokens: int = 512
     # Wall-clock cap on one fetch (dial + frames); charged against the
     # request's deadline budget like any other phase.

@@ -698,7 +698,7 @@ class ModelRunner:
         # pass the prompt uniformly; the spec runner needs it for its
         # n-gram history (engine/spec.py).  ``slot_key`` seeds the slot's
         # private sampling stream (scheduler derives it from the request
-        # seed); default keeps direct callers (bench, tests) deterministic.
+        # seed); default keeps direct callers (tests) deterministic.
         if slot_key is None:
             slot_key = default_slot_key(slot)
         recent_row = self._recent_from_prompt(
@@ -731,8 +731,8 @@ class ModelRunner:
 
         No host readback: chained calls pipeline — the next chunk dispatches
         while the previous one executes, so only the final readback pays the
-        host↔device round trip.  The scheduler and bench.py read tokens
-        back with ``np.asarray`` when they need them.
+        host↔device round trip.  The scheduler reads tokens back with
+        ``np.asarray`` when it needs them.
         """
         # Each distinct chunk length is a static arg → its own XLA program.
         t_c = ENGINE_TELEMETRY.compile_begin("decode", num_steps)

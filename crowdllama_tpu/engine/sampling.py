@@ -52,7 +52,7 @@ def split_slot_keys(keys: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
 
 
 def default_slot_key(slot: int) -> jax.Array:
-    """Deterministic per-slot key for direct runner callers (bench, tests)
+    """Deterministic per-slot key for direct runner callers (tests)
     that don't plumb a request seed — THE single definition, so the
     fallback cannot drift between the contiguous and paged runners."""
     return jax.random.fold_in(jax.random.PRNGKey(0), slot)

@@ -279,11 +279,10 @@ class Gateway:
         # detected by the first failed roundtrip and retried fresh.
         from crowdllama_tpu.net.host import StreamPool
 
-        # max_per_key matches typical per-worker request concurrency (the
-        # scaling bench drives 8 clients): with only 4 slots, a 1-worker
-        # swarm under 8-way concurrency redials on half its requests and
-        # the "small swarm" points pay handshakes the 16-worker points
-        # don't — skewing any cross-size CPU comparison.
+        # max_per_key matches typical per-worker request concurrency:
+        # with only 4 slots, a 1-worker swarm under 8-way concurrency
+        # redials on half its requests and pays handshakes a larger
+        # swarm doesn't.
         self._stream_pool = StreamPool(max_per_key=8)
         # Per-phase CPU attribution for the request hot path (monotonic
         # perf_counter_ns sums; exposed in /metrics and hotpath_snapshot):
@@ -335,8 +334,8 @@ class Gateway:
         self._affinity_repointed = 0
         self._kv_hints = 0
         # Cross-replica affinity: continuations whose pin came from the
-        # gossip map rather than this process's own LRU (the number the
-        # multi_gateway bench reports as cross-replica hit-rate).
+        # gossip map rather than this process's own LRU
+        # (crowdllama_gateway_gossip_affinity_hits_total).
         self._gossip_affinity_hits = 0
         # Per-tenant inflight (weighted-fair admission): tenant -> count.
         self._tenant_inflight: dict[str, int] = {}
@@ -344,8 +343,7 @@ class Gateway:
         # "off" routes plain streams; "gateway" drafts locally from
         # spec_draft_path and streams DraftChunk frames ahead of the
         # worker; "worker" sends pure ack credits (worker-paced remote
-        # speculation — the RTT-linear baseline the bench compares
-        # against).  The drafter loads lazily on first use so a gateway
+        # speculation — the RTT-linear baseline).  The drafter loads lazily on first use so a gateway
         # that never sees a remote-draft stream never touches jax.
         if spec_pipeline not in ("off", "gateway", "worker"):
             raise ValueError(
@@ -505,7 +503,7 @@ class Gateway:
     # Each helper charges the SAME timing to the process-wide _perf counters
     # (PR 1 exposition, hotpath_snapshot) and — when the caller passes a
     # per-request accumulator ``acc`` — to that request's trace spans, so
-    # bench phase numbers and /debug/trace spans are one instrumentation.
+    # the counters and /debug/trace spans are one instrumentation.
 
     def _encode_frame(self, msg, acc: dict | None = None) -> bytes:
         """Serialize a request ONCE per _route attempt; the same bytes are

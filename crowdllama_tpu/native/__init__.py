@@ -12,7 +12,7 @@ The first build can take tens of seconds.  ``load()`` therefore refuses to
 compile synchronously while an asyncio event loop is running on the
 calling thread — it kicks the build to a daemon thread and returns None
 (Python fallback) until the artifact is ready.  Call ``ensure_built()``
-from synchronous startup code (or ``make test`` / bench harnesses) to
+from synchronous startup code (or ``make test``) to
 front-load the compile.
 
 Set CROWDLLAMA_NO_NATIVE=1 to force the Python fallbacks.
@@ -357,7 +357,7 @@ def load() -> ctypes.CDLL | None:
 
 
 def ensure_built() -> bool:
-    """Blocking build+load for synchronous startup paths (make test, bench,
+    """Blocking build+load for synchronous startup paths (make test,
     process main before the loop starts).  Returns True when native is
     ready."""
     if env_flag("CROWDLLAMA_NO_NATIVE"):

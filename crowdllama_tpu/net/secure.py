@@ -41,10 +41,9 @@ CHUNK = 256 * 1024
 
 # Process-wide AEAD CPU attribution (seal + open), fed by every
 # SecureWriter/SecureReader in the process.  Per-request CPU breakdowns
-# (gateway.hotpath_snapshot, benchmarks/swarm_scaling.py) read deltas of
-# these to report aead_us.  Process-wide is deliberate: the swarm benches
-# run gateway and workers in one process, and splitting the counter per
-# stream would put a dict lookup on every frame for no analytical gain.
+# (gateway.hotpath_snapshot) read deltas of these to report aead_us.
+# Process-wide is deliberate: splitting the counter per stream would put
+# a dict lookup on every frame for no analytical gain.
 _aead_ns = 0
 _aead_ops = 0
 

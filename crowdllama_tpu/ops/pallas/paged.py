@@ -36,9 +36,8 @@ ceil(C/QB) prefill-chunk blocks differ only in their scalar-prefetched
 (q_start, kv_len, q_valid) metadata and page-table row, the sequential
 kv walk stops at each block's causal/validity bound (density-
 proportional cost), and the page-gather DMA is the double-buffered
-BlockSpec pipeline itself.  The v1 additive pair (decode kernel +
-chunk kernel, two launches) remains as the plain decode path and the
-TP building block.
+BlockSpec pipeline itself.  The decode kernel remains as the plain
+decode path and the TP building block.
 
 The reference has no kernels at all (compute is delegated to Ollama,
 /root/reference/pkg/crowdllama/api.go:108-160).
@@ -320,7 +319,7 @@ def flash_paged_decode_attention(
     return out.reshape(b, h, dh)
 
 
-# Query rows per chunk-kernel grid block.  32 keeps the fp32 online-
+# Query rows per ragged-kernel grid block.  32 keeps the fp32 online-
 # softmax scratch ([Hkv, QB*G, Dh] acc + two [Hkv, QB*G, _LANES] carries)
 # comfortably inside VMEM for Llama-class head counts.
 _CHUNK_QB = 32
@@ -338,12 +337,12 @@ def ragged_pallas_refusal(page_size: int, head_dim: int,
     (:func:`flash_ragged_paged_attention`), whose blocks are uniform
     [Hkv, QB, G, Dh] query tiles, so the constraints are the decode gate
     plus the chunk-sized VMEM footprint (QB*G query rows instead of G
-    per kv head) — identical bounds to the v1 kernel pair."""
+    per kv head)."""
     why = paged_pallas_refusal(page_size, head_dim, n_shards,
                                num_kv_heads, itemsize, quant)
     if why:
         return why
-    # Chunk kernel holds [Hkv, QB*G, Dh] fp32 acc + 2x [Hkv, QB*G, _LANES]
+    # A query block holds [Hkv, QB*G, Dh] fp32 acc + 2x [Hkv, QB*G, _LANES]
     # carries; with num_kv_heads=0 (availability probe) assume one head.
     hkv_local = max(max(num_kv_heads, 1) // max(n_shards, 1), 1)
     # G is unknown at probe time; bound by a generous 16 query groups.
@@ -362,180 +361,6 @@ def ragged_pallas_supported(page_size: int, head_dim: int,
                             quant: bool = False) -> bool:
     return not ragged_pallas_refusal(page_size, head_dim, n_shards,
                                      num_kv_heads, itemsize, quant)
-
-
-def _chunk_kernel(
-    # scalar prefetch
-    pages_ref,    # [NP] int32 — the chunk slot's page-table row
-    info_ref,     # [3] int32 — (ctx, kv_len, window)
-    layer_ref,    # [1] int32 — pool layer (read by the index maps only)
-    # operands: q, then PAIRS x (k, v), then PAIRS x (ks, vs) if quant
-    q_ref,        # [Hkv, QB, G, Dh] — one query block of the chunk
-    *refs,
-    scale: float,
-    softcap: float,
-    page: int,
-    pairs: int,
-    quant: bool,
-):
-    """Causal prefill-chunk attention over the slot's paged KV.
-
-    Structurally the decode kernel with QB*G query rows per kv head in
-    place of G: grid (q_blocks, kv_steps), online softmax carried across
-    the sequential kv dimension, causal + window masking per query row.
-    The fresh chunk's own KV has already been scattered into the pool by
-    the caller, so positions [ctx, kv_len) are read back like any other
-    page (self-attention within the chunk falls out of the causal mask)."""
-    kv = refs[: 2 * pairs]
-    scs = refs[2 * pairs: 4 * pairs] if quant else ()
-    o_ref, acc_ref, m_ref, l_ref = refs[-4:]
-
-    qb = pl.program_id(0)
-    p = pl.program_id(1)
-    num_steps = pl.num_programs(1)
-    ctx = info_ref[0]
-    kv_len = info_ref[1]
-    window = info_ref[2]
-    hkv, qbw, g, dh = q_ref.shape
-    rows = qbw * g
-
-    @pl.when(p == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    # Keys this q block can see: validity bound AND the causal bound of
-    # the block's last row — later pages are compute-skipped entirely.
-    block_bound = jnp.minimum(kv_len, ctx + (qb + 1) * qbw)
-    # Query positions per row: row r covers query (qb*QB + r//G).
-    qpos = (ctx + qb * qbw
-            + jax.lax.broadcasted_iota(jnp.int32, (1, rows, 1), 1) // g)
-
-    def _tile(j):
-        k_ref, v_ref = kv[2 * j], kv[2 * j + 1]
-        base = (p * pairs + j) * page
-
-        @pl.when(base < block_bound)
-        def _body():
-            q = q_ref[...].astype(jnp.float32).reshape(hkv, rows, dh)
-            k_tile = k_ref[...].astype(jnp.float32)  # [Hkv, page, Dh]
-            v_tile = v_ref[...].astype(jnp.float32)
-            kpos = base + jax.lax.broadcasted_iota(jnp.int32, (1, 1, page), 2)
-
-            logits = jax.lax.dot_general(
-                q, k_tile, (((2,), (2,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32,
-            ) * scale
-            if quant:
-                logits = logits * scs[2 * j][...].astype(jnp.float32)[:, None]
-            logits = _softcap(logits, softcap)
-
-            mask = (kpos < kv_len) & (kpos <= qpos)
-            mask &= (window <= 0) | (kpos > qpos - window)
-            logits = jnp.where(mask, logits, NEG_INF)
-
-            m_prev = m_ref[:, :, :1]
-            l_prev = l_ref[:, :, :1]
-            m_new = jnp.maximum(m_prev,
-                                jnp.max(logits, axis=-1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            pr = jnp.exp(logits - m_new) * mask.astype(jnp.float32)
-            l_new = l_prev * alpha + jnp.sum(pr, axis=-1, keepdims=True)
-            if quant:
-                pr = pr * scs[2 * j + 1][...].astype(jnp.float32)[:, None]
-            pv = jax.lax.dot_general(
-                pr, v_tile, (((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32,
-            )
-            acc_ref[...] = acc_ref[...] * alpha + pv
-            m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-            l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
-
-    for j in range(pairs):
-        _tile(j)
-
-    @pl.when(p == num_steps - 1)
-    def _finalize():
-        l = l_ref[:, :, :1]
-        l = jnp.where(l == 0.0, 1.0, l)
-        out = (acc_ref[...] / l).astype(o_ref.dtype)
-        o_ref[...] = out.reshape(hkv, qbw, g, dh)
-
-
-def flash_ragged_chunk_attention(
-    q: jnp.ndarray,           # [C, H, Dh] — the chunk's query rows
-    pool_k: jnp.ndarray,      # [L, P, Hkv, page, Dh]
-    pool_v: jnp.ndarray,
-    layer: int | jnp.ndarray,  # scalar int32 — the layer whose pages to read
-    pages: jnp.ndarray,       # [NP] int32 — the chunk slot's page row
-    ctx_len: jnp.ndarray,     # scalar int32 — tokens already in the pool
-    kv_len: jnp.ndarray,      # scalar int32 — ctx_len + valid chunk rows
-    scale: float,
-    softcap: float = 0.0,
-    sliding_window: int | jnp.ndarray = 0,
-    k_scale: jnp.ndarray | None = None,  # [L, P, Hkv, page]
-    v_scale: jnp.ndarray | None = None,
-) -> jnp.ndarray:
-    """One prefill chunk's attention over its slot's paged KV in layer
-    ``layer`` of the stacked pool.
-
-    The chunk's own K/V must already be scattered into the pool (the
-    engine writes them in the same step); query row j attends kv
-    positions < ctx_len + j + 1.  Rows past the valid chunk length
-    produce garbage the caller drops.  Output [C, H, Dh]."""
-    c, h, dh = q.shape
-    _, _, hkv, page, _ = pool_k.shape
-    g = h // hkv
-    np_ = pages.shape[0]
-    quant = k_scale is not None
-
-    qb = _CHUNK_QB
-    qblocks = -(-c // qb)
-    # [C, H, Dh] -> [Hkv, Cpad, G, Dh]: kv-head-major so the kernel's dot
-    # batches over Hkv like the decode kernel.
-    qx = q.reshape(c, hkv, g, dh).transpose(1, 0, 2, 3)
-    if qblocks * qb != c:
-        qx = jnp.pad(qx, ((0, 0), (0, qblocks * qb - c), (0, 0), (0, 0)))
-
-    info = jnp.stack([
-        jnp.asarray(ctx_len, jnp.int32).reshape(()),
-        jnp.asarray(kv_len, jnp.int32).reshape(()),
-        jnp.asarray(sliding_window, jnp.int32).reshape(()),
-    ])
-    pages = pages.astype(jnp.int32)
-
-    def q_map(qi, pi, *refs):
-        return (0, qi, 0, 0)
-
-    pairs, steps, kv_specs, kv_operands = _page_stream(
-        pool_k, pool_v, k_scale, v_scale, np_,
-        lambda qi, idx, pr, *refs: pr[idx])
-
-    kernel = functools.partial(
-        _chunk_kernel,
-        scale=scale, softcap=float(softcap or 0.0), page=page,
-        pairs=pairs, quant=quant,
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(qblocks, steps),
-        in_specs=[pl.BlockSpec((hkv, qb, g, dh), q_map), *kv_specs],
-        out_specs=pl.BlockSpec((hkv, qb, g, dh), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((hkv, qb * g, dh), jnp.float32),
-            pltpu.VMEM((hkv, qb * g, _LANES), jnp.float32),
-            pltpu.VMEM((hkv, qb * g, _LANES), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((hkv, qblocks * qb, g, dh), q.dtype),
-        interpret=_interpret(),
-        name="ragged_chunk_attention",
-    )(pages, info, _layer_operand(layer), qx, *kv_operands)
-    return out[:, :c].transpose(1, 0, 2, 3).reshape(c, h, dh)
 
 
 def ragged_paged_attention_ref(
@@ -652,8 +477,8 @@ def _ragged_v2_kernel(
       the slot is inactive — the block skips entirely), so row 0 sees
       exactly the decode kernel's ``kpos < seq_len`` window;
     - a CHUNK block j has ``q_start = ctx + j*QB`` and ``q_valid =
-      clip(chunk_len - j*QB, 0, QB)`` — exactly the chunk kernel's
-      causal prefill over the slot's pages.
+      clip(chunk_len - j*QB, 0, QB)`` — a causal prefill over the
+      slot's pages.
 
     Cost is density-proportional by construction: the sequential kv grid
     walks ``table_ref[n]`` only up to ``min(kv_len, q_start + q_valid)``
@@ -760,11 +585,9 @@ def flash_ragged_paged_attention(
     """Ragged-paged attention v2 layout: the whole mixed batch — B decode
     sequences + one prefill chunk — in a SINGLE pallas_call.
 
-    v1 ran the additive kernel pair (decode kernel + chunk kernel, two
-    launches, two grids).  v2 packs both into one grid of ``B +
-    ceil(C/QB)`` uniform head-packed query blocks whose behavior is
-    driven entirely by a scalar-prefetched ``(q_start, kv_len, q_valid)``
-    row and a per-block page-table row (decode block n gets slot n's
+    One grid of ``B + ceil(C/QB)`` uniform head-packed query blocks whose
+    behavior is driven entirely by a scalar-prefetched ``(q_start, kv_len,
+    q_valid)`` row and a per-block page-table row (decode block n gets slot n's
     row; every chunk block gets ``chunk_slot``'s).  Pages come from layer
     ``layer`` of the stacked pool; the chunk's fresh KV must already be
     scattered into it.  Output [B + C, H, Dh]."""
@@ -869,10 +692,8 @@ def ragged_paged_attention(
     otherwise the pure-JAX reference runs (tier-1 / CPU).  Both require
     the chunk's fresh KV to already be scattered into the pool; the ref
     additionally takes it as ``chunk_k``/``chunk_v`` operands so its
-    self block matches the monolithic prefill bitwise.  The v1 additive
-    pair (:func:`flash_paged_decode_attention` +
-    :func:`flash_ragged_chunk_attention`) remains for the plain decode
-    path / TP wrapper and as the per-population building blocks."""
+    self block matches the monolithic prefill bitwise.  The plain decode
+    path and the TP wrapper use :func:`flash_paged_decode_attention`."""
     if not use_pallas:
         return ragged_paged_attention_ref(
             q, chunk_k, chunk_v, pool_k, pool_v, layer, page_table, q_lens,

@@ -29,8 +29,8 @@ Hysteresis shape (classic dual-watermark with cooldown):
   the survivors and briefly LOOKS hot).
 
 ``simulate()`` runs the controller against a deterministic queueing model
-through a 4x load swing and returns a tick-by-tick record — the committed
-``benchmarks/results/AUTOSCALE_SIM_*.json`` artifact comes from it.
+through a 4x load swing and returns a tick-by-tick record
+(``tests/test_autoscale.py`` asserts on it).
 """
 
 from __future__ import annotations

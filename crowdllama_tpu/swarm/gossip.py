@@ -223,7 +223,7 @@ class TenantQuotas:
     ``fair_share`` is the weighted share of a gateway's inflight cap the
     tenant may occupy while the cap is under pressure: quota weights
     divide the cap, so one hot tenant saturating its share cannot starve
-    a light tenant's admission (the tenant-isolation bench phase)."""
+    a light tenant's admission."""
 
     def __init__(self, quotas: dict[str, float], node_id: str = ""):
         if not quotas:

@@ -7,8 +7,7 @@ acceptance rates across paths turns into a numeric lottery.  The
 generators here build weights whose margins are O(1) by construction, so
 path-stable greedy decode is a property of the checkpoint, not luck.
 
-Shared by benchmarks/spec_rtt.py and the speculative-pipeline chaos
-tests; jax is imported lazily so the module stays importable from
+Used by the speculative-pipeline chaos tests; jax is imported lazily so the module stays importable from
 accelerator-free test collection.
 """
 

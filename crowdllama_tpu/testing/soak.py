@@ -28,7 +28,8 @@ the jitter RNG is seeded, so a red soak replays with the same seed.
 Which concurrent stream absorbs a given fault depends on interleaving,
 but every invariant above is interleaving-independent by construction.
 
-Artifact: ``benchmarks/results/SOAK_seed<seed>.json``.
+Artifact: ``SOAK_seed<seed>.json`` under ``--out-dir`` (default: a fresh
+temporary directory; the last line printed names the file).
 
 Run: ``make chaos-soak`` (wired into ``make test``) or::
 
@@ -42,6 +43,7 @@ import argparse
 import asyncio
 import json
 import sys
+import tempfile
 import time
 from pathlib import Path
 
@@ -376,14 +378,16 @@ def main(argv: list[str] | None = None) -> int:
     # healthy targets, so a kill replay always has somewhere to go.
     ap.add_argument("--workers", type=int, default=5)
     ap.add_argument("--concurrency", type=int, default=16)
-    ap.add_argument("--out-dir", type=Path,
-                    default=Path("benchmarks/results"))
+    ap.add_argument("--out-dir", type=Path, default=None,
+                    help="where the report goes (default: a fresh "
+                         "temporary directory)")
     args = ap.parse_args(argv)
     if args.workers < 3:
         ap.error("--workers must be >= 3 (two stalls quarantine two)")
+    out_dir = args.out_dir or Path(tempfile.mkdtemp(prefix="soak_"))
     try:
         asyncio.run(run_soak(args.seed, args.streams, args.workers,
-                             args.concurrency, args.out_dir))
+                             args.concurrency, out_dir))
     except SoakFailure as e:
         print(f"error: {e}", file=sys.stderr)
         return 1

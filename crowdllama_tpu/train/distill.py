@@ -2,9 +2,9 @@
 
 Trains a small (default 2-layer) draft transformer to mimic the MAIN
 model's next-token distribution, so ``spec_decode=draft`` proposes tokens
-the verifier actually accepts — the bench bracketed a 1.12 tokens/step
-floor (random-init draft) and a 4.79 ceiling (self-draft); this loop is
-what moves real deployments off the floor.
+the verifier actually accepts: a random-init draft is accepted almost
+never, a self-draft always; this loop is what moves a deployment off the
+floor.
 
 Pure JAX, no training framework: the corpus is synthetic sequences
 SAMPLED FROM THE TEACHER ITSELF (plus an optional text file), the loss is
@@ -45,7 +45,7 @@ log = logging.getLogger("crowdllama.train.distill")
 class DistillConfig:
     teacher: str = "tiny-test"   # registry name of the main model
     teacher_path: str = ""       # its checkpoint ("" = random init, the
-    #                              tier-1/bench teacher: seed-0 init is
+    #                              tier-1 teacher: seed-0 init is
     #                              exactly what the test engine serves)
     draft_layers: int = 2
     steps: int = 1200
@@ -142,8 +142,8 @@ def rollout_corpus(cfg: ModelConfig, params, key, num_seqs: int,
 
 
 def corpus_from_text(path: str, vocab_size: int, seq_len: int) -> np.ndarray:
-    """Byte-level tokenization of a text file (bytes mod vocab — the same
-    scheme bench.py's natural-text workload uses), chunked into [N, S]."""
+    """Byte-level tokenization of a text file (bytes mod vocab), chunked
+    into [N, S]."""
     data = np.frombuffer(open(path, "rb").read(), np.uint8).astype(np.int32)
     data = data % vocab_size
     n = len(data) // seq_len

@@ -1,6 +1,6 @@
 """Where JAX's persistent compilation cache lives — the ONE setter.
 
-Every process that compiles (worker CLI, bench.py, benchmarks/*,
+Every process that compiles (worker CLI, benchmarks/chip/,
 chip_smoke.py, the tests) calls :func:`enable_compile_cache` before its
 first compile.  The directory is part of the cache key, so it must not
 move between runs: where ``JAX_COMPILATION_CACHE_DIR`` is set JAX's own

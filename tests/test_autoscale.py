@@ -1,8 +1,7 @@
 """Elastic drain/scale loop (crowdllama_tpu/swarm/autoscale.py): the
 hysteresis controller that turns the swarm's load gauges into
 drain/undrain decisions, its victim selection, the /metrics parser it
-feeds from, and the deterministic simulation behind the committed
-``benchmarks/results/AUTOSCALE_SIM_*.json`` artifact."""
+feeds from, and its deterministic simulation (``simulate()``)."""
 
 from crowdllama_tpu.swarm import (
     AutoscaleConfig,

@@ -1,10 +1,18 @@
-"""CLI surface tests: parser wiring, version, config layering from env."""
+"""CLI surface tests: parser wiring, version, config layering from env,
+where the compile cache goes, and a consumer node that never touches JAX."""
 
 import os
+import subprocess
+import sys
+from pathlib import Path
+
+import jax
 
 from crowdllama_tpu.cli.dht import main as dht_main
 from crowdllama_tpu.cli.main import build_parser, main
 from crowdllama_tpu.config import Configuration
+
+REPO = Path(__file__).resolve().parent.parent
 
 
 def test_version_command(capsys):
@@ -113,3 +121,64 @@ async def test_run_chat_one_shot_and_history(capsys):
         assert await _run_chat(args) == 1
     finally:
         await teardown()
+
+
+def test_compile_cache_helper_honours_env_else_fixed_checkout_path(
+        monkeypatch):
+    from crowdllama_tpu.utils import jaxcache
+
+    def configured():
+        return getattr(jax.config, jaxcache.CACHE_OPTION)
+
+    before = configured()
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/placed")
+    assert jaxcache.enable_compile_cache() == "/somewhere/placed"
+    assert configured() == before  # JAX's own handling stands
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        assert jaxcache.enable_compile_cache() == str(REPO / ".jax_cache")
+        assert configured() == str(REPO / ".jax_cache")
+    finally:
+        jax.config.update(jaxcache.CACHE_OPTION, before)  # as found
+
+
+_CONSUMER_SCRIPT = """
+import asyncio, sys
+from crowdllama_tpu.config import Configuration
+from crowdllama_tpu.engine.engine import FakeEngine
+from crowdllama_tpu.gateway.gateway import Gateway
+from crowdllama_tpu.obs.http import node_metric_lines
+from crowdllama_tpu.peer.peer import Peer
+from crowdllama_tpu.utils.crypto_compat import Ed25519PrivateKey
+
+async def main():
+    cfg = Configuration(listen_host="127.0.0.1")
+    peer = Peer(Ed25519PrivateKey.generate(), cfg, engine=FakeEngine(models=[]),
+                worker_mode=False)
+    await peer.start()
+    try:
+        peer.update_metadata()
+        assert peer.resource.accelerator == "", peer.resource.accelerator
+        gw = Gateway(peer, port=0)
+        resp = await gw.handle_metrics(None)
+        text = resp.text + "\\n".join(node_metric_lines(peer))
+        assert 'crowdllama_device_memory_bytes_limit{device="0"} 0' in text
+    finally:
+        await peer.stop()
+
+asyncio.run(main())
+if "jax" in sys.modules:
+    from jax._src import xla_bridge
+    assert not xla_bridge.backends_are_initialized(), "backend initialized"
+print("clean")
+"""
+
+
+def test_consumer_peer_start_leaves_jax_backends_uninitialised():
+    """Gateway/consumer nodes (and their /metrics) never initialize a JAX
+    backend: on the chip it belongs to the worker process beside them."""
+    proc = subprocess.run([sys.executable, "-c", _CONSUMER_SCRIPT],
+                          cwd=REPO, capture_output=True, text=True,
+                          timeout=120)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert proc.stdout.strip().endswith("clean")

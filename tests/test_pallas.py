@@ -183,8 +183,7 @@ def test_ragged_v2_matches_reference(softcap, window):
 
 
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
-@pytest.mark.parametrize("kernel", ["decode", "ragged_v2", "chunk",
-                                    "decode_tp"])
+@pytest.mark.parametrize("kernel", ["decode", "ragged_v2", "decode_tp"])
 def test_stacked_pool_kernel_reads_its_layer(kernel, kv):
     """Every paged kernel takes the whole ``[L, P, Hkv, page, Dh]`` pool and
     a (traced) layer index: its answer at layer ``l`` is bit for bit the
@@ -223,7 +222,7 @@ def test_stacked_pool_kernel_reads_its_layer(kernel, kv):
                 return pp.flash_paged_decode_attention_tp(
                     q, pk, pv, layer, table, lens, scale, mesh,
                     sliding_window=40, k_scale=sk, v_scale=sv)
-    elif kernel == "ragged_v2":
+    else:
         q = jax.random.normal(ks[2], (b + c, h, dh), dt)
         q_lens = jnp.asarray([1, 0, c], jnp.int32)
         kv_lens = jnp.asarray([70, 0, ctx + c], jnp.int32)
@@ -232,14 +231,6 @@ def test_stacked_pool_kernel_reads_its_layer(kernel, kv):
             return pp.flash_ragged_paged_attention(
                 q, pk, pv, layer, table, q_lens, kv_lens, jnp.int32(1),
                 scale, sliding_window=40, k_scale=sk, v_scale=sv)
-    else:
-        q = jax.random.normal(ks[2], (c, h, dh), dt)
-
-        def run(pk, pv, sk, sv, layer):
-            return pp.flash_ragged_chunk_attention(
-                q, pk, pv, layer, table[1], jnp.int32(ctx),
-                jnp.int32(ctx + c), scale, sliding_window=40,
-                k_scale=sk, v_scale=sv)
 
     def cut(a, layer):
         return None if a is None else a[layer][None]

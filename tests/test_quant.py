@@ -4,6 +4,7 @@ integration, and mesh sharding of QTensor leaves."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from crowdllama_tpu.models import transformer as T
 from crowdllama_tpu.models.config import get_config
@@ -258,3 +259,31 @@ def test_int4_runner_decodes():
     state = runner.insert(state, 0, ks, vs, plen, tok, 0.0, 1.0)
     toks, state = runner.decode_steps(state, 4)
     assert toks.shape == (4, runner.max_slots)
+
+
+def test_load_params_for_random_init_is_seeded_and_never_builds_bf16_tree(
+        monkeypatch):
+    from crowdllama_tpu.config import Configuration
+    from crowdllama_tpu.engine import weights
+    from crowdllama_tpu.ops import quant
+
+    cfg = weights.resolve_clamped_model_config(
+        Configuration(model="tiny-test", quantize="int8"))
+    real_init = T.init_params
+
+    def abstract_only(cfg_, key, *a, **kw):
+        assert isinstance(key, jax.core.Tracer), (
+            "init_params ran concretely: the bf16 tree was materialized")
+        return real_init(cfg_, key, *a, **kw)
+
+    monkeypatch.setattr(T, "init_params", abstract_only)
+    monkeypatch.setattr(quant, "quantize_params", lambda *a, **k: (
+        pytest.fail("quantize-after-init ran")))
+    config = Configuration(model="tiny-test", quantize="int8")
+    a = weights.load_params_for(config, cfg)
+    b = weights.load_params_for(config, cfg)
+    la, lb = jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)
+    assert len(la) == len(lb) > 0
+    for x, y in zip(la, lb):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    assert any(x.dtype == np.int8 for x in la)
