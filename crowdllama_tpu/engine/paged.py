@@ -52,6 +52,7 @@ from crowdllama_tpu.engine.sampling import (
     REPEAT_LAST_N,
     apply_repeat_penalty,
     default_slot_key,
+    ring_with_first,
     sample_tokens,
     sample_tokens_slots,
     split_slot_keys,
@@ -425,7 +426,8 @@ class PagedModelRunner(ModelRunner):
                    temperature, top_p, top_k, repeat_penalty, recent_row,
                    slot_key) -> PagedDecodeState:
         """``state`` with ``slot`` live: its KV (and whatever else the
-        model carries per slot) is already in place."""
+        model carries per slot) is already in place.  ``recent_row`` is
+        the ring of the prompt alone; the first token joins it here."""
         return replace(
             state,
             seq_lens=state.seq_lens.at[slot].set(plen),
@@ -435,7 +437,8 @@ class PagedModelRunner(ModelRunner):
             top_p=state.top_p.at[slot].set(top_p),
             top_k=state.top_k.at[slot].set(top_k),
             repeat_penalty=state.repeat_penalty.at[slot].set(repeat_penalty),
-            recent=state.recent.at[slot].set(recent_row),
+            recent=state.recent.at[slot].set(
+                ring_with_first(recent_row, plen, first_token)),
             keys=state.keys.at[slot].set(slot_key))
 
     def _release_paged_impl(self, state: PagedDecodeState, slot):
@@ -686,7 +689,7 @@ class PagedModelRunner(ModelRunner):
         )
         ENGINE_TELEMETRY.compile_end("ctx_prefill", bucket, t_c)
         self._pending_match = (keys, matched)
-        return int(tok), ks, vs, plen
+        return tok, ks, vs, plen
 
     def _decode_layers(self, params, x, positions, pools, attend, st,
                        live, chunk=None):
@@ -1104,8 +1107,10 @@ class PagedModelRunner(ModelRunner):
                             keys[ki - 1], set()).add(keys[ki])
         if slot_key is None:
             slot_key = default_slot_key(slot)
-        recent_row = self._recent_from_prompt(
-            list(prompt_tokens or []), first_token, plen=plen)
+        # ``first_token`` may still be on the device (prefill's scalar):
+        # the program puts it into the prompt's ring itself.
+        recent_row = self._recent_from_prompt(list(prompt_tokens or []),
+                                              plen=plen)
         t_c = ENGINE_TELEMETRY.compile_begin("insert_paged", ks.shape[3])
         out = self._insert_paged(
             state, jnp.asarray(fresh, jnp.int32), ks, vs, jnp.int32(slot),
@@ -1438,9 +1443,9 @@ class PagedModelRunner(ModelRunner):
         activate the slot.  Returns (first_token, new_state)."""
         assert job.finished and job.last_logits is not None
         plen = len(job.prompt_ids)
+        recent_row = jnp.asarray(self._recent_from_prompt(job.prompt_ids))
         logits = apply_repeat_penalty(
-            job.last_logits[None, :],
-            jnp.asarray(self._recent_from_prompt(job.prompt_ids))[None],
+            job.last_logits[None, :], recent_row[None],
             jnp.float32(repeat_penalty)[None])
         tok = sample_tokens(logits,
                             jnp.float32(temperature)[None],
@@ -1449,13 +1454,11 @@ class PagedModelRunner(ModelRunner):
         first = int(tok)
         if slot_key is None:
             slot_key = default_slot_key(job.slot)
-        recent_row = self._recent_from_prompt(job.prompt_ids, first,
-                                              plen=plen)
         t_c = ENGINE_TELEMETRY.compile_begin("ragged_finish", 0)
         state = self._ragged_activate(
             state, jnp.int32(job.slot), jnp.int32(plen), jnp.int32(first),
             jnp.float32(temperature), jnp.float32(top_p), jnp.int32(top_k),
-            jnp.float32(repeat_penalty), jnp.asarray(recent_row), slot_key)
+            jnp.float32(repeat_penalty), recent_row, slot_key)
         ENGINE_TELEMETRY.compile_end("ragged_finish", 0, t_c)
         self._host_seq[job.slot] = plen
         self._ragged_index(job)

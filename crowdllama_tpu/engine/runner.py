@@ -31,6 +31,7 @@ from crowdllama_tpu.engine.sampling import (
     REPEAT_LAST_N,
     apply_repeat_penalty,
     default_slot_key,
+    ring_with_first,
     sample_tokens,
     sample_tokens_slots,
     split_slot_keys,
@@ -336,7 +337,8 @@ class ModelRunner:
             top_p=state.top_p.at[slot].set(top_p),
             top_k=state.top_k.at[slot].set(top_k),
             repeat_penalty=state.repeat_penalty.at[slot].set(repeat_penalty),
-            recent=state.recent.at[slot].set(recent_row),
+            recent=state.recent.at[slot].set(
+                ring_with_first(recent_row, plen, first_token)),
             keys=state.keys.at[slot].set(slot_key),
             k_scale=k_scale, v_scale=v_scale,
             hist=state.hist,
@@ -607,7 +609,9 @@ class ModelRunner:
     def prefill(self, prompt_ids: list[int], temperature: float, top_p: float,
                 key: jax.Array, state: DecodeState | None = None,
                 top_k: int = 0, repeat_penalty: float = 1.0):
-        """Run bucketed prefill; returns (first_token, ks, vs, plen).
+        """Run bucketed prefill; returns (first_token, ks, vs, plen), the
+        token as the device scalar the program sampled: nothing here waits
+        for the device, and :meth:`insert` takes the scalar as it is.
 
         ``state`` is accepted (and ignored) so the scheduler can pass its
         live decode state uniformly; the paged runner uses it for prefix-
@@ -625,7 +629,7 @@ class ModelRunner:
             jnp.asarray(self._recent_from_prompt(prompt_ids)), key,
         )
         ENGINE_TELEMETRY.compile_end("prefill", bucket, t_c)
-        return int(tok), ks, vs, plen
+        return tok, ks, vs, plen
 
     _EMBED_BATCH = (1, 2, 4, 8)  # padded batch sizes (bounds compile count)
 
@@ -701,8 +705,10 @@ class ModelRunner:
         # seed); default keeps direct callers (tests) deterministic.
         if slot_key is None:
             slot_key = default_slot_key(slot)
-        recent_row = self._recent_from_prompt(
-            list(prompt_tokens or []), first_token, plen=plen)
+        # The ring of the prompt alone: the program writes ``first_token``
+        # (a Python int, or prefill's scalar still on the device) into it.
+        recent_row = self._recent_from_prompt(list(prompt_tokens or []),
+                                              plen=plen)
         # Insert compiles once per prefill-bucket KV width (ks [L,1,Hkv,T,Dh]).
         sig = ks.shape[3]
         t_c = ENGINE_TELEMETRY.compile_begin("insert", sig)

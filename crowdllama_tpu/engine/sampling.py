@@ -40,6 +40,16 @@ def apply_repeat_penalty(logits, recent, penalty):
     return jnp.where(presence & (pen != 1.0), adj, logits)
 
 
+def ring_with_first(ring, plen, first_token):
+    """The last-N ring [N] of a prompt of ``plen`` tokens with the first
+    sampled token in it, traced: the token sits at sequence position
+    ``plen``, so in ring slot ``plen % N`` (over the prompt token N
+    positions back, if the prompt is that long).  The insert programs
+    write it here, on the device, so the host need not know the token to
+    seed the ring."""
+    return ring.at[plen % REPEAT_LAST_N].set(first_token)
+
+
 def split_slot_keys(keys: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Per-slot PRNG split: keys [B, 2] -> (carry [B, 2], sub [B, 2]).
 

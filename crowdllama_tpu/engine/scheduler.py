@@ -22,6 +22,11 @@ with it.  Each chunk carries a snapshot of the slots it was dispatched for;
 emission checks slot identity against the snapshot, so a slot retired (or
 retired-and-readmitted) between dispatch and readback never receives
 another chunk's tokens.
+
+An admission does not drain the device either: prefill hands its sampled
+token back as a device scalar, insert takes it unread, and the host reads
+and emits it only after the NEXT flight — which already carries the new row
+— is in the device's queue (``_place``, ``_emit_firsts``).
 """
 
 from __future__ import annotations
@@ -150,6 +155,9 @@ class _SlotInfo:
     req: GenRequest
     prompt_len: int = 0
     generated: int = 0
+    # The request's first token while it is still on the device and not
+    # yet emitted (``Scheduler._place``); None from its emission on.
+    first_dev: object = None
 
 
 @dataclass
@@ -875,13 +883,22 @@ class Scheduler:
         await self._place(req, slot, ks, vs, plen, first)
 
     async def _place(self, req: GenRequest, slot: int, ks, vs, plen: int,
-                     first: int) -> None:
-        """Insert a prefilled request into its slot and emit its first
-        token (shared by monolithic and chunked admission).  Runs the
-        insert on the dispatch executor: under multi-host serving
-        (parallel/replicated.py) every runner call is also a cross-host
-        broadcast, which must never block the event loop."""
+                     first) -> None:
+        """Insert a prefilled request into its slot (shared by monolithic
+        and chunked admission).  ``first`` is what the runner's prefill
+        handed back.  A Python int (a chunked finish, the multi-host
+        wrapper) is emitted here, behind the insert.  Anything else is the
+        sampled scalar still on the device: the insert takes it unread, the
+        slot is placed so the next flight carries the row, and
+        ``_emit_firsts`` reads and emits the token once that flight is
+        queued — the host waits for nothing in between, so the device
+        never drains across an admission.  Runs the insert on the dispatch
+        executor: under multi-host serving (parallel/replicated.py) every
+        runner call is also a cross-host broadcast, which must never block
+        the event loop."""
         loop = asyncio.get_running_loop()
+        on_host = isinstance(first, (int, np.integer))
+        ENGINE_TELEMETRY.admission_inc("host" if on_host else "device")
 
         def insert():
             return self.runner.insert(
@@ -891,10 +908,52 @@ class Scheduler:
                 repeat_penalty=req.repeat_penalty)
 
         self.state = await self._call(loop, "insert", insert)
-        await self._stamp_first_token(req)
         info = _SlotInfo(req=req, prompt_len=plen)
+        if not on_host:
+            info.first_dev = first
+            self.slots[slot] = info
+            return
+        await self._stamp_first_token(req)
         self.slots[slot] = info
-        self._emit_first(req, first, info)
+        self._emit_first(req, int(first), info)
+        await self._flush_releases(loop)
+
+    def _firsts(self) -> "list[tuple[int, _SlotInfo]]":
+        """The slots whose first token is still on the device."""
+        return [(i, s) for i, s in enumerate(self.slots)
+                if isinstance(s, _SlotInfo) and s.first_dev is not None]
+
+    async def _emit_firsts(self, loop,
+                           firsts: "list[tuple[int, _SlotInfo]]") -> None:
+        """Read and emit the first tokens ``_place`` left on the device.
+
+        ``firsts`` are the slots placed before this turn's flight was
+        dispatched (``_firsts()`` right behind the dispatch), and the call
+        comes once this turn's own admissions are queued too: the flight
+        carries their rows and the device has work for as long as the host
+        waits.  The read runs on a pool thread (the loop stays free, the
+        dispatch stream untouched) and waits for the prefill alone — the
+        device runs its queue in order, so whatever was ahead of the
+        prefill is done when it is.  No flight with such a row is retired
+        before this point, so a stream's first token always precedes its
+        flight tokens.  A slot that was swept meanwhile (cancelled,
+        migrated, failed) has no owner left: its token is dropped, and the
+        flight's row is discarded by the snapshot check like any overshoot
+        — as is the row of a stream whose first token ends it (``_emit``
+        releases the slot here, between dispatches)."""
+
+        def read(token) -> int:
+            with jax.profiler.TraceAnnotation(SCHED_READBACK, first_token=1):
+                return int(token)
+
+        for slot, info in firsts:
+            if self.slots[slot] is not info:
+                continue
+            first = await loop.run_in_executor(None, read, info.first_dev)
+            info.first_dev = None
+            await self._stamp_first_token(info.req)
+            if self.slots[slot] is info and not info.req.finished:
+                self._emit_first(info.req, first, info)
         await self._flush_releases(loop)
 
     async def _flush_releases(self, loop) -> None:
@@ -966,9 +1025,11 @@ class Scheduler:
             req = info.req
             if req.eos_id is not None and req.eos_id >= 0:
                 eos[i] = req.eos_id
+            # a first token still on the device is as good as emitted
+            generated = info.generated + (info.first_dev is not None)
             budgets[i] = max(0, min(
-                req.max_tokens - info.generated,
-                (self.runner.max_seq - 1) - info.prompt_len - info.generated))
+                req.max_tokens - generated,
+                (self.runner.max_seq - 1) - info.prompt_len - generated))
         return eos, budgets
 
     def _spec_retune(self, accepted: int, offered: int) -> None:
@@ -1432,6 +1493,9 @@ class Scheduler:
                                 top_k=req.top_k,
                                 repeat_penalty=req.repeat_penalty)
 
+                        # ragged_finish reads its token itself (and no
+                        # insert follows it): an admission on the host.
+                        ENGINE_TELEMETRY.admission_inc("host")
                         try:
                             first, self.state = await self._call(
                                 loop, "ragged_finish", finish)
@@ -1446,6 +1510,10 @@ class Scheduler:
                         self._emit_first(req, first, info)
                         await self._flush_releases(loop)
             elif paced:
+                # Credits are positioned against emitted counts, and a
+                # paced round gives up the overlap anyway: first tokens
+                # go out before the round, not behind it.
+                await self._emit_firsts(loop, self._firsts())
                 dispatched = await self._dispatch_paced(loop, paced)
             elif live:
                 done_dev = None
@@ -1469,6 +1537,11 @@ class Scheduler:
                     tokens_dev=tokens_dev, snapshot=list(self.slots),
                     dispatched_at=time.monotonic(), done_dev=done_dev,
                     counters_dev=self._flight_counters())
+
+        # Every slot placed so far rides the flight just queued: the host
+        # may wait for their first tokens — once this turn's own admissions
+        # are queued behind it too, so that the wait keeps nothing back.
+        firsts = self._firsts()
 
         # Advance an in-progress LEGACY chunked admission by ONE prefill
         # chunk (ragged jobs already advanced inside the dispatch above).
@@ -1621,6 +1694,7 @@ class Scheduler:
             if sum(1 for s in self.slots if isinstance(s, _SlotInfo)) > 1:
                 break
 
+        await self._emit_firsts(loop, firsts)
         # Retire the PREVIOUS chunk (readback overlaps the new dispatch and
         # any prefill above).
         await self._retire_inflight(loop)

@@ -455,6 +455,12 @@ class EngineTelemetry:
             LabelGuard(allowed=DISPATCH_CLASSES))
         for cls in DISPATCH_CLASSES:
             self.host_gap_seconds.labels(cls)
+        # Admissions by where their first token was when the scheduler
+        # placed them (Scheduler._place): "device" — prefill's scalar went
+        # into the insert unread and the next flight was queued before the
+        # host read anything; "host" — the runner handed back an int (the
+        # chunked and ragged finishes, the multi-host wrapper).
+        self._admissions = {"device": 0, "host": 0}
 
     def _key(self, program: str, bucket: object) -> tuple[str, str]:
         return (self.program_guard.value(program),
@@ -517,6 +523,10 @@ class EngineTelemetry:
             self._prefix["tokens_reused"] += max(0, int(tokens_reused))
             self._prefix["hits"] += max(0, int(hits))
 
+    def admission_inc(self, first_token: str) -> None:
+        with self._lock:
+            self._admissions[first_token] += 1
+
     def moe_assignments_inc(self, held: int, left_out: int) -> None:
         with self._lock:
             self._moe_assignments["yes"] += max(0, int(held))
@@ -561,6 +571,7 @@ class EngineTelemetry:
             flight_steps = dict(self._flight_steps)
             startup = dict(self._startup)
             moe = dict(self._moe_assignments)
+            admissions = dict(self._admissions)
             state_bytes = sorted(self._state_bytes.items())
         out.append("# TYPE crowdllama_engine_attention_path gauge")
         if not attention:
@@ -628,6 +639,10 @@ class EngineTelemetry:
             "crowdllama_prefill_chunk_seconds"))
         out.extend(self.host_gap_seconds.expose(
             "crowdllama_host_gap_seconds"))
+        out.append("# TYPE crowdllama_admissions_total counter")
+        for where, n in admissions.items():
+            out.append(f'crowdllama_admissions_total{{first_token="{where}"'
+                       f'}} {n}')
         return out
 
 

@@ -192,9 +192,12 @@ class ReplicatedRunner:
                     floats=(float(temperature), float(top_p),
                             float(repeat_penalty)),
                     key=key, prompt=prompt_ids)
-        return self.inner.prefill(prompt_ids, temperature, top_p, key,
-                                  state=state, top_k=top_k,
-                                  repeat_penalty=repeat_penalty)
+        tok, ks, vs, plen = self.inner.prefill(
+            prompt_ids, temperature, top_p, key, state=state, top_k=top_k,
+            repeat_penalty=repeat_penalty)
+        # The insert frame carries the token to every process as an int,
+        # so each of them builds the same insert program from it.
+        return int(tok), ks, vs, plen
 
     def prefill_begin(self, prompt_ids, state=None):
         self._bcast(_OP_PREFILL_BEGIN, ints=(len(prompt_ids),),

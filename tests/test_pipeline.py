@@ -94,7 +94,7 @@ def test_runner_pp_matches_dense_greedy():
         first, ks, vs, plen = r.prefill(prompt, 0.0, 1.0, jax.random.PRNGKey(0))
         state = r.insert(state, 0, ks, vs, plen, first, 0.0, 1.0)
         toks, state = r.decode_steps(state, 8)
-        return [first] + [int(t) for t in toks[:, 0]]
+        return [int(first)] + [int(t) for t in toks[:, 0]]
 
     base = run("1x1x1x1x1")
     pp = run("1x2x1x1x2")  # pp=2, tp=2

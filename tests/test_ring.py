@@ -102,7 +102,7 @@ def test_runner_sp_matches_dense_greedy():
         first, ks, vs, plen = r.prefill(prompt, 0.0, 1.0, jax.random.PRNGKey(0))
         state = r.insert(state, 0, ks, vs, plen, first, 0.0, 1.0)
         toks, state = r.decode_steps(state, 8)
-        return [first] + [int(t) for t in toks[:, 0]]
+        return [int(first)] + [int(t) for t in toks[:, 0]]
 
     base = run("1x1x1x1")
     sp = run("1x4x1x2")  # sp=4, tp=2

@@ -31,7 +31,7 @@ def _spec_rollout(spec, prompt, steps, temperature=0.0):
                                        jax.random.PRNGKey(7))
     state = spec.insert(state, 0, ks, vs, plen, first, temperature, 1.0,
                         prompt_tokens=prompt)
-    toks = [first]
+    toks = [int(first)]
     packed, state = spec.decode_steps(state, steps)
     for step in range(packed.shape[0]):
         n = int(packed[step, 0, 0])

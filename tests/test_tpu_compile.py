@@ -344,6 +344,59 @@ def test_dense_programs_are_what_they_were_without_riding_banks(
     assert lowered() == with_it
 
 
+@pytest.mark.parametrize("program,differs", [
+    ("decode_1", False), ("decode_8", False), ("ragged_step", False),
+    ("prefill", False), ("insert", True)])
+def test_only_the_insert_program_takes_the_first_token_into_the_ring(
+        mistral_runner, one_chip, monkeypatch, program, differs):
+    """An admission's first token stays on the device: the insert program
+    writes it into the slot's repeat-penalty ring, at ``plen % N``, where
+    the host used to hand over a ring with the token already in it.  That
+    write is the WHOLE difference on the device: with it taken out, the
+    insert program is another module and the decode (1 and 8 steps),
+    ragged-step and prefill programs lower to the same text — so neither
+    a step nor a warm-up is a program more or another program."""
+    from crowdllama_tpu.engine import paged
+    from crowdllama_tpu.engine.runner import REPEAT_LAST_N
+
+    r, params, state, table = mistral_runner("bf16")
+
+    def i32(*shape):
+        return _sds(shape, jnp.int32, one_chip)
+
+    def f32():
+        return _sds((), jnp.float32, one_chip)
+
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    key = _sds(key.shape, key.dtype, one_chip)
+    bucket = r.buckets[0]
+
+    def lowered() -> str:
+        jax.clear_caches()  # a bound method's trace is cached by equality
+        if program.startswith("decode"):
+            return jax.jit(
+                r._decode_paged_impl, donate_argnums=(1,), static_argnums=(3,)
+            ).lower(params, state, table, int(program[-1])).as_text()
+        if program == "ragged_step":
+            return jax.jit(
+                r._ragged_step_impl, donate_argnums=(1,), static_argnums=(7,)
+            ).lower(params, state, table, i32(1, CHUNK), i32(1), i32(), i32(),
+                    1).as_text()
+        if program == "prefill":
+            return jax.jit(r._prefill_impl).lower(
+                params, i32(1, bucket), i32(), f32(), f32(), i32(), f32(),
+                i32(REPEAT_LAST_N), key).as_text()
+        kv = _sds((LAYERS, 1, HKV, PAGE, DH), jnp.bfloat16, one_chip)
+        return jax.jit(r._insert_paged_impl, donate_argnums=(0,)).lower(
+            state, i32(1), kv, kv, i32(), i32(), i32(), f32(),
+            f32(), i32(), f32(), i32(REPEAT_LAST_N), key).as_text()
+
+    with_it = lowered()
+    monkeypatch.setattr(paged, "ring_with_first",
+                        lambda ring, plen, first_token: ring)
+    assert (lowered() != with_it) is differs
+
+
 # ------------------------ the expert layer, at Mixtral-8x7B widths (4 layers)
 
 MOE_SLOTS, MOE_LAYERS = 16, 4
