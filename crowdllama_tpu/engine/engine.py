@@ -612,6 +612,8 @@ class JaxEngine(Engine):
         self._runner = await loop.run_in_executor(None, _build)
         ENGINE_TELEMETRY.moe_matmul_path_set(
             getattr(self._runner, "moe_matmul_path", ""))
+        ENGINE_TELEMETRY.ssm_update_path_set(
+            getattr(self._runner, "ssm_update_path", ""))
         t_w = time.monotonic()
         if self.config.warmup:
             await loop.run_in_executor(None, self._warmup)

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 from crowdllama_tpu.engine.paged import PagedDecodeState, PagedModelRunner
 from crowdllama_tpu.models import hybrid as H
 from crowdllama_tpu.obs.metrics import ENGINE_TELEMETRY
+from crowdllama_tpu.ops.ssm import ssm_update_path
 
 log = logging.getLogger("crowdllama.engine.hybrid")
 
@@ -80,6 +81,15 @@ class HybridPagedModelRunner(PagedModelRunner):
                 f"{cfg.name!r} is served on one device: the recurrent state "
                 f"and the per-kind parameter stacks have no partition rules "
                 f"(mesh {dict(self.mesh.shape)})")
+        # which path the Mamba layers' one-step update takes in every
+        # decode-type program (ops/ssm.py ssm_update_at decides from the
+        # backend and the state's shape): crowdllama_ssm_update_path
+        self.ssm_update_path, why = ssm_update_path(
+            (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state))
+        log.info("state-space update path: %s", self.ssm_update_path)
+        if why and jax.default_backend() == "tpu":
+            log.warning("the state-space update runs as two XLA fusions on "
+                        "this TPU, not the Pallas kernel: %s", why)
         # the parent's out_shardings name three arrays
         self._prefill = jax.jit(self._prefill_impl)
         self._take_counts = jax.jit(self._take_counts_impl,
@@ -155,22 +165,22 @@ class HybridPagedModelRunner(PagedModelRunner):
             return out
 
         def ssm_fn(i, lp, xbc, dt):
-            y, tail, state = H.mamba_mix(
+            y, tail, stack = H.mamba_mix(
                 lp, cfg, xbc[:b, None], dt[:b, None], box["conv"][i],
-                box["ssm"][i], active)
+                box["ssm"], active, layer=i)
             y = y[:, 0]
             if chunk is not None:
                 slot, valid = chunk
+                at = (i, slot, 0, 0, 0)
                 yc, tc, sc = H.mamba_mix(
                     lp, cfg, xbc[None, b:], dt[None, b:],
                     jax.lax.dynamic_index_in_dim(tail, slot, 0),
-                    jax.lax.dynamic_index_in_dim(state, slot, 0),
+                    jax.lax.dynamic_slice(stack, at, (1, 1) + stack.shape[2:])[0],
                     valid[None].astype(jnp.int32))
                 tail = jax.lax.dynamic_update_index_in_dim(tail, tc[0], slot, 0)
-                state = jax.lax.dynamic_update_index_in_dim(state, sc[0],
-                                                            slot, 0)
+                stack = jax.lax.dynamic_update_slice(stack, sc[None], at)
                 y = jnp.concatenate([y, yc[0]])
-            box["ssm"] = box["ssm"].at[i].set(state)
+            box["ssm"] = stack
             box["conv"] = box["conv"].at[i].set(tail)
             return y
 

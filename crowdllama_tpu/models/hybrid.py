@@ -167,16 +167,19 @@ def _normed(lp: Params, cfg: ModelConfig, x):
     return rms_norm(x, lp["norm"], cfg.rms_norm_eps)
 
 
-def mamba_mix(lp: Params, cfg: ModelConfig, xbc, dt, tail, state, valid):
+def mamba_mix(lp: Params, cfg: ModelConfig, xbc, dt, tail, state, valid,
+              layer=None):
     """Convolution, activation and the state-space recurrence of one Mamba
     layer for S sequences of T rows, from ``(tail, state)`` to theirs after
     each sequence's ``valid`` real rows.
 
     xbc ``[S, T, conv_dim]`` and dt ``[S, T, H]`` as the input projection
     gave them; tail ``[S, conv_dim, K-1]``; state ``[S, H, P, N]`` float32;
-    valid ``[S]``.  T = 1 is the decode step's one-step update, anything
-    longer the chunked scan.  Returns (y ``[S, T, d_inner]`` float32, tail,
-    state)."""
+    valid ``[S]``: the chunked scan.  With ``layer`` — the decode step's
+    one-step update, T = 1 — ``state`` is the carried stack ``[L_M, S, H,
+    P, N]`` and that layer's slab of it is updated where it lies
+    (``ssm.ssm_update_at``).  Returns (y ``[S, T, d_inner]`` float32, tail,
+    state — the stack, given one)."""
     z = sizes(cfg)
     s, t = xbc.shape[:2]
     h, p, g, n = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state
@@ -190,9 +193,10 @@ def mamba_mix(lp: Params, cfg: ModelConfig, xbc, dt, tail, state, valid):
     dt = jnp.where(jnp.arange(t)[None, :, None] < valid[:, None, None], dt, 0.0)
     a = -jnp.exp(lp["A_log"].astype(F32))
     d = lp["D"].astype(F32)
-    if t == 1:
-        y, state = ssm.ssm_update(x[:, 0], dt[:, 0], a, b[:, 0], c[:, 0], d,
-                                  state)
+    if layer is not None:
+        assert t == 1, t
+        y, state = ssm.ssm_update_at(x[:, 0], dt[:, 0], a, b[:, 0], c[:, 0],
+                                     d, state, layer)
         y = y[:, None]
     else:
         y, state = ssm.ssd_scan(x, dt, a, b, c, d, state, cfg.ssm_chunk)

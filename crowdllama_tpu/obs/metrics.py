@@ -440,6 +440,10 @@ class EngineTelemetry:
         # newest engine's expert layers multiply their banks (ops/quant.py
         # qragged_dot; set at JaxEngine.start, "" without expert layers).
         self._moe_matmul_path = ""
+        # "pallas" | "xla": how the newest engine's Mamba layers update the
+        # carried state in a decode step (ops/ssm.py ssm_update_at; set at
+        # JaxEngine.start, "" without Mamba layers).
+        self._ssm_update_path = ""
         # Unified ragged batch (docs/RAGGED_BATCH.md): wall time per
         # prefill chunk carried inside a decode dispatch.  Engine-plane
         # like the compile histogram (the scheduler's dispatch loop
@@ -495,6 +499,10 @@ class EngineTelemetry:
     def moe_matmul_path_set(self, path: str) -> None:
         with self._lock:
             self._moe_matmul_path = path
+
+    def ssm_update_path_set(self, path: str) -> None:
+        with self._lock:
+            self._ssm_update_path = path
 
     def padding_inc(self, useful: int, waste: int) -> None:
         """Account one padded dispatch: ``useful`` real tokens rode it,
@@ -566,6 +574,7 @@ class EngineTelemetry:
             cache_hits = sorted(self._cache_hits.items())
             attention = sorted(self._attention_paths.items())
             moe_path = self._moe_matmul_path
+            ssm_path = self._ssm_update_path
             prefix = dict(self._prefix)
             flight_seconds = dict(self._flight_seconds)
             flight_steps = dict(self._flight_steps)
@@ -583,6 +592,9 @@ class EngineTelemetry:
         out.append("# TYPE crowdllama_moe_matmul_path gauge")
         out.append(f'crowdllama_moe_matmul_path{{path="{moe_path or "none"}"'
                    f'}} {1 if moe_path else 0}')
+        out.append("# TYPE crowdllama_ssm_update_path gauge")
+        out.append(f'crowdllama_ssm_update_path{{path="{ssm_path or "none"}"'
+                   f'}} {1 if ssm_path else 0}')
         out.append("# TYPE crowdllama_xla_compiles_total counter")
         if not compiles:
             out.append('crowdllama_xla_compiles_total{program="none",'

@@ -17,7 +17,13 @@ masked (``exp(0) = 1``, ``0 * x (x) B = 0``).
 
 Everything here is float32: the state is carried over thousands of steps
 and is the one place of this model where bf16 rounding accumulates.
-Plain XLA; a Pallas kernel for the update would be named ``ssm_...``
+
+This file is plain XLA.  The decode step's update of the CARRIED stack goes
+through :func:`ssm_update_at`, which on one TPU device hands the stack to
+the Pallas kernel ``ssm_update`` (``ops/pallas/ssm.py``: one pass over the
+state, where XLA makes two fusions of :func:`ssm_update`'s two expressions
+and reads the state twice) and elsewhere runs :func:`ssm_update` on the
+layer's slice; the kernels of this family are named ``ssm_...``
 (benchmarks/chip/TRACING.nemotron_h.md).
 """
 
@@ -71,6 +77,32 @@ def ssm_update(x, dt, a, b, c, d, state):
     state = state * decay + (dt[..., None] * x)[..., None] * bh[:, :, None, :]
     y = jnp.einsum("shpn,shn->shp", state, ch) + d[:, None] * x
     return y, state
+
+
+def ssm_update_path(state_shape: tuple[int, ...]) -> tuple[str, str]:
+    """(path, why not the kernel) of :func:`ssm_update_at` for a state
+    ``[.., H, P, N]``, from the backend and the shape — the names
+    ``crowdllama_ssm_update_path`` exports: ``pallas`` or ``xla``."""
+    from crowdllama_tpu.ops.pallas.ssm import ssm_update_refusal
+
+    why = ssm_update_refusal(tuple(state_shape))
+    return ("xla" if why else "pallas"), why
+
+
+def ssm_update_at(x, dt, a, b, c, d, stack, layer):
+    """:func:`ssm_update` on layer ``layer``'s slab of the carried stack
+    ``[L_M, S, H, P, N]``; returns (y, the stack with that slab updated).
+
+    On one TPU device (or in forced interpret mode) with a head dim of
+    whole sublanes and a state size of whole lanes: the Pallas
+    ``ssm_update``, which takes the whole stack and writes the slab in
+    place.  Elsewhere: :func:`ssm_update` on the slice."""
+    if ssm_update_path(stack.shape)[0] == "pallas":
+        from crowdllama_tpu.ops.pallas.ssm import ssm_update as kernel
+
+        return kernel(x, dt, a, b, c, d, stack, layer)
+    y, state = ssm_update(x, dt, a, b, c, d, stack[layer])
+    return y, stack.at[layer].set(state)
 
 
 @jax.named_scope("ssm_scan")

@@ -21,6 +21,12 @@ the position, (worst position, mean over positions):
   about 2.5 times that.  A missing scale, a wrong cast or a dropped layer
   moves both by whole standard deviations.  These rows run a router that
   chooses all its experts (``ALL_CHOSEN``, and why).
+
+The float32 row runs twice: as the CPU serves it (the decode step's state
+update as XLA's two fusions), and with the Pallas ``ssm_update`` in
+interpret mode in every decode-type program (``float32-kernel``: a state
+size of whole lanes, which the kernel asks for) — the kernel's precision
+gate, since the chip's own check would pass a bf16 state (PERF.md §7).
 """
 
 import json
@@ -58,6 +64,27 @@ ALL_CHOSEN = replace(CFG, num_experts=8, num_experts_per_tok=8,
 LIMITS = {"float32": (1e-3, 1e-4), "bfloat16": (0.15, 0.08),
           "int8": (0.15, 0.08)}
 PATHS = ("prefill", "decode", "ragged", "megastep")
+# the rows of LIMITS, and the float32 row with the state-update kernel
+ROWS = [*LIMITS, "float32-kernel"]
+
+
+@pytest.fixture
+def state_kernel(monkeypatch):
+    """``(row) -> precision``: for a ``-kernel`` row, the Pallas
+    ``ssm_update`` in interpret mode, which a runner built afterwards
+    takes for a state size of whole lanes (``kernel_cfg``)."""
+    def steer(row: str) -> str:
+        if row.endswith("-kernel"):
+            monkeypatch.setenv("CROWDLLAMA_PALLAS_INTERPRET", "1")
+        return row.removesuffix("-kernel")
+
+    return steer
+
+
+def kernel_cfg(row: str):
+    """The configuration a row runs: with the kernel, a state of 128."""
+    cfg = cfg_of(row.removesuffix("-kernel"))
+    return replace(cfg, ssm_state=128) if row.endswith("-kernel") else cfg
 
 
 def hf_of(cfg) -> dict:
@@ -113,8 +140,8 @@ class Probe(HybridPagedModelRunner):
         return super()._sampled(st, logits, pools, changed)
 
 
-def make_runner(precision: str, cls=Probe, **kwargs):
-    cfg = cfg_of(precision)
+def make_runner(precision: str, cls=Probe, cfg=None, **kwargs):
+    cfg = cfg or cfg_of(precision)
     dtype = jnp.float32 if precision == "float32" else jnp.bfloat16
     # 4 slots + chunks of 32 tokens: a 100-token prompt takes four steps
     return cls(cfg, params=make_params(precision, cfg), max_slots=4,
@@ -206,18 +233,25 @@ def run_path(r, path: str) -> list[tuple]:
 
 
 @pytest.mark.parametrize("path", PATHS)
-@pytest.mark.parametrize("precision", list(LIMITS))
-def test_logits_match_the_reference(precision, path):
-    r = make_runner(precision)
+@pytest.mark.parametrize("row", ROWS)
+def test_logits_match_the_reference(row, path, state_kernel):
+    precision = state_kernel(row)
+    r = make_runner(precision, cfg=kernel_cfg(row))
+    assert r.ssm_update_path == ("pallas" if row.endswith("-kernel")
+                                 else "xla")
     worst_lim, mean_lim = LIMITS[precision]
     for what, logits, ids, positions in run_path(r, path):
         worst, mean = distance(logits, ids, positions, r)
         assert worst <= worst_lim and mean <= mean_lim, (what, worst, mean)
 
 
-def test_bf16_state_in_place_of_float32_reads_over_the_limit(monkeypatch):
+@pytest.mark.parametrize("row", ["float32", "float32-kernel"])
+def test_bf16_state_in_place_of_float32_reads_over_the_limit(
+        row, monkeypatch, state_kernel):
     """The float32 row of LIMITS holds the state's precision: a state
-    rounded to bf16 after every update fails it."""
+    rounded to bf16 after every update fails it, on either path."""
+    from crowdllama_tpu.ops.pallas import ssm as kernel
+
     def rounded(fn):
         def wrapped(*args, **kwargs):
             y, state = fn(*args, **kwargs)
@@ -226,7 +260,8 @@ def test_bf16_state_in_place_of_float32_reads_over_the_limit(monkeypatch):
 
     monkeypatch.setattr(ssm, "ssm_update", rounded(ssm.ssm_update))
     monkeypatch.setattr(ssm, "ssd_scan", rounded(ssm.ssd_scan))
-    r = make_runner("float32")
+    monkeypatch.setattr(kernel, "ssm_update", rounded(kernel.ssm_update))
+    r = make_runner(state_kernel(row), cfg=kernel_cfg(row))
     worst = max(distance(logits, ids, positions, r)[0]
                 for _, logits, ids, positions in run_path(r, "ragged"))
     assert worst > 3 * LIMITS["float32"][0], worst

@@ -82,6 +82,16 @@ def expert_kernel(monkeypatch):
                         lambda q_shape, n_devices=1: "")
 
 
+@pytest.fixture
+def state_kernel(monkeypatch):
+    """The Mamba layers' decode-step update takes the ``ssm_update`` kernel,
+    as on the chip (the same steering, for the same reason)."""
+    from crowdllama_tpu.ops.pallas import ssm as ssm_kernel
+
+    monkeypatch.setattr(ssm_kernel, "ssm_update_refusal",
+                        lambda state_shape: "")
+
+
 def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
@@ -298,6 +308,35 @@ def test_ragged_step_program_keeps_the_pool_in_place(mistral_runner, one_chip,
     _assert_pool_stays_in_place(compiled, kv)
 
 
+def _lower_program(r, params, state, table, one_chip, program: str) -> str:
+    """The lowered text of one of a paged runner's programs (1-step decode,
+    1-step ragged step, the first prefill bucket), traced anew."""
+    from crowdllama_tpu.engine.runner import REPEAT_LAST_N
+
+    def i32(*shape):
+        return _sds(shape, jnp.int32, one_chip)
+
+    def f32():
+        return _sds((), jnp.float32, one_chip)
+
+    jax.clear_caches()  # a bound method's trace is cached by equality
+    if program == "decode":
+        return jax.jit(
+            r._decode_paged_impl, donate_argnums=(1,), static_argnums=(3,)
+        ).lower(params, state, table, 1).as_text()
+    if program == "ragged_step":
+        return jax.jit(
+            r._ragged_step_impl, donate_argnums=(1,), static_argnums=(7,)
+        ).lower(params, state, table, i32(1, r.ragged_chunk), i32(1), i32(),
+                i32(), 1).as_text()
+    assert program == "prefill", program
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    return jax.jit(r._prefill_impl).lower(
+        params, i32(1, r.buckets[0]), i32(), f32(), f32(), i32(), f32(),
+        i32(REPEAT_LAST_N), _sds(key.shape, key.dtype, one_chip)
+    ).as_text()
+
+
 @pytest.mark.parametrize("program", ["decode", "ragged_step", "prefill"])
 def test_dense_programs_are_what_they_were_without_riding_banks(
         mistral_runner, one_chip, monkeypatch, program):
@@ -306,42 +345,56 @@ def test_dense_programs_are_what_they_were_without_riding_banks(
     they are (no ``LayerOf``, no scanned layer index beside them), and the
     lowered program is, to the byte, the one a loop without it lowers."""
     from crowdllama_tpu.engine import paged
-    from crowdllama_tpu.engine.runner import REPEAT_LAST_N
     from crowdllama_tpu.models import transformer
     from crowdllama_tpu.ops.quant import ride_banks
 
     r, params, state, table = mistral_runner("bf16")
+    assert r.ragged_chunk == CHUNK
     layers, bind = ride_banks(params["layers"])
     assert layers is params["layers"] and bind(layers) is layers
 
-    def i32(*shape):
-        return _sds(shape, jnp.int32, one_chip)
-
-    def f32():
-        return _sds((), jnp.float32, one_chip)
-
-    def lowered() -> str:
-        jax.clear_caches()  # a bound method's trace is cached by equality
-        if program == "decode":
-            return jax.jit(
-                r._decode_paged_impl, donate_argnums=(1,), static_argnums=(3,)
-            ).lower(params, state, table, 1).as_text()
-        if program == "ragged_step":
-            return jax.jit(
-                r._ragged_step_impl, donate_argnums=(1,), static_argnums=(7,)
-            ).lower(params, state, table, i32(1, CHUNK), i32(1), i32(), i32(),
-                    1).as_text()
-        key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
-        return jax.jit(r._prefill_impl).lower(
-            params, i32(1, r.buckets[0]), i32(), f32(), f32(), i32(), f32(),
-            i32(REPEAT_LAST_N), _sds(key.shape, key.dtype, one_chip)
-        ).as_text()
-
-    with_it = lowered()
+    with_it = _lower_program(r, params, state, table, one_chip, program)
     for mod in (paged, transformer):
         monkeypatch.setattr(mod, "ride_banks",
                             lambda layers: (layers, lambda lp: lp))
-    assert lowered() == with_it
+    assert _lower_program(r, params, state, table, one_chip,
+                          program) == with_it
+
+
+@pytest.mark.parametrize("program", ["decode", "ragged_step", "prefill"])
+@pytest.mark.parametrize("model", ["mistral", "mixtral"])
+def test_programs_without_mamba_layers_never_meet_the_state_kernel(
+        request, one_chip, monkeypatch, model, program):
+    """The ``ssm_update`` kernel and its gate serve the ``nemotron_h``
+    family alone: with the gate steered open as on the chip, Mistral's and
+    Mixtral's decode, ragged-step and prefill programs lower to the same
+    text as with it shut, and lowering them enters neither ``ops/ssm.py``
+    nor the kernel's file — their step programs are the parent's."""
+    from crowdllama_tpu.ops import ssm
+    from crowdllama_tpu.ops.pallas import ssm as ssm_kernel
+
+    build = request.getfixturevalue(f"{model}_runner")
+    r, params, state, table = build(*(["bf16"] if model == "mistral" else []))
+    assert not hasattr(r, "ssm_update_path")
+
+    def never(*args, **kwargs):
+        raise AssertionError("a model without Mamba layers met ops/ssm.py")
+
+    def lowered(steered: bool) -> str:
+        if steered:
+            monkeypatch.setattr(ssm_kernel, "ssm_update_refusal",
+                                lambda shape: "")
+            for mod, names in ((ssm, ("ssm_update_at", "ssm_update",
+                                      "ssd_scan", "causal_conv",
+                                      "ssm_update_path")),
+                               (ssm_kernel, ("ssm_update", "_ssm_update"))):
+                for name in names:
+                    monkeypatch.setattr(mod, name, never)
+        return _lower_program(r, params, state, table, one_chip, program)
+
+    # one call site: a kernel's serialized body carries its call stack
+    shut, steered = (lowered(k) for k in (False, True))
+    assert steered == shut
 
 
 @pytest.mark.parametrize("program,differs", [
@@ -488,7 +541,8 @@ def test_moe_ragged_step_program_reads_the_int8_banks_in_place(
 
 
 @pytest.fixture
-def nemotron_runner(one_chip, monkeypatch, tmp_path, expert_kernel):
+def nemotron_runner(one_chip, monkeypatch, tmp_path, expert_kernel,
+                    state_kernel):
     """``() -> (runner, params, state, page table)``: the hybrid runner at
     the widths, depth and share of ``nemotron-3-super-p1-ep4-int8`` (the
     benchmark's configuration file, read as the worker reads it), int8,
@@ -517,6 +571,7 @@ def nemotron_runner(one_chip, monkeypatch, tmp_path, expert_kernel):
         r = HybridPagedModelRunner(cfg, params=shapes, mesh_spec="1x1",
                                    max_slots=slots, max_seq=PREFILL_T,
                                    page_size=PAGE)
+        assert r.ssm_update_path == "pallas"
         r.attention_paths = {**r.attention_paths, "decode": "pallas",
                              "ragged_step": "pallas"}
 
@@ -621,5 +676,56 @@ def test_hybrid_decode_step_lowers_one_kernel_body_per_shape(nemotron_runner):
         (704, 1024, 2688), (704, 2688, 1024)]
     for name, *_ in bodies:
         assert len(re.findall(rf"call @{name}\(", text)) == 5
-    # two grouped-matmul kernels and the attention layer's
-    assert text.count("stablehlo.custom_call @tpu_custom_call") == 3
+    # and the five Mamba layers' state update is one body, called five times
+    (update,) = re.findall(r"func\.func private @(_ssm_update\w*)\(", text)
+    assert len(re.findall(rf"call @{update}\(", text)) == 5
+    # two grouped-matmul kernels, the state update and the attention layer's
+    assert text.count("stablehlo.custom_call @tpu_custom_call") == 4
+
+
+@pytest.mark.parametrize("steps", [1, 2])
+def test_hybrid_decode_program_passes_over_the_state_once(nemotron_runner,
+                                                          steps):
+    """The decode step's state-space update is the ``ssm_update`` kernel on
+    the WHOLE carried stack, aliased in to out, inside the step loop: five
+    custom calls a step (the cell serves with 1- and 2-step programs), and
+    nothing else that touches the state — no XLA fusion with the stack or a
+    layer's slab of it among its operands (the benchmark's
+    ``kernel.ssm_state_roofline`` would charge it to the share, rightly) and
+    no ``copy`` of either: one copy of the 671 MB stack is 1.6 ms on the
+    chip, more than the kernel gains."""
+    r, params, state, table = nemotron_runner()
+    compiled = jax.jit(
+        r._decode_paged_impl, donate_argnums=(1,), static_argnums=(3,)
+    ).lower(params, state, table, steps).compile()
+    lines = compiled.as_text().splitlines()
+    calls = [ln for ln in lines if " custom-call(" in ln
+             and "%ssm_update" in ln.split(" = ")[0]]
+    assert len(calls) == 5, calls
+    stack = "f32[" + ",".join(map(str, state.ssm.shape)) + "]"
+    slab = "f32[" + ",".join(map(str, state.ssm.shape[1:])) + "]"
+    for ln in calls:
+        assert stack in ln.split(" custom-call(")[0], ln.strip()[:200]
+    for ln in lines:
+        if any(f" {op}(" in ln for op in ("fusion", "copy", "copy-start")):
+            assert stack not in ln and slab not in ln, ln.strip()[:200]
+    assert compiled.memory_analysis().alias_size_in_bytes >= (
+        state.ssm.size * 4)
+
+
+def test_ssm_update_kernel_compiles_at_the_cell_shape(one_chip):
+    """The kernel alone, with the tile it chooses at the cell's state
+    ``[5, 32, 128, 64, 128]``: no temporary, the stack handed back."""
+    from crowdllama_tpu.ops.pallas.ssm import ssm_update
+
+    def f32(*shape):
+        return _sds(shape, jnp.float32, one_chip)
+
+    m, s, h, p, n, g = 5, 32, 128, 64, 128, 8
+    compiled = jax.jit(ssm_update, donate_argnums=(6,)).lower(
+        f32(s, h, p), f32(s, h), f32(h), f32(s, g, n), f32(s, g, n), f32(h),
+        f32(m, s, h, p, n), _sds((), jnp.int32, one_chip)).compile()
+    _assert_kernel(compiled)
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes == m * s * h * p * n * 4
+    assert ma.temp_size_in_bytes < (1 << 20)
