@@ -13,6 +13,7 @@ from __future__ import annotations
 import asyncio
 import logging
 import os
+import socket
 import time
 from dataclasses import dataclass
 from typing import AsyncIterator
@@ -78,6 +79,19 @@ class ProfileDisabled(RuntimeError):
 
 class ProfileBusy(RuntimeError):
     """A profiler trace is already running (or none is, on stop)."""
+
+
+# The shortest trace profile_stop collects, and capture_profile's floor.  A
+# decode flight is 40-100 ms, so a trace stopped as soon as it was started
+# holds nothing for a reader; and collecting is no small thing to do for
+# nothing: the session describes every PROGRAM that ran while it was on (the
+# HLO protos, 11 MB for a decode program of ~4,000 ops, 31 MB with an
+# admission's programs), which took 1.5-1.9 s mostly and 3.7-20 s inside an
+# admission burst, whatever the trace held.  Dropping the session took
+# 0.24-0.27 s, 14 of 14.  Half a second, because a stop asked for at once
+# found the trace on for 0.00-0.01 s mostly and for 0.16, 0.19 and 0.35 s when
+# the loop was late to it — under the same bursts (PERF.md §6, PR 37).
+MIN_TRACE_S = 0.5
 
 
 class StopMatcher:
@@ -537,6 +551,7 @@ class JaxEngine(Engine):
         # trace (profile_start's answer) while it can be stopped.
         self._profile_latch = False
         self._profile: dict | None = None
+        self._profile_session = None
         self._profile_seq = 0
 
     def attach_peer(self, peer) -> None:
@@ -614,6 +629,10 @@ class JaxEngine(Engine):
             getattr(self._runner, "moe_matmul_path", ""))
         ENGINE_TELEMETRY.ssm_update_path_set(
             getattr(self._runner, "ssm_update_path", ""))
+        ENGINE_TELEMETRY.kda_update_path_set(
+            getattr(self._runner, "kda_update_path", ""))
+        ENGINE_TELEMETRY.attn_decode_path_set(
+            getattr(self._runner, "attn_decode_path", ""))
         t_w = time.monotonic()
         if self.config.warmup:
             await loop.run_in_executor(None, self._warmup)
@@ -1075,12 +1094,15 @@ class JaxEngine(Engine):
 
         def _start() -> None:
             import jax
+            from jax._src.lib import _profiler
 
             opts = jax.profiler.ProfileOptions()
             opts.python_tracer_level = 0
             opts.host_tracer_level = 2
             os.makedirs(path, exist_ok=True)
-            jax.profiler.start_trace(path, profiler_options=opts)
+            # the session jax.profiler.start_trace makes, held here so that
+            # profile_stop can write the XSpace alone
+            self._profile_session = _profiler.ProfilerSession(opts)
             started["started_monotonic"] = time.monotonic()
             started["started_unix"] = time.time()
 
@@ -1095,7 +1117,9 @@ class JaxEngine(Engine):
     async def profile_stop(self) -> dict:
         """End the running trace; answers once the ``.xplane.pb`` is
         written, with the artifact directory and the host's clock at start
-        and stop, monotonic and unix."""
+        and stop, monotonic and unix.  A trace stopped before it was on for
+        :data:`MIN_TRACE_S` is ended without being collected: the artifact
+        directory stays empty and ``xspace_bytes`` is 0."""
         if not self.config.profile_dir:
             raise ProfileDisabled("profiling disabled: set profile_dir")
         if self._profile is None:
@@ -1103,23 +1127,48 @@ class JaxEngine(Engine):
         done, self._profile = self._profile, None
 
         def _stop() -> None:
-            import jax
-
             done["stopped_monotonic"] = time.monotonic()
             done["stopped_unix"] = time.time()
-            jax.profiler.stop_trace()
+            session, self._profile_session = self._profile_session, None
+            if (done["stopped_monotonic"] - done["started_monotonic"]
+                    < MIN_TRACE_S):
+                # dropping the last reference stops the tracers; nothing is
+                # gathered from them
+                del session
+                xspace = b""
+            else:
+                xspace = session.stop()
+            done["collected_monotonic"] = time.monotonic()
+            if xspace:
+                # TensorBoard's layout.  jax.profiler.stop_trace would also
+                # convert every event to a trace.json.gz that nothing here
+                # reads: three quarters of a stop on the chip (PERF.md, PR 37)
+                run = os.path.join(done["artifact"], "plugins", "profile",
+                                   time.strftime("%Y_%m_%d_%H_%M_%S"))
+                os.makedirs(run, exist_ok=True)
+                with open(os.path.join(
+                        run, f"{socket.gethostname()}.xplane.pb"), "wb") as f:
+                    f.write(xspace)
+            done["xspace_bytes"] = len(xspace)
             done["written_monotonic"] = time.monotonic()
 
         try:
             await asyncio.get_running_loop().run_in_executor(None, _stop)
         finally:
             self._profile_latch = False
+        log.info("profiler trace %d: on for %.2fs, %s in %.2fs, "
+                 "%.1f MB written in %.2fs", self._profile_seq,
+                 done["stopped_monotonic"] - done["started_monotonic"],
+                 "collected" if done["xspace_bytes"] else "dropped",
+                 done["collected_monotonic"] - done["stopped_monotonic"],
+                 done["xspace_bytes"] / 1e6,
+                 done["written_monotonic"] - done["collected_monotonic"])
         return done
 
     async def capture_profile(self, seconds: float = 3.0) -> str:
         """A trace of a fixed window of live serving: start, sleep, stop.
         Returns the trace directory (TensorBoard-loadable)."""
-        seconds = min(max(float(seconds), 0.1), 60.0)
+        seconds = min(max(float(seconds), MIN_TRACE_S), 60.0)
         await self.profile_start()
         try:
             await asyncio.sleep(seconds)

@@ -1,18 +1,22 @@
 """The paged runner for a model whose layers differ in kind
 (models/hybrid.py): two kinds of state in one cache manager.
 
-* Paged KV, for the ATTENTION layers only: the pools' leading axis is
-  ``cfg.layers_of("*")``, not ``num_layers``; page table, allocator,
-  Pallas decode / ragged / flash-prefill kernels are the parent's.
-* Per-slot recurrent state for the Mamba layers
-  (``PagedDecodeState.ssm`` / ``.conv``): written by prefill (``insert``
-  places it, or the ragged step's chunk continues the slot's own), carried
-  IN PLACE through the decode and ragged steps and both megasteps as part
-  of the donated state, zeroed on release.
+* Paged KV, for the ATTENTION layers only: the pools' leading axis is the
+  attention layers (``kv_layers``), not ``num_layers``; page table,
+  allocator, Pallas decode / ragged / flash-prefill kernels are the
+  parent's.  A latent layer (``L``, MLA served absorbed) keeps ONE row
+  ``[c ; k_rope]`` a token: ``pool_k`` is ``[L_A, P+1, 1, page, row]``,
+  ``pool_v`` is None, and the decode kernel is
+  ``paged_decode_attention_mla`` (ops/pallas/paged.py).
+* Per-slot recurrent state for the Mamba or KDA layers
+  (``PagedDecodeState.ssm`` or ``.kda``, and ``.conv``): written by prefill
+  (``insert`` places it, or the ragged step's chunk continues the slot's
+  own), carried IN PLACE through the decode and ragged steps and both
+  megasteps as part of the donated state, zeroed on release.
 
 What rests on "tokens done == pages of KV that can be handed over" cannot
 be right for such a slot — a page of KV says nothing of the state the
-Mamba layers have reached — so the prefix cache is off (every admission is
+recurrent layers have reached — so the prefix cache is off (every admission is
 a miss), ``export_pages`` / ``import_pages`` raise, the drain hand-off ships
 no pages (the successor replays the tokens), and speculation is refused at
 construction (engine/factory.py, engine/spec.py): each with
@@ -32,6 +36,7 @@ import jax.numpy as jnp
 from crowdllama_tpu.engine.paged import PagedDecodeState, PagedModelRunner
 from crowdllama_tpu.models import hybrid as H
 from crowdllama_tpu.obs.metrics import ENGINE_TELEMETRY
+from crowdllama_tpu.ops.kda import kda_update_path
 from crowdllama_tpu.ops.ssm import ssm_update_path
 
 log = logging.getLogger("crowdllama.engine.hybrid")
@@ -41,15 +46,16 @@ log = logging.getLogger("crowdllama.engine.hybrid")
 @dataclass
 class HybridPrefill:
     """What a prompt's prefill leaves to be placed in a slot: the attention
-    layers' KV ``[L_A, 1, Hkv, T, Dh]`` and the Mamba layers' state after
-    the last prompt token.  It travels where the parent's ``ks`` does
+    layers' KV ``[L_A, 1, Hkv, T, Dh]`` (``v`` None for latent rows) and
+    the recurrent layers' state after the last prompt token, under the
+    names ``PagedDecodeState`` keeps it (``models/hybrid.py``
+    ``zero_recurrent``).  It travels where the parent's ``ks`` does
     (``prefill`` -> ``insert``, and as a chunked job's accumulator), hence
     ``shape``."""
 
     k: jnp.ndarray
-    v: jnp.ndarray
-    ssm: jnp.ndarray    # [L_M, 1, H, P, N] float32
-    conv: jnp.ndarray   # [L_M, 1, conv_dim, K-1]
+    v: jnp.ndarray | None
+    rec: dict[str, jnp.ndarray]
 
     @property
     def shape(self):
@@ -62,7 +68,7 @@ def refuse_speculation(cfg, what: str) -> None:
     if cfg.is_hybrid:
         raise ValueError(
             f"{what} cannot serve {cfg.name!r}: a rejected draft token "
-            f"cannot be rolled back out of the Mamba layers' state "
+            f"cannot be rolled back out of the recurrent layers' state "
             f"({H.NO_PAGES})")
 
 
@@ -81,30 +87,42 @@ class HybridPagedModelRunner(PagedModelRunner):
                 f"{cfg.name!r} is served on one device: the recurrent state "
                 f"and the per-kind parameter stacks have no partition rules "
                 f"(mesh {dict(self.mesh.shape)})")
-        # which path the Mamba layers' one-step update takes in every
-        # decode-type program (ops/ssm.py ssm_update_at decides from the
-        # backend and the state's shape): crowdllama_ssm_update_path
-        self.ssm_update_path, why = ssm_update_path(
-            (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state))
-        log.info("state-space update path: %s", self.ssm_update_path)
+        if cfg.kv_lora_rank and self.kv_dtype == "int8":
+            raise ValueError(f"{cfg.name!r} keeps latent rows, key and "
+                             f"value in one: no int8 KV for them")
+        # which path the recurrent layers' one-step update takes in every
+        # decode-type program (ops/ssm.py ssm_update_at, ops/kda.py
+        # kda_update_at decide from the backend and the state's shape):
+        # crowdllama_ssm_update_path, crowdllama_kda_update_path
+        self.ssm_update_path = self.kda_update_path = why = ""
+        if cfg.layers_of("M"):
+            self.ssm_update_path, why = ssm_update_path(
+                (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state))
+            log.info("state-space update path: %s", self.ssm_update_path)
+        if cfg.layers_of("K"):
+            self.kda_update_path, why = kda_update_path(
+                (cfg.kda_heads, cfg.kda_head_dim, cfg.kda_head_dim))
+            log.info("delta-rule update path: %s", self.kda_update_path)
         if why and jax.default_backend() == "tpu":
-            log.warning("the state-space update runs as two XLA fusions on "
-                        "this TPU, not the Pallas kernel: %s", why)
+            log.warning("the recurrent state's update runs as XLA fusions "
+                        "on this TPU, not the Pallas kernel: %s", why)
         # the parent's out_shardings name three arrays
         self._prefill = jax.jit(self._prefill_impl)
         self._take_counts = jax.jit(self._take_counts_impl,
                                     donate_argnums=(0,))
         self._flight_counts = None
+        # the state fields the recurrent layers keep (ssm | kda, conv)
+        self._rec_names = tuple(H.zero_recurrent(cfg, 0))
         # slots a cancelled ragged prefill left with a half-advanced state
         self._dirty: set[int] = set()
 
     # ------------------------------------------------------------- programs
 
     def _prefill_forward(self, params, tokens, positions, kv_valid):
-        logits, ks, vs, ssm, conv, _ = H.prefill(
+        logits, ks, vs, rec, _ = H.prefill(
             params, self.cfg, tokens, positions, kv_valid,
             n_shards=self.mesh.size)
-        return logits, HybridPrefill(ks, vs, ssm, conv), None
+        return logits, HybridPrefill(ks, vs, rec), None
 
     @partial(jax.jit, static_argnums=0, donate_argnums=(5,))
     def _prefill_chunk(self, params, tokens, chunk_len, ctx_len,
@@ -114,14 +132,14 @@ class HybridPagedModelRunner(PagedModelRunner):
                                           chunk_len - 1)
         kv_valid = (jnp.arange(t) < chunk_len)[None, :]
         ctx_valid = (jnp.arange(ctx.k.shape[3]) < ctx_len)[None, :]
-        logits, ks, vs, ssm, conv, _ = H.prefill(
-            params, self.cfg, tokens, positions, kv_valid, ssm0=ctx.ssm,
-            conv0=ctx.conv, ctx_k=ctx.k, ctx_v=ctx.v, ctx_valid=ctx_valid)
+        logits, ks, vs, rec, _ = H.prefill(
+            params, self.cfg, tokens, positions, kv_valid, rec0=ctx.rec,
+            ctx_k=ctx.k, ctx_v=ctx.v, ctx_valid=ctx_valid)
         k = jax.lax.dynamic_update_slice(
             ctx.k, ks.astype(ctx.k.dtype), (0, 0, 0, ctx_len, 0))
-        v = jax.lax.dynamic_update_slice(
+        v = None if vs is None else jax.lax.dynamic_update_slice(
             ctx.v, vs.astype(ctx.v.dtype), (0, 0, 0, ctx_len, 0))
-        return logits[0, chunk_len - 1], HybridPrefill(k, v, ssm, conv), None
+        return logits[0, chunk_len - 1], HybridPrefill(k, v, rec), None
 
     def _hidden_states(self, params, tokens, positions, kv_valid):
         return H.prefill(params, self.cfg, tokens, positions, kv_valid,
@@ -129,19 +147,30 @@ class HybridPagedModelRunner(PagedModelRunner):
 
     def _insert_paged_impl(self, state, page_idx, ks: HybridPrefill, vs,
                            slot, *rest):
-        state = super()._insert_paged_impl(state, page_idx, ks.k, ks.v, slot,
-                                           *rest)
-        return replace(
-            state, ssm=state.ssm.at[:, slot].set(ks.ssm[:, 0]),
-            conv=state.conv.at[:, slot].set(
-                ks.conv[:, 0].astype(state.conv.dtype)))
+        if ks.v is None:    # latent rows: pages of the one pool
+            # a page at a time, in place (engine/paged.py ``_put_rows`` has
+            # why not a scatter: it copied the whole pool twice an insert)
+            pool, pg = state.pool_k, self.page_size
+            for j in range(ks.k.shape[3] // pg):
+                pool = jax.lax.dynamic_update_slice(
+                    pool, ks.k[:, :, :, j * pg:(j + 1) * pg].astype(
+                        pool.dtype), (0, page_idx[j], 0, 0, 0))
+            state = self._activated(replace(state, pool_k=pool), slot, *rest)
+        else:
+            state = super()._insert_paged_impl(state, page_idx, ks.k, ks.v,
+                                               slot, *rest)
+        return replace(state, **{
+            name: getattr(state, name).at[:, slot].set(
+                a[:, 0].astype(getattr(state, name).dtype))
+            for name, a in ks.rec.items()})
 
     def _release_paged_impl(self, state, slot):
         """A slot's next prompt may arrive in chunks, which continue from
         the slot's own state: it starts from zero because it ended so."""
         state = super()._release_paged_impl(state, slot)
-        return replace(state, ssm=state.ssm.at[:, slot].set(0.0),
-                       conv=state.conv.at[:, slot].set(0.0))
+        return replace(state, **{
+            name: getattr(state, name).at[:, slot].set(0.0)
+            for name in self._rec_names})
 
     def _take_counts_impl(self, state):
         return state.moe_rows + 0, replace(
@@ -155,7 +184,8 @@ class HybridPagedModelRunner(PagedModelRunner):
         ``chunk = (slot, valid rows)``'s own state.  ``positions`` has no
         reader here: nothing rotates."""
         cfg, b = self.cfg, self.max_slots
-        box = {"pools": pools, "ssm": st.ssm, "conv": st.conv}
+        box = {"pools": pools,
+               **{name: getattr(st, name) for name in self._rec_names}}
         active = st.active.astype(jnp.int32)
 
         def attn_fn(i, q, k, v):
@@ -164,50 +194,53 @@ class HybridPagedModelRunner(PagedModelRunner):
             box["pools"] = after["pools"]
             return out
 
-        def ssm_fn(i, lp, xbc, dt):
-            y, tail, stack = H.mamba_mix(
-                lp, cfg, xbc[:b, None], dt[:b, None], box["conv"][i],
-                box["ssm"], active, layer=i)
+        def rec_fn(kind, i, lp, *inputs):
+            mix, name = H.MIX[kind], H.STATE[kind]
+            y, tail, stack = mix(
+                lp, cfg, *(a[:b, None] for a in inputs), box["conv"][i],
+                box[name], active, layer=i)
             y = y[:, 0]
             if chunk is not None:
                 slot, valid = chunk
                 at = (i, slot, 0, 0, 0)
-                yc, tc, sc = H.mamba_mix(
-                    lp, cfg, xbc[None, b:], dt[None, b:],
+                yc, tc, sc = mix(
+                    lp, cfg, *(a[None, b:] for a in inputs),
                     jax.lax.dynamic_index_in_dim(tail, slot, 0),
                     jax.lax.dynamic_slice(stack, at, (1, 1) + stack.shape[2:])[0],
                     valid[None].astype(jnp.int32))
                 tail = jax.lax.dynamic_update_index_in_dim(tail, tc[0], slot, 0)
                 stack = jax.lax.dynamic_update_slice(stack, sc[None], at)
                 y = jnp.concatenate([y, yc[0]])
-            box["ssm"] = stack
+            box[name] = stack
             box["conv"] = box["conv"].at[i].set(tail)
             return y
 
-        x, counts = H.run_layers(params["layers"], cfg, x, ssm_fn, attn_fn,
+        x, counts = H.run_layers(params["layers"], cfg, x, rec_fn, attn_fn,
                                  live)
-        return x, box["pools"], {"ssm": box["ssm"], "conv": box["conv"],
-                                 "moe_rows": st.moe_rows + counts}
+        return x, box["pools"], {
+            **{name: box[name] for name in self._rec_names},
+            "moe_rows": st.moe_rows + counts}
 
     # ------------------------------------------------------------------ API
 
     def init_state(self, seed: int = 0) -> PagedDecodeState:
         state = super().init_state(seed)
         self._dirty.clear()
-        ssm, conv = H.zero_recurrent(self.cfg, self.max_slots, self.dtype)
-        state = replace(state, ssm=ssm, conv=conv,
-                        moe_rows=jnp.zeros((2,), jnp.int32))
+        rec = H.zero_recurrent(self.cfg, self.max_slots, self.dtype)
+        state = replace(state, **rec, moe_rows=jnp.zeros((2,), jnp.int32))
         ENGINE_TELEMETRY.state_bytes_set({
-            "kv_pool": sum(a.nbytes for a in (state.pool_k, state.pool_v,
-                                              state.k_scale, state.v_scale)
-                           if a is not None),
-            "ssm": ssm.nbytes, "conv": conv.nbytes})
+            "latent_cache" if state.pool_v is None else "kv_pool":
+            sum(a.nbytes for a in (state.pool_k, state.pool_v,
+                                   state.k_scale, state.v_scale)
+                if a is not None),
+            **{name: a.nbytes for name, a in rec.items()}})
         return state
 
     def prefill_begin(self, prompt_ids, state=None):
         job = super().prefill_begin(prompt_ids, state)
         job.ctx_k = HybridPrefill(
-            job.ctx_k, job.ctx_v, *H.zero_recurrent(self.cfg, 1, self.dtype))
+            job.ctx_k, None if self.cfg.kv_lora_rank else job.ctx_v,
+            H.zero_recurrent(self.cfg, 1, self.dtype))
         job.ctx_v = None
         return job
 

@@ -65,6 +65,7 @@ from crowdllama_tpu.ops.pallas.megastep import (run_decode_megastep,
 from crowdllama_tpu.ops.pallas.paged import (
     flash_paged_decode_attention,
     flash_paged_decode_attention_tp,
+    paged_decode_attention_mla,
     paged_pallas_refusal,
     ragged_paged_attention,
     ragged_pallas_refusal,
@@ -131,6 +132,8 @@ def _write_kv(put, pk, pv, ksc, vsc, k, v):
     """The four pools after ``put(pool, rows)`` has written one layer's
     fresh K/V — quantized first, scales alongside, where the pools are
     int8 (``ksc``/``vsc`` are None otherwise and stay None)."""
+    if pv is None:      # latent rows: the one pool is key and value
+        return put(pk, k.astype(pk.dtype)), None, None, None
     if ksc is None:
         return (put(pk, k.astype(pk.dtype)), put(pv, v.astype(pv.dtype)),
                 None, None)
@@ -146,7 +149,9 @@ class PagesExhausted(ValueError):
 @dataclass
 class PagedDecodeState:
     pool_k: jnp.ndarray    # [L, P, Hkv, page, Dh]
-    pool_v: jnp.ndarray
+    # None for latent attention (cfg.kv_lora_rank): a page of pool_k holds
+    # one row [c ; k_rope] a token, key and value both
+    pool_v: jnp.ndarray | None
     seq_lens: jnp.ndarray  # [B]
     tokens: jnp.ndarray    # [B]
     active: jnp.ndarray    # [B]
@@ -167,13 +172,14 @@ class PagedDecodeState:
     # model's own contiguous KV cache [Ld, B, Hkvd, S, Dhd].
     draft_k: jnp.ndarray | None = None
     draft_v: jnp.ndarray | None = None
-    # Models with Mamba layers only (engine/hybrid.py): each slot's
-    # recurrent state, per Mamba layer — the state-space state [L_M, B, H,
-    # P, N] float32 and the convolution's last K-1 inputs [L_M, B,
-    # conv_dim, K-1] — beside pools that cover the attention layers alone;
-    # and the expert layers' [held, left out] assignment counts since the
-    # scheduler last took them.
+    # Models with recurrent layers only (engine/hybrid.py): each slot's
+    # recurrent state, per recurrent layer — Mamba's state-space state [L_M,
+    # B, H, P, N] or KDA's matrix a head [L_K, B, H, dk, dv], float32, and
+    # the convolutions' last K-1 inputs [L, B, conv_dim, K-1] — beside
+    # pools that cover the attention layers alone; and the expert layers'
+    # [held, left out] assignment counts since the scheduler last took them.
     ssm: jnp.ndarray | None = None
+    kda: jnp.ndarray | None = None
     conv: jnp.ndarray | None = None
     moe_rows: jnp.ndarray | None = None
 
@@ -183,7 +189,7 @@ jax.tree_util.register_dataclass(
     data_fields=["pool_k", "pool_v", "seq_lens", "tokens", "active",
                  "temperature", "top_p", "top_k", "repeat_penalty",
                  "recent", "keys", "k_scale", "v_scale", "hist",
-                 "draft_k", "draft_v", "ssm", "conv", "moe_rows"],
+                 "draft_k", "draft_v", "ssm", "kda", "conv", "moe_rows"],
     meta_fields=[],
 )
 
@@ -230,6 +236,9 @@ class PagedModelRunner(ModelRunner):
         # Before super().__init__: the base constructor ends by resolving
         # the attention paths, and the paged gates need the page size.
         self.page_size = page_size
+        # what a page holds (crowdllama_attn_decode_path): K and V pools,
+        # or one latent row a token that is both
+        self.attn_decode_path = "mla" if cfg.kv_lora_rank else "gqa"
         super().__init__(cfg, *args, **kwargs)
         from crowdllama_tpu.parallel.mesh import AXIS_DP
 
@@ -315,9 +324,12 @@ class PagedModelRunner(ModelRunner):
         ragged = (f"mesh has {self.mesh.size} devices (the ragged kernel is "
                   f"not shard_map-wrapped)" if self.mesh.size > 1
                   else ragged_pallas_refusal(*gate))
+        decode = paged_pallas_refusal(*gate)
+        if not decode and self.cfg.kv_lora_rank % 128:
+            decode = (f"the latent row's value is its first "
+                      f"{self.cfg.kv_lora_rank} entries: not whole lanes")
         return {**super()._attention_refusals(),
-                "decode": paged_pallas_refusal(*gate),
-                "ragged_step": ragged}
+                "decode": decode, "ragged_step": ragged}
 
     # ------------------------------------------------------------ allocator
 
@@ -805,6 +817,14 @@ class PagedModelRunner(ModelRunner):
 
                 @jax.named_scope("attention")
                 def read(q, pk2, pv2, ks2, vs2):
+                    if pv2 is None:     # latent rows (one device)
+                        if use_kernel:
+                            return paged_decode_attention_mla(
+                                q, pk2, li, page_table, lens, scale,
+                                cfg.kv_lora_rank)
+                        kc = pk2[li, page_table].transpose(
+                            0, 2, 1, 3, 4).reshape(b, hkv, view_len, dh)
+                        return decode_attention(q, kc, kc, lens, scale)
                     if use_kernel:
                         if sharded:
                             return flash_paged_decode_attention_tp(
@@ -939,7 +959,10 @@ class PagedModelRunner(ModelRunner):
                     # so the reference path's self block matches monolithic
                     # prefill bitwise (bf16 pools).
                     chunk_k = k[b:].transpose(1, 0, 2)[None]
-                    chunk_v = v[b:].transpose(1, 0, 2)[None]
+                    if pv2 is None:     # latent rows: the key is the value
+                        chunk_v, pv2 = chunk_k, pk2
+                    else:
+                        chunk_v = v[b:].transpose(1, 0, 2)[None]
                     return ragged_paged_attention(
                         q, chunk_k, chunk_v, pk2, pv2, li, page_table,
                         q_lens, kv_lens, chunk_slot, scale,
@@ -1036,7 +1059,8 @@ class PagedModelRunner(ModelRunner):
         b = self.max_slots
         return PagedDecodeState(
             pool_k=jax.device_put(jnp.zeros(shape, pool_dtype), pool_sharding),
-            pool_v=jax.device_put(jnp.zeros(shape, pool_dtype), pool_sharding),
+            pool_v=(None if self.cfg.kv_lora_rank else jax.device_put(
+                jnp.zeros(shape, pool_dtype), pool_sharding)),
             k_scale=(jax.device_put(jnp.zeros(shape[:-1], jnp.bfloat16),
                                     scale_sharding) if quantized else None),
             v_scale=(jax.device_put(jnp.zeros(shape[:-1], jnp.bfloat16),

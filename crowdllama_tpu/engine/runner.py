@@ -137,9 +137,9 @@ class ModelRunner:
     ):
         if cfg.is_hybrid and not self.serves_hybrid:
             raise ValueError(
-                f"{type(self).__name__} cannot serve {cfg.name!r}: its Mamba "
-                f"layers' per-slot state lives in the paged runner of "
-                f"engine/hybrid.py alone")
+                f"{type(self).__name__} cannot serve {cfg.name!r}: its "
+                f"recurrent layers' per-slot state lives in the paged runner "
+                f"of engine/hybrid.py alone")
         self.cfg = cfg
         self.max_slots = max_slots
         self.max_seq = max_seq or cfg.max_context_length
@@ -221,8 +221,9 @@ class ModelRunner:
     @property
     def kv_layers(self) -> int:
         """Layers that keep KV: all of them, but for a model whose layers
-        differ in kind (engine/hybrid.py)."""
-        return self.cfg.layers_of("*")
+        differ in kind (engine/hybrid.py): its attention layers, latent
+        ones (``L``) among them."""
+        return self.cfg.layers_of("*") + self.cfg.layers_of("L")
 
     # ------------------------------------------------------- attention paths
 
@@ -272,7 +273,8 @@ class ModelRunner:
         cfg, layers = self.cfg, self.params["layers"]
         self.moe_matmul_path = ""
         if cfg.is_hybrid:
-            bank = layers["moe"][0]["w1"]
+            bank = (layers["moe"][0]["w1"] if "moe" in layers
+                    else layers["smoe"][0]["w_gate"])
         elif cfg.is_moe and cfg.moe_dispatch == "sorted":
             bank = layers["w_gate"]
         else:

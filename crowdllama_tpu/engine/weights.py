@@ -276,11 +276,14 @@ def resolve_model_config(name: str, model_path: str = "",
 #: config.json ``model_type`` -> family; a type that is not here is an error
 _FAMILIES = {"llama": "llama", "mistral": "mistral", "mixtral": "mixtral",
              "gemma2": "gemma2", "qwen2": "qwen2", "qwen3": "qwen3",
-             "qwen3_moe": "qwen3", "nemotron_h": "nemotron_h"}
+             "qwen3_moe": "qwen3", "nemotron_h": "nemotron_h",
+             "kimi_linear": "kimi_linear"}
 #: keys that say the block is not the one the dense families share: reading
 #: past them would serve another model under this one's name
 _FOREIGN_KEYS = ("hybrid_override_pattern", "n_routed_experts",
-                 "n_shared_experts", "ssm_state_size")
+                 "n_shared_experts", "ssm_state_size", "linear_attn_config",
+                 "kv_lora_rank", "first_k_dense_replace",
+                 "num_shared_experts")
 _FOREIGN_PREFIXES = ("mamba_", "moe_")
 
 
@@ -302,9 +305,12 @@ def config_from_hf_dir(path: str | Path) -> ModelConfig:
         else "mistral" if "mistral" in arch
         else "qwen3" if "qwen3" in arch
         else "qwen2" if "qwen2" in arch
-        else "nemotron_h" if "nemotronh" in arch else "llama")
+        else "nemotron_h" if "nemotronh" in arch
+        else "kimi_linear" if "kimilinear" in arch else "llama")
     if family == "nemotron_h":
         return _nemotron_h_config(d)
+    if family == "kimi_linear":
+        return _kimi_linear_config(d)
     odd = sorted(k for k, v in d.items() if v not in (None, 0, False)
                  and (k in _FOREIGN_KEYS or k.startswith(_FOREIGN_PREFIXES)))
     if odd:
@@ -392,4 +398,68 @@ def _nemotron_h_config(d: dict) -> ModelConfig:
         moe_shared_intermediate_size=d["moe_shared_expert_intermediate_size"],
         moe_routed_scaling=float(d.get("routed_scaling_factor", 1.0)),
         moe_norm_topk=bool(d.get("norm_topk_prob", True)),
+    )
+
+
+def _kimi_linear_config(d: dict) -> ModelConfig:
+    """``model_type: kimi_linear``.  A published layer is two sublayers of
+    the pattern: its mixer — ``K`` where ``linear_attn_config.kda_layers``
+    (1-indexed) names it, ``L`` where ``full_attn_layers`` does — then its
+    feed-forward, ``D`` for the first ``first_k_dense_replace`` layers and
+    ``S`` after.  ``num_experts`` counts the experts held HERE; where that
+    is a share, ``num_experts_published`` gives the router's width and
+    ``expert_parallel_rank`` which share (the benchmark's cut states both).
+    The latent attention is served absorbed: one kv head whose row is
+    ``kv_lora_rank + qk_rope_head_dim`` wide (models/config.py).  With
+    ``mla_use_nope`` nothing rotates: ``rope_theta`` has no reader."""
+    lin = d["linear_attn_config"]
+    n = d["num_hidden_layers"]
+    kda_at, mla_at = set(lin["kda_layers"]), set(lin["full_attn_layers"])
+    if kda_at & mla_at or kda_at | mla_at != set(range(1, n + 1)):
+        raise ValueError(
+            f"kda_layers {sorted(kda_at)} and full_attn_layers "
+            f"{sorted(mla_at)} must name each of the layers 1..{n} once")
+    served = {"mla_use_nope": True, "q_lora_rank": None,
+              "moe_router_activation_func": "sigmoid", "num_expert_group": 1,
+              "topk_group": 1, "moe_layer_freq": 1, "hidden_act": "silu",
+              "num_nextn_predict_layers": 0, "rope_scaling": None}
+    odd = {k: d[k] for k, v in served.items() if d.get(k, v) != v}
+    if odd:
+        raise ValueError(f"kimi_linear is served with {served} only; this "
+                         f"config.json says {odd}")
+    if d["v_head_dim"] != d["qk_nope_head_dim"]:
+        raise ValueError("the kv up-projection is read as heads of "
+                         "[k_nope | v] of one width each")
+    dense = d.get("first_k_dense_replace", 0)
+    pattern = "".join(("K" if i in kda_at else "L")
+                      + ("D" if i <= dense else "S")
+                      for i in range(1, n + 1))
+    held = d["num_experts"]
+    return ModelConfig(
+        name=d.get("_name_or_path", "hf-model"), family="kimi_linear",
+        vocab_size=d["vocab_size"], hidden_size=d["hidden_size"],
+        intermediate_size=d["intermediate_size"], num_layers=n,
+        num_heads=d["num_attention_heads"], num_kv_heads=1,
+        head_dim=d["kv_lora_rank"] + d["qk_rope_head_dim"],
+        query_pre_attn_scalar=float(d["qk_nope_head_dim"]
+                                    + d["qk_rope_head_dim"]),
+        rms_norm_eps=d.get("rms_norm_eps", 1e-5),
+        tie_word_embeddings=d.get("tie_word_embeddings", False),
+        max_context_length=d.get("max_position_embeddings",
+                                 d.get("model_max_length", 4096)),
+        layer_pattern=pattern, kda_heads=lin["num_heads"],
+        kda_head_dim=lin["head_dim"],
+        kda_conv_kernel=lin["short_conv_kernel_size"],
+        kda_gate_rank=lin["head_dim"],
+        kv_lora_rank=d["kv_lora_rank"],
+        qk_nope_head_dim=d["qk_nope_head_dim"],
+        qk_rope_head_dim=d["qk_rope_head_dim"], v_head_dim=d["v_head_dim"],
+        num_experts=d.get("num_experts_published", held),
+        experts_held=held, expert_rank=d.get("expert_parallel_rank", 0),
+        num_experts_per_tok=d["num_experts_per_token"],
+        moe_intermediate_size=d["moe_intermediate_size"],
+        moe_shared_intermediate_size=(d["num_shared_experts"]
+                                      * d["moe_intermediate_size"]),
+        moe_routed_scaling=float(d.get("routed_scaling_factor", 1.0)),
+        moe_norm_topk=bool(d.get("moe_renormalize", True)),
     )

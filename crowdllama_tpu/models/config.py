@@ -38,7 +38,8 @@ class RopeScaling:
 class ModelConfig:
     name: str = "custom"
     # "llama" | "mistral" | "gemma2" | "mixtral" | "qwen2" | "qwen3" |
-    # "nemotron_h" (layers of three kinds: models/hybrid.py)
+    # "nemotron_h" | "kimi_linear" (layers that differ in kind:
+    # models/hybrid.py)
     family: str = "llama"
     vocab_size: int = 32000
     hidden_size: int = 2048
@@ -75,9 +76,13 @@ class ModelConfig:
     # exact); "dense": compute-all-experts reference semantics.
     moe_dispatch: str = "sorted"
 
-    # Hybrid specifics (family="nemotron_h", models/hybrid.py).  Every
-    # layer is ONE mixer, its kind a character of ``layer_pattern``: "M"
-    # Mamba-2, "E" latent mixture of experts, "*" attention (no rotary).
+    # Hybrid specifics (models/hybrid.py).  Every sublayer is ONE mixer
+    # behind one RMSNorm, its kind a character of ``layer_pattern``:
+    # family "nemotron_h" — "M" Mamba-2, "E" latent mixture of experts, "*"
+    # attention (no rotary), one sublayer a layer; family "kimi_linear" —
+    # "K" delta-rule linear attention (KDA), "L" latent attention (MLA, no
+    # rotary), "D" dense SwiGLU, "S" SwiGLU mixture of experts with a
+    # shared expert, two sublayers (mixer, then feed-forward) a layer.
     # ``num_experts`` is the router's width; this worker holds
     # ``experts_held`` of them (0 = all), those of ``expert_rank``.
     layer_pattern: str = ""
@@ -94,6 +99,21 @@ class ModelConfig:
     moe_norm_topk: bool = True
     experts_held: int = 0
     expert_rank: int = 0
+    # KDA: heads of a [kda_head_dim, kda_head_dim] float32 state each, a
+    # causal convolution behind each of q, k, v, two low-rank gates.
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv_kernel: int = 4
+    kda_gate_rank: int = 0
+    kda_chunk: int = 64
+    # MLA, served ABSORBED: the cache keeps one row [c ; k_rope] a token, so
+    # ``num_kv_heads`` is 1 and ``head_dim`` the row's width (kv_lora_rank +
+    # qk_rope_head_dim); the softmax scale (qk_nope + qk_rope)^-1/2 is
+    # ``query_pre_attn_scalar``.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     def __post_init__(self) -> None:
         if self.moe_dispatch not in ("sorted", "dense"):
@@ -101,11 +121,14 @@ class ModelConfig:
                 f"moe_dispatch must be 'sorted' or 'dense', "
                 f"got {self.moe_dispatch!r}")
         if self.layer_pattern:
-            odd = set(self.layer_pattern) - set("ME*")
-            if odd or len(self.layer_pattern) != self.num_layers:
+            kinds, per = (("KLDS", 2) if self.family == "kimi_linear"
+                          else ("ME*", 1))
+            odd = set(self.layer_pattern) - set(kinds)
+            if odd or len(self.layer_pattern) != per * self.num_layers:
                 raise ValueError(
-                    f"layer_pattern {self.layer_pattern!r} must give one of "
-                    f"M, E, * for each of the {self.num_layers} layers")
+                    f"layer_pattern {self.layer_pattern!r} must give {per} "
+                    f"of {', '.join(kinds)} for each of the "
+                    f"{self.num_layers} layers")
             held = self.experts_held or self.num_experts
             if held * (self.expert_rank + 1) > self.num_experts:
                 raise ValueError(
@@ -117,8 +140,8 @@ class ModelConfig:
         return bool(self.layer_pattern)
 
     def layers_of(self, kind: str) -> int:
-        """How many layers are of ``kind`` ("M", "E" or "*"); every layer of
-        a model without a pattern is an attention layer."""
+        """How many sublayers are of ``kind`` (a character of the pattern);
+        every layer of a model without a pattern is an attention layer."""
         if not self.layer_pattern:
             return self.num_layers if kind == "*" else 0
         return self.layer_pattern.count(kind)
@@ -220,6 +243,21 @@ TINY_TEST_NEMOTRON_H = _register(ModelConfig(
     ssm_chunk=8, num_experts=16, num_experts_per_tok=4, experts_held=8,
     moe_intermediate_size=48, moe_latent_size=32,
     moe_shared_intermediate_size=96, moe_routed_scaling=2.5,
+    max_context_length=256,
+))
+
+# Both mixers and both feed-forwards of the family: a dense first layer, 16
+# experts behind the router of which this worker holds 8 (rank 0 of 2),
+# top-4, more than one KDA chunk in the smallest prefill bucket.
+TINY_TEST_KIMI_LINEAR = _register(ModelConfig(
+    name="tiny-test-kimi-linear", family="kimi_linear", vocab_size=512,
+    hidden_size=64, intermediate_size=96, num_layers=4, num_heads=4,
+    num_kv_heads=1, head_dim=48, query_pre_attn_scalar=32.0,
+    layer_pattern="KDKSLSKS", kda_heads=4, kda_head_dim=16,
+    kda_gate_rank=16, kda_chunk=8, kv_lora_rank=32, qk_nope_head_dim=16,
+    qk_rope_head_dim=16, v_head_dim=16, num_experts=16,
+    num_experts_per_tok=4, experts_held=8, moe_intermediate_size=32,
+    moe_shared_intermediate_size=32, moe_routed_scaling=2.446,
     max_context_length=256,
 ))
 

@@ -1,14 +1,20 @@
-"""Layers of three kinds in one model (family ``nemotron_h``): Mamba-2,
-a latent mixture of experts with a shared expert, and attention without
-rotation, each layer ONE mixer behind one RMSNorm:
+"""Models whose layers differ in kind, each sublayer ONE mixer behind one
+RMSNorm:
 
-    x <- x + mixer_kind(RMSNorm(x))          kind = cfg.layer_pattern[layer]
+    x <- x + mixer_kind(RMSNorm(x))          kind = cfg.layer_pattern[i]
 
-The equations are written out in the plain reference
-(benchmarks/chip/harness/reference/nemotron_h.py); this file is the
-program's side of them.  Parameters are kept per KIND
-(``params["layers"]["mamba" | "moe" | "attn"]``: a list, one dict of leaves
-for each layer of that kind in order) and the layer loop is unrolled over
+Family ``nemotron_h``: Mamba-2 (``M``), a latent mixture of experts with a
+shared expert (``E``), attention without rotation (``*``), one sublayer a
+layer.  Family ``kimi_linear``: delta-rule linear attention (``K``, KDA:
+``ops/kda.py``) or latent attention without rotation (``L``, MLA), then a
+dense SwiGLU (``D``) or a SwiGLU mixture of experts with a shared expert
+(``S``) — a published layer is two entries of the pattern.
+
+The equations are written out in the plain references
+(benchmarks/chip/harness/reference/nemotron_h.py, kimi_linear.py); this
+file is the program's side of them.  Parameters are kept per KIND
+(``params["layers"][STACK[kind]]``: a list, one dict of leaves for each
+sublayer of that kind in order) and the layer loop is unrolled over
 the pattern.  A list and not arrays with a leading layer axis: an unrolled
 loop takes layer i by a static slice, and XLA materialized every such slice
 (4.4 GB of temporaries at the benchmark's cut, all of the layer weights a
@@ -21,10 +27,13 @@ nothing, a prefill chunk continuing a slot, a decode step over the paged
 state, the ragged step's mixed rows):
 
 * ``attn_fn(i, q, k, v) -> attn`` — the i-th attention layer's cache write
-  and read; q ``[..., H, Dh]``, k, v ``[..., Hkv, Dh]``.
-* ``ssm_fn(i, lp, xbc, dt) -> y`` — the i-th Mamba layer's convolution
-  tail and state: it splits the rows into sequences and runs
-  :func:`mamba_mix` on each, from and to wherever the layout keeps them.
+  and read; q ``[..., H, Dh]``, k, v ``[..., Hkv, Dh]``.  A latent layer
+  hands over ONE row a token as ``k`` and ``v = None``: the value is that
+  row (the cache keeps no second pool), and the caller takes its share.
+* ``rec_fn(kind, i, lp, *inputs) -> y`` — the i-th recurrent layer's (``M``
+  or ``K``) convolution tail and state: it splits the rows into sequences
+  and runs ``MIX[kind]`` (:func:`mamba_mix`, :func:`kda_mix`) on each, from
+  and to wherever the layout keeps them.
 
 The expert layer holds a SHARE of the experts (``cfg.experts_held`` of the
 router's ``cfg.num_experts``, those of ``cfg.expert_rank``): it routes over
@@ -43,21 +52,25 @@ import jax
 import jax.numpy as jnp
 
 from crowdllama_tpu.models.config import ModelConfig
-from crowdllama_tpu.ops import ssm
+from crowdllama_tpu.ops import kda, ssm
 from crowdllama_tpu.ops.attention import (
     prefill_attention,
     prefill_attention_ctx,
 )
 from crowdllama_tpu.ops.norms import rms_norm
-from crowdllama_tpu.ops.quant import qeinsum, qragged_dot
+from crowdllama_tpu.ops.quant import dequant, qeinsum, qragged_dot
 
 Params = dict[str, Any]
 F32 = jnp.float32
 
-#: the parameter stack of each kind of layer
-STACK = {"M": "mamba", "E": "moe", "*": "attn"}
+#: the parameter stack of each kind of sublayer
+STACK = {"M": "mamba", "E": "moe", "*": "attn",
+         "K": "kda", "L": "mla", "D": "mlp", "S": "smoe"}
+#: the kinds that keep paged KV, and the per-slot state of the recurrent ones
+ATTENTION = "*L"
+STATE = {"M": "ssm", "K": "kda"}
 #: why whatever rests on "tokens done == pages of KV to hand over" declines
-#: a model with Mamba layers (prefix reuse, page export and import, the
+#: a model with recurrent layers (prefix reuse, page export and import, the
 #: drain hand-off, speculation's rollback)
 NO_PAGES = "recurrent state has no page to export"
 
@@ -65,14 +78,24 @@ NO_PAGES = "recurrent state has no page to export"
 def sizes(cfg: ModelConfig) -> dict[str, int]:
     d_inner = cfg.ssm_heads * cfg.ssm_head_dim
     bc = cfg.ssm_groups * cfg.ssm_state
-    return {"d_inner": d_inner, "bc": bc, "conv_dim": d_inner + 2 * bc,
-            "in_proj": 2 * d_inner + 2 * bc + cfg.ssm_heads,
-            "held": cfg.experts_held or cfg.num_experts}
+    z = {"d_inner": d_inner, "bc": bc, "conv_dim": d_inner + 2 * bc,
+         "in_proj": 2 * d_inner + 2 * bc + cfg.ssm_heads,
+         "held": cfg.experts_held or cfg.num_experts}
+    if cfg.kda_heads:   # family kimi_linear
+        hk = cfg.kda_heads * cfg.kda_head_dim
+        z.update({
+            "hk": hk,
+            # the channels a KDA layer convolves: [q | k | v]
+            "conv_dim": 3 * hk,
+            "kda_in": 3 * hk + 2 * cfg.kda_gate_rank + cfg.kda_heads,
+            "q_dim": cfg.num_heads * (cfg.qk_nope_head_dim
+                                      + cfg.qk_rope_head_dim)})
+    return z
 
 
 def _shapes(cfg: ModelConfig) -> dict[str, dict[str, tuple[int, ...]]]:
     """Per kind, each leaf's shape for ONE layer."""
-    z = sizes(cfg)
+    z = {"hk": 0, "kda_in": 0, "q_dim": 0, **sizes(cfg)}
     d, dh = cfg.hidden_size, cfg.resolved_head_dim()
     h, hkv = cfg.num_heads, cfg.num_kv_heads
     lat, f = cfg.moe_latent_size, cfg.moe_intermediate_size
@@ -93,6 +116,32 @@ def _shapes(cfg: ModelConfig) -> dict[str, dict[str, tuple[int, ...]]]:
         "attn": {
             "norm": (d,), "wq": (d, h * dh), "wk": (d, hkv * dh),
             "wv": (d, hkv * dh), "wo": (h * dh, d)},
+        # w_in = [W_q | W_k | W_v | W_f_down | W_g_down | W_beta]
+        "kda": {
+            "norm": (d,), "w_in": (d, z["kda_in"]),
+            "conv_w": (3 * z["hk"], cfg.kda_conv_kernel),
+            "w_f_up": (cfg.kda_gate_rank, z["hk"]), "dt_bias": (z["hk"],),
+            "A_log": (cfg.kda_heads,),
+            "w_g_up": (cfg.kda_gate_rank, z["hk"]),
+            "o_norm": (cfg.kda_head_dim,), "wo": (z["hk"], d)},
+        # w_in = [W_q | W_kva]; w_kvb a head [k_nope | v]
+        "mla": {
+            "norm": (d,), "w_in": (d, z["q_dim"] + dh),
+            "kv_norm": (cfg.kv_lora_rank,),
+            "w_kvb": (cfg.kv_lora_rank,
+                      h * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+            "wo": (h * cfg.v_head_dim, d)},
+        # w_gu = [W_gate | W_up]
+        "mlp": {
+            "norm": (d,), "w_gu": (d, 2 * cfg.intermediate_size),
+            "w_down": (cfg.intermediate_size, d)},
+        "smoe": {
+            "norm": (d,), "router": (d, cfg.num_experts),
+            "router_bias": (cfg.num_experts,),
+            "w_gate": (z["held"], d, f), "w_up": (z["held"], d, f),
+            "w_down": (z["held"], f, d),
+            "ws_gu": (d, 2 * cfg.moe_shared_intermediate_size),
+            "ws_down": (cfg.moe_shared_intermediate_size, d)},
     }
 
 
@@ -141,7 +190,7 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
 
     def leaf(name, shape):
         k = next(keys)
-        if name in ("norm", "gate_norm"):
+        if name in ("norm", "gate_norm", "o_norm", "kv_norm"):
             return jnp.ones(shape, dtype)
         special = special_leaf(name, shape, k, dtype)
         return dense(k, shape) if special is None else special
@@ -149,7 +198,7 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
     layers = {
         name: [{k: leaf(k, shape) for k, shape in _shapes(cfg)[name].items()}
                for _ in range(cfg.layers_of(kind))]
-        for kind, name in STACK.items()}
+        for kind, name in STACK.items() if cfg.layers_of(kind)}
     params: Params = {
         "embed": dense(next(keys), (cfg.vocab_size, cfg.hidden_size)),
         "layers": layers,
@@ -203,7 +252,7 @@ def mamba_mix(lp: Params, cfg: ModelConfig, xbc, dt, tail, state, valid,
     return y.reshape(s, t, z["d_inner"]), tail, state
 
 
-def mamba_body(lp: Params, cfg: ModelConfig, x, ssm_fn):
+def mamba_body(lp: Params, cfg: ModelConfig, x, rec_fn):
     """One Mamba-2 layer minus its state policy.  x ``[..., D]``."""
     z = sizes(cfg)
     with jax.named_scope("ssm_proj"):
@@ -211,7 +260,7 @@ def mamba_body(lp: Params, cfg: ModelConfig, x, ssm_fn):
         gate = zxd[..., :z["d_inner"]]
         xbc = zxd[..., z["d_inner"]:z["d_inner"] + z["conv_dim"]]
         dt = zxd[..., z["d_inner"] + z["conv_dim"]:]
-    y = ssm_fn(lp, xbc, dt)                     # [..., d_inner] float32
+    y = rec_fn(lp, xbc, dt)                     # [..., d_inner] float32
     with jax.named_scope("ssm_proj"):
         # gate first, then an RMSNorm over each group's share of d_inner
         y = y * jax.nn.silu(gate.astype(F32))
@@ -240,6 +289,102 @@ def attn_body(lp: Params, cfg: ModelConfig, x, attn_fn):
         return x + qeinsum("...k,kd->...d", attn.reshape(*lead, -1), lp["wo"])
 
 
+def kda_mix(lp: Params, cfg: ModelConfig, qkv, g, beta, tail, state, valid,
+            layer=None):
+    """Convolutions, activation, norms and the delta-rule recurrence of one
+    KDA layer for S sequences of T rows, from ``(tail, state)`` to theirs
+    after each sequence's ``valid`` real rows.
+
+    qkv ``[S, T, 3 H dk]`` as the input projection gave them (q, k and v
+    each have a depthwise convolution of their own: one over the three side
+    by side); g ``[S, T, H, dk]`` the log decay and beta ``[S, T, H]`` the
+    step size; tail ``[S, K-1, 3 H dk]`` (rows: ``ssm.causal_conv_rows``);
+    state ``[S, H, dk, dv]`` float32;
+    valid ``[S]``: the chunked form.  With ``layer`` — the decode step's
+    one-step update, T = 1 — ``state`` is the carried stack ``[L_K, S, H,
+    dk, dv]`` and that layer's slab of it is updated where it lies
+    (``kda.kda_update_at``).  Returns (o ``[S, T, H, dv]`` float32, tail,
+    state — the stack, given one)."""
+    s, t = qkv.shape[:2]
+    h, dk = cfg.kda_heads, cfg.kda_head_dim
+    conv, tail = ssm.causal_conv_rows(qkv, tail, lp["conv_w"], None, valid)
+    q, k, v = (m.reshape(s, t, h, dk)
+               for m in jnp.split(jax.nn.silu(conv), 3, axis=-1))
+    q, k = kda.l2norm(q) * dk ** -0.5, kda.l2norm(k)
+    # a row that is not real moves neither state nor tail
+    real = jnp.arange(t)[None, :] < valid[:, None]
+    g = jnp.where(real[..., None, None], g.astype(F32), 0.0)
+    beta = jnp.where(real[..., None], beta.astype(F32), 0.0)
+    if layer is not None:
+        assert t == 1, t
+        o, state = kda.kda_update_at(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                                     beta[:, 0], state, layer)
+        return o[:, None], tail, state
+    o, state = kda.kda_chunk_scan(q, k, v, g, beta, state, cfg.kda_chunk)
+    return o, tail, state
+
+
+def kda_body(lp: Params, cfg: ModelConfig, x, rec_fn):
+    """One KDA layer minus its state policy.  x ``[..., D]``."""
+    z = sizes(cfg)
+    h, dk, r = cfg.kda_heads, cfg.kda_head_dim, cfg.kda_gate_rank
+    lead = x.shape[:-1]
+    with jax.named_scope("kda_proj"):
+        zin = qeinsum("...d,dk->...k", _normed(lp, cfg, x), lp["w_in"])
+        qkv, f, gd, b = jnp.split(
+            zin, [3 * z["hk"], 3 * z["hk"] + r, 3 * z["hk"] + 2 * r], axis=-1)
+        # decay, a channel of a head: -exp(A_log) softplus(W_f x + dt_bias)
+        f = qeinsum("...r,rk->...k", f, lp["w_f_up"]).astype(F32)
+        g = jax.nn.softplus(f + lp["dt_bias"].astype(F32)).reshape(
+            *lead, h, dk) * -jnp.exp(lp["A_log"].astype(F32))[:, None]
+        beta = jax.nn.sigmoid(b.astype(F32))
+    o = rec_fn(lp, qkv, g, beta)                # [..., H, dv] float32
+    with jax.named_scope("kda_proj"):
+        gate = jax.nn.sigmoid(qeinsum(
+            "...r,rk->...k", gd, lp["w_g_up"]).astype(F32))
+        o = o * jax.lax.rsqrt(
+            jnp.mean(o * o, -1, keepdims=True) + cfg.rms_norm_eps)
+        o = (o * lp["o_norm"].astype(F32)).reshape(*lead, -1) * gate
+        return x + qeinsum("...k,kd->...d", o.astype(x.dtype), lp["wo"])
+
+
+def mla_body(lp: Params, cfg: ModelConfig, x, attn_fn):
+    """One latent-attention layer minus its cache policy, ABSORBED: the
+    key half of the kv up-projection is folded into the query and the value
+    half applied after the softmax, so that attention is every head's
+    ``[q~ ; q_rope]`` against ONE row ``[c ; k_rope]`` a token, whose first
+    ``kv_lora_rank`` values are also the value.  No rotation."""
+    r, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    dq = dn + cfg.qk_rope_head_dim
+    lead = x.shape[:-1]
+    with jax.named_scope("attn_proj"):
+        zin = qeinsum("...d,dk->...k", _normed(lp, cfg, x), lp["w_in"])
+        q = zin[..., :sizes(cfg)["q_dim"]].reshape(*lead, cfg.num_heads, dq)
+        c = rms_norm(zin[..., -(r + cfg.qk_rope_head_dim):-cfg.qk_rope_head_dim],
+                     lp["kv_norm"], cfg.rms_norm_eps)
+        row = jnp.concatenate([c, zin[..., -cfg.qk_rope_head_dim:]], -1)
+        w_kvb = dequant(lp["w_kvb"]).reshape(r, cfg.num_heads, -1)
+        q = jnp.concatenate([
+            jnp.einsum("...hd,rhd->...hr", q[..., :dn], w_kvb[..., :dn]),
+            q[..., dn:]], -1)
+    attn = attn_fn(q, row[..., None, :], None)[..., :r]
+    with jax.named_scope("attn_proj"):
+        o = jnp.einsum("...hr,rhd->...hd", attn, w_kvb[..., dn:])
+        return x + qeinsum("...k,kd->...d", o.reshape(*lead, -1), lp["wo"])
+
+
+def mlp_body(lp: Params, cfg: ModelConfig, x):
+    """One dense SwiGLU feed-forward."""
+    with jax.named_scope("mlp"):
+        return x + _swiglu(_normed(lp, cfg, x), lp["w_gu"], lp["w_down"])
+
+
+def _swiglu(h, w_gu, w_down):
+    gate, up = jnp.split(qeinsum("...d,df->...f", h, w_gu), 2, axis=-1)
+    mid = jax.nn.silu(gate.astype(F32)) * up.astype(F32)
+    return qeinsum("...f,fd->...d", mid.astype(h.dtype), w_down)
+
+
 def relu2(x):
     r = jax.nn.relu(x.astype(F32))
     return r * r
@@ -261,50 +406,88 @@ def route(lp: Params, cfg: ModelConfig, h):
     return w * cfg.moe_routed_scaling, topi
 
 
+def held_sum(cfg: ModelConfig, u, topw, topi, live, experts):
+    """The held experts' part of a routed sum, through the sorted grouped
+    matmul.  u ``[N, W]`` the experts' input; topw, topi ``[N, K]`` each
+    token's weights and choices over ALL experts; live ``[N]`` bool;
+    ``experts(xs, group_sizes) -> ys``: the held bank on rows sorted by
+    expert.  Returns (sum ``[N, W']`` float32, [rows computed here, rows
+    left to the other ranks] over the live tokens)."""
+    held = sizes(cfg)["held"]
+    n, k = topi.shape
+    local = topi - cfg.expert_rank * held
+    mine = (local >= 0) & (local < held)
+    # another rank's rows sort behind every held expert's group
+    e_flat = jnp.where(mine, local, held).reshape(-1)
+    order = jnp.argsort(e_flat)
+    t_sorted = jnp.repeat(jnp.arange(n), k)[order]
+    group_sizes = jnp.bincount(e_flat, length=held + 1)[:held]
+    ys = experts(jnp.take(u, t_sorted, axis=0), group_sizes)
+    contrib = jnp.where(mine.reshape(-1)[order][:, None],
+                        ys.astype(F32) * topw.reshape(-1)[order][:, None],
+                        0.0)
+    acc = jnp.zeros((n, ys.shape[-1]), F32).at[t_sorted].add(contrib)
+    rows = jnp.sum(mine & live[:, None])
+    counts = jnp.stack([rows, jnp.sum(live) * k - rows]).astype(jnp.int32)
+    return acc, counts
+
+
 def moe_body(lp: Params, cfg: ModelConfig, x, live):
-    """One expert layer: the held experts' part of the routed sum through
-    the sorted grouped matmul, plus the shared expert.  x ``[..., D]``;
-    live ``[...]`` bool marks the rows that are real tokens.  Returns (x,
-    [rows computed here, rows left to the other ranks]) — counted over the
-    live rows."""
-    z = sizes(cfg)
-    held, lo = z["held"], cfg.expert_rank * z["held"]
+    """One latent expert layer: the held experts' part of the routed sum
+    plus the shared expert.  x ``[..., D]``; live ``[...]`` bool marks the
+    rows that are real tokens.  Returns (x, [rows computed here, rows left
+    to the other ranks]) — counted over the live rows."""
     shape = x.shape
     h = _normed(lp, cfg, x).reshape(-1, shape[-1])
-    n, k = h.shape[0], cfg.num_experts_per_tok
     topw, topi = route(lp, cfg, h)
     with jax.named_scope("moe_latent"):
         u = qeinsum("nd,dl->nl", h, lp["w_lat_down"])
-    with jax.named_scope("moe"):
-        local = topi - lo
-        mine = (local >= 0) & (local < held)
-        # another rank's rows sort behind every held expert's group
-        e_flat = jnp.where(mine, local, held).reshape(-1)
-        order = jnp.argsort(e_flat)
-        t_sorted = jnp.repeat(jnp.arange(n), k)[order]
-        xs = jnp.take(u, t_sorted, axis=0)                   # [NK, latent]
-        group_sizes = jnp.bincount(e_flat, length=held + 1)[:held]
+
+    def experts(xs, group_sizes):
         up = qragged_dot(xs, lp["w1"], group_sizes)
-        ys = qragged_dot(relu2(up).astype(xs.dtype), lp["w2"], group_sizes)
-        contrib = jnp.where(mine.reshape(-1)[order][:, None],
-                            ys.astype(F32) * topw.reshape(-1)[order][:, None],
-                            0.0)
-        acc = jnp.zeros((n, u.shape[-1]), F32).at[t_sorted].add(contrib)
+        return qragged_dot(relu2(up).astype(xs.dtype), lp["w2"], group_sizes)
+
+    with jax.named_scope("moe"):
+        acc, counts = held_sum(cfg, u, topw, topi, live.reshape(-1), experts)
     with jax.named_scope("moe_latent"):
         routed = qeinsum("nl,ld->nd", acc.astype(x.dtype), lp["w_lat_up"])
     with jax.named_scope("moe_shared"):
         mid = qeinsum("nd,df->nf", h, lp["ws1"])
         shared = qeinsum("nf,fd->nd", relu2(mid).astype(x.dtype), lp["ws2"])
-    rows = jnp.sum(mine & live.reshape(-1)[:, None])
-    counts = jnp.stack([rows, jnp.sum(live) * k - rows]).astype(jnp.int32)
     return x + (routed + shared).reshape(shape), counts
 
 
-def run_layers(layers: Params, cfg: ModelConfig, x, ssm_fn, attn_fn, live):
+def smoe_body(lp: Params, cfg: ModelConfig, x, live):
+    """One SwiGLU expert layer at the model's width: the held experts' part
+    of the routed sum (three grouped matmuls) plus the shared expert.
+    Arguments and result as :func:`moe_body`."""
+    shape = x.shape
+    h = _normed(lp, cfg, x).reshape(-1, shape[-1])
+    topw, topi = route(lp, cfg, h)
+
+    def experts(xs, group_sizes):
+        gate = qragged_dot(xs, lp["w_gate"], group_sizes)
+        up = qragged_dot(xs, lp["w_up"], group_sizes)
+        mid = jax.nn.silu(gate.astype(F32)) * up.astype(F32)
+        return qragged_dot(mid.astype(xs.dtype), lp["w_down"], group_sizes)
+
+    with jax.named_scope("moe"):
+        acc, counts = held_sum(cfg, h, topw, topi, live.reshape(-1), experts)
+    with jax.named_scope("moe_shared"):
+        shared = _swiglu(h, lp["ws_gu"], lp["ws_down"])
+    return x + (acc.astype(x.dtype) + shared).reshape(shape), counts
+
+
+#: a recurrent kind's state policy runs this on each sequence's rows
+MIX = {"M": mamba_mix, "K": kda_mix}
+
+
+def run_layers(layers: Params, cfg: ModelConfig, x, rec_fn, attn_fn, live):
     """The layer loop, unrolled over ``cfg.layer_pattern``.  Returns (x,
     the expert layers' [held, left out] assignment counts)."""
     counts = jnp.zeros((2,), jnp.int32)
     seen = dict.fromkeys(STACK, 0)
+    attn_seen = 0
     for kind in cfg.layer_pattern:
         i = seen[kind]
         seen[kind] += 1
@@ -316,70 +499,89 @@ def run_layers(layers: Params, cfg: ModelConfig, x, ssm_fn, attn_fn, live):
         # the benchmark's cut: deviceless compile, PR 27) and stream two
         # bytes a weight a step where int8 streams one.
         lp, x = jax.lax.optimization_barrier((lp, x))
-        if kind == "M":
-            x = mamba_body(lp, cfg, x, partial(ssm_fn, i))
-        elif kind == "E":
-            x, c = moe_body(lp, cfg, x, live)
-            counts = counts + c
+        if kind in MIX:
+            body = mamba_body if kind == "M" else kda_body
+            x = body(lp, cfg, x, partial(rec_fn, kind, i))
+        elif kind in ATTENTION:
+            body = attn_body if kind == "*" else mla_body
+            x = body(lp, cfg, x, partial(attn_fn, attn_seen))
+            attn_seen += 1
+        elif kind == "D":
+            x = mlp_body(lp, cfg, x)
         else:
-            x = attn_body(lp, cfg, x, partial(attn_fn, i))
+            x, c = (moe_body if kind == "E" else smoe_body)(lp, cfg, x, live)
+            counts = counts + c
     return x, counts
 
 
 # ------------------------------------------------------------------ prefill
 
 def zero_recurrent(cfg: ModelConfig, seqs: int, dtype=jnp.bfloat16):
-    """(ssm ``[L_M, S, H, P, N]`` float32, conv ``[L_M, S, conv_dim, K-1]``)
-    of sequences that have seen nothing."""
+    """The recurrent layers' state of sequences that have seen nothing, by
+    the name ``PagedDecodeState`` keeps it under: ``ssm`` ``[L_M, S, H, P,
+    N]`` or ``kda`` ``[L_K, S, H, dk, dv]``, float32, and ``conv`` — Mamba's
+    ``[L_M, S, conv_dim, K-1]``, KDA's as rows ``[L_K, S, K-1, conv_dim]``
+    (a model has layers of one recurrent kind)."""
+    z = sizes(cfg)
+    if cfg.layers_of("K"):
+        lk, dk = cfg.layers_of("K"), cfg.kda_head_dim
+        return {"kda": jnp.zeros((lk, seqs, cfg.kda_heads, dk, dk), F32),
+                "conv": jnp.zeros((lk, seqs, cfg.kda_conv_kernel - 1,
+                                   z["conv_dim"]), dtype)}
     lm = cfg.layers_of("M")
-    return (jnp.zeros((lm, seqs, cfg.ssm_heads, cfg.ssm_head_dim,
-                       cfg.ssm_state), F32),
-            jnp.zeros((lm, seqs, sizes(cfg)["conv_dim"],
-                       cfg.ssm_conv_kernel - 1), dtype))
+    return {"ssm": jnp.zeros((lm, seqs, cfg.ssm_heads, cfg.ssm_head_dim,
+                              cfg.ssm_state), F32),
+            "conv": jnp.zeros((lm, seqs, z["conv_dim"],
+                               cfg.ssm_conv_kernel - 1), dtype)}
 
 
 def prefill(params: Params, cfg: ModelConfig, tokens, positions, kv_valid,
-            ssm0=None, conv0=None, ctx_k=None, ctx_v=None, ctx_valid=None,
+            rec0=None, ctx_k=None, ctx_v=None, ctx_valid=None,
             n_shards: int = 1, unembed: bool = True):
     """Forward over ``tokens [B, T]`` (padded; ``kv_valid [B, T]`` marks the
-    real ones, which lead).  With ``ssm0``/``conv0`` and ``ctx_k``/``ctx_v``
-    (``[L_A, B, Hkv, C, Dh]``) the rows continue sequences already seen:
-    the Mamba layers from that state, the attention layers over that
+    real ones, which lead).  With ``rec0`` (as :func:`zero_recurrent` gives
+    it) and ``ctx_k``/``ctx_v`` (``[L_A, B, Hkv, C, Dh]``; a latent cache
+    has no ``ctx_v``) the rows continue sequences already seen: the
+    recurrent layers from that state, the attention layers over that
     context.  Returns (logits ``[B, T, V]`` — or the final hidden states
-    with ``unembed=False`` — ks, vs ``[L_A, B, Hkv, T, Dh]``, ssm, conv,
-    counts)."""
+    with ``unembed=False`` — ks, vs ``[L_A, B, Hkv, T, Dh]`` (vs None for
+    latent rows), the recurrent state after the last real row, counts)."""
     from crowdllama_tpu.models import transformer as T
 
     b = tokens.shape[0]
     scale = T.attn_scale(cfg)
     n_valid = jnp.sum(kv_valid, axis=-1).astype(jnp.int32)
-    if ssm0 is None:
-        ssm0, conv0 = zero_recurrent(cfg, b, params["embed"].dtype)
+    if rec0 is None:
+        rec0 = zero_recurrent(cfg, b, params["embed"].dtype)
     kv, rec = {}, {}
 
     def attn_fn(i, q, k, v):
-        kh, vh = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
-        kv[i] = (kh, vh)
+        kh = k.transpose(0, 2, 1, 3)
+        vh = kh if v is None else v.transpose(0, 2, 1, 3)
+        kv[i] = (kh, None if v is None else vh)
         with jax.named_scope("attention"):
             if ctx_k is not None:
                 return prefill_attention_ctx(
-                    q, kh, vh, positions, ctx_k[i], ctx_v[i], ctx_valid,
+                    q, kh, vh, positions, ctx_k[i],
+                    (ctx_k if ctx_v is None else ctx_v)[i], ctx_valid,
                     scale, kv_valid=kv_valid)
             return prefill_attention(q, kh, vh, positions, scale,
                                      kv_valid=kv_valid, n_shards=n_shards)
 
-    def ssm_fn(i, lp, xbc, dt):
-        y, tail, state = mamba_mix(lp, cfg, xbc, dt, conv0[i], ssm0[i],
-                                   n_valid)
+    def rec_fn(kind, i, lp, *inputs):
+        y, tail, state = MIX[kind](lp, cfg, *inputs, rec0["conv"][i],
+                                   rec0[STATE[kind]][i], n_valid)
         rec[i] = (state, tail)
         return y
 
     x, counts = run_layers(params["layers"], cfg, T._embed(params, cfg, tokens),
-                           ssm_fn, attn_fn, kv_valid)
+                           rec_fn, attn_fn, kv_valid)
     ks = jnp.stack([kv[i][0] for i in range(len(kv))])
-    vs = jnp.stack([kv[i][1] for i in range(len(kv))])
-    ssm_out = jnp.stack([rec[i][0] for i in range(len(rec))])
-    conv_out = jnp.stack([rec[i][1] for i in range(len(rec))])
+    vs = (None if kv[0][1] is None
+          else jnp.stack([kv[i][1] for i in range(len(kv))]))
+    state_name, = set(rec0) - {"conv"}
+    rec_out = {state_name: jnp.stack([rec[i][0] for i in range(len(rec))]),
+               "conv": jnp.stack([rec[i][1] for i in range(len(rec))])}
     out = (T._unembed(params, cfg, x) if unembed else rms_norm(
         x, params["final_norm"], cfg.rms_norm_eps))
-    return out, ks, vs, ssm_out, conv_out, counts
+    return out, ks, vs, rec_out, counts

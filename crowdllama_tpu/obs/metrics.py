@@ -444,6 +444,12 @@ class EngineTelemetry:
         # carried state in a decode step (ops/ssm.py ssm_update_at; set at
         # JaxEngine.start, "" without Mamba layers).
         self._ssm_update_path = ""
+        # The same for the KDA layers' delta-rule update ("pallas" | "xla":
+        # ops/kda.py kda_update_at) and for what the decode attention reads
+        # ("mla": one latent row a token, key and value both; "gqa": K and V
+        # pools), "" where the model has neither.
+        self._kda_update_path = ""
+        self._attn_decode_path = ""
         # Unified ragged batch (docs/RAGGED_BATCH.md): wall time per
         # prefill chunk carried inside a decode dispatch.  Engine-plane
         # like the compile histogram (the scheduler's dispatch loop
@@ -503,6 +509,14 @@ class EngineTelemetry:
     def ssm_update_path_set(self, path: str) -> None:
         with self._lock:
             self._ssm_update_path = path
+
+    def kda_update_path_set(self, path: str) -> None:
+        with self._lock:
+            self._kda_update_path = path
+
+    def attn_decode_path_set(self, path: str) -> None:
+        with self._lock:
+            self._attn_decode_path = path
 
     def padding_inc(self, useful: int, waste: int) -> None:
         """Account one padded dispatch: ``useful`` real tokens rode it,
@@ -575,6 +589,8 @@ class EngineTelemetry:
             attention = sorted(self._attention_paths.items())
             moe_path = self._moe_matmul_path
             ssm_path = self._ssm_update_path
+            kda_path = self._kda_update_path
+            attn_decode = self._attn_decode_path
             prefix = dict(self._prefix)
             flight_seconds = dict(self._flight_seconds)
             flight_steps = dict(self._flight_steps)
@@ -595,6 +611,12 @@ class EngineTelemetry:
         out.append("# TYPE crowdllama_ssm_update_path gauge")
         out.append(f'crowdllama_ssm_update_path{{path="{ssm_path or "none"}"'
                    f'}} {1 if ssm_path else 0}')
+        out.append("# TYPE crowdllama_kda_update_path gauge")
+        out.append(f'crowdllama_kda_update_path{{path="{kda_path or "none"}"'
+                   f'}} {1 if kda_path else 0}')
+        out.append("# TYPE crowdllama_attn_decode_path gauge")
+        out.append(f'crowdllama_attn_decode_path{{path="'
+                   f'{attn_decode or "none"}"}} {1 if attn_decode else 0}')
         out.append("# TYPE crowdllama_xla_compiles_total counter")
         if not compiles:
             out.append('crowdllama_xla_compiles_total{program="none",'
@@ -642,6 +664,17 @@ class EngineTelemetry:
             out.append('crowdllama_engine_state_bytes{kind="none"} 0')
         for kind, n in state_bytes:
             out.append(f'crowdllama_engine_state_bytes{{kind="{kind}"}} {n}')
+        # what the slots' state costs beside the KV: the recurrent layers'
+        # (kind ssm | kda | conv), and a latent cache's rows
+        out.append("# TYPE crowdllama_recurrent_state_bytes gauge")
+        recurrent = [(k, n) for k, n in state_bytes
+                     if k in ("ssm", "kda", "conv")]
+        for kind, n in recurrent or [("none", 0)]:
+            out.append(f'crowdllama_recurrent_state_bytes{{kind="{kind}"}} '
+                       f'{n}')
+        out.append("# TYPE crowdllama_latent_cache_bytes gauge")
+        out.append(f"crowdllama_latent_cache_bytes "
+                   f"{dict(state_bytes).get('latent_cache', 0)}")
         out.append("# TYPE crowdllama_startup_seconds gauge")
         for phase in STARTUP_PHASES:
             out.append(f'crowdllama_startup_seconds{{phase="{phase}"}} '

@@ -39,6 +39,9 @@ from crowdllama_tpu.utils.env import env_flag
 # (BlockSpecs below); cap their combined footprint well under the ~16 MB of
 # VMEM so Q/O/accumulators and double-buffering still fit.
 _VMEM_KV_BUDGET_BYTES = 8 * 1024 * 1024
+# Elements of a query block [TQ, G, Dh] (and of its float32 accumulator)
+# where a kv head's queries are wider than 16 x 128.
+_Q_BLOCK_ELEMS = 256 * 8 * 128
 
 
 def pallas_refusal(seq_len: int, head_dim: int, itemsize: int = 2,
@@ -170,7 +173,12 @@ def flash_prefill_attention(
     b, t, h, dh = q.shape
     hkv = k.shape[1]
     g = h // hkv
-    tq = _tile(t, 256)
+    # 256 query rows a block for every GQA family (up to 16 query heads a kv
+    # head at Dh 128: the blocks the chip has run); a latent head (32 queries
+    # on ONE 576-wide row, models/hybrid.py) gets the rows that fit
+    # _Q_BLOCK_ELEMS, or the block outgrows VMEM
+    tq = _tile(t, 256 if g * dh <= 16 * 128
+               else max(8, _Q_BLOCK_ELEMS // (g * dh)))
     tk = _tile(t, 512)
 
     qg = q.reshape(b, t, hkv, g, dh)

@@ -319,6 +319,116 @@ def flash_paged_decode_attention(
     return out.reshape(b, h, dh)
 
 
+def _mla_decode_kernel(table_ref, seqlen_ref, layer_ref, q_ref, *refs,
+                       scale: float, page: int, pairs: int, latent: int):
+    """:func:`_decode_kernel` for a latent page: ONE shared kv head whose
+    row ``[c ; k_rope]`` is the key, and whose first ``latent`` values are
+    the value — the row is fetched once and read twice."""
+    del table_ref, layer_ref  # the index maps' alone
+    rows = refs[:pairs]                          # [page, Dh] tiles
+    o_ref, acc_ref, m_ref, l_ref = refs[-4:]
+    b, p = pl.program_id(0), pl.program_id(1)
+    seq_len = seqlen_ref[b]
+
+    @pl.when(p == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    for j in range(pairs):
+        base = (p * pairs + j) * page
+
+        @pl.when(base < seq_len)
+        def _body(row_ref=rows[j], base=base):
+            row = row_ref[...]                   # [page, Dh]
+            # bf16 x bf16 products are exact in the float32 accumulator
+            logits = jax.lax.dot_general(
+                q_ref[...], row, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale   # [H, page]
+            kpos = base + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
+            mask = kpos < seq_len
+            logits = jnp.where(mask, logits, NEG_INF)
+            m_prev, l_prev = m_ref[:, :1], l_ref[:, :1]
+            m_new = jnp.maximum(m_prev,
+                                jnp.max(logits, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            pr = jnp.exp(logits - m_new) * mask.astype(jnp.float32)
+            l_new = l_prev * alpha + jnp.sum(pr, axis=-1, keepdims=True)
+            value = row[:, :latent]
+            # the probabilities in two pieces of the value's type (16 bits
+            # of a bf16 pool's 24; all of a float32 pool's): two passes of
+            # the MXU where a float32 matmul is six
+            hi = pr.astype(value.dtype)
+            pv = jnp.dot(hi, value, preferred_element_type=jnp.float32)
+            if value.dtype != jnp.float32:
+                lo = (pr - hi.astype(jnp.float32)).astype(value.dtype)
+                pv += jnp.dot(lo, value, preferred_element_type=jnp.float32)
+            acc_ref[...] = acc_ref[...] * alpha + pv
+            m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+            l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+
+    @pl.when(p == pl.num_programs(1) - 1)
+    def _finalize():
+        l = l_ref[:, :1]
+        o_ref[...] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
+                      ).astype(o_ref.dtype)
+
+
+def paged_decode_attention_mla(
+    q: jnp.ndarray,           # [B, H, Dh] absorbed queries
+    pool: jnp.ndarray,        # [L, P, 1, page, Dh] latent rows [c ; k_rope]
+    layer: int | jnp.ndarray,
+    page_table: jnp.ndarray,  # [B, NP] int32
+    seq_lens: jnp.ndarray,    # [B] int32 (incl. the pending token)
+    scale: float,
+    latent: int,
+) -> jnp.ndarray:
+    """One cached decode step of absorbed latent attention (MLA) over
+    layer ``layer`` of the stacked latent pool: every head's query against
+    the one shared row a token, the value that row's first ``latent``
+    entries; output ``[B, H, latent]`` (still to be expanded by the value
+    half of the kv up-projection).  The pool has no V twin: a page is
+    fetched once."""
+    b, h, dh = q.shape
+    _, _, hkv, page, _ = pool.shape
+    assert hkv == 1 and latent % _LANES == 0, (pool.shape, latent)
+    np_ = page_table.shape[1]
+    pairs = 2 if np_ >= 2 else 1
+
+    def row_map_at(j):
+        def row_map(bi, pi, table, lens, layer):
+            return (layer[0], table[bi, jnp.minimum(pi * pairs + j, np_ - 1)],
+                    0, 0, 0)
+        return row_map
+
+    def q_map(bi, pi, *refs):
+        return (bi, 0, 0)
+
+    kernel = functools.partial(_mla_decode_kernel, scale=scale, page=page,
+                               pairs=pairs, latent=latent)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b, -(-np_ // pairs)),
+            in_specs=[pl.BlockSpec((None, h, dh), q_map),
+                      *(pl.BlockSpec((None, None, None, page, dh),
+                                     row_map_at(j)) for j in range(pairs))],
+            out_specs=pl.BlockSpec((None, h, latent), q_map),
+            scratch_shapes=[
+                pltpu.VMEM((h, latent), jnp.float32),
+                pltpu.VMEM((h, _LANES), jnp.float32),
+                pltpu.VMEM((h, _LANES), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, h, latent), q.dtype),
+        interpret=_interpret(),
+        name="paged_decode_attention_mla",
+    )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
+      _layer_operand(layer), q, *([pool] * pairs))
+
+
 # Query rows per ragged-kernel grid block.  32 keeps the fp32 online-
 # softmax scratch ([Hkv, QB*G, Dh] acc + two [Hkv, QB*G, _LANES] carries)
 # comfortably inside VMEM for Llama-class head counts.

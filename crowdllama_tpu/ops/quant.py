@@ -43,7 +43,10 @@ QUANT_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
               # models/hybrid.py: Mamba projections, latent projections,
               # the held expert banks, the shared expert
               "w_in", "w_out", "w_lat_down", "w_lat_up", "w1", "w2",
-              "ws1", "ws2")
+              "ws1", "ws2",
+              # KDA's two gates, the latent kv up-projection, the dense and
+              # shared SwiGLU's [gate | up]
+              "w_f_up", "w_g_up", "w_kvb", "w_gu", "ws_gu", "ws_down")
 
 
 @jax.tree_util.register_dataclass
@@ -337,7 +340,7 @@ def random_quantized_params(cfg, key: jax.Array, dtype=jnp.bfloat16,
     leaf_keys = jax.random.split(key, len(flat))
 
     norm_names = ("ln1", "ln2", "post_ln1", "post_ln2", "q_norm", "k_norm",
-                  "final_norm", "norm", "gate_norm")
+                  "final_norm", "norm", "gate_norm", "o_norm", "kv_norm")
 
     def build(path, sds, k):
         name = path[-1].key

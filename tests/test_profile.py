@@ -14,7 +14,7 @@ from aiohttp.test_utils import TestClient, TestServer
 
 from crowdllama_tpu.config import Configuration, Intervals
 from crowdllama_tpu.core.messages import create_generate_request
-from crowdllama_tpu.engine.engine import FakeEngine, JaxEngine
+from crowdllama_tpu.engine.engine import MIN_TRACE_S, FakeEngine, JaxEngine
 from crowdllama_tpu.ipc.server import IPCServer
 from crowdllama_tpu.obs import NodeObs
 from crowdllama_tpu.obs import trace as obs_trace
@@ -59,11 +59,12 @@ async def test_capture_profile_writes_trace_with_the_schedulers_phases(
         trace_dir = await engine.capture_profile(seconds=0.2)
         await gen
         assert list(Path(trace_dir).rglob("*.xplane.pb"))
-        # start; serve a request, let the loop park, wake it with another
-        # (a phase is recorded when it ends); stop
+        # start; serve a request, let the loop park (and the trace grow
+        # long enough to be collected), wake it with another (a phase is
+        # recorded when it ends); stop
         await engine.profile_start()
         await _generate(engine)
-        await asyncio.sleep(0.05)
+        await asyncio.sleep(MIN_TRACE_S)
         await _generate(engine, max_tokens=4)
         trace_dir = (await engine.profile_stop())["artifact"]
         # host events on the profiler's clock, as host_tracer_level 2 (what
@@ -130,16 +131,35 @@ async def test_worker_http_control_starts_and_stops_one_trace(tmp_path):
                 str(tmp_path / "traces"))
             assert (await _post(s, srv.port, "start"))[0] == 409
             await _generate(engine, max_tokens=8)
+            await asyncio.sleep(MIN_TRACE_S)
             status, done = await _post(s, srv.port, "stop")
             assert status == 200 and done["artifact"] == started["artifact"]
-            assert list(Path(done["artifact"]).rglob("*.xplane.pb"))
+            # the XSpace alone, in TensorBoard's layout: no trace.json.gz,
+            # whose conversion was most of a stop's time on the chip
+            files = [f for f in Path(done["artifact"]).rglob("*") if f.is_file()]
+            assert len(files) == 1 and files[0].name.endswith(".xplane.pb")
+            assert files[0].parent.parent == Path(
+                done["artifact"]) / "plugins" / "profile"
+            assert files[0].stat().st_size == done["xspace_bytes"] > 0
             assert (done["started_monotonic"] < done["stopped_monotonic"]
+                    <= done["collected_monotonic"]
                     <= done["written_monotonic"])
             assert 0 < done["stopped_unix"] - done["started_unix"] < 60
-            # a second trace goes to a directory of its own
+            # a second trace goes to a directory of its own; stopped at
+            # once (under MIN_TRACE_S) it is dropped, not collected: the
+            # directory stays empty, and a third trace is whole after it
             status, again = await _post(s, srv.port, "start")
             assert status == 200 and again["artifact"] != done["artifact"]
-            assert (await _post(s, srv.port, "stop"))[0] == 200
+            status, short = await _post(s, srv.port, "stop")
+            assert status == 200 and short["xspace_bytes"] == 0
+            assert (short["stopped_monotonic"] - short["started_monotonic"]
+                    < MIN_TRACE_S)
+            assert list(Path(short["artifact"]).iterdir()) == []
+            assert (await _post(s, srv.port, "start"))[0] == 200
+            await asyncio.sleep(MIN_TRACE_S)
+            status, third = await _post(s, srv.port, "stop")
+            assert status == 200 and third["xspace_bytes"] > 0
+            assert list(Path(third["artifact"]).rglob("*.xplane.pb"))
     finally:
         await srv.stop()
         await engine.stop()

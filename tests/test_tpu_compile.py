@@ -729,3 +729,195 @@ def test_ssm_update_kernel_compiles_at_the_cell_shape(one_chip):
     ma = compiled.memory_analysis()
     assert ma.alias_size_in_bytes == m * s * h * p * n * 4
     assert ma.temp_size_in_bytes < (1 << 20)
+
+
+# ------------- delta-rule linear attention and latent attention (PR 37)
+
+
+@pytest.fixture
+def kda_kernel(monkeypatch):
+    """The KDA layers' decode-step update takes the ``kda_update`` kernel,
+    as on the chip (the same steering as ``state_kernel``)."""
+    from crowdllama_tpu.ops.pallas import kda as kda_kernel
+
+    monkeypatch.setattr(kda_kernel, "kda_update_refusal",
+                        lambda state_shape: "")
+
+
+@pytest.fixture
+def kimi_runner(one_chip, monkeypatch, tmp_path, expert_kernel, kda_kernel):
+    """``() -> (runner, params, state, page table)``: the hybrid runner at
+    the widths, depth and share of ``kimi-linear-48b-p1-ep8-int8`` (the
+    benchmark's configuration file, read as the worker reads it), int8, 32
+    slots, context 1536, built from shapes alone."""
+    import json
+    from pathlib import Path
+
+    from crowdllama_tpu.engine import runner as runner_mod
+    from crowdllama_tpu.engine.hybrid import HybridPagedModelRunner
+    from crowdllama_tpu.engine.weights import resolve_model_config
+    from crowdllama_tpu.ops.quant import random_quantized_params
+
+    monkeypatch.setattr(runner_mod, "shard_params", lambda p, cfg, mesh: p)
+    doc = json.loads((Path(__file__).resolve().parents[1] / "benchmarks"
+                      / "chip" / "configs"
+                      / "kimi-linear-48b-p1-ep8-int8.json").read_text())
+    slots, ctx = doc["bench"]["slots"], doc["bench"]["context"]
+    (tmp_path / "config.json").write_text(json.dumps(
+        {k: v for k, v in doc.items() if k != "bench"}))
+
+    def build():
+        cfg = resolve_model_config(doc["bench"]["name"], str(tmp_path),
+                                   max_context_length=ctx)
+        shapes = jax.eval_shape(lambda: random_quantized_params(
+            cfg, jax.random.PRNGKey(0), dtype=jnp.bfloat16))
+        r = HybridPagedModelRunner(cfg, params=shapes, mesh_spec="1x1",
+                                   max_slots=slots, max_seq=ctx,
+                                   page_size=PAGE)
+        assert r.kda_update_path == "pallas" and r.ssm_update_path == ""
+        r.attention_paths = {**r.attention_paths, "decode": "pallas",
+                             "ragged_step": "pallas"}
+
+        def on_chip(tree):
+            return jax.tree_util.tree_map(
+                lambda a: _sds(a.shape, a.dtype, one_chip), tree)
+
+        table = _sds((slots, ctx // PAGE), jnp.int32, one_chip)
+        return r, on_chip(shapes), on_chip(jax.eval_shape(r.init_state)), table
+
+    return build
+
+
+def _calls(text: str, name: str) -> list[str]:
+    return [ln for ln in text.splitlines() if " custom-call(" in ln
+            and f"%{name}" in ln.split(" = ")[0]]
+
+
+@pytest.mark.parametrize("steps", [1, 2])
+def test_kimi_decode_program_keeps_its_state_in_place(kimi_runner, steps):
+    """All 27 layers of the cut in one step program: the latent pool (no V
+    twin), the KDA matrices and the convolutions' tails are handed back
+    where they lay; twenty ``kda_update`` calls a step on the WHOLE stack,
+    seven ``paged_decode_attention_mla``, 78 grouped matmuls (26 expert
+    layers, three int8 banks); no fusion or copy with the state or a bank
+    among its operands; the temporaries are under a tenth of the weights."""
+    r, params, state, table = kimi_runner()
+    assert state.pool_v is None and state.pool_k.shape == (
+        7, 32 * 12 + 1, 1, PAGE, 576)
+    assert state.kda.shape == (20, 32, 32, 128, 128)
+    assert state.conv.shape == (20, 32, 3, 12288)
+    compiled = jax.jit(
+        r._decode_paged_impl, donate_argnums=(1,), static_argnums=(3,)
+    ).lower(params, state, table, steps).compile()
+    ma = compiled.memory_analysis()
+    kept = sum(a.size * a.dtype.itemsize
+               for a in (state.pool_k, state.kda, state.conv))
+    assert ma.alias_size_in_bytes >= kept, (ma.alias_size_in_bytes, kept)
+    weights = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree_util.tree_leaves(params))
+    assert 7.2e9 < weights < 7.4e9 and ma.temp_size_in_bytes < weights // 10
+    text = compiled.as_text()
+    assert len(_calls(text, "kda_update")) == 20
+    assert len(_calls(text, "paged_decode_attention_mla")) == 7
+    assert len(_calls(text, "moe_grouped_matmul")) == 78
+    stack = "f32[20,32,32,128,128]"
+    for ln in _calls(text, "kda_update"):
+        assert stack in ln.split(" custom-call(")[0], ln.strip()[:200]
+    for ln in text.splitlines():
+        if any(f" {op}(" in ln for op in ("fusion", "copy", "copy-start")):
+            assert stack not in ln and "f32[32,32,128,128]" not in ln, (
+                ln.strip()[:200])
+            assert not any(f"= {t}[32,{d}]" in ln for t in ("bf16", "s8")
+                           for d in ("2304,1024", "1024,2304")), (
+                ln.strip()[:200])
+
+
+def test_kimi_ragged_step_program_compiles_with_its_state_in_place(
+        kimi_runner, one_chip):
+    """Decode rows beside a 512-token chunk: the v2 ragged kernel over one
+    576-wide latent row a token (key and value both), the chunk's rows
+    through the chunkwise delta rule into the slot's own slab."""
+    r, params, state, table = kimi_runner()
+    assert r.ragged_chunk == CHUNK
+
+    def i32(*shape):
+        return _sds(shape, jnp.int32, one_chip)
+
+    compiled = jax.jit(
+        r._ragged_step_impl, donate_argnums=(1,), static_argnums=(7,)
+    ).lower(params, state, table, i32(1, CHUNK), i32(1), i32(), i32(),
+            1).compile()
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= sum(
+        a.size * a.dtype.itemsize
+        for a in (state.pool_k, state.kda, state.conv))
+    text = compiled.as_text()
+    assert len(_calls(text, "kda_update")) == 20
+    assert len(_calls(text, "moe_grouped_matmul")) == 78
+    assert not _calls(text, "paged_decode_attention")
+
+
+def test_kda_update_kernel_compiles_at_the_cell_shape(one_chip):
+    """The kernel alone, with the tile it chooses at the cell's state
+    ``[20, 32, 32, 128, 128]``: the stack handed back, and what rides
+    beside the tile (four columns a head) under 3 MB."""
+    from crowdllama_tpu.ops.pallas.kda import choose_head_block, kda_update
+
+    def f32(*shape):
+        return _sds(shape, jnp.float32, one_chip)
+
+    m, s, h, dk = 20, 32, 32, 128
+    assert choose_head_block(h, dk, dk) == 16
+    compiled = jax.jit(kda_update, donate_argnums=(5,)).lower(
+        f32(s, h, dk), f32(s, h, dk), f32(s, h, dk), f32(s, h, dk), f32(s, h),
+        f32(m, s, h, dk, dk), _sds((), jnp.int32, one_chip)).compile()
+    _assert_kernel(compiled)
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes == m * s * h * dk * dk * 4
+    assert ma.temp_size_in_bytes < 3 * (1 << 20)
+
+
+def test_latent_attention_kernels_compile_at_the_cell_shape(one_chip):
+    """One shared kv head, 32 query heads, rows of 576: the latent decode
+    kernel, and the accepted prefill and ragged kernels with the row as key
+    AND value (the prefill kernel's query block shrinks to fit VMEM)."""
+    from crowdllama_tpu.ops.pallas.paged import (paged_decode_attention_mla,
+                                                 ragged_paged_attention)
+
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    layers, slots, heads, row, latent, np_ = 7, 32, 32, 576, 512, 12
+    pool = _sds((layers, slots * np_ + 1, 1, PAGE, row), bf16, one_chip)
+    table = _sds((slots, np_), i32, one_chip)
+    scale = 192 ** -0.5
+
+    def decode(q, pool, li, table, lens):
+        return paged_decode_attention_mla(q, pool, li, table, lens, scale,
+                                          latent)
+
+    compiled = jax.jit(decode).lower(
+        _sds((slots, heads, row), bf16, one_chip), pool,
+        _sds((), i32, one_chip), table, _sds((slots,), i32, one_chip)
+    ).compile()
+    assert len(_calls(compiled.as_text(), "paged_decode_attention_mla")) == 1
+
+    for t in (256, 1536):
+        def prefill(q, k, pos, valid):
+            return flash_prefill_attention(q, k, k, pos, scale,
+                                           kv_valid=valid)
+
+        _assert_kernel(jax.jit(prefill).lower(
+            _sds((1, t, heads, row), bf16, one_chip),
+            _sds((1, 1, t, row), bf16, one_chip),
+            _sds((1, t), i32, one_chip),
+            _sds((1, t), jnp.bool_, one_chip)).compile())
+
+    def ragged(q, ck, pool, li, table, ql, kl, cs):
+        return ragged_paged_attention(q, ck, ck, pool, pool, li, table, ql,
+                                      kl, cs, scale, use_pallas=True)
+
+    _assert_kernel(jax.jit(ragged).lower(
+        _sds((slots + CHUNK, heads, row), bf16, one_chip),
+        _sds((1, 1, CHUNK, row), bf16, one_chip), pool,
+        _sds((), i32, one_chip), table, _sds((slots + 1,), i32, one_chip),
+        _sds((slots + 1,), i32, one_chip), _sds((), i32, one_chip)
+    ).compile())
