@@ -119,7 +119,7 @@ class Configuration:
     max_batch_slots: int = 8
     max_context_length: int = 2048
     mesh_shape: str = ""  # e.g. "1x8" → (dp=1, tp=8); empty = all devices on tp
-    decode_chunk: int = 8  # decode steps per device dispatch
+    decode_chunk: int = 8  # decode steps per dispatch while every slot is taken
     # Unified ragged batch (docs/RAGGED_BATCH.md): long prompts prefill
     # INSIDE the decode dispatch — each step decodes every active slot and
     # carries one prefill chunk of up to (step_token_budget -

@@ -471,6 +471,11 @@ class EngineTelemetry:
         # host read anything; "host" — the runner handed back an int (the
         # chunked and ragged finishes, the multi-host wrapper).
         self._admissions = {"device": 0, "host": 0}
+        # Retired decode flights by length (Scheduler._chunk_size): "short"
+        # — fewer steps than decode_chunk, which outside spec probes and
+        # gateway-paced rounds means a slot was free; "full" — decode_chunk
+        # steps (or a megastep's K): every slot was taken.
+        self._flights = {"short": 0, "full": 0}
 
     def _key(self, program: str, bucket: object) -> tuple[str, str]:
         return (self.program_guard.value(program),
@@ -526,11 +531,13 @@ class EngineTelemetry:
             self._padding["waste"] += max(0, int(waste))
 
     def flight_inc(self, cls: str, seconds: float, steps: int,
-                   useful: int, waste: int) -> None:
+                   useful: int, waste: int, short: bool) -> None:
         """Account one retired decode flight under one lock: its wall time
-        and steps under its dispatch class, and its padding as
-        :meth:`padding_inc` would."""
+        and steps under its dispatch class, its padding as
+        :meth:`padding_inc` would, and its length (``short``: dispatched
+        with fewer steps than decode_chunk)."""
         with self._lock:
+            self._flights["short" if short else "full"] += 1
             self._flight_seconds[cls] += max(0.0, float(seconds))
             self._flight_steps[cls] += max(0, int(steps))
             self._padding["useful"] += max(0, int(useful))
@@ -597,6 +604,7 @@ class EngineTelemetry:
             startup = dict(self._startup)
             moe = dict(self._moe_assignments)
             admissions = dict(self._admissions)
+            flights = dict(self._flights)
             state_bytes = sorted(self._state_bytes.items())
         out.append("# TYPE crowdllama_engine_attention_path gauge")
         if not attention:
@@ -687,6 +695,10 @@ class EngineTelemetry:
         out.append("# TYPE crowdllama_admissions_total counter")
         for where, n in admissions.items():
             out.append(f'crowdllama_admissions_total{{first_token="{where}"'
+                       f'}} {n}')
+        out.append("# TYPE crowdllama_engine_flights_total counter")
+        for length, n in flights.items():
+            out.append(f'crowdllama_engine_flights_total{{length="{length}"'
                        f'}} {n}')
         return out
 

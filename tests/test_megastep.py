@@ -426,7 +426,9 @@ async def test_ragged_megastep_spec_retune_streams_identical():
 
     cfg = get_config("tiny-test", max_context_length=256)
     params = T.init_params(cfg, KEY, dtype=jnp.bfloat16)
-    runner = SpecPagedModelRunner(cfg, params=params, max_slots=4,
+    # three slots for three requests: the fused path, like every full-size
+    # flight, is dispatched only while no slot is free
+    runner = SpecPagedModelRunner(cfg, params=params, max_slots=3,
                                   max_seq=256, page_size=32, mesh_spec="1",
                                   draft_len=3, step_token_budget=96,
                                   prefix_cache=False)
@@ -568,6 +570,9 @@ async def test_ragged_megastep_drain_at_fused_boundary_resumes():
     kv_cfg = dict(model=MODEL, kv_layout="paged", kv_page_size=16,
                   kv_ship=True, kv_ship_min_tokens=16, kv_ship_timeout=2.0,
                   step_token_budget=32, decode_chunk=4, megastep_k=4,
+                  # one slot: the lone request saturates the batch, and
+                  # only a saturated batch flies full-size (fused) flights
+                  max_batch_slots=1,
                   # Byte-identity vs the prefix-hit rerun is a per-device-
                   # program contract: see the mesh note in
                   # tests/test_drain.py::test_drain_mid_chunked_prefill_

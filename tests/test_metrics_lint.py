@@ -274,7 +274,8 @@ async def test_gateway_and_worker_metrics_lint():
                 "crowdllama_xla_compile_seconds") == "histogram"
             for fam in ("crowdllama_xla_compiles_total",
                         "crowdllama_padding_waste_tokens_total",
-                        "crowdllama_useful_tokens_total"):
+                        "crowdllama_useful_tokens_total",
+                        "crowdllama_engine_flights_total"):
                 assert types.get(fam) == "counter", f"{fam} missing"
             for fam in ("crowdllama_device_memory_bytes_in_use",
                         "crowdllama_device_memory_bytes_limit"):
@@ -343,6 +344,30 @@ async def test_gateway_and_worker_metrics_lint():
         await obs_srv.stop()
         await worker.stop()
         await boot_host.close()
+
+
+def test_flight_length_counter_lint():
+    """crowdllama_engine_flights_total{length}: one counter family, both
+    lengths rendered at 0 before any flight, each retired flight counted
+    under exactly one."""
+    from crowdllama_tpu.obs.metrics import EngineTelemetry
+
+    tele = EngineTelemetry()
+    text = "\n".join(tele.expose())
+    assert _lint(text)["crowdllama_engine_flights_total"] == "counter"
+    for length in ("short", "full"):
+        assert (f'crowdllama_engine_flights_total{{length="{length}"}} 0'
+                in text.splitlines())
+    tele.flight_inc("plain", seconds=0.01, steps=1, useful=1, waste=7,
+                    short=True)
+    tele.flight_inc("plain", seconds=0.08, steps=8, useful=64, waste=0,
+                    short=False)
+    tele.flight_inc("plain", seconds=0.08, steps=8, useful=64, waste=0,
+                    short=False)
+    lines = tele.expose()
+    _lint("\n".join(lines))
+    assert 'crowdllama_engine_flights_total{length="short"} 1' in lines
+    assert 'crowdllama_engine_flights_total{length="full"} 2' in lines
 
 
 def test_spec_gauges_lint():

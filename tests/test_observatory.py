@@ -220,6 +220,39 @@ async def test_duty_cycle_gauges_under_megastep_run():
         > plain_before
 
 
+@pytest.mark.parametrize("slots,length", [(4, "short"), (1, "full")],
+                         ids=["half-empty", "saturated"])
+async def test_flight_length_counter_follows_occupancy(slots, length):
+    """crowdllama_engine_flights_total: a lone stream among free slots
+    flies one step at a time and counts ``short``; the same stream on a
+    worker it fills flies ``decode_chunk`` steps and counts ``full``."""
+    from test_admission_pipeline import _drain
+    from test_engine import _step_recorder
+
+    from crowdllama_tpu.engine.scheduler import GenRequest, Scheduler
+    from crowdllama_tpu.obs.metrics import ENGINE_TELEMETRY
+
+    before = dict(ENGINE_TELEMETRY._flights)
+    runner = _step_recorder(slots)
+    sched = Scheduler(runner, decode_chunk=8)
+    sched.start()
+    try:
+        req = GenRequest(prompt_ids=[11, 2], max_tokens=30, eos_id=-1)
+        await sched.submit(req)
+        await _drain(req)
+    finally:
+        await sched.stop()
+    grew = {k: ENGINE_TELEMETRY._flights[k] - before[k] for k in before}
+    other = "full" if length == "short" else "short"
+    # (the flight queued behind the one that ended the stream may not have
+    # been retired)
+    assert grew[other] == 0 and grew[length] >= 3, grew
+    assert runner.flights - 1 <= grew[length] <= runner.flights, grew
+    assert (f'crowdllama_engine_flights_total{{length="{length}"}} '
+            f'{ENGINE_TELEMETRY._flights[length]}'
+            in ENGINE_TELEMETRY.expose())
+
+
 def test_multi_engine_max_merges_duty_cycle():
     """Duty cycle is a ratio: MultiEngine must max-merge it across
     children, not sum it past 1.0."""

@@ -76,8 +76,9 @@ async def test_capture_profile_writes_trace_with_the_schedulers_phases(
                 f"{obs_trace.SCHED_DISPATCH}.insert",
                 f"{obs_trace.SCHED_DISPATCH}.decode"} <= set(events), sorted(
                     n for n in events if n.startswith("sched"))
-        # the dispatch class and the step count ride as arguments
-        assert {"dispatch": "plain", "steps": 8} in events[
+        # the dispatch class and the step count ride as arguments (a lone
+        # request leaves slots free: one step a flight)
+        assert {"dispatch": "plain", "steps": 1} in events[
             f"{obs_trace.SCHED_DISPATCH}.decode"]
         assert {"dispatch": "plain"} in events[obs_trace.SCHED_READBACK]
     finally:
@@ -297,7 +298,9 @@ async def test_counters_move_as_the_request_mix_says():
         flights = sched.host_dispatches - dispatches
         assert flights >= 3
         steps = grew(t0, t1, "crowdllama_engine_flight_steps_total")
-        assert flights <= steps <= flights * sched.decode_chunk
+        # (the flight queued behind the one that ended the stream may
+        # still be in the air)
+        assert flights - 1 <= steps <= flights * sched.decode_chunk
         assert steps == grew(
             t0, t1,
             'crowdllama_engine_flight_steps_total{dispatch="plain"}')

@@ -69,14 +69,15 @@ _FOLLOWER = _COMMON + textwrap.dedent("""
 
 _FAULT = textwrap.dedent("""
     # Deterministic dispatch fault on BOTH processes: the first decode
-    # chunk of exactly 5 steps raises.  The leader's scheduler recovery
+    # dispatch raises (warm-up is off, so it is the doomed request's: one
+    # step, slots being free).  The leader's scheduler recovery
     # fails the in-flight request, broadcasts INIT, and keeps serving;
     # the follower must survive the SAME error and stay in lockstep.
     from crowdllama_tpu.engine.runner import ModelRunner
     _orig_dsd = ModelRunner.decode_steps_device
     _fired = [False]
     def _faulty(self, state, num_steps=1):
-        if num_steps == 5 and not _fired[0]:
+        if not _fired[0]:
             _fired[0] = True
             raise RuntimeError("injected dispatch fault")
         return _orig_dsd(self, state, num_steps)
@@ -88,8 +89,7 @@ _LEADER_FAULT = _COMMON + _FAULT + textwrap.dedent("""
     from crowdllama_tpu.engine.engine import JaxEngine
 
     async def main():
-        cfg.decode_chunk = 5
-        cfg.warmup = False  # warmup's chunk of decode_chunk would trip it
+        cfg.warmup = False  # warmup's own decode dispatch would trip it
         eng = JaxEngine(cfg)
         await eng.start()
         try:
