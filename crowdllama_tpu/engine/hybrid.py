@@ -227,7 +227,8 @@ class HybridPagedModelRunner(PagedModelRunner):
         state = super().init_state(seed)
         self._dirty.clear()
         rec = H.zero_recurrent(self.cfg, self.max_slots, self.dtype)
-        state = replace(state, **rec, moe_rows=jnp.zeros((2,), jnp.int32))
+        state = replace(state, **rec,
+                        moe_rows=jnp.zeros((len(H.COUNTS),), jnp.int32))
         ENGINE_TELEMETRY.state_bytes_set({
             "latent_cache" if state.pool_v is None else "kv_pool":
             sum(a.nbytes for a in (state.pool_k, state.pool_v,
@@ -256,7 +257,8 @@ class HybridPagedModelRunner(PagedModelRunner):
         return state
 
     def flight_counters(self):
-        """Device array [held, left out] of the newest flight, once."""
+        """Device array of the newest flight's ``models/hybrid.py``
+        ``COUNTS``, once."""
         counts, self._flight_counts = self._flight_counts, None
         return counts
 

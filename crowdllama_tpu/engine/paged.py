@@ -177,7 +177,7 @@ class PagedDecodeState:
     # B, H, P, N] or KDA's matrix a head [L_K, B, H, dk, dv], float32, and
     # the convolutions' last K-1 inputs [L, B, conv_dim, K-1] — beside
     # pools that cover the attention layers alone; and the expert layers'
-    # [held, left out] assignment counts since the scheduler last took them.
+    # counts (models/hybrid.py COUNTS) since the scheduler last took them.
     ssm: jnp.ndarray | None = None
     kda: jnp.ndarray | None = None
     conv: jnp.ndarray | None = None

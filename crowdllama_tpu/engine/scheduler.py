@@ -186,8 +186,8 @@ class _InFlightChunk:
     # payload carrying the tokens that slot emitted in the flight.
     verify_meta: list | None = None
     # Counters the flight's programs kept on the device (a runner's
-    # ``flight_counters``; engine/hybrid.py: the expert layers' [held,
-    # left out] assignments), read back in the same transfer as the tokens.
+    # ``flight_counters``; engine/hybrid.py: the expert layers' rows and
+    # banks), read back in the same transfer as the tokens.
     counters_dev: object = None
 
 
@@ -1726,7 +1726,7 @@ class Scheduler:
         tokens, done, counters = await loop.run_in_executor(self._exec,
                                                             readback)
         if counters is not None:
-            ENGINE_TELEMETRY.moe_assignments_inc(*counters)
+            ENGINE_TELEMETRY.moe_counts_inc(cls, counters)
         now = time.monotonic()
         with jax.profiler.TraceAnnotation(SCHED_EMIT, dispatch=cls):
             emitted, dt = self._account_and_emit(fl, cls, tokens, done, now)

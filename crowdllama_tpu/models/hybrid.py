@@ -45,7 +45,7 @@ of the sum, which on one chip is simply not there.
 from __future__ import annotations
 
 import math
-from functools import partial
+from functools import partial, reduce
 from typing import Any
 
 import jax
@@ -58,7 +58,9 @@ from crowdllama_tpu.ops.attention import (
     prefill_attention_ctx,
 )
 from crowdllama_tpu.ops.norms import rms_norm
-from crowdllama_tpu.ops.quant import dequant, qeinsum, qragged_dot
+from crowdllama_tpu.ops.quant import (
+    dequant, qeinsum, qragged_dot, qragged_fetched,
+)
 
 Params = dict[str, Any]
 F32 = jnp.float32
@@ -406,13 +408,25 @@ def route(lp: Params, cfg: ModelConfig, h):
     return w * cfg.moe_routed_scaling, topi
 
 
+#: What an expert layer counts of one call, on the device (int32, in this
+#: order; the engine sums them over the expert layers and a flight's steps
+#: and the scheduler reads them back with the flight's tokens):
+#: token-expert rows of live tokens computed here / left to the ranks that
+#: hold their expert; held banks (one expert's matrices in one layer) that
+#: some row of the call, live or padding, is routed to / that the grouped
+#: matmuls read from HBM, routed to or not / held at all.
+COUNTS = ("rows_held", "rows_left_out", "banks_routed", "banks_fetched",
+          "banks_held")
+
+
 def held_sum(cfg: ModelConfig, u, topw, topi, live, experts):
     """The held experts' part of a routed sum, through the sorted grouped
     matmul.  u ``[N, W]`` the experts' input; topw, topi ``[N, K]`` each
     token's weights and choices over ALL experts; live ``[N]`` bool;
-    ``experts(xs, group_sizes) -> ys``: the held bank on rows sorted by
-    expert.  Returns (sum ``[N, W']`` float32, [rows computed here, rows
-    left to the other ranks] over the live tokens)."""
+    ``experts(xs, dot) -> ys``: the held bank on rows sorted by expert,
+    ``dot(x, w)`` being the grouped matmul of those rows by one of its
+    matrices.  Returns (sum ``[N, W']`` float32, this call's
+    :data:`COUNTS`, a scalar each)."""
     held = sizes(cfg)["held"]
     n, k = topi.shape
     local = topi - cfg.expert_rank * held
@@ -422,30 +436,35 @@ def held_sum(cfg: ModelConfig, u, topw, topi, live, experts):
     order = jnp.argsort(e_flat)
     t_sorted = jnp.repeat(jnp.arange(n), k)[order]
     group_sizes = jnp.bincount(e_flat, length=held + 1)[:held]
-    ys = experts(jnp.take(u, t_sorted, axis=0), group_sizes)
+    fetched = []    # of each grouped matmul, the banks it reads
+
+    def dot(x, w):
+        fetched.append(qragged_fetched(x, w, group_sizes))
+        return qragged_dot(x, w, group_sizes)
+
+    ys = experts(jnp.take(u, t_sorted, axis=0), dot)
     contrib = jnp.where(mine.reshape(-1)[order][:, None],
                         ys.astype(F32) * topw.reshape(-1)[order][:, None],
                         0.0)
     acc = jnp.zeros((n, ys.shape[-1]), F32).at[t_sorted].add(contrib)
     rows = jnp.sum(mine & live[:, None])
-    counts = jnp.stack([rows, jnp.sum(live) * k - rows]).astype(jnp.int32)
-    return acc, counts
+    # a bank is all of an expert's matrices: fetched if any of them is
+    return acc, (rows, jnp.sum(live) * k - rows, jnp.sum(group_sizes > 0),
+                 jnp.sum(reduce(jnp.logical_or, fetched)), held)
 
 
 def moe_body(lp: Params, cfg: ModelConfig, x, live):
     """One latent expert layer: the held experts' part of the routed sum
     plus the shared expert.  x ``[..., D]``; live ``[...]`` bool marks the
-    rows that are real tokens.  Returns (x, [rows computed here, rows left
-    to the other ranks]) — counted over the live rows."""
+    rows that are real tokens.  Returns (x, the call's :data:`COUNTS`)."""
     shape = x.shape
     h = _normed(lp, cfg, x).reshape(-1, shape[-1])
     topw, topi = route(lp, cfg, h)
     with jax.named_scope("moe_latent"):
         u = qeinsum("nd,dl->nl", h, lp["w_lat_down"])
 
-    def experts(xs, group_sizes):
-        up = qragged_dot(xs, lp["w1"], group_sizes)
-        return qragged_dot(relu2(up).astype(xs.dtype), lp["w2"], group_sizes)
+    def experts(xs, dot):
+        return dot(relu2(dot(xs, lp["w1"])).astype(xs.dtype), lp["w2"])
 
     with jax.named_scope("moe"):
         acc, counts = held_sum(cfg, u, topw, topi, live.reshape(-1), experts)
@@ -465,11 +484,10 @@ def smoe_body(lp: Params, cfg: ModelConfig, x, live):
     h = _normed(lp, cfg, x).reshape(-1, shape[-1])
     topw, topi = route(lp, cfg, h)
 
-    def experts(xs, group_sizes):
-        gate = qragged_dot(xs, lp["w_gate"], group_sizes)
-        up = qragged_dot(xs, lp["w_up"], group_sizes)
+    def experts(xs, dot):
+        gate, up = dot(xs, lp["w_gate"]), dot(xs, lp["w_up"])
         mid = jax.nn.silu(gate.astype(F32)) * up.astype(F32)
-        return qragged_dot(mid.astype(xs.dtype), lp["w_down"], group_sizes)
+        return dot(mid.astype(xs.dtype), lp["w_down"])
 
     with jax.named_scope("moe"):
         acc, counts = held_sum(cfg, h, topw, topi, live.reshape(-1), experts)
@@ -484,8 +502,10 @@ MIX = {"M": mamba_mix, "K": kda_mix}
 
 def run_layers(layers: Params, cfg: ModelConfig, x, rec_fn, attn_fn, live):
     """The layer loop, unrolled over ``cfg.layer_pattern``.  Returns (x,
-    the expert layers' [held, left out] assignment counts)."""
-    counts = jnp.zeros((2,), jnp.int32)
+    the expert layers' :data:`COUNTS` summed, int32 ``[5]``)."""
+    # scalars until the end: a vector a layer was a concatenate a layer
+    # (1.2 us each on the chip: PERF.md §6, PR 40)
+    counts = (0,) * len(COUNTS)
     seen = dict.fromkeys(STACK, 0)
     attn_seen = 0
     for kind in cfg.layer_pattern:
@@ -510,8 +530,8 @@ def run_layers(layers: Params, cfg: ModelConfig, x, rec_fn, attn_fn, live):
             x = mlp_body(lp, cfg, x)
         else:
             x, c = (moe_body if kind == "E" else smoe_body)(lp, cfg, x, live)
-            counts = counts + c
-    return x, counts
+            counts = tuple(a + b for a, b in zip(counts, c))
+    return x, jnp.stack(counts).astype(jnp.int32)
 
 
 # ------------------------------------------------------------------ prefill

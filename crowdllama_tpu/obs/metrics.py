@@ -17,6 +17,7 @@ request field (label-cardinality DoS on the scrape pipeline).
 
 from __future__ import annotations
 
+import os
 import re
 import threading
 import time
@@ -47,7 +48,23 @@ DISPATCH_CLASSES = ("plain", "megastep", "ragged", "spec")
 # one series per class from the first scrape.
 FLIGHT_CLASSES = ("plain", "megastep", "ragged", "ragged_mega", "spec")
 # Phases of a worker's start that crowdllama_startup_seconds reports.
-STARTUP_PHASES = ("weights", "warmup", "ready")
+STARTUP_PHASES = ("weights", "warmup", "ready", "process")
+
+
+def process_age_seconds() -> float | None:
+    """Seconds since the operating system started this process: before the
+    interpreter, every import and — under a launcher that asks JAX for its
+    devices first — reaching the chip, none of which a clock started in
+    Python can see.  From /proc (Linux); None where there is none."""
+    try:
+        with open("/proc/self/stat") as f:
+            # the fields after the command's closing parenthesis; the
+            # 22nd of the line is the start, in ticks since boot
+            ticks = int(f.read().rpartition(")")[2].split()[19])
+        return (time.clock_gettime(time.CLOCK_BOOTTIME)
+                - ticks / os.sysconf("SC_CLK_TCK"))
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
 
 
 def _fmt(v: float) -> str:
@@ -423,6 +440,12 @@ class EngineTelemetry:
         # to the ranks that hold their expert ("no"), counted on the device
         # and read back with each flight's tokens.
         self._moe_assignments = {"yes": 0, "no": 0}
+        # The same layer's banks (one held expert's matrices in one layer
+        # in one step), by the flight's dispatch class: those some row was
+        # routed to, those none was, and those the grouped matmuls read
+        # from HBM whichever — counted and read back the same way.
+        self._moe_banks = {cls: {"routed": 0, "unrouted": 0, "fetched": 0}
+                           for cls in FLIGHT_CLASSES}
         # Bytes of each kind of per-request state the newest runner's
         # init_state allocated (engine/hybrid.py: KV pool, state-space
         # state, convolution tail).
@@ -556,10 +579,18 @@ class EngineTelemetry:
         with self._lock:
             self._admissions[first_token] += 1
 
-    def moe_assignments_inc(self, held: int, left_out: int) -> None:
+    def moe_counts_inc(self, dispatch: str, counts) -> None:
+        """One flight's expert-layer counts (models/hybrid.py ``COUNTS``),
+        already on the host."""
+        held, left_out, routed, fetched, banks = (
+            max(0, int(c)) for c in counts)
         with self._lock:
-            self._moe_assignments["yes"] += max(0, int(held))
-            self._moe_assignments["no"] += max(0, int(left_out))
+            self._moe_assignments["yes"] += held
+            self._moe_assignments["no"] += left_out
+            by = self._moe_banks[dispatch]
+            by["routed"] += routed
+            by["unrouted"] += banks - routed
+            by["fetched"] += fetched
 
     def state_bytes_set(self, by_kind: dict[str, int]) -> None:
         with self._lock:
@@ -603,6 +634,7 @@ class EngineTelemetry:
             flight_steps = dict(self._flight_steps)
             startup = dict(self._startup)
             moe = dict(self._moe_assignments)
+            moe_banks = {cls: dict(by) for cls, by in self._moe_banks.items()}
             admissions = dict(self._admissions)
             flights = dict(self._flights)
             state_bytes = sorted(self._state_bytes.items())
@@ -667,6 +699,15 @@ class EngineTelemetry:
         for held, n in moe.items():
             out.append(f'crowdllama_moe_assignments_total{{held="{held}"}} '
                        f'{n}')
+        out.append("# TYPE crowdllama_moe_banks_total counter")
+        for cls in FLIGHT_CLASSES:
+            for state in ("routed", "unrouted"):
+                out.append(f'crowdllama_moe_banks_total{{dispatch="{cls}",'
+                           f'state="{state}"}} {moe_banks[cls][state]}')
+        out.append("# TYPE crowdllama_moe_banks_fetched_total counter")
+        for cls in FLIGHT_CLASSES:
+            out.append(f'crowdllama_moe_banks_fetched_total{{dispatch="{cls}"'
+                       f'}} {moe_banks[cls]["fetched"]}')
         out.append("# TYPE crowdllama_engine_state_bytes gauge")
         if not state_bytes:
             out.append('crowdllama_engine_state_bytes{kind="none"} 0')

@@ -133,10 +133,35 @@ def choose_tiles(m: int, groups: int, d_in: int, d_out: int,
     return tm, tk, tn
 
 
+def _group_tiles(sizes, starts, ends, tm: int, tiles_m: int):
+    """(first row tile, number of visits) of every group: a visit for every
+    row tile the group's rows lie in, and ONE (of the tile its rows would
+    start in) for a group that has none — which is what fetches an
+    unrouted bank.  The one statement of that rule: :func:`_visits` lays
+    the grid out from it and :func:`banks_fetched` counts from it."""
+    first_tile = lax.min(lax.div(starts, jnp.int32(tm)),
+                         jnp.int32(tiles_m - 1))
+    tiles = lax.select(sizes > 0,
+                       lax.div(ends + jnp.int32(tm - 1), jnp.int32(tm))
+                       - first_tile, lax.full_like(sizes, 1))
+    return first_tile, tiles
+
+
+def banks_fetched(group_sizes: jnp.ndarray, m: int,
+                  q_shape: tuple[int, ...], x_itemsize: int = 2):
+    """bool ``[E]``: the groups whose bank ``moe_grouped_matmul`` reads
+    from HBM for ``m`` rows against a bank of shape ``q_shape`` — those it
+    visits at least once, by the tiles it would choose.  What the expert
+    layer's ``fetched`` counter counts (models/hybrid.py ``held_sum``)."""
+    tm = choose_tiles(m, q_shape[-3], *q_shape[-2:], x_itemsize)[0]
+    sizes = group_sizes.astype(jnp.int32)
+    ends = lax.cumsum(sizes, axis=0)
+    return _group_tiles(sizes, ends - sizes, ends, tm, -(-m // tm))[1] > 0
+
+
 def _visits(group_sizes: jnp.ndarray, tm: int, tiles_m: int):
-    """The (group, row tile) pairs to visit, in row order: every row tile a
-    group's rows lie in, and one visit (of the tile its rows would start
-    in) for a group that has none.  Returns (group ids [V], row-tile ids
+    """The (group, row tile) pairs to visit, in row order, as
+    :func:`_group_tiles` counts them.  Returns (group ids [V], row-tile ids
     [V], group starts [E], group ends [E], number of visits) with V =
     tiles_m + E - 1, the most there can be; entries past the number of
     visits are never read.  Written in ``lax`` primitives: every ``jnp`` wrapper here is one
@@ -146,11 +171,7 @@ def _visits(group_sizes: jnp.ndarray, tm: int, tiles_m: int):
     sizes = group_sizes.astype(jnp.int32)
     ends = lax.cumsum(sizes, axis=0)
     starts = ends - sizes
-    first_tile = lax.min(lax.div(starts, jnp.int32(tm)),
-                         jnp.int32(tiles_m - 1))
-    tiles = lax.select(sizes > 0,
-                       lax.div(ends + jnp.int32(tm - 1), jnp.int32(tm))
-                       - first_tile, lax.full_like(sizes, 1))
+    first_tile, tiles = _group_tiles(sizes, starts, ends, tm, tiles_m)
     last_visit = lax.cumsum(tiles, axis=0)   # one past the group's last
     v_max = tiles_m + e - 1
     visit = lax.iota(jnp.int32, v_max)

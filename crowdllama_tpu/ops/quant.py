@@ -243,6 +243,20 @@ def qragged_dot(xs: jnp.ndarray, w, group_sizes: jnp.ndarray) -> jnp.ndarray:
         return jax.lax.ragged_dot(xs, bank, group_sizes)
 
 
+def qragged_fetched(xs: jnp.ndarray, w, group_sizes: jnp.ndarray):
+    """bool ``[E]``: the banks of ``w`` that ``qragged_dot(xs, w,
+    group_sizes)`` reads from HBM.  The int8 kernel's own rule
+    (ops/pallas/moe.py ``banks_fetched``); ``lax.ragged_dot`` on the other
+    paths reads every bank, dequantized whole or plain."""
+    if ragged_dot_path(w)[0] == "int8_kernel":
+        from crowdllama_tpu.ops.pallas.moe import banks_fetched
+
+        q = (w.stack if isinstance(w, LayerOf) else w).q
+        return banks_fetched(group_sizes, xs.shape[0], q.shape,
+                             xs.dtype.itemsize)
+    return jnp.ones(group_sizes.shape, bool)
+
+
 # the scanned entry that stands in for the banks that ride
 _LAYER_INDEX = "_layer_index"
 

@@ -332,7 +332,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
     with jax.default_matmul_precision("highest"):
         uncut = R.mixer("E", h, lp, R.hyper(hf_of(whole)))
         shared = R.shared_part(h, lp)
-        total, rows = 0.0, np.zeros(2, np.int64)
+        total, rows = 0.0, np.zeros(len(H.COUNTS), np.int64)
         for rank in range(4):
             cfg = replace(CFG, experts_held=4, expert_rank=rank)
             mine = {**lp, "w1": lp["w1"][4 * rank:4 * rank + 4],
@@ -347,6 +347,72 @@ def test_the_shares_add_up_to_the_uncut_layer():
     # every token-expert row is computed by exactly one rank
     assert rows[0] == 24 * CFG.num_experts_per_tok
     assert rows[1] == 3 * rows[0]
+    # and of the 16 banks the four ranks hold, none routed to twice
+    assert 0 < rows[2] <= rows[3] == rows[4] == 16
+
+
+def routing(case: str, experts: int = 16, held: int = 8, k: int = 4):
+    """(topi ``[6, k]``, live ``[6]``) of a call whose held banks are the
+    first ``held``: each token's k distinct experts, some of them another
+    rank's."""
+    rng = np.random.default_rng(7)
+    topi = np.stack([rng.permutation(experts)[:k] for _ in range(6)])
+    live = np.ones(6, bool)
+    if case == "padded":        # padding rows are routed and multiplied too
+        live[2:] = False
+    elif case == "empty-banks":  # every held choice falls on bank 0 or 1
+        topi = np.where(topi < held, topi % 2, topi)
+    elif case == "none-routed":
+        topi = held + topi % (experts - held)
+    return topi, live
+
+
+def banks_visited(held_choices, held: int, rows: int, width: int) -> int:
+    """How many of ``held`` banks ``[width, width]`` the grouped matmul
+    kernel visits for ``rows`` float32 rows, ``held_choices`` of them with a
+    held expert: the distinct groups among the visits ``_visits`` lays out
+    — the rule the ``fetched`` counter has to follow."""
+    from crowdllama_tpu.ops.pallas import moe as K
+
+    tm = K.choose_tiles(rows, held, width, width, 4)[0]
+    group_ids, _, _, _, visits = K._visits(
+        jnp.bincount(jnp.asarray(held_choices), length=held), tm,
+        -(-rows // tm))
+    return len(np.unique(np.asarray(group_ids)[:int(visits)]))
+
+
+@pytest.mark.parametrize("path", ["ragged_dot", "kernel"])
+@pytest.mark.parametrize("case", ["live-only", "padded", "empty-banks",
+                                  "none-routed"])
+def test_held_sum_counts_the_banks_routed_and_the_banks_fetched(
+        case, path, monkeypatch):
+    """``routed`` is a recount of ``topi``; ``fetched`` is what the grouped
+    matmul's own rule visits (``ops/pallas/moe.py`` ``_visits``) and every
+    held bank where ``lax.ragged_dot`` multiplies."""
+    from crowdllama_tpu.ops.quant import QTensor, ragged_dot_path
+
+    if path == "kernel":
+        monkeypatch.setenv("CROWDLLAMA_PALLAS_INTERPRET", "1")
+    held, k, w = 8, CFG.num_experts_per_tok, 128
+    bank = QTensor(
+        q=jax.random.randint(KEY, (held, w, w), -127, 128, jnp.int8),
+        s=jnp.full((held, w), 0.01, jnp.float32))
+    assert (ragged_dot_path(bank)[0] == "int8_kernel") == (path == "kernel")
+    topi, live = routing(case, CFG.num_experts, held, k)
+    n = len(topi)
+    _, counts = H.held_sum(
+        CFG, jax.random.normal(KEY, (n, w)), jnp.ones((n, k)),
+        jnp.asarray(topi), jnp.asarray(live), lambda xs, dot: dot(xs, bank))
+    counts = dict(zip(H.COUNTS, np.asarray(counts).tolist()))
+    mine = topi < held
+    assert counts["rows_held"] == (mine & live[:, None]).sum()
+    assert counts["rows_left_out"] == live.sum() * k - counts["rows_held"]
+    assert counts["banks_routed"] == len(np.unique(topi[mine]))
+    assert counts["banks_held"] == held
+    assert counts["banks_fetched"] == (
+        banks_visited(topi[mine], held, n * k, w) if path == "kernel"
+        else held)
+    assert counts["banks_routed"] <= counts["banks_fetched"] <= held
 
 
 # ------------------------------------------------------------- the engine
@@ -398,6 +464,24 @@ def test_what_rests_on_exportable_pages_declines_by_name():
         build_runner(config, resolve_serving_plan(config, 1), CFG, r.params)
 
 
+# the expert layers' bank counters, whole and of the ragged flights
+BANKS = ("crowdllama_moe_banks_total", "crowdllama_moe_banks_fetched_total",
+         'crowdllama_moe_banks_total{dispatch="ragged",state="routed"}',
+         'crowdllama_moe_banks_total{dispatch="ragged",state="unrouted"}',
+         'crowdllama_moe_banks_fetched_total{dispatch="ragged"}')
+
+
+def check_banks(grew: dict, banks_a_step: int) -> None:
+    """What a served engine's scrape says of the banks: every step of
+    every flight counts every held bank of every expert layer once, as
+    routed or as unrouted; on the CPU ``lax.ragged_dot`` reads them all; a
+    prompt admitted in chunks flew ragged flights, counted as such."""
+    total, fetched, routed, unrouted, ragged_fetched = (grew[n] for n in BANKS)
+    assert total == fetched == banks_a_step * grew[
+        "crowdllama_engine_flight_steps_total"]
+    assert 0 < routed and routed + unrouted == ragged_fetched < total
+
+
 async def test_served_through_the_engine_with_its_counters():
     """The normal path: JaxEngine -> scheduler -> the hybrid runner, ragged
     admission and megastep on; every admission a prefix miss; the expert
@@ -422,7 +506,8 @@ async def test_served_through_the_engine_with_its_counters():
         before = {n: series(n) for n in (
             "crowdllama_moe_assignments_total",
             "crowdllama_prompt_tokens_total",
-            "crowdllama_prefix_tokens_reused_total")}
+            "crowdllama_prefix_tokens_reused_total",
+            *BANKS, "crowdllama_engine_flight_steps_total")}
         prompt = "one two three four five six seven eight nine ten " * 2
         n = len(engine.tokenizer.encode(prompt))
         assert n > 2 * engine._runner.ragged_chunk     # admitted in chunks
@@ -439,6 +524,7 @@ async def test_served_through_the_engine_with_its_counters():
         assert 2 * n * k <= rows <= 2 * (n + 16) * k
         held = series('crowdllama_moe_assignments_total{held="yes"}')
         assert 0.3 < held / series("crowdllama_moe_assignments_total") < 0.7
+        check_banks(grew, CFG.layers_of("E") * H.sizes(CFG)["held"])
         st = engine.scheduler.state
         assert series('crowdllama_engine_state_bytes{kind="ssm"}'
                       ) == st.ssm.nbytes

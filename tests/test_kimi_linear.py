@@ -430,7 +430,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
     with jax.default_matmul_precision("highest"):
         uncut = R.mixer("S", h, lp, R.hyper(hf_of(whole)))
         shared = R.swiglu(h, lp["ws_gu"], lp["ws_down"])
-        total, rows = 0.0, np.zeros(2, np.int64)
+        total, rows = 0.0, np.zeros(len(H.COUNTS), np.int64)
         for rank in range(ranks):
             cfg = replace(CFG, experts_held=held, expert_rank=rank)
             mine = {**lp, **{b: lp[b][held * rank:held * (rank + 1)]
@@ -445,6 +445,45 @@ def test_the_shares_add_up_to_the_uncut_layer():
     # every token-expert row is computed by exactly one rank
     assert rows[0] == 24 * CFG.num_experts_per_tok
     assert rows[1] == (ranks - 1) * rows[0]
+    # and of the 16 banks the ranks hold, none routed to twice
+    assert 0 < rows[2] <= rows[3] == rows[4] == CFG.num_experts
+
+
+@pytest.mark.parametrize("path", ["ragged_dot", "kernel"])
+@pytest.mark.parametrize("case", ["live-only", "padded", "none-live"])
+def test_a_swiglu_expert_layer_counts_a_bank_once(case, path, monkeypatch):
+    """The layer's three grouped matmuls share their groups: a bank routed
+    to is one bank, and a bank fetched is one bank — by the kernel's own
+    rule (``ops/pallas/moe.py`` ``_visits``) where it multiplies, every
+    held bank where ``lax.ragged_dot`` does; ``routed`` is a recount of the
+    router's choices over every row, live or padding."""
+    from crowdllama_tpu.ops.quant import quantize_weight, ragged_dot_path
+    from test_hybrid import banks_visited
+
+    if path == "kernel":
+        monkeypatch.setenv("CROWDLLAMA_PALLAS_INTERPRET", "1")
+    # whole lanes, which the kernel asks for; 3 rows x 4 choices over 16
+    # experts leave most of the 8 held banks without a row
+    cfg = replace(CFG, hidden_size=128, moe_intermediate_size=128)
+    lp = T.init_params(cfg, KEY, jnp.float32)["layers"]["smoe"][0]
+    banks = ("w_gate", "w_up", "w_down")
+    lp = {**lp, **{b: quantize_weight(lp[b], jnp.float32) for b in banks}}
+    assert all((ragged_dot_path(lp[b])[0] == "int8_kernel")
+               == (path == "kernel") for b in banks)
+    n, k, held = 3, cfg.num_experts_per_tok, H.sizes(cfg)["held"]
+    live = {"live-only": [True] * 3, "padded": [True, False, False],
+            "none-live": [False] * 3}[case]
+    x = jax.random.normal(jax.random.PRNGKey(5), (n, cfg.hidden_size))
+    _, counts = H.smoe_body(lp, cfg, x, jnp.asarray(live))
+    counts = dict(zip(H.COUNTS, np.asarray(counts).tolist()))
+    topi = np.asarray(H.route(lp, cfg, H._normed(lp, cfg, x))[1])
+    mine = topi < held
+    assert counts["rows_held"] == (mine & np.asarray(live)[:, None]).sum()
+    assert counts["banks_routed"] == len(np.unique(topi[mine])) < held
+    assert counts["banks_held"] == held
+    assert counts["banks_fetched"] == (
+        banks_visited(topi[mine], held, n * k, 128) if path == "kernel"
+        else held)
 
 
 # ------------------------------------------------------------- the engine
@@ -549,6 +588,7 @@ async def test_served_through_the_engine_with_its_gauges_and_counters():
     from crowdllama_tpu.config import Configuration, Intervals
     from crowdllama_tpu.engine.engine import JaxEngine
     from crowdllama_tpu.obs.metrics import ENGINE_TELEMETRY
+    from test_hybrid import BANKS, check_banks
 
     def series(name: str) -> float:
         return sum(float(ln.rsplit(" ", 1)[1])
@@ -562,7 +602,7 @@ async def test_served_through_the_engine_with_its_gauges_and_counters():
     await engine.start()
     try:
         assert isinstance(engine._runner, HybridPagedModelRunner)
-        names = ("crowdllama_moe_assignments_total",
+        names = (*BANKS, "crowdllama_moe_assignments_total",
                  "crowdllama_prompt_tokens_total",
                  "crowdllama_prefix_tokens_reused_total",
                  "crowdllama_admissions_total",
@@ -588,6 +628,7 @@ async def test_served_through_the_engine_with_its_gauges_and_counters():
         assert tokens * k <= rows <= (tokens + 3 * 16) * k
         held = series('crowdllama_moe_assignments_total{held="yes"}')
         assert 0.3 < held / series("crowdllama_moe_assignments_total") < 0.7
+        check_banks(grew, CFG.layers_of("S") * H.sizes(CFG)["held"])
         st = engine.scheduler.state
         assert series('crowdllama_kda_update_path{path="xla"}') == 1
         assert series('crowdllama_ssm_update_path{path="none"}') == 0

@@ -275,7 +275,10 @@ async def test_gateway_and_worker_metrics_lint():
             for fam in ("crowdllama_xla_compiles_total",
                         "crowdllama_padding_waste_tokens_total",
                         "crowdllama_useful_tokens_total",
-                        "crowdllama_engine_flights_total"):
+                        "crowdllama_engine_flights_total",
+                        "crowdllama_moe_assignments_total",
+                        "crowdllama_moe_banks_total",
+                        "crowdllama_moe_banks_fetched_total"):
                 assert types.get(fam) == "counter", f"{fam} missing"
             for fam in ("crowdllama_device_memory_bytes_in_use",
                         "crowdllama_device_memory_bytes_limit"):
@@ -368,6 +371,71 @@ def test_flight_length_counter_lint():
     _lint("\n".join(lines))
     assert 'crowdllama_engine_flights_total{length="short"} 1' in lines
     assert 'crowdllama_engine_flights_total{length="full"} 2' in lines
+
+
+def test_moe_bank_counters_lint():
+    """crowdllama_moe_banks_total{dispatch,state} and
+    crowdllama_moe_banks_fetched_total{dispatch}: every dispatch class
+    rendered at 0 before any flight; a flight's counts land under its own
+    class, a bank as routed or as unrouted; the assignment counter beside
+    them keeps its two series."""
+    from crowdllama_tpu.obs.metrics import FLIGHT_CLASSES, EngineTelemetry
+
+    tele = EngineTelemetry()
+    lines = tele.expose()
+    types = _lint("\n".join(lines))
+    assert types["crowdllama_moe_banks_total"] == "counter"
+    assert types["crowdllama_moe_banks_fetched_total"] == "counter"
+    for cls in FLIGHT_CLASSES:
+        for state in ("routed", "unrouted"):
+            assert (f'crowdllama_moe_banks_total{{dispatch="{cls}",'
+                    f'state="{state}"}} 0') in lines
+        assert (f'crowdllama_moe_banks_fetched_total{{dispatch="{cls}"}} 0'
+                in lines)
+    # [rows held, rows left out, banks routed, banks fetched, banks held]
+    tele.moe_counts_inc("plain", [5, 3, 4, 8, 8])
+    tele.moe_counts_inc("plain", [6, 2, 3, 8, 8])
+    tele.moe_counts_inc("ragged", [50, 30, 8, 8, 8])
+    lines = tele.expose()
+    _lint("\n".join(lines))
+    for line in ('crowdllama_moe_banks_total{dispatch="plain",state="routed"} 7',
+                 'crowdllama_moe_banks_total{dispatch="plain",'
+                 'state="unrouted"} 9',
+                 'crowdllama_moe_banks_fetched_total{dispatch="plain"} 16',
+                 'crowdllama_moe_banks_total{dispatch="ragged",'
+                 'state="routed"} 8',
+                 'crowdllama_moe_banks_total{dispatch="ragged",'
+                 'state="unrouted"} 0',
+                 'crowdllama_moe_banks_fetched_total{dispatch="ragged"} 8',
+                 'crowdllama_moe_banks_fetched_total{dispatch="megastep"} 0',
+                 'crowdllama_moe_assignments_total{held="yes"} 61',
+                 'crowdllama_moe_assignments_total{held="no"} 35'):
+        assert line in lines, line
+
+
+def test_startup_phases_lint():
+    """crowdllama_startup_seconds{phase}: every phase rendered from boot;
+    ``process`` counts from the operating system's record of the process's
+    start, which lies before the import that ``ready`` counts from."""
+    import time
+
+    from crowdllama_tpu.obs.metrics import (
+        ENGINE_TELEMETRY, STARTUP_PHASES, EngineTelemetry,
+        process_age_seconds)
+
+    assert STARTUP_PHASES == ("weights", "warmup", "ready", "process")
+    tele = EngineTelemetry()
+    lines = tele.expose()
+    assert _lint("\n".join(lines))["crowdllama_startup_seconds"] == "gauge"
+    for phase in STARTUP_PHASES:
+        assert f'crowdllama_startup_seconds{{phase="{phase}"}} 0.000' in lines
+    age = process_age_seconds()
+    since_import = time.monotonic() - ENGINE_TELEMETRY.t_import
+    # the kernel's clock ticks a hundred times a second
+    assert age is not None and since_import - 0.02 <= age < 86400
+    tele.startup_set("process", age)
+    assert (f'crowdllama_startup_seconds{{phase="process"}} {age:.3f}'
+            in tele.expose())
 
 
 def test_spec_gauges_lint():
