@@ -627,6 +627,8 @@ class JaxEngine(Engine):
             return runner
 
         self._runner = await loop.run_in_executor(None, _build)
+        ENGINE_TELEMETRY.weight_layouts_set(
+            getattr(self._runner, "weight_layouts", {}))
         ENGINE_TELEMETRY.moe_matmul_path_set(
             getattr(self._runner, "moe_matmul_path", ""))
         ENGINE_TELEMETRY.ssm_update_path_set(

@@ -56,6 +56,7 @@ from crowdllama_tpu.parallel.pipeline import (
 from crowdllama_tpu.parallel.sharding import (
     cache_pspec,
     filter_spec,
+    placed_layouts,
     shard_params,
 )
 
@@ -190,6 +191,10 @@ class ModelRunner:
         if params is None:
             params = T.init_params(cfg, jax.random.PRNGKey(seed), dtype=dtype)
         self.params = shard_params(params, cfg, mesh)
+        # crowdllama_weight_layout: how the attention projections lie
+        self.weight_layouts = placed_layouts(self.params)
+        log.info("weight layouts: %s", " ".join(
+            f"{k}={v}" for k, v in sorted(self.weight_layouts.items())))
 
         self._replicated = NamedSharding(mesh, P())
         self._cache_sharding = NamedSharding(mesh, cache_pspec(mesh))

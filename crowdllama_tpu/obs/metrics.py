@@ -473,6 +473,11 @@ class EngineTelemetry:
         # pools), "" where the model has neither.
         self._kda_update_path = ""
         self._attn_decode_path = ""
+        # leaf ("wq", "wk") -> "input_minor" | "default": how the newest
+        # engine's attention projections lie on the device, read from the
+        # placed arrays (parallel/sharding.py placed_layouts; set at
+        # JaxEngine.start).
+        self._weight_layouts: dict[str, str] = {}
         # Unified ragged batch (docs/RAGGED_BATCH.md): wall time per
         # prefill chunk carried inside a decode dispatch.  Engine-plane
         # like the compile histogram (the scheduler's dispatch loop
@@ -529,6 +534,10 @@ class EngineTelemetry:
     def attention_paths_set(self, paths: dict[str, str]) -> None:
         with self._lock:
             self._attention_paths = dict(paths)
+
+    def weight_layouts_set(self, layouts: dict[str, str]) -> None:
+        with self._lock:
+            self._weight_layouts = dict(layouts)
 
     def moe_matmul_path_set(self, path: str) -> None:
         with self._lock:
@@ -629,6 +638,7 @@ class EngineTelemetry:
             ssm_path = self._ssm_update_path
             kda_path = self._kda_update_path
             attn_decode = self._attn_decode_path
+            weight_layouts = sorted(self._weight_layouts.items())
             prefix = dict(self._prefix)
             flight_seconds = dict(self._flight_seconds)
             flight_steps = dict(self._flight_steps)
@@ -657,6 +667,12 @@ class EngineTelemetry:
         out.append("# TYPE crowdllama_attn_decode_path gauge")
         out.append(f'crowdllama_attn_decode_path{{path="'
                    f'{attn_decode or "none"}"}} {1 if attn_decode else 0}')
+        out.append("# TYPE crowdllama_weight_layout gauge")
+        if not weight_layouts:
+            out.append('crowdllama_weight_layout{leaf="none",layout="none"} 0')
+        for leaf, layout in weight_layouts:
+            out.append(f'crowdllama_weight_layout{{leaf="{leaf}",'
+                       f'layout="{layout}"}} 1')
         out.append("# TYPE crowdllama_xla_compiles_total counter")
         if not compiles:
             out.append('crowdllama_xla_compiles_total{program="none",'

@@ -7,12 +7,17 @@ still sees bf16 inputs.  Where the dequantize (convert + broadcast
 multiply) happens depends on the consumer.  A dense ``qeinsum`` is an XLA
 dot, and XLA fuses the dequantize into its operand read: Mistral-7B's
 three MLP matmuls stream their int8 weights at 730 GB/s, 89% of a v5e's
-peak (PERF.md §5).  ``lax.ragged_dot`` is a custom call that nothing
-fuses into: an expert bank dequantized for it was written whole to HBM as
-bf16 and read again, five times the bytes, 87% of a Mixtral decode step
-(PERF_LEDGER.jsonl, PR 27).  So an int8 bank goes to a grouped matmul
-that converts in VMEM (ops/pallas/moe.py); :func:`qragged_dot` says which
-input takes which path.
+peak (PERF.md §5).  It reads the operand where it lies only if it lies as
+the dot wants it: of a stacked layer's seven dense matrices XLA's TPU
+layout assignment wants ``wq`` and ``wk`` with the INPUT dimension minor
+and copied them first when they lay row-major, so on one TPU device
+``shard_params`` places those two payloads that way (parallel/sharding.py
+``weight_layout``; PERF.md §6, PR 41).  ``lax.ragged_dot`` is a custom
+call that nothing fuses into: an expert bank dequantized for it was
+written whole to HBM as bf16 and read again, five times the bytes, 87% of
+a Mixtral decode step (PERF_LEDGER.jsonl, PR 27).  So an int8 bank goes to
+a grouped matmul that converts in VMEM (ops/pallas/moe.py);
+:func:`qragged_dot` says which input takes which path.
 
 Int8×int8 MXU matmuls (dynamic activation quantization) were measured
 SLOWER at serving batch sizes (B=8: 6.5 ms/step) — the per-step activation

@@ -526,6 +526,12 @@ async def test_served_through_the_engine_with_its_counters():
         assert 0.3 < held / series("crowdllama_moe_assignments_total") < 0.7
         check_banks(grew, CFG.layers_of("E") * H.sizes(CFG)["held"])
         st = engine.scheduler.state
+        # a list of rank-2 layers takes no layout (its attention layer has
+        # a wq and a wk), and the CPU none either
+        for leaf in ("wq", "wk"):
+            assert series(f'crowdllama_weight_layout{{leaf="{leaf}",'
+                          'layout="default"}') == 1
+        assert series("crowdllama_weight_layout") == 2
         assert series('crowdllama_engine_state_bytes{kind="ssm"}'
                       ) == st.ssm.nbytes
         assert series('crowdllama_engine_state_bytes{kind="kv_pool"}'

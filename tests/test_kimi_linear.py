@@ -633,6 +633,8 @@ async def test_served_through_the_engine_with_its_gauges_and_counters():
         assert series('crowdllama_kda_update_path{path="xla"}') == 1
         assert series('crowdllama_ssm_update_path{path="none"}') == 0
         assert series('crowdllama_attn_decode_path{path="mla"}') == 1
+        assert series('crowdllama_weight_layout{leaf="wq",layout="default"}'
+                      ) == 1  # it has no leaf of that name at all
         assert series('crowdllama_recurrent_state_bytes{kind="kda"}'
                       ) == st.kda.nbytes
         assert series('crowdllama_recurrent_state_bytes{kind="conv"}'

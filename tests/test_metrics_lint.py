@@ -413,6 +413,26 @@ def test_moe_bank_counters_lint():
         assert line in lines, line
 
 
+def test_weight_layout_gauge_lint():
+    """crowdllama_weight_layout{leaf,layout}: one gauge family, an info
+    series a leaf (value 1) with the layout the placed array reported —
+    both label values — and the ``none`` row at 0 before any engine."""
+    from crowdllama_tpu.obs.metrics import EngineTelemetry
+
+    tele = EngineTelemetry()
+    lines = tele.expose()
+    assert _lint("\n".join(lines))["crowdllama_weight_layout"] == "gauge"
+    assert 'crowdllama_weight_layout{leaf="none",layout="none"} 0' in lines
+    tele.weight_layouts_set({"wq": "input_minor", "wk": "default"})
+    lines = tele.expose()
+    _lint("\n".join(lines))
+    for series in ('crowdllama_weight_layout{leaf="wq",layout="input_minor"}',
+                   'crowdllama_weight_layout{leaf="wk",layout="default"}'):
+        assert f"{series} 1" in lines
+    assert sum(ln.startswith("crowdllama_weight_layout{")
+               for ln in lines) == 2
+
+
 def test_startup_phases_lint():
     """crowdllama_startup_seconds{phase}: every phase rendered from boot;
     ``process`` counts from the operating system's record of the process's

@@ -287,3 +287,119 @@ def test_load_params_for_random_init_is_seeded_and_never_builds_bf16_tree(
     for x, y in zip(la, lb):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
     assert any(x.dtype == np.int8 for x in la)
+
+
+# ------------- where the int8 attention projections lie (parallel/sharding.py)
+
+
+def _leaf(kind: str, *shape):
+    """A parameter leaf from shapes alone: ``int8`` / ``int4`` / ``bf16``."""
+    from crowdllama_tpu.ops.quant import QTensor4
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    if kind == "bf16":
+        return sds(shape, jnp.bfloat16)
+    scale = sds(shape[:-2] + shape[-1:], jnp.bfloat16)
+    if kind == "int4":
+        return QTensor4(q=sds(shape[:-1] + (shape[-1] // 2,), jnp.int8),
+                        s=scale)
+    return QTensor(q=sds(shape, jnp.int8), s=scale)
+
+
+@pytest.mark.parametrize("name,leaf,devices,order", [
+    # the stacked int8 projections every step program reads input-minor
+    ("wq", ("int8", 32, 4096, 4096), ["tpu"], (0, 2, 1)),
+    ("wk", ("int8", 32, 4096, 1024), ["tpu"], (0, 2, 1)),
+    # read where they lie
+    ("wv", ("int8", 32, 4096, 1024), ["tpu"], None),
+    ("wo", ("int8", 32, 4096, 4096), ["tpu"], None),
+    ("w_gate", ("int8", 32, 4096, 14336), ["tpu"], None),
+    ("w_up", ("int8", 32, 4096, 14336), ["tpu"], None),
+    ("w_down", ("int8", 32, 14336, 4096), ["tpu"], None),
+    ("w_gate", ("int8", 4, 8, 4096, 14336), ["tpu"], None),  # a kernel's bank
+    ("wq", ("int8", 4096, 4096), ["tpu"], None),  # a list-of-layers model's
+    ("wk", ("int8", 4096, 1024), ["tpu"], None),
+    ("wq", ("bf16", 32, 4096, 4096), ["tpu"], None),
+    ("wq", ("int4", 32, 4096, 4096), ["tpu"], None),
+    # the CPU keeps default layouts; a mesh of several devices today's
+    ("wq", ("int8", 32, 4096, 4096), ["cpu"], None),
+    ("wq", ("int8", 32, 4096, 4096), ["tpu"] * 4, None),
+    ("wk", ("int8", 32, 4096, 1024), ["tpu"] * 4, None),
+])
+def test_weight_layout_table(name, leaf, devices, order):
+    from types import SimpleNamespace
+
+    from crowdllama_tpu.parallel.sharding import weight_layout
+
+    devices = [SimpleNamespace(platform=p) for p in devices]
+    assert weight_layout(name, _leaf(*leaf), devices) == order
+
+
+def test_shard_params_on_the_cpu_places_what_it_always_placed():
+    """The CPU backend keeps default layouts: every placed leaf is the
+    given one bit for bit, row-major, and the gauge says ``default``."""
+    from crowdllama_tpu.parallel.mesh import build_mesh
+    from crowdllama_tpu.parallel.sharding import placed_layouts, shard_params
+
+    cfg = get_config("tiny-test", max_context_length=32)
+    qparams = quantize_params(
+        T.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.bfloat16))
+    placed = shard_params(qparams, cfg, build_mesh("1x1"))
+    given, got = (jax.tree_util.tree_leaves(t) for t in (qparams, placed))
+    assert len(given) == len(got)
+    for a, b in zip(given, got):
+        assert a.dtype == b.dtype and np.array_equal(a, b)
+        assert b.format.layout.major_to_minor == tuple(range(b.ndim))
+    assert placed_layouts(placed) == {"wq": "default", "wk": "default"}
+
+
+@pytest.mark.parametrize("mesh_spec,layout", [("1x1", "input_minor"),
+                                              ("1x1x1x1x2", "default")])
+def test_shard_params_places_the_projections_as_the_rule_says(
+        monkeypatch, caplog, mesh_spec, layout):
+    """The mechanism end to end, with the rule told the CPU's devices are a
+    TPU's (the CPU backend honours a layout too): on one device ``wq.q`` and
+    ``wk.q`` lie input-minor — the same values, the same logits from a
+    program compiled for the committed layout — and nothing else moved; a
+    mesh of several devices keeps the default and one log line says so.
+
+    Placed TWICE, the second time with nothing compiled in this process:
+    a relayout's executable loaded back from the persistent cache hands out
+    a buffer labelled row-major that holds the other order (jax 0.9.0, the
+    chip and the CPU alike), so ``shard_params`` compiles it outside the
+    cache (utils/jaxcache.py ``compile_cache_bypassed``)."""
+    import logging
+    from types import SimpleNamespace
+
+    from crowdllama_tpu.parallel import sharding
+    from crowdllama_tpu.parallel.mesh import build_mesh
+
+    rule = sharding.weight_layout
+    monkeypatch.setattr(
+        sharding, "weight_layout", lambda name, leaf, devices: rule(
+            name, leaf, [SimpleNamespace(platform="tpu")] * len(devices)))
+    cfg = get_config("tiny-test", max_context_length=32)
+    qparams = quantize_params(
+        T.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.bfloat16))
+    sharding.shard_params(qparams, cfg, build_mesh(mesh_spec))
+    jax.clear_caches()
+    with caplog.at_level(logging.INFO, logger=sharding.__name__):
+        placed = sharding.shard_params(qparams, cfg, build_mesh(mesh_spec))
+    said = [r for r in caplog.records
+            if "keep the default layout" in r.message]
+    assert len(said) == (layout == "default")
+    assert sharding.placed_layouts(placed) == {"wq": layout, "wk": layout}
+    minor = {"input_minor": (0, 2, 1), "default": (0, 1, 2)}[layout]
+    for name, leaf in placed["layers"].items():
+        if isinstance(leaf, QTensor):
+            assert leaf.q.format.layout.major_to_minor == (
+                minor if name in ("wq", "wk") else (0, 1, 2)), name
+            assert np.array_equal(leaf.q, qparams["layers"][name].q)
+    if layout == "input_minor":
+        tokens, pos = jnp.asarray([[1, 2, 3]]), jnp.arange(3)[None, :]
+        fwd = jax.jit(lambda p: T.prefill(p, cfg, tokens, pos)[0])
+        np.testing.assert_allclose(
+            np.asarray(fwd(placed), np.float32),
+            np.asarray(fwd(qparams), np.float32), rtol=0.02, atol=0.02)

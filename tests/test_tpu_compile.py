@@ -13,10 +13,13 @@ module-scoped fixture: only one process at a time may load the TPU's
 library, so nothing here touches it at import or collection time.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.layout import Format, Layout
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
@@ -94,6 +97,37 @@ def state_kernel(monkeypatch):
 
 def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _placed(shapes, one_chip, rule=True):
+    """The parameters' shapes on the described chip as ``shard_params``
+    places the arrays: each int8 payload in the order the SAME rule gives
+    it (parallel/sharding.py ``weight_layout``, asked about the described
+    device), so these tests compile what a worker on the chip serves.
+    ``rule=False``: every leaf in the default layout, as before PR 41."""
+    from crowdllama_tpu.ops.quant import QTensor
+    from crowdllama_tpu.parallel.sharding import weight_layout
+
+    devices = list(one_chip.device_set)
+
+    def place(path, a):
+        if not isinstance(a, QTensor):
+            return _sds(a.shape, a.dtype, one_chip)
+        order = rule and weight_layout(getattr(path[-1], "key", ""), a,
+                                       devices)
+        where = Format(Layout(major_to_minor=order),
+                       one_chip) if order else one_chip
+        return QTensor(q=_sds(a.q.shape, a.q.dtype, where),
+                       s=_sds(a.s.shape, a.s.dtype, one_chip),
+                       mesh_devices=a.mesh_devices)
+
+    return jax.tree_util.tree_map_with_path(
+        place, shapes, is_leaf=lambda x: isinstance(x, QTensor))
+
+
+def _on_chip(tree, one_chip):
+    return jax.tree_util.tree_map(
+        lambda a: _sds(a.shape, a.dtype, one_chip), tree)
 
 
 def _pool(kv, sharding, scale_sharding=None):
@@ -223,10 +257,11 @@ def test_moe_grouped_matmul_compiles(one_chip, shape):
 
 @pytest.fixture
 def mistral_runner(one_chip, monkeypatch):
-    """``(kv) -> (runner, params, state, page table)``: a PagedModelRunner
-    at Mistral-7B widths with LAYERS layers of int8 weights, as the chip
-    benchmark serves it, built from shapes alone — nothing is allocated —
-    and the shapes of its arguments on the described chip."""
+    """``(kv, layers=LAYERS) -> (runner, params, state, page table)``: a
+    PagedModelRunner at Mistral-7B widths with ``layers`` layers of int8
+    weights, as the chip benchmark serves it, built from shapes alone —
+    nothing is allocated — and the shapes of its arguments on the described
+    chip, the parameters' laid out as ``shard_params`` places them."""
     from crowdllama_tpu.engine import runner as runner_mod
     from crowdllama_tpu.engine.paged import PagedModelRunner
     from crowdllama_tpu.models.config import get_config
@@ -236,8 +271,8 @@ def mistral_runner(one_chip, monkeypatch):
     # this backend, gate its kernels off: steer both here, in the test.
     monkeypatch.setattr(runner_mod, "shard_params", lambda p, cfg, mesh: p)
 
-    def build(kv):
-        cfg = get_config("mistral-7b", num_layers=LAYERS,
+    def build(kv, layers=LAYERS):
+        cfg = get_config("mistral-7b", num_layers=layers,
                          max_context_length=PREFILL_T)
         shapes = jax.eval_shape(lambda: random_quantized_params(
             cfg, jax.random.PRNGKey(0), dtype=jnp.bfloat16))
@@ -247,12 +282,9 @@ def mistral_runner(one_chip, monkeypatch):
         r.attention_paths = {**r.attention_paths, "decode": "pallas",
                              "ragged_step": "pallas"}
 
-        def on_chip(tree):
-            return jax.tree_util.tree_map(
-                lambda a: _sds(a.shape, a.dtype, one_chip), tree)
-
         table = _sds((SLOTS, PAGES_PER_SLOT), jnp.int32, one_chip)
-        return r, on_chip(shapes), on_chip(jax.eval_shape(r.init_state)), table
+        return (r, _placed(shapes, one_chip),
+                _on_chip(jax.eval_shape(r.init_state), one_chip), table)
 
     return build
 
@@ -308,9 +340,11 @@ def test_ragged_step_program_keeps_the_pool_in_place(mistral_runner, one_chip,
     _assert_pool_stays_in_place(compiled, kv)
 
 
-def _lower_program(r, params, state, table, one_chip, program: str) -> str:
-    """The lowered text of one of a paged runner's programs (1-step decode,
-    1-step ragged step, the first prefill bucket), traced anew."""
+def _lowered(r, params, state, table, one_chip, program: str):
+    """One of a paged runner's programs, traced anew and lowered for the
+    described chip: ``decode`` (one step) or ``decode_<steps>``,
+    ``ragged_step`` (one step), ``prefill`` (the first bucket) or
+    ``prefill_<bucket>``."""
     from crowdllama_tpu.engine.runner import REPEAT_LAST_N
 
     def i32(*shape):
@@ -319,22 +353,26 @@ def _lower_program(r, params, state, table, one_chip, program: str) -> str:
     def f32():
         return _sds((), jnp.float32, one_chip)
 
+    name, _, size = program.partition("_")
     jax.clear_caches()  # a bound method's trace is cached by equality
-    if program == "decode":
+    if name == "decode":
         return jax.jit(
             r._decode_paged_impl, donate_argnums=(1,), static_argnums=(3,)
-        ).lower(params, state, table, 1).as_text()
+        ).lower(params, state, table, int(size or 1))
     if program == "ragged_step":
         return jax.jit(
             r._ragged_step_impl, donate_argnums=(1,), static_argnums=(7,)
         ).lower(params, state, table, i32(1, r.ragged_chunk), i32(1), i32(),
-                i32(), 1).as_text()
-    assert program == "prefill", program
+                i32(), 1)
+    assert name == "prefill", program
     key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
     return jax.jit(r._prefill_impl).lower(
-        params, i32(1, r.buckets[0]), i32(), f32(), f32(), i32(), f32(),
-        i32(REPEAT_LAST_N), _sds(key.shape, key.dtype, one_chip)
-    ).as_text()
+        params, i32(1, int(size or r.buckets[0])), i32(), f32(), f32(),
+        i32(), f32(), i32(REPEAT_LAST_N), _sds(key.shape, key.dtype, one_chip))
+
+
+def _lower_program(r, params, state, table, one_chip, program: str) -> str:
+    return _lowered(r, params, state, table, one_chip, program).as_text()
 
 
 @pytest.mark.parametrize("program", ["decode", "ragged_step", "prefill"])
@@ -478,12 +516,9 @@ def mixtral_runner(one_chip, monkeypatch, expert_kernel):
         r.attention_paths = {**r.attention_paths, "decode": "pallas",
                              "ragged_step": "pallas"}
 
-        def on_chip(tree):
-            return jax.tree_util.tree_map(
-                lambda a: _sds(a.shape, a.dtype, one_chip), tree)
-
         table = _sds((MOE_SLOTS, PAGES_PER_SLOT), jnp.int32, one_chip)
-        return r, on_chip(shapes), on_chip(jax.eval_shape(r.init_state)), table
+        return (r, _placed(shapes, one_chip),
+                _on_chip(jax.eval_shape(r.init_state), one_chip), table)
 
     return build
 
@@ -537,6 +572,85 @@ def test_moe_ragged_step_program_reads_the_int8_banks_in_place(
     _assert_banks_are_read_in_place(compiled, state)
 
 
+# ---------- where the attention projections lie (PR 41, ``weight_layout``)
+
+_PROJECTION_WRITTEN = re.compile(
+    r" = s8\[(\d+,)?4096,(4096|1024)\]\S* "
+    r"(copy|copy-done|slice-done|fusion)\(")
+
+
+def _projections_written(text: str) -> list[str]:
+    """The lines of a compiled program that WRITE an int8 attention
+    projection — a layer's ``wq``/``wo`` (4096 x 4096) or ``wk``/``wv``
+    (4096 x 1024), or a whole stack of them: a ``copy``, an async
+    ``slice-done`` / ``copy-done``, or a fusion with such a result (XLA's
+    ``constant_dynamic-slice_fusion`` of a layer).  A dot that reads its
+    weight where it lies has the product as its result and is not here."""
+    return [ln.strip()[:120] for ln in text.splitlines()
+            if _PROJECTION_WRITTEN.search(ln)]
+
+
+def _order(leaf) -> tuple[int, ...] | None:
+    layout = leaf.q.format.layout
+    return None if layout is None else layout.major_to_minor
+
+
+@pytest.mark.parametrize("program", ["decode_1", "decode_8", "ragged_step"])
+@pytest.mark.parametrize("model", ["mistral", "mixtral"])
+def test_step_programs_read_the_attention_projections_where_they_lie(
+        request, one_chip, model, program):
+    """XLA wants the int8 ``wq`` and ``wk`` of the layer loop with the
+    input dimension minor.  Placed row-major they were copied before every
+    read — ``constant_dynamic-slice_fusion.4`` + ``copy.273`` (``wq``) and
+    ``.5`` + ``copy.276`` (``wk``) a layer in the one-step and the ragged
+    program, ``copy.231`` / ``copy.230`` of the whole stacks a dispatch in
+    the 8-step one (Mixtral: ``copy.770``, ``copy.773``; four
+    ``slice-done``), 17% of a one-step Mistral decode on the chip (PERF.md
+    §6, PR 41).  Placed as ``weight_layout`` says, no program writes a
+    projection at all; ``wv`` and ``wo`` are read where they always lay."""
+    build = request.getfixturevalue(f"{model}_runner")
+    r, params, state, table = build(*(["bf16"] if model == "mistral" else []))
+    layers = params["layers"]
+    assert _order(layers["wq"]) == _order(layers["wk"]) == (0, 2, 1)
+    assert _order(layers["wv"]) is None and _order(layers["wo"]) is None
+    compiled = _lowered(r, params, state, table, one_chip, program).compile()
+    assert _projections_written(compiled.as_text()) == []
+
+
+def test_eight_step_program_holds_no_relaid_stack_at_full_depth(
+        mistral_runner, one_chip):
+    """All 32 layers of Mistral-7B: the 8-step program used to keep
+    ``copy.231 = s8[32,4096,4096]{1,2,0}`` and ``copy.230 =
+    s8[32,4096,1024]{1,2,0}`` as HBM temporaries for the length of a
+    flight, 672.8 MB; now its temporaries are under ONE layer's ``wq``."""
+    r, params, state, table = mistral_runner("bf16", layers=32)
+    compiled = _lowered(r, params, state, table, one_chip,
+                        "decode_8").compile()
+    assert _projections_written(compiled.as_text()) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 4096 * 4096
+
+
+@pytest.mark.parametrize("bucket", [32, 512])
+@pytest.mark.parametrize("model", ["mistral", "mixtral"])
+def test_prefill_programs_still_copy_wv_a_layer(request, one_chip, model,
+                                                bucket):
+    """What is knowingly left: a prefill program wants ``wv`` input-minor
+    too and copies it a layer (a slice fusion and a ``copy`` of
+    ``s8[1,4096,1024]``, 4.2 MB; three such pairs before PR 41).  An
+    input-minor ``wv`` would clear it and put a copy of the whole ``wv``
+    stack into every 8-step decode dispatch instead, twice the bytes where
+    four such flights fly for each prefill (PERF.md §6, PR 41)."""
+    build = request.getfixturevalue(f"{model}_runner")
+    r, params, state, table = build(*(["bf16"] if model == "mistral" else []))
+    compiled = _lowered(r, params, state, table, one_chip,
+                        f"prefill_{bucket}").compile()
+    written = _projections_written(compiled.as_text())
+    assert len(written) == 2, written
+    sliced, copied = sorted(written, key=lambda ln: " copy(" in ln)
+    assert " = s8[1,4096,1024]{2,1,0" in sliced and " fusion(" in sliced
+    assert " = s8[1,4096,1024]{1,2,0" in copied and " copy(" in copied
+
+
 # ------------- a model whose layers differ in kind, at the benchmark's cut
 
 
@@ -575,12 +689,9 @@ def nemotron_runner(one_chip, monkeypatch, tmp_path, expert_kernel,
         r.attention_paths = {**r.attention_paths, "decode": "pallas",
                              "ragged_step": "pallas"}
 
-        def on_chip(tree):
-            return jax.tree_util.tree_map(
-                lambda a: _sds(a.shape, a.dtype, one_chip), tree)
-
         table = _sds((slots, PAGES_PER_SLOT), jnp.int32, one_chip)
-        return r, on_chip(shapes), on_chip(jax.eval_shape(r.init_state)), table
+        return (r, _placed(shapes, one_chip),
+                _on_chip(jax.eval_shape(r.init_state), one_chip), table)
 
     return build
 
@@ -778,12 +889,9 @@ def kimi_runner(one_chip, monkeypatch, tmp_path, expert_kernel, kda_kernel):
         r.attention_paths = {**r.attention_paths, "decode": "pallas",
                              "ragged_step": "pallas"}
 
-        def on_chip(tree):
-            return jax.tree_util.tree_map(
-                lambda a: _sds(a.shape, a.dtype, one_chip), tree)
-
         table = _sds((slots, ctx // PAGE), jnp.int32, one_chip)
-        return r, on_chip(shapes), on_chip(jax.eval_shape(r.init_state)), table
+        return (r, _placed(shapes, one_chip),
+                _on_chip(jax.eval_shape(r.init_state), one_chip), table)
 
     return build
 
@@ -921,3 +1029,25 @@ def test_latent_attention_kernels_compile_at_the_cell_shape(one_chip):
         _sds((), i32, one_chip), table, _sds((slots + 1,), i32, one_chip),
         _sds((slots + 1,), i32, one_chip), _sds((), i32, one_chip)
     ).compile())
+
+
+@pytest.mark.parametrize("model", ["nemotron", "kimi"])
+def test_list_of_layers_models_are_placed_as_they_were(request, one_chip,
+                                                       model):
+    """``weight_layout`` matches a STACKED rank-3 int8 ``wq``/``wk``.  A
+    model whose parameters are a list of layers of rank-2 leaves
+    (models/hybrid.py) takes no layout at all — Nemotron's attention layer
+    has a ``wq`` and a ``wk``, Kimi has neither — so every shape is what it
+    was without the rule and the one-step decode program lowers to the same
+    text: the two hybrid cells are PR 41's controls."""
+    r, params, state, table = request.getfixturevalue(f"{model}_runner")()
+    plain = _placed(params, one_chip, rule=False)
+    assert jax.tree_util.tree_leaves(params) == jax.tree_util.tree_leaves(
+        plain)
+    assert all(leaf.format.layout is None
+               for leaf in jax.tree_util.tree_leaves(params))
+    # one call site: a kernel's serialized body carries its call stack
+    with_rule, without = (
+        _lower_program(r, p, state, table, one_chip, "decode")
+        for p in (params, plain))
+    assert with_rule == without
