@@ -730,13 +730,19 @@ class JaxEngine(Engine):
             # its TTFT.  The decode_chunk-step variant compiles on first
             # use (only dispatched while the batch is saturated, where one
             # compile amortizes immediately).
-            job = r.ragged_begin(list(range(1, r.ragged_chunk + 2)), 0,
-                                 state=state)
-            while not job.finished:
-                _, state = r.ragged_step(state, job, 1)
-            _, state = r.ragged_finish(state, job, 0.0, 1.0,
-                                       jax.random.PRNGKey(0))
-            state = r.release(state, 0)
+            # A runner whose unified programs take ONE page-table width
+            # (engine/hybrid.py: a model with window layers, whose every
+            # long prompt comes this way) is compiled for both flight
+            # lengths here: all its admissions will ever dispatch.
+            for k in sorted({1, self.config.decode_chunk}
+                            if r.ragged_width_fixed else {1}):
+                job = r.ragged_begin(list(range(1, r.ragged_chunk + 2)), 0,
+                                     state=state)
+                while not job.finished:
+                    _, state = r.ragged_step(state, job, k)
+                _, state = r.ragged_finish(state, job, 0.0, 1.0,
+                                           jax.random.PRNGKey(0))
+                state = r.release(state, 0)
         log.info("warmup compile done")
 
     async def drain(self, timeout: float = 30.0) -> bool:

@@ -143,7 +143,10 @@ def _write_kv(put, pk, pv, ksc, vsc, k, v):
 
 
 class PagesExhausted(ValueError):
-    """No free KV pages (overcommitted pool) — reject the request."""
+    """No free KV pages (overcommitted pool) — reject the request.  The
+    message names the pool: ``full`` is the one every layer's pages came
+    from before a model had window layers, and the only one a slot can find
+    empty (a window layer's pages are its slot's own ring: engine/hybrid.py)."""
 
 
 @dataclass
@@ -182,6 +185,12 @@ class PagedDecodeState:
     kda: jnp.ndarray | None = None
     conv: jnp.ndarray | None = None
     moe_rows: jnp.ndarray | None = None
+    # Models with window layers only (engine/hybrid.py): those layers' own
+    # pool [L_W, B * ring + 1, Hkv, page, Dh], a ring of pages a slot
+    # (ops/pallas/paged.py ``Ring``); pool_k / pool_v then cover the layers
+    # that attend to the whole context.
+    wpool_k: jnp.ndarray | None = None
+    wpool_v: jnp.ndarray | None = None
 
 
 jax.tree_util.register_dataclass(
@@ -189,7 +198,8 @@ jax.tree_util.register_dataclass(
     data_fields=["pool_k", "pool_v", "seq_lens", "tokens", "active",
                  "temperature", "top_p", "top_k", "repeat_penalty",
                  "recent", "keys", "k_scale", "v_scale", "hist",
-                 "draft_k", "draft_v", "ssm", "kda", "conv", "moe_rows"],
+                 "draft_k", "draft_v", "ssm", "kda", "conv", "moe_rows",
+                 "wpool_k", "wpool_v"],
     meta_fields=[],
 )
 
@@ -209,6 +219,9 @@ class PagedModelRunner(ModelRunner):
     #: runners that replay frames (parallel/replicated.py) opt out with an
     #: explicit False.
     supports_ragged = True
+    #: the unified programs' page table has one width, not
+    #: :meth:`_ragged_window`'s power of two that grows with the slots
+    ragged_width_fixed = False
 
     def __init__(self, cfg, *args, page_size: int = 128, pool_tokens: int = 0,
                  prefix_cache: bool = True, step_token_budget: int = 0,
@@ -309,6 +322,12 @@ class PagedModelRunner(ModelRunner):
                                        donate_argnums=(1,),
                                        static_argnums=(9,))
 
+    @property
+    def pool_layers(self) -> int:
+        """Layers whose pages come from ``pool_k`` / ``pool_v``: those that
+        keep KV, but for a model's window layers (engine/hybrid.py)."""
+        return self.kv_layers
+
     def _attention_refusals(self) -> dict[str, str]:
         from crowdllama_tpu.parallel.mesh import AXIS_TP
 
@@ -338,7 +357,7 @@ class PagedModelRunner(ModelRunner):
             self._evict_cached(n - len(self._free_pages))
         if len(self._free_pages) < n:
             raise PagesExhausted(
-                f"kv pool exhausted: need {n} pages, "
+                f"kv pool exhausted (the full pool): need {n} pages, "
                 f"{len(self._free_pages)} free (pool={self.total_pages})")
         pages = [self._free_pages.pop() for _ in range(n)]
         # A recycled page starts its next life uncounted.  Growth and
@@ -777,7 +796,6 @@ class PagedModelRunner(ModelRunner):
         dh = cfg.resolved_head_dim()
         hkv = cfg.num_kv_heads
         scale = T.attn_scale(cfg)
-        view_len = self.max_pages_per_slot * pg
         slot_idx = jnp.arange(b)
         quant = self.kv_dtype == "int8"
         # Fused kernel reads pages via the scalar-prefetched table; the jnp
@@ -801,13 +819,24 @@ class PagedModelRunner(ModelRunner):
                                  self.total_pages)  # [B]
             offset = positions % pg
 
-            def attend(pools, window, li):
+            def attend(pools, window, li, ring=None):
                 pk, pv, ksc, vsc = pools
                 after = {}
+                pages, table, klens = cur_page, page_table, lens
+                name = "paged_decode_attention"
+                if ring is not None:
+                    # a window layer: its own pool, a ring of it a slot,
+                    # read from the page its window starts in
+                    pages = jnp.where(
+                        st.active, ring.page_of(slot_idx, positions // pg),
+                        pk.shape[1] - 1)
+                    table, klens = ring.decode_view(lens, pg)
+                    window = ring.window
+                    name += "_window"
 
                 @jax.named_scope("kv_write")
                 def write(k, v):
-                    put = partial(_put_rows, layer=li, pages=cur_page,
+                    put = partial(_put_rows, layer=li, pages=pages,
                                   offsets=offset)
                     return _write_kv(put, pk, pv, ksc, vsc, k, v)
 
@@ -817,6 +846,7 @@ class PagedModelRunner(ModelRunner):
 
                 @jax.named_scope("attention")
                 def read(q, pk2, pv2, ks2, vs2):
+                    view_len = table.shape[1] * pg
                     if pv2 is None:     # latent rows (one device)
                         if use_kernel:
                             return paged_decode_attention_mla(
@@ -833,14 +863,14 @@ class PagedModelRunner(ModelRunner):
                                 sliding_window=window,
                                 k_scale=ks2, v_scale=vs2)
                         return flash_paged_decode_attention(
-                            q, pk2, pv2, li, page_table, lens, scale,
+                            q, pk2, pv2, li, table, klens, scale,
                             softcap=cfg.attn_logit_softcap,
                             sliding_window=window,
-                            k_scale=ks2, v_scale=vs2)
+                            k_scale=ks2, v_scale=vs2, name=name)
                     # Virtual-contiguous view of each slot's pages.
-                    kc = pk2[li, page_table].transpose(
+                    kc = pk2[li, table].transpose(
                         0, 2, 1, 3, 4).reshape(b, hkv, view_len, dh)
-                    vc = pv2[li, page_table].transpose(
+                    vc = pv2[li, table].transpose(
                         0, 2, 1, 3, 4).reshape(b, hkv, view_len, dh)
                     if quant:
                         ksg = ks2[li, page_table].transpose(
@@ -851,7 +881,7 @@ class PagedModelRunner(ModelRunner):
                             q, kc, ksg, vc, vsg, lens, scale,
                             softcap=cfg.attn_logit_softcap,
                             sliding_window=window)
-                    return decode_attention(q, kc, vc, lens, scale,
+                    return decode_attention(q, kc, vc, klens, scale,
                                             softcap=cfg.attn_logit_softcap,
                                             sliding_window=window)
 
@@ -932,20 +962,28 @@ class PagedModelRunner(ModelRunner):
                 lens_dec.astype(jnp.int32),
                 (ctx_i + valid).astype(jnp.int32)[None]])
 
-            def attend(pools, window, li):
+            def attend(pools, window, li, ring=None):
                 pk, pv, ksc, vsc = pools
                 after = {}
+                pages, row, dump = cur_page, chunk_pages, self.total_pages
+                if ring is not None:    # a window layer's pool (see above)
+                    dump = pk.shape[1] - 1
+                    pages = jnp.where(
+                        st.active,
+                        ring.page_of(slot_idx, positions_dec // pg), dump)
+                    row = ring.page_of(
+                        chunk_slot, jnp.arange(self.max_pages_per_slot))
 
                 @jax.named_scope("kv_write")
                 def write(k, v):
                     def put(pool, rows):
                         # [B + C, Hkv, ...]: decode rows, then the chunk's.
                         pool = _put_rows(pool, rows[:b], layer=li,
-                                         pages=cur_page, offsets=dec_offs)
+                                         pages=pages, offsets=dec_offs)
                         return _put_chunk(
                             pool, jnp.swapaxes(rows[b:], 0, 1), layer=li,
-                            page_row=chunk_pages, start=ctx_i,
-                            valid=valid, dump_page=self.total_pages)
+                            page_row=row, start=ctx_i,
+                            valid=valid, dump_page=dump)
 
                     return _write_kv(put, pk, pv, ksc, vsc, k, v)
 
@@ -968,7 +1006,7 @@ class PagedModelRunner(ModelRunner):
                         q_lens, kv_lens, chunk_slot, scale,
                         softcap=cfg.attn_logit_softcap,
                         sliding_window=window, k_scale=ks2, v_scale=vs2,
-                        use_pallas=use_pallas)
+                        use_pallas=use_pallas, ring=ring)
 
                 return attn_fn, after
 
@@ -1031,7 +1069,7 @@ class PagedModelRunner(ModelRunner):
         from crowdllama_tpu.parallel.mesh import AXIS_TP
         from crowdllama_tpu.parallel.sharding import filter_spec
 
-        l = self.kv_layers
+        l = self.pool_layers
         hkv, dh = self.cfg.num_kv_heads, self.cfg.resolved_head_dim()
         # +1: reserved dump page absorbing inactive slots' decode writes.
         shape = (l, self.total_pages + 1, hkv, self.page_size, dh)
@@ -1144,6 +1182,28 @@ class PagedModelRunner(ModelRunner):
         )
         ENGINE_TELEMETRY.compile_end("insert_paged", ks.shape[3], t_c)
         return out
+
+    @property
+    def _page_bytes(self) -> int:
+        """Bytes of one page of one layer, K and V (and their scales)."""
+        hkv, dh = self.cfg.num_kv_heads, self.cfg.resolved_head_dim()
+        if self.kv_dtype == "int8":
+            return 2 * hkv * self.page_size * (dh + 2)
+        twins = 1 if self.cfg.kv_lora_rank else 2
+        return (twins * hkv * self.page_size * dh
+                * jnp.dtype(self.dtype).itemsize)
+
+    def kv_gauges(self) -> dict[str, float]:
+        """What the pools hold and could hold, for the scheduler's gauges
+        (rendered as the engine's ``kv_pool_bytes{kind}`` and
+        ``kv_live_bytes{kind}``): ``full`` is this pool, whose pages a slot
+        keeps for its whole context; live = pages some slot or the prefix
+        index holds."""
+        page = self._page_bytes * self.pool_layers
+        return {
+            "kv_pool_bytes|kind=full": float(self.total_pages * page),
+            "kv_live_bytes|kind=full": float(
+                (self.total_pages - len(self._free_pages)) * page)}
 
     def release(self, state: PagedDecodeState, slot: int):
         self._free(slot)
@@ -1450,44 +1510,47 @@ class PagedModelRunner(ModelRunner):
             job.indexed += 1
 
     @partial(jax.jit, static_argnums=0, donate_argnums=(1,))
-    def _ragged_activate(self, state: PagedDecodeState, slot, plen,
-                         first_token, temperature, top_p, top_k,
-                         repeat_penalty, recent_row, slot_key):
-        """Flip a ragged-prefilled slot live: the KV is already in its
-        pages, so this is _insert_paged minus the pool scatter."""
-        return self._activated(state, slot, plen, first_token, temperature,
-                               top_p, top_k, repeat_penalty, recent_row,
-                               slot_key)
+    def _ragged_finish(self, state: PagedDecodeState, last_logits, slot,
+                       plen, temperature, top_p, top_k, repeat_penalty,
+                       recent_row, key, slot_key):
+        """Sample a ragged-prefilled prompt's first token
+        (prefill_finish's exact math) and flip its slot live — the KV is
+        already in its pages, so this is _insert_paged minus the pool
+        scatter — in ONE program: as a dozen eager ones behind the job's
+        last flight the device idled ~20 ms an admission between them
+        (PERF.md section 6, PR 42)."""
+        logits = apply_repeat_penalty(last_logits[None, :], recent_row[None],
+                                      repeat_penalty[None])
+        tok = sample_tokens(logits, temperature[None], top_p[None], key,
+                            top_k=top_k[None])[0]
+        return tok, self._activated(state, slot, plen, tok, temperature,
+                                    top_p, top_k, repeat_penalty, recent_row,
+                                    slot_key)
 
     def ragged_finish(self, state: PagedDecodeState, job: "RaggedPrefillJob",
                       temperature: float, top_p: float, key,
                       slot_key=None, top_k: int = 0,
                       repeat_penalty: float = 1.0):
-        """Sample the first token (prefill_finish's exact math) and
-        activate the slot.  Returns (first_token, new_state)."""
+        """Sample the first token and activate the slot.  Returns
+        (first_token, new_state); the token is the sampled scalar still on
+        the device, as ``prefill`` hands its own back, so that whoever asks
+        next need not wait for the device's queue to empty."""
         assert job.finished and job.last_logits is not None
         plen = len(job.prompt_ids)
-        recent_row = jnp.asarray(self._recent_from_prompt(job.prompt_ids))
-        logits = apply_repeat_penalty(
-            job.last_logits[None, :], recent_row[None],
-            jnp.float32(repeat_penalty)[None])
-        tok = sample_tokens(logits,
-                            jnp.float32(temperature)[None],
-                            jnp.float32(top_p)[None], key,
-                            top_k=jnp.int32(top_k)[None])[0]
-        first = int(tok)
         if slot_key is None:
             slot_key = default_slot_key(job.slot)
         t_c = ENGINE_TELEMETRY.compile_begin("ragged_finish", 0)
-        state = self._ragged_activate(
-            state, jnp.int32(job.slot), jnp.int32(plen), jnp.int32(first),
+        tok, state = self._ragged_finish(
+            state, job.last_logits, jnp.int32(job.slot), jnp.int32(plen),
             jnp.float32(temperature), jnp.float32(top_p), jnp.int32(top_k),
-            jnp.float32(repeat_penalty), recent_row, slot_key)
+            jnp.float32(repeat_penalty),
+            jnp.asarray(self._recent_from_prompt(job.prompt_ids)), key,
+            slot_key)
         ENGINE_TELEMETRY.compile_end("ragged_finish", 0, t_c)
         self._host_seq[job.slot] = plen
         self._ragged_index(job)
         self._ragged_slot = None
-        return first, state
+        return tok, state
 
     def ragged_abort(self, job: "RaggedPrefillJob") -> None:
         """Abandon a mid-flight ragged prefill (cancel / migrate / error):

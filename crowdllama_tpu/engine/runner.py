@@ -138,9 +138,10 @@ class ModelRunner:
     ):
         if cfg.is_hybrid and not self.serves_hybrid:
             raise ValueError(
-                f"{type(self).__name__} cannot serve {cfg.name!r}: its "
-                f"recurrent layers' per-slot state lives in the paged runner "
-                f"of engine/hybrid.py alone")
+                f"{type(self).__name__} cannot serve {cfg.name!r}: what its "
+                f"layers of several kinds keep a slot (recurrent state, a "
+                f"window pool) lives in the paged runner of engine/hybrid.py "
+                f"alone")
         self.cfg = cfg
         self.max_slots = max_slots
         self.max_seq = max_seq or cfg.max_context_length
@@ -226,9 +227,9 @@ class ModelRunner:
     @property
     def kv_layers(self) -> int:
         """Layers that keep KV: all of them, but for a model whose layers
-        differ in kind (engine/hybrid.py): its attention layers, latent
-        ones (``L``) among them."""
-        return self.cfg.layers_of("*") + self.cfg.layers_of("L")
+        differ in kind (engine/hybrid.py): its attention layers of every
+        kind (``*``, latent ``L``, gated window ``W`` and full ``F``)."""
+        return sum(self.cfg.layers_of(kind) for kind in "*LWF")
 
     # ------------------------------------------------------- attention paths
 

@@ -679,6 +679,9 @@ class Scheduler:
             used = sum(s.prompt_len + s.generated for s in self.slots
                        if isinstance(s, _SlotInfo))
             g["kv_cache_utilization"] = used / (total * max(1, r.max_seq))
+        # Paged KV by kind of pool (engine/paged.py, engine/hybrid.py):
+        # capacity, bytes live, window pages written over.
+        g.update(getattr(r, "kv_gauges", dict)())
         # Unified ragged batch (docs/RAGGED_BATCH.md): slots mid-chunked-
         # prefill (0 or 1 — one chunked admission at a time) and the token
         # budget the last dispatched step actually carried (live decode
@@ -1338,6 +1341,7 @@ class Scheduler:
                 if (self._chunking is not None
                     and getattr(self._chunking[2], "ragged", False))
                 else None)
+        late = None  # a first token this turn's ragged finish left on device
         if rjob is not None and rjob[0].cancelled:
             req, slot, job = rjob
             self._chunking = None
@@ -1496,22 +1500,33 @@ class Scheduler:
                                 top_k=req.top_k,
                                 repeat_penalty=req.repeat_penalty)
 
-                        # ragged_finish reads its token itself (and no
-                        # insert follows it): an admission on the host.
-                        ENGINE_TELEMETRY.admission_inc("host")
+                        # As ``_place``: a Python int (the multi-host
+                        # wrapper) is emitted here; anything else is the
+                        # sampled scalar still on the device, which the
+                        # activation took unread — the slot's rows ride
+                        # the NEXT flight, and ``_emit_firsts`` reads the
+                        # token once that one is queued (``late``: not
+                        # behind this turn's flight, which is the job's
+                        # own), so the device does not drain here.
                         try:
                             first, self.state = await self._call(
                                 loop, "ragged_finish", finish)
-                            await self._stamp_first_token(req)
                         except BaseException:
                             self.slots[slot] = None
                             req.finish("error: engine failure")
                             raise
                         info = _SlotInfo(req=req,
                                          prompt_len=len(req.prompt_ids))
+                        on_host = isinstance(first, (int, np.integer))
+                        ENGINE_TELEMETRY.admission_inc(
+                            "host" if on_host else "device")
                         self.slots[slot] = info
-                        self._emit_first(req, first, info)
-                        await self._flush_releases(loop)
+                        if on_host:
+                            await self._stamp_first_token(req)
+                            self._emit_first(req, int(first), info)
+                            await self._flush_releases(loop)
+                        else:
+                            info.first_dev = late = first
             elif paced:
                 # Credits are positioned against emitted counts, and a
                 # paced round gives up the overlap anyway: first tokens
@@ -1544,7 +1559,7 @@ class Scheduler:
         # Every slot placed so far rides the flight just queued: the host
         # may wait for their first tokens — once this turn's own admissions
         # are queued behind it too, so that the wait keeps nothing back.
-        firsts = self._firsts()
+        firsts = [f for f in self._firsts() if f[1].first_dev is not late]
 
         # Advance an in-progress LEGACY chunked admission by ONE prefill
         # chunk (ragged jobs already advanced inside the dispatch above).

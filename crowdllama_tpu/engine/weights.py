@@ -277,7 +277,7 @@ def resolve_model_config(name: str, model_path: str = "",
 _FAMILIES = {"llama": "llama", "mistral": "mistral", "mixtral": "mixtral",
              "gemma2": "gemma2", "qwen2": "qwen2", "qwen3": "qwen3",
              "qwen3_moe": "qwen3", "nemotron_h": "nemotron_h",
-             "kimi_linear": "kimi_linear"}
+             "kimi_linear": "kimi_linear", "afmoe": "afmoe"}
 #: keys that say the block is not the one the dense families share: reading
 #: past them would serve another model under this one's name
 _FOREIGN_KEYS = ("hybrid_override_pattern", "n_routed_experts",
@@ -306,11 +306,14 @@ def config_from_hf_dir(path: str | Path) -> ModelConfig:
         else "qwen3" if "qwen3" in arch
         else "qwen2" if "qwen2" in arch
         else "nemotron_h" if "nemotronh" in arch
-        else "kimi_linear" if "kimilinear" in arch else "llama")
+        else "kimi_linear" if "kimilinear" in arch
+        else "afmoe" if "afmoe" in arch else "llama")
     if family == "nemotron_h":
         return _nemotron_h_config(d)
     if family == "kimi_linear":
         return _kimi_linear_config(d)
+    if family == "afmoe":
+        return _afmoe_config(d)
     odd = sorted(k for k, v in d.items() if v not in (None, 0, False)
                  and (k in _FOREIGN_KEYS or k.startswith(_FOREIGN_PREFIXES)))
     if odd:
@@ -462,4 +465,63 @@ def _kimi_linear_config(d: dict) -> ModelConfig:
                                       * d["moe_intermediate_size"]),
         moe_routed_scaling=float(d.get("routed_scaling_factor", 1.0)),
         moe_norm_topk=bool(d.get("moe_renormalize", True)),
+    )
+
+
+def _afmoe_config(d: dict) -> ModelConfig:
+    """``model_type: afmoe``.  A published layer is two sublayers of the
+    pattern: its attention — ``W`` where ``layer_types`` says
+    ``sliding_attention`` (inside ``sliding_window``, rotated), ``F`` where
+    it says ``full_attention`` (the whole context, not rotated) — then its
+    feed-forward, ``D`` for the first ``num_dense_layers`` layers and ``S``
+    after.  ``num_experts`` counts the experts held HERE; where that is a
+    share, ``num_experts_published`` gives the router's width and
+    ``expert_parallel_rank`` which share (the benchmark's cut states both).
+    With ``mup_enabled`` the embedding is scaled by ``sqrt(hidden_size)``."""
+    n = d["num_hidden_layers"]
+    kinds = {"sliding_attention": "W", "full_attention": "F"}
+    types = d["layer_types"]
+    if len(types) != n or set(types) - set(kinds):
+        raise ValueError(
+            f"layer_types must name each of the {n} layers "
+            f"{' or '.join(kinds)}; it has {len(types)} entries of "
+            f"{sorted(set(types))}")
+    served = {"score_func": "sigmoid", "hidden_act": "silu", "n_group": 1,
+              "topk_group": 1, "num_expert_groups": 1,
+              "num_limited_groups": 1, "rope_scaling": None,
+              "attention_bias": False}
+    odd = {k: d[k] for k, v in served.items() if d.get(k, v) != v}
+    if odd:
+        raise ValueError(f"afmoe is served with {served} only; this "
+                         f"config.json says {odd}")
+    if "W" in map(kinds.get, types) and not d.get("sliding_window"):
+        raise ValueError("layer_types names sliding_attention layers and "
+                         "sliding_window gives them no width")
+    dense = d.get("num_dense_layers", 0)
+    pattern = "".join(kinds[t] + ("D" if i < dense else "S")
+                      for i, t in enumerate(types))
+    held = d["num_experts"]
+    return ModelConfig(
+        name=d.get("_name_or_path", "hf-model"), family="afmoe",
+        vocab_size=d["vocab_size"], hidden_size=d["hidden_size"],
+        intermediate_size=d["intermediate_size"], num_layers=n,
+        num_heads=d["num_attention_heads"],
+        num_kv_heads=d["num_key_value_heads"], head_dim=d.get("head_dim", 0),
+        rope_theta=float(d.get("rope_theta", 10000.0)),
+        rms_norm_eps=d.get("rms_norm_eps", 1e-5),
+        tie_word_embeddings=d.get("tie_word_embeddings", False),
+        max_context_length=d.get("max_position_embeddings", 4096),
+        sliding_window=d.get("sliding_window") or 0,
+        post_norms=True, qk_norm=True,
+        embedding_multiplier=(d["hidden_size"] ** 0.5
+                              if d.get("mup_enabled") else 0.0),
+        layer_pattern=pattern,
+        num_experts=d.get("num_experts_published", held),
+        experts_held=held, expert_rank=d.get("expert_parallel_rank", 0),
+        num_experts_per_tok=d["num_experts_per_tok"],
+        moe_intermediate_size=d["moe_intermediate_size"],
+        moe_shared_intermediate_size=(d.get("num_shared_experts", 1)
+                                      * d["moe_intermediate_size"]),
+        moe_routed_scaling=float(d.get("route_scale", 1.0)),
+        moe_norm_topk=bool(d.get("route_norm", True)),
     )

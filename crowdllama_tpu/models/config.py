@@ -38,7 +38,7 @@ class RopeScaling:
 class ModelConfig:
     name: str = "custom"
     # "llama" | "mistral" | "gemma2" | "mixtral" | "qwen2" | "qwen3" |
-    # "nemotron_h" | "kimi_linear" (layers that differ in kind:
+    # "nemotron_h" | "kimi_linear" | "afmoe" (layers that differ in kind:
     # models/hybrid.py)
     family: str = "llama"
     vocab_size: int = 32000
@@ -82,7 +82,11 @@ class ModelConfig:
     # attention (no rotary), one sublayer a layer; family "kimi_linear" —
     # "K" delta-rule linear attention (KDA), "L" latent attention (MLA, no
     # rotary), "D" dense SwiGLU, "S" SwiGLU mixture of experts with a
-    # shared expert, two sublayers (mixer, then feed-forward) a layer.
+    # shared expert, two sublayers (mixer, then feed-forward) a layer;
+    # family "afmoe" — "W" gated attention inside ``sliding_window`` with
+    # rotary embedding, "F" gated full attention without it, then "D" or
+    # "S", two sublayers a layer, each sublayer's output normed AGAIN before
+    # it joins the residual stream (``post_norms``).
     # ``num_experts`` is the router's width; this worker holds
     # ``experts_held`` of them (0 = all), those of ``expert_rank``.
     layer_pattern: str = ""
@@ -121,8 +125,8 @@ class ModelConfig:
                 f"moe_dispatch must be 'sorted' or 'dense', "
                 f"got {self.moe_dispatch!r}")
         if self.layer_pattern:
-            kinds, per = (("KLDS", 2) if self.family == "kimi_linear"
-                          else ("ME*", 1))
+            kinds, per = {"kimi_linear": ("KLDS", 2),
+                          "afmoe": ("WFDS", 2)}.get(self.family, ("ME*", 1))
             odd = set(self.layer_pattern) - set(kinds)
             if odd or len(self.layer_pattern) != per * self.num_layers:
                 raise ValueError(
@@ -259,6 +263,20 @@ TINY_TEST_KIMI_LINEAR = _register(ModelConfig(
     num_experts_per_tok=4, experts_held=8, moe_intermediate_size=32,
     moe_shared_intermediate_size=32, moe_routed_scaling=2.446,
     max_context_length=256,
+))
+
+# Both attention kinds and both feed-forwards of the family: a dense first
+# layer, three window layers to one full layer, a window of two pages of 8,
+# 16 experts behind the router of which this worker holds 8 (rank 0 of 2),
+# top-4, six query heads a kv head.
+TINY_TEST_AFMOE = _register(ModelConfig(
+    name="tiny-test-afmoe", family="afmoe", vocab_size=512, hidden_size=64,
+    intermediate_size=96, num_layers=5, num_heads=12, num_kv_heads=2,
+    head_dim=16, layer_pattern="WDWSWSWSFS", sliding_window=16,
+    post_norms=True, qk_norm=True, embedding_multiplier=8.0,
+    num_experts=16, num_experts_per_tok=4, experts_held=8,
+    moe_intermediate_size=32, moe_shared_intermediate_size=32,
+    moe_routed_scaling=2.448, max_context_length=256,
 ))
 
 # ---- production models (BASELINE.json configs) ----------------------------

@@ -1,17 +1,27 @@
 """Models whose layers differ in kind, each sublayer ONE mixer behind one
-RMSNorm:
+RMSNorm, joining the residual stream in one of two forms:
 
     x <- x + mixer_kind(RMSNorm(x))          kind = cfg.layer_pattern[i]
+    x <- x + RMSNorm_post(mixer_kind(RMSNorm(x)))       (``cfg.post_norms``)
+
+A model need not have a recurrent layer to come through here: what the
+file keeps is one definition of each kind of sublayer for every layout of
+its state.
 
 Family ``nemotron_h``: Mamba-2 (``M``), a latent mixture of experts with a
 shared expert (``E``), attention without rotation (``*``), one sublayer a
 layer.  Family ``kimi_linear``: delta-rule linear attention (``K``, KDA:
 ``ops/kda.py``) or latent attention without rotation (``L``, MLA), then a
 dense SwiGLU (``D``) or a SwiGLU mixture of experts with a shared expert
-(``S``) — a published layer is two entries of the pattern.
+(``S``) — a published layer is two entries of the pattern.  Family
+``afmoe``: gated attention with a per-head RMSNorm of q and k, inside a
+sliding window and rotated (``W``) or over the whole context and not
+(``F``), then ``D`` or ``S``; the second residual form; the embedding times
+``cfg.embedding_multiplier`` (muP).
 
 The equations are written out in the plain references
-(benchmarks/chip/harness/reference/nemotron_h.py, kimi_linear.py); this
+(benchmarks/chip/harness/reference/nemotron_h.py, kimi_linear.py,
+afmoe.py); this
 file is the program's side of them.  Parameters are kept per KIND
 (``params["layers"][STACK[kind]]``: a list, one dict of leaves for each
 sublayer of that kind in order) and the layer loop is unrolled over
@@ -30,6 +40,8 @@ state, the ragged step's mixed rows):
   and read; q ``[..., H, Dh]``, k, v ``[..., Hkv, Dh]``.  A latent layer
   hands over ONE row a token as ``k`` and ``v = None``: the value is that
   row (the cache keeps no second pool), and the caller takes its share.
+  :func:`attn_kinds` says which kind the i-th is: a window layer's cache
+  may keep only the pages its window still reaches (engine/hybrid.py).
 * ``rec_fn(kind, i, lp, *inputs) -> y`` — the i-th recurrent layer's (``M``
   or ``K``) convolution tail and state: it splits the rows into sequences
   and runs ``MIX[kind]`` (:func:`mamba_mix`, :func:`kda_mix`) on each, from
@@ -58,6 +70,7 @@ from crowdllama_tpu.ops.attention import (
     prefill_attention_ctx,
 )
 from crowdllama_tpu.ops.norms import rms_norm
+from crowdllama_tpu.ops.rope import rope_angles, rotate_half
 from crowdllama_tpu.ops.quant import (
     dequant, qeinsum, qragged_dot, qragged_fetched,
 )
@@ -67,14 +80,25 @@ F32 = jnp.float32
 
 #: the parameter stack of each kind of sublayer
 STACK = {"M": "mamba", "E": "moe", "*": "attn",
-         "K": "kda", "L": "mla", "D": "mlp", "S": "smoe"}
+         "K": "kda", "L": "mla", "D": "mlp", "S": "smoe",
+         "W": "wattn", "F": "fattn"}
 #: the kinds that keep paged KV, and the per-slot state of the recurrent ones
-ATTENTION = "*L"
+ATTENTION = "*LWF"
 STATE = {"M": "ssm", "K": "kda"}
 #: why whatever rests on "tokens done == pages of KV to hand over" declines
 #: a model with recurrent layers (prefix reuse, page export and import, the
 #: drain hand-off, speculation's rollback)
 NO_PAGES = "recurrent state has no page to export"
+#: why the same declines a model with window layers: their pool is a ring a
+#: slot (engine/hybrid.py), so the pages of a prompt's first tokens are gone
+#: by the time anyone could share, ship or roll back to them
+NO_WINDOW_PAGES = "a window layer no longer holds a prefix's pages"
+
+
+def attn_kinds(cfg: ModelConfig) -> str:
+    """The kinds of the attention layers, in the order ``attn_fn`` counts
+    them."""
+    return "".join(k for k in cfg.layer_pattern if k in ATTENTION)
 
 
 def sizes(cfg: ModelConfig) -> dict[str, int]:
@@ -101,7 +125,13 @@ def _shapes(cfg: ModelConfig) -> dict[str, dict[str, tuple[int, ...]]]:
     d, dh = cfg.hidden_size, cfg.resolved_head_dim()
     h, hkv = cfg.num_heads, cfg.num_kv_heads
     lat, f = cfg.moe_latent_size, cfg.moe_intermediate_size
+    # the sandwich form: a second gain, over the sublayer's output
+    post = {"post_norm": (d,)} if cfg.post_norms else {}
+    gated = {"norm": (d,), "wq": (d, h * dh), "wk": (d, hkv * dh),
+             "wv": (d, hkv * dh), "wg": (d, h * dh), "q_norm": (dh,),
+             "k_norm": (dh,), "wo": (h * dh, d), **post}
     return {
+        "wattn": gated, "fattn": gated,
         "mamba": {
             "norm": (d,), "w_in": (d, z["in_proj"]),
             "conv_w": (z["conv_dim"], cfg.ssm_conv_kernel),
@@ -136,14 +166,14 @@ def _shapes(cfg: ModelConfig) -> dict[str, dict[str, tuple[int, ...]]]:
         # w_gu = [W_gate | W_up]
         "mlp": {
             "norm": (d,), "w_gu": (d, 2 * cfg.intermediate_size),
-            "w_down": (cfg.intermediate_size, d)},
+            "w_down": (cfg.intermediate_size, d), **post},
         "smoe": {
             "norm": (d,), "router": (d, cfg.num_experts),
             "router_bias": (cfg.num_experts,),
             "w_gate": (z["held"], d, f), "w_up": (z["held"], d, f),
             "w_down": (z["held"], f, d),
             "ws_gu": (d, 2 * cfg.moe_shared_intermediate_size),
-            "ws_down": (cfg.moe_shared_intermediate_size, d)},
+            "ws_down": (cfg.moe_shared_intermediate_size, d), **post},
     }
 
 
@@ -192,7 +222,8 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
 
     def leaf(name, shape):
         k = next(keys)
-        if name in ("norm", "gate_norm", "o_norm", "kv_norm"):
+        if name in ("norm", "gate_norm", "o_norm", "kv_norm", "post_norm",
+                    "q_norm", "k_norm"):
             return jnp.ones(shape, dtype)
         special = special_leaf(name, shape, k, dtype)
         return dense(k, shape) if special is None else special
@@ -216,6 +247,14 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
 
 def _normed(lp: Params, cfg: ModelConfig, x):
     return rms_norm(x, lp["norm"], cfg.rms_norm_eps)
+
+
+def _joined(lp: Params, cfg: ModelConfig, x, y):
+    """The residual stream after a sublayer whose mixer gave ``y``: ``x +
+    y``, or ``x + RMSNorm_post(y)`` where the layer has the second gain."""
+    if "post_norm" in lp:
+        y = rms_norm(y, lp["post_norm"], cfg.rms_norm_eps)
+    return x + y
 
 
 def mamba_mix(lp: Params, cfg: ModelConfig, xbc, dt, tail, state, valid,
@@ -289,6 +328,36 @@ def attn_body(lp: Params, cfg: ModelConfig, x, attn_fn):
     attn = attn_fn(q, k, v)
     with jax.named_scope("attn_proj"):
         return x + qeinsum("...k,kd->...d", attn.reshape(*lead, -1), lp["wo"])
+
+
+def gattn_body(lp: Params, cfg: ModelConfig, x, attn_fn, angles=None):
+    """One gated attention layer (``W``, ``F``) minus its cache policy: q
+    and k normed a head before any rotation, rotated by ``angles`` (cos,
+    sin ``[..., Dh/2]`` of each row's position; None: no rotation), and the
+    softmax's output times ``sigmoid(W_g a)`` before ``W_o``."""
+    dh = cfg.resolved_head_dim()
+    lead = x.shape[:-1]
+    with jax.named_scope("attn_proj"):
+        h = _normed(lp, cfg, x)
+        q = qeinsum("...d,dk->...k", h, lp["wq"]).reshape(
+            *lead, cfg.num_heads, dh)
+        k = qeinsum("...d,dk->...k", h, lp["wk"]).reshape(
+            *lead, cfg.num_kv_heads, dh)
+        v = qeinsum("...d,dk->...k", h, lp["wv"]).reshape(
+            *lead, cfg.num_kv_heads, dh)
+        with jax.named_scope("attn_gate"):
+            gate = jax.nn.sigmoid(
+                qeinsum("...d,dk->...k", h, lp["wg"]).astype(F32))
+        with jax.named_scope("qk_norm"):
+            q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
+            k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
+        if angles is not None:
+            q, k = rotate_half(q, *angles), rotate_half(k, *angles)
+    attn = attn_fn(q, k, v)
+    with jax.named_scope("attn_proj"):
+        with jax.named_scope("attn_gate"):
+            y = (attn.reshape(*lead, -1).astype(F32) * gate).astype(x.dtype)
+        return _joined(lp, cfg, x, qeinsum("...k,kd->...d", y, lp["wo"]))
 
 
 def kda_mix(lp: Params, cfg: ModelConfig, qkv, g, beta, tail, state, valid,
@@ -378,7 +447,8 @@ def mla_body(lp: Params, cfg: ModelConfig, x, attn_fn):
 def mlp_body(lp: Params, cfg: ModelConfig, x):
     """One dense SwiGLU feed-forward."""
     with jax.named_scope("mlp"):
-        return x + _swiglu(_normed(lp, cfg, x), lp["w_gu"], lp["w_down"])
+        return _joined(lp, cfg, x, _swiglu(_normed(lp, cfg, x), lp["w_gu"],
+                                           lp["w_down"]))
 
 
 def _swiglu(h, w_gu, w_down):
@@ -493,16 +563,21 @@ def smoe_body(lp: Params, cfg: ModelConfig, x, live):
         acc, counts = held_sum(cfg, h, topw, topi, live.reshape(-1), experts)
     with jax.named_scope("moe_shared"):
         shared = _swiglu(h, lp["ws_gu"], lp["ws_down"])
-    return x + (acc.astype(x.dtype) + shared).reshape(shape), counts
+    return _joined(lp, cfg, x,
+                   (acc.astype(x.dtype) + shared).reshape(shape)), counts
 
 
 #: a recurrent kind's state policy runs this on each sequence's rows
 MIX = {"M": mamba_mix, "K": kda_mix}
 
 
-def run_layers(layers: Params, cfg: ModelConfig, x, rec_fn, attn_fn, live):
-    """The layer loop, unrolled over ``cfg.layer_pattern``.  Returns (x,
-    the expert layers' :data:`COUNTS` summed, int32 ``[5]``)."""
+def run_layers(layers: Params, cfg: ModelConfig, x, rec_fn, attn_fn, live,
+               positions=None):
+    """The layer loop, unrolled over ``cfg.layer_pattern``; ``positions``
+    (as ``x`` without its last axis) for the layers that rotate.  Returns
+    (x, the expert layers' :data:`COUNTS` summed, int32 ``[5]``)."""
+    angles = (rope_angles(positions, cfg.resolved_head_dim(), cfg.rope_theta)
+              if "W" in cfg.layer_pattern else None)
     # scalars until the end: a vector a layer was a concatenate a layer
     # (1.2 us each on the chip: PERF.md §6, PR 40)
     counts = (0,) * len(COUNTS)
@@ -523,9 +598,12 @@ def run_layers(layers: Params, cfg: ModelConfig, x, rec_fn, attn_fn, live):
             body = mamba_body if kind == "M" else kda_body
             x = body(lp, cfg, x, partial(rec_fn, kind, i))
         elif kind in ATTENTION:
-            body = attn_body if kind == "*" else mla_body
-            x = body(lp, cfg, x, partial(attn_fn, attn_seen))
+            fn = partial(attn_fn, attn_seen)
             attn_seen += 1
+            if kind in "WF":
+                x = gattn_body(lp, cfg, x, fn, angles if kind == "W" else None)
+            else:
+                x = (attn_body if kind == "*" else mla_body)(lp, cfg, x, fn)
         elif kind == "D":
             x = mlp_body(lp, cfg, x)
         else:
@@ -541,7 +619,10 @@ def zero_recurrent(cfg: ModelConfig, seqs: int, dtype=jnp.bfloat16):
     the name ``PagedDecodeState`` keeps it under: ``ssm`` ``[L_M, S, H, P,
     N]`` or ``kda`` ``[L_K, S, H, dk, dv]``, float32, and ``conv`` — Mamba's
     ``[L_M, S, conv_dim, K-1]``, KDA's as rows ``[L_K, S, K-1, conv_dim]``
-    (a model has layers of one recurrent kind)."""
+    (a model has layers of one recurrent kind; one with none keeps
+    nothing)."""
+    if not any(kind in cfg.layer_pattern for kind in STATE):
+        return {}
     z = sizes(cfg)
     if cfg.layers_of("K"):
         lk, dk = cfg.layers_of("K"), cfg.kda_head_dim
@@ -575,6 +656,9 @@ def prefill(params: Params, cfg: ModelConfig, tokens, positions, kv_valid,
         rec0 = zero_recurrent(cfg, b, params["embed"].dtype)
     kv, rec = {}, {}
 
+    windows = [cfg.sliding_window if kind == "W" else 0
+               for kind in attn_kinds(cfg)]
+
     def attn_fn(i, q, k, v):
         kh = k.transpose(0, 2, 1, 3)
         vh = kh if v is None else v.transpose(0, 2, 1, 3)
@@ -584,8 +668,9 @@ def prefill(params: Params, cfg: ModelConfig, tokens, positions, kv_valid,
                 return prefill_attention_ctx(
                     q, kh, vh, positions, ctx_k[i],
                     (ctx_k if ctx_v is None else ctx_v)[i], ctx_valid,
-                    scale, kv_valid=kv_valid)
+                    scale, sliding_window=windows[i], kv_valid=kv_valid)
             return prefill_attention(q, kh, vh, positions, scale,
+                                     sliding_window=windows[i],
                                      kv_valid=kv_valid, n_shards=n_shards)
 
     def rec_fn(kind, i, lp, *inputs):
@@ -595,13 +680,16 @@ def prefill(params: Params, cfg: ModelConfig, tokens, positions, kv_valid,
         return y
 
     x, counts = run_layers(params["layers"], cfg, T._embed(params, cfg, tokens),
-                           rec_fn, attn_fn, kv_valid)
+                           rec_fn, attn_fn, kv_valid, positions)
     ks = jnp.stack([kv[i][0] for i in range(len(kv))])
     vs = (None if kv[0][1] is None
           else jnp.stack([kv[i][1] for i in range(len(kv))]))
-    state_name, = set(rec0) - {"conv"}
-    rec_out = {state_name: jnp.stack([rec[i][0] for i in range(len(rec))]),
-               "conv": jnp.stack([rec[i][1] for i in range(len(rec))])}
+    rec_out = {}
+    if rec0:
+        state_name, = set(rec0) - {"conv"}
+        rec_out = {
+            state_name: jnp.stack([rec[i][0] for i in range(len(rec))]),
+            "conv": jnp.stack([rec[i][1] for i in range(len(rec))])}
     out = (T._unembed(params, cfg, x) if unembed else rms_norm(
         x, params["final_norm"], cfg.rms_norm_eps))
     return out, ks, vs, rec_out, counts

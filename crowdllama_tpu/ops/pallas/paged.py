@@ -46,6 +46,7 @@ The reference has no kernels at all (compute is delegated to Ollama,
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -62,6 +63,41 @@ _LANES = 128
 # K+V tile bytes per fetched page must fit the budget x (pairs, double
 # buffering) alongside q/output/scratch.
 _VMEM_TILE_BUDGET = 8 * 1024 * 1024
+
+
+class Ring(NamedTuple):
+    """How a WINDOW layer's pool is laid out (engine/hybrid.py): slot ``s``
+    owns pages ``s * pages .. (s + 1) * pages - 1`` of it for as long as it
+    lives, and its logical page ``p`` (tokens ``p * page ..``) lies in
+    ``s * pages + p % pages`` until page ``p + pages`` is written over it —
+    by which time no query of the slot looks ``window`` tokens back that far
+    (``pages * page >= window + the widest write + page``).  A reader gets a
+    page table of its own, :meth:`view`: only the pages its window reaches,
+    oldest first, and positions counted from the first of them."""
+
+    pages: int
+    window: int
+
+    def page_of(self, slot, logical):
+        return slot * self.pages + logical % self.pages
+
+    def view(self, slots, q_start, span: int, page: int):
+        """For queries at positions ``q_start .. q_start + span - 1`` of
+        ``slots`` (both ``[N]``): (first ``[N]`` — the logical page the
+        oldest key any of them sees lies in, table ``[N, cols]`` — that page
+        and the ``cols - 1`` after it, as pool pages)."""
+        cols = -(-(self.window + max(span, 1) - 2) // page) + 1
+        assert cols <= self.pages, (self, span, page)
+        first = jnp.maximum(q_start - self.window + 1, 0) // page
+        logical = first[:, None] + jnp.arange(cols, dtype=jnp.int32)[None, :]
+        return first, self.page_of(slots[:, None], logical).astype(jnp.int32)
+
+    def decode_view(self, lens, page: int):
+        """For every slot's one decode query, the newest of its ``lens``
+        tokens: (table ``[B, cols]``, lengths counted from its first page)."""
+        slots = jnp.arange(lens.shape[0], dtype=jnp.int32)
+        first, table = self.view(slots, lens - 1, 1, page)
+        return table, lens - first * page
 
 
 def _pairs_bytes(hkv: int, page: int, dh: int, itemsize: int) -> int:
@@ -272,6 +308,7 @@ def flash_paged_decode_attention(
     sliding_window: int | jnp.ndarray = 0,
     k_scale: jnp.ndarray | None = None,  # [L, P, Hkv, page] int8 pools only
     v_scale: jnp.ndarray | None = None,
+    name: str = "paged_decode_attention",  # the call's name in a trace
 ) -> jnp.ndarray:
     """One cached decode step over layer ``layer`` of the stacked paged
     pool; output [B, H, Dh]."""
@@ -314,7 +351,7 @@ def flash_paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, dh), q.dtype),
         interpret=_interpret(),
-        name="paged_decode_attention",
+        name=name,
     )(table, seq_lens, window, _layer_operand(layer), qg, *kv_operands)
     return out.reshape(b, h, dh)
 
@@ -489,6 +526,7 @@ def ragged_paged_attention_ref(
     sliding_window: int | jnp.ndarray = 0,
     k_scale: jnp.ndarray | None = None,
     v_scale: jnp.ndarray | None = None,
+    ring: Ring | None = None,
 ) -> jnp.ndarray:
     """Pure-JAX unified ragged batch attention (reference semantics).
 
@@ -503,7 +541,13 @@ def ragged_paged_attention_ref(
     step, and chunk rows run exactly :func:`prefill_attention_ctx` with
     the paged prefix as the cached context — the same code paths the
     monolithic admission path uses — so unified streams match monolithic
-    streams bitwise on bf16 pools."""
+    streams bitwise on bf16 pools.
+
+    With ``ring`` the pool is a window layer's (:class:`Ring`):
+    ``page_table`` gives only the number of slots, every sequence reads its
+    ring through a view that starts where its window does, and lengths and
+    positions are counted from there (the masks are differences of
+    positions, so nothing else changes)."""
     from crowdllama_tpu.ops.attention import (
         decode_attention,
         decode_attention_q,
@@ -513,9 +557,18 @@ def ragged_paged_attention_ref(
     b = page_table.shape[0]
     c = chunk_k.shape[2]
     _, _, hkv, page, dh = pool_k.shape
+    quant = k_scale is not None
+    ctx = kv_lens[b] - q_lens[b]
+    chunk_row = page_table[chunk_slot]
+    if ring is not None:
+        assert not quant, "a window layer's pool is not int8"
+        sliding_window = ring.window
+        page_table, lens_dec = ring.decode_view(kv_lens[:b], page)
+        kv_lens = kv_lens.at[:b].set(lens_dec)
+        first_c, chunk_row = ring.view(chunk_slot[None], ctx[None], 1, page)
+        ctx, chunk_row = ctx - first_c[0] * page, chunk_row[0]
     np_ = page_table.shape[1]
     w = np_ * page
-    quant = k_scale is not None
 
     # --- decode rows: identical to the plain paged decode fallback ---
     view_k = pool_k[layer, page_table].transpose(0, 2, 1, 3, 4).reshape(
@@ -536,15 +589,14 @@ def ragged_paged_attention_ref(
             sliding_window=sliding_window)
 
     # --- chunk rows: prefix pages as cached context + fresh self block ---
-    ctx = kv_lens[b] - q_lens[b]
-    cpk = pool_k[layer, page_table[chunk_slot]]
-    cpv = pool_v[layer, page_table[chunk_slot]]
+    cpk = pool_k[layer, chunk_row]
+    cpv = pool_v[layer, chunk_row]
     ctx_k = cpk.transpose(1, 0, 2, 3).reshape(1, hkv, w, dh)
     ctx_v = cpv.transpose(1, 0, 2, 3).reshape(1, hkv, w, dh)
     if quant:
-        csk = k_scale[layer, page_table[chunk_slot]].transpose(
+        csk = k_scale[layer, chunk_row].transpose(
             1, 0, 2).reshape(1, hkv, w, 1)
-        csv = v_scale[layer, page_table[chunk_slot]].transpose(
+        csv = v_scale[layer, chunk_row].transpose(
             1, 0, 2).reshape(1, hkv, w, 1)
         ctx_k = ctx_k.astype(jnp.float32) * csk.astype(jnp.float32)
         ctx_v = ctx_v.astype(jnp.float32) * csv.astype(jnp.float32)
@@ -626,6 +678,16 @@ def _ragged_v2_kernel(
     qpos = q_start + row_iota // g
     row_ok = row_iota // g < q_valid
 
+    # bf16 queries meet bf16 pages on the MXU as they are: a product of two
+    # bf16 values is exact in the float32 accumulator, so the scores are a
+    # float32 matmul's up to the order of the sums, in one pass of the MXU
+    # where a float32 matmul takes six (a block of QB x G query rows is
+    # bound by its matmuls: 3 us a page at 192 rows, PERF.md section 6,
+    # PR 42); the probabilities then go to the value matmul in two bf16
+    # pieces, 16 of their 24 bits, as in the latent decode kernel above.
+    native = (not quant and q_ref.dtype == jnp.bfloat16
+              and kv[0].dtype == jnp.bfloat16)
+
     def _tile(j):
         k_ref, v_ref = kv[2 * j], kv[2 * j + 1]
         base = (p * pairs + j) * page
@@ -633,8 +695,12 @@ def _ragged_v2_kernel(
         @pl.when((base < block_bound) & (q_valid > 0))
         def _body():
             q = q_ref[...].astype(jnp.float32).reshape(hkv, rows, dh)
-            k_tile = k_ref[...].astype(jnp.float32)  # [Hkv, page, Dh]
-            v_tile = v_ref[...].astype(jnp.float32)
+            if native:
+                q = q.astype(jnp.bfloat16)
+                k_tile, v_tile = k_ref[...], v_ref[...]
+            else:
+                k_tile = k_ref[...].astype(jnp.float32)  # [Hkv, page, Dh]
+                v_tile = v_ref[...].astype(jnp.float32)
             kpos = base + jax.lax.broadcasted_iota(jnp.int32, (1, 1, page), 2)
 
             logits = jax.lax.dot_general(
@@ -658,10 +724,18 @@ def _ragged_v2_kernel(
             l_new = l_prev * alpha + jnp.sum(pr, axis=-1, keepdims=True)
             if quant:
                 pr = pr * scs[2 * j + 1][...].astype(jnp.float32)[:, None]
-            pv = jax.lax.dot_general(
-                pr, v_tile, (((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32,
-            )
+
+            def by_values(probs):
+                return jax.lax.dot_general(
+                    probs, v_tile, (((2,), (1,)), ((0,), (0,))),
+                    preferred_element_type=jnp.float32)
+
+            if native:
+                hi = pr.astype(jnp.bfloat16)
+                lo = (pr - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+                pv = by_values(hi) + by_values(lo)
+            else:
+                pv = by_values(pr)
             acc_ref[...] = acc_ref[...] * alpha + pv
             m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
             l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
@@ -691,16 +765,22 @@ def flash_ragged_paged_attention(
     sliding_window: int | jnp.ndarray = 0,
     k_scale: jnp.ndarray | None = None,
     v_scale: jnp.ndarray | None = None,
+    ring: Ring | None = None,
 ) -> jnp.ndarray:
-    """Ragged-paged attention v2 layout: the whole mixed batch — B decode
-    sequences + one prefill chunk — in a SINGLE pallas_call.
+    """Ragged-paged attention over the mixed batch — B decode sequences +
+    one prefill chunk: the decode rows through
+    :func:`flash_paged_decode_attention`, the chunk through the v2 kernel.
 
-    One grid of ``B + ceil(C/QB)`` uniform head-packed query blocks whose
-    behavior is driven entirely by a scalar-prefetched ``(q_start, kv_len,
-    q_valid)`` row and a per-block page-table row (decode block n gets slot n's
-    row; every chunk block gets ``chunk_slot``'s).  Pages come from layer
+    The chunk is one grid of ``ceil(C/QB)`` uniform head-packed query blocks
+    whose behavior is driven entirely by a scalar-prefetched ``(q_start,
+    kv_len, q_valid)`` row and a per-block page-table row
+    (``chunk_slot``'s).  Pages come from layer
     ``layer`` of the stacked pool; the chunk's fresh KV must already be
-    scattered into it.  Output [B + C, H, Dh]."""
+    scattered into it.  Output [B + C, H, Dh].
+
+    With ``ring`` the pool is a window layer's (:class:`Ring`): every
+    block's table row is its own view of its slot's ring, from the page its
+    first query's window starts in, and its metadata counts from there."""
     bc, h, dh = q.shape
     _, _, hkv, page, _ = pool_k.shape
     g = h // hkv
@@ -711,29 +791,38 @@ def flash_ragged_paged_attention(
 
     qb = _CHUNK_QB
     jblocks = -(-c // qb)
-    nb = b + jblocks
-    # Decode rows ride in block row 0 (rows 1.. are dead weight a decode
-    # block's q_valid=1 masks off — uniform blocks are what let one
-    # program serve both populations); chunk rows pack [Hkv, C, G, Dh]
-    # kv-head-major then split into QB-row blocks.
-    qd = q[:b].reshape(b, hkv, g, dh)[:, :, None]          # [B,Hkv,1,G,Dh]
-    qd = jnp.pad(qd, ((0, 0), (0, 0), (0, qb - 1), (0, 0), (0, 0)))
+    # The decode rows go through the decode kernel, the very call of the
+    # plain decode step; only the chunk's rows are packed into QB-row
+    # blocks [Hkv, C, G, Dh], kv-head-major.  (As block row 0 of a block of
+    # its own, rows 1.. dead weight, a decode row cost QB times its math:
+    # 3.3 of the 5.0 ms a window layer's call took at 32 slots, six query
+    # heads a kv head and a 4096-token window — PERF.md section 6, PR 42.)
+    table = page_table.astype(jnp.int32)
+    table_dec, lens_dec = table, kv_lens[:b]
+    if ring is not None:
+        sliding_window = ring.window
+        table_dec, lens_dec = ring.decode_view(lens_dec, page)
+    out_dec = flash_paged_decode_attention(
+        q[:b], pool_k, pool_v, layer, table_dec, lens_dec, scale,
+        softcap=softcap, sliding_window=sliding_window, k_scale=k_scale,
+        v_scale=v_scale, name=("paged_decode_attention" if ring is None
+                               else "paged_decode_attention_window"))
     qc = q[b:].reshape(c, hkv, g, dh).transpose(1, 0, 2, 3)
     if jblocks * qb != c:
         qc = jnp.pad(qc, ((0, 0), (0, jblocks * qb - c), (0, 0), (0, 0)))
-    qc = qc.reshape(hkv, jblocks, qb, g, dh).transpose(1, 0, 2, 3, 4)
-    qx = jnp.concatenate([qd, qc], axis=0)                 # [NB,Hkv,QB,G,Dh]
+    qx = qc.reshape(hkv, jblocks, qb, g, dh).transpose(1, 0, 2, 3, 4)
 
-    table = page_table.astype(jnp.int32)
     ctx = (kv_lens[b] - q_lens[b]).astype(jnp.int32)
     j_idx = jnp.arange(jblocks, dtype=jnp.int32)
-    blk_table = jnp.concatenate([
-        table, jnp.broadcast_to(table[chunk_slot][None], (jblocks, np_))])
-    q_start = jnp.concatenate([kv_lens[:b] - 1, ctx + j_idx * qb])
-    kv_len_blk = jnp.concatenate([
-        kv_lens[:b], jnp.broadcast_to(kv_lens[b], (jblocks,))])
-    q_valid = jnp.concatenate([
-        q_lens[:b], jnp.clip(q_lens[b] - j_idx * qb, 0, qb)])
+    blk_table = jnp.broadcast_to(table[chunk_slot][None], (jblocks, np_))
+    q_start = ctx + j_idx * qb
+    kv_len_blk = jnp.broadcast_to(kv_lens[b], (jblocks,))
+    q_valid = jnp.clip(q_lens[b] - j_idx * qb, 0, qb)
+    if ring is not None:
+        slots = jnp.broadcast_to(chunk_slot.astype(jnp.int32), (jblocks,))
+        first, blk_table = ring.view(slots, q_start, qb, page)
+        np_ = blk_table.shape[1]
+        q_start, kv_len_blk = q_start - first * page, kv_len_blk - first * page
     blk_info = jnp.stack(
         [q_start, kv_len_blk, q_valid], axis=1).astype(jnp.int32)
     window = jnp.asarray(sliding_window, jnp.int32).reshape(1)
@@ -752,7 +841,7 @@ def flash_ragged_paged_attention(
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(nb, steps),
+        grid=(jblocks, steps),
         in_specs=[pl.BlockSpec((None, hkv, qb, g, dh), q_map), *kv_specs],
         out_specs=pl.BlockSpec((None, hkv, qb, g, dh), q_map),
         scratch_shapes=[
@@ -764,12 +853,12 @@ def flash_ragged_paged_attention(
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((nb, hkv, qb, g, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((jblocks, hkv, qb, g, dh), q.dtype),
         interpret=_interpret(),
-        name="ragged_paged_attention",
+        name=("ragged_paged_attention" if ring is None
+              else "ragged_paged_attention_window"),
     )(blk_table, blk_info, window, _layer_operand(layer), qx, *kv_operands)
-    out_dec = out[:b, :, 0].reshape(b, h, dh)
-    out_chunk = out[b:].transpose(1, 0, 2, 3, 4).reshape(
+    out_chunk = out.transpose(1, 0, 2, 3, 4).reshape(
         hkv, jblocks * qb, g, dh)[:, :c].transpose(1, 0, 2, 3).reshape(
         c, h, dh)
     return jnp.concatenate([out_dec, out_chunk], axis=0)
@@ -792,6 +881,7 @@ def ragged_paged_attention(
     k_scale: jnp.ndarray | None = None,
     v_scale: jnp.ndarray | None = None,
     use_pallas: bool = False,
+    ring: Ring | None = None,
 ) -> jnp.ndarray:
     """Unified ragged batch attention over layer ``layer`` of the stacked
     paged pool.
@@ -808,11 +898,12 @@ def ragged_paged_attention(
         return ragged_paged_attention_ref(
             q, chunk_k, chunk_v, pool_k, pool_v, layer, page_table, q_lens,
             kv_lens, chunk_slot, scale, softcap=softcap,
-            sliding_window=sliding_window, k_scale=k_scale, v_scale=v_scale)
+            sliding_window=sliding_window, k_scale=k_scale, v_scale=v_scale,
+            ring=ring)
     return flash_ragged_paged_attention(
         q, pool_k, pool_v, layer, page_table, q_lens, kv_lens, chunk_slot,
         scale, softcap=softcap, sliding_window=sliding_window,
-        k_scale=k_scale, v_scale=v_scale)
+        k_scale=k_scale, v_scale=v_scale, ring=ring)
 
 
 def flash_paged_decode_attention_tp(

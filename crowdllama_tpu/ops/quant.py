@@ -51,7 +51,9 @@ QUANT_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
               "ws1", "ws2",
               # KDA's two gates, the latent kv up-projection, the dense and
               # shared SwiGLU's [gate | up]
-              "w_f_up", "w_g_up", "w_kvb", "w_gu", "ws_gu", "ws_down")
+              "w_f_up", "w_g_up", "w_kvb", "w_gu", "ws_gu", "ws_down",
+              # the output gate of a gated attention layer
+              "wg")
 
 
 @jax.tree_util.register_dataclass
@@ -359,7 +361,8 @@ def random_quantized_params(cfg, key: jax.Array, dtype=jnp.bfloat16,
     leaf_keys = jax.random.split(key, len(flat))
 
     norm_names = ("ln1", "ln2", "post_ln1", "post_ln2", "q_norm", "k_norm",
-                  "final_norm", "norm", "gate_norm", "o_norm", "kv_norm")
+                  "final_norm", "norm", "gate_norm", "o_norm", "kv_norm",
+                  "post_norm")
 
     def build(path, sds, k):
         name = path[-1].key

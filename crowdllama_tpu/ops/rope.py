@@ -41,6 +41,15 @@ def rope_table(max_len: int, head_dim: int, theta: float,
     return jnp.cos(angles), jnp.sin(angles)
 
 
+def rope_angles(positions: jnp.ndarray, head_dim: int,
+                theta: float) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """(cos, sin) ``[..., Dh/2]`` of ``positions [...]`` themselves: what
+    :func:`rope_table` holds at those rows, without the table."""
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    angles = positions.astype(jnp.float32)[..., None] * inv_freq
+    return jnp.cos(angles), jnp.sin(angles)
+
+
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
                cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarray:
     """Rotate ``x`` [..., T, H, Dh] by per-token ``positions`` [..., T].
@@ -48,9 +57,13 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
     Uses the 'rotate_half' convention (x split into two halves), matching the
     HF Llama implementation so converted checkpoints are bit-compatible.
     """
+    return rotate_half(x, cos[positions], sin[positions])
+
+
+def rotate_half(x: jnp.ndarray, c: jnp.ndarray, s: jnp.ndarray) -> jnp.ndarray:
+    """``x`` [..., T, H, Dh] rotated by each token's own angles: ``c``, ``s``
+    [..., T, Dh/2] their cosines and sines."""
     dtype = x.dtype
-    c = cos[positions]  # [..., T, Dh/2]
-    s = sin[positions]
     c = jnp.expand_dims(c, axis=-2)  # broadcast over heads: [..., T, 1, Dh/2]
     s = jnp.expand_dims(s, axis=-2)
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)

@@ -124,6 +124,33 @@ def test_decode_inactive_slot_is_finite_free():
     assert np.isfinite(np.asarray(out)).all()
 
 
+def test_ragged_v2_on_bf16_pages_is_the_float32_kernel():
+    """bf16 queries and pages go to the MXU as they are (exact products,
+    float32 sums; the probabilities in two bf16 pieces): the chunk's rows
+    read what the float32 kernel reads of the same values, to bf16's own
+    rounding of the output."""
+    from crowdllama_tpu.ops.pallas.paged import flash_ragged_paged_attention
+
+    b, h, hkv, dh, page = 2, 12, 2, 16, 32
+    c, ctx = 40, 50
+    q, pool_k, pool_v = (
+        jax.random.normal(k, shape).astype(jnp.bfloat16)
+        for k, shape in zip(jax.random.split(jax.random.PRNGKey(8), 3),
+                            ((b + c, h, dh), (1, 9, hkv, page, dh),
+                             (1, 9, hkv, page, dh))))
+    table = jnp.asarray([[1, 2, 3, 4], [5, 6, 7, 8]], jnp.int32)
+    args = (0, table, jnp.asarray([1, 0, c], jnp.int32),
+            jnp.asarray([70, 0, ctx + c], jnp.int32), jnp.int32(1),
+            dh ** -0.5)
+    got = flash_ragged_paged_attention(q, pool_k, pool_v, *args)
+    want = flash_ragged_paged_attention(*(
+        a.astype(jnp.float32) for a in (q, pool_k, pool_v)), *args)
+    rows = np.r_[0, b:b + c]
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32)[rows], np.asarray(want)[rows],
+        rtol=1e-2, atol=1e-2)
+
+
 @pytest.mark.parametrize("softcap,window", [(0.0, 0), (30.0, 0), (0.0, 9)])
 def test_ragged_v2_matches_reference(softcap, window):
     """Ragged-paged attention v2 (ONE kernel, head-packed query blocks,

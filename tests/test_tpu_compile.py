@@ -289,13 +289,14 @@ def mistral_runner(one_chip, monkeypatch):
     return build
 
 
-def _assert_pool_stays_in_place(compiled, kv):
+def _assert_pool_stays_in_place(compiled, kv, kernels=1):
     """The step updates the donated pool where it lies: no temporary as
     large as ONE layer's K+V slice, no ``copy`` whose result has the shape
     of the pool, of a layer's slice of it or of the scales, and one
-    attention kernel per layer per step (the benchmark's readers divide
-    the traced custom calls by the layers to count steps; the layer and
-    step loops are rolled, so that is one call site in the text)."""
+    attention kernel per layer per DECODE step (the benchmark's readers
+    divide the traced custom calls by the layers to count steps; the layer
+    and step loops are rolled, so that is one call site in the text) — two
+    in a ragged step: the decode rows' and the chunk's."""
     itemsize = 1 if kv == "int8" else 2
     layer_kv = 2 * POOL_PAGES * HKV * PAGE * DH * itemsize
     ma = compiled.memory_analysis()
@@ -310,7 +311,7 @@ def _assert_pool_stays_in_place(compiled, kv):
     for line in text.splitlines():
         head = line.split(" copy(")[0] if " copy(" in line else ""
         assert pool_dims not in head, f"pool-shaped copy: {line.strip()[:200]}"
-    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == kernels
 
 
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
@@ -337,7 +338,7 @@ def test_ragged_step_program_keeps_the_pool_in_place(mistral_runner, one_chip,
         r._ragged_step_impl, donate_argnums=(1,), static_argnums=(7,)
     ).lower(params, state, table, i32(1, CHUNK), i32(1), i32(), i32(),
             1).compile()
-    _assert_pool_stays_in_place(compiled, kv)
+    _assert_pool_stays_in_place(compiled, kv, kernels=2)
 
 
 def _lowered(r, params, state, table, one_chip, program: str):
@@ -962,7 +963,10 @@ def test_kimi_ragged_step_program_compiles_with_its_state_in_place(
     text = compiled.as_text()
     assert len(_calls(text, "kda_update")) == 20
     assert len(_calls(text, "moe_grouped_matmul")) == 78
-    assert not _calls(text, "paged_decode_attention")
+    # the decode rows beside the chunk: the GQA decode kernel on the one
+    # latent pool (key and value both), not the decode STEP's _mla kernel
+    assert len(_calls(text, "paged_decode_attention")) == 7
+    assert not _calls(text, "paged_decode_attention_mla")
 
 
 def test_kda_update_kernel_compiles_at_the_cell_shape(one_chip):
@@ -1051,3 +1055,112 @@ def test_list_of_layers_models_are_placed_as_they_were(request, one_chip,
         _lower_program(r, p, state, table, one_chip, "decode")
         for p in (params, plain))
     assert with_rule == without
+
+
+# ---- family afmoe: window and full layers on a cache of two kinds of page
+
+@pytest.fixture
+def trinity_runner(one_chip, monkeypatch, tmp_path, expert_kernel):
+    """``() -> (runner, params, state, page table)``: the hybrid runner at
+    the widths, depth and share of ``trinity-large-p1-ep8-int8`` (the
+    benchmark's configuration file, read as the worker reads it), int8, 32
+    slots, the served context (5120), built from shapes alone."""
+    import json
+    from pathlib import Path
+
+    from crowdllama_tpu.engine import runner as runner_mod
+    from crowdllama_tpu.engine.hybrid import HybridPagedModelRunner
+    from crowdllama_tpu.engine.weights import resolve_model_config
+    from crowdllama_tpu.ops.quant import random_quantized_params
+
+    monkeypatch.setattr(runner_mod, "shard_params", lambda p, cfg, mesh: p)
+    doc = json.loads((Path(__file__).resolve().parents[1] / "benchmarks"
+                      / "chip" / "configs"
+                      / "trinity-large-p1-ep8-int8.json").read_text())
+    slots, ctx = doc["bench"]["slots"], doc["bench"]["context"]
+    (tmp_path / "config.json").write_text(json.dumps(
+        {k: v for k, v in doc.items() if k != "bench"}))
+
+    def build():
+        cfg = resolve_model_config(doc["bench"]["name"], str(tmp_path),
+                                   max_context_length=ctx)
+        shapes = jax.eval_shape(lambda: random_quantized_params(
+            cfg, jax.random.PRNGKey(0), dtype=jnp.bfloat16))
+        r = HybridPagedModelRunner(cfg, params=shapes, mesh_spec="1x1",
+                                   max_slots=slots, max_seq=ctx,
+                                   page_size=PAGE)
+        r.attention_paths = {**r.attention_paths, "decode": "pallas",
+                             "ragged_step": "pallas"}
+        table = _sds((slots, ctx // PAGE), jnp.int32, one_chip)
+        return (r, _placed(shapes, one_chip),
+                _on_chip(jax.eval_shape(r.init_state), one_chip), table)
+
+    return build
+
+
+@pytest.mark.parametrize("steps", [1, 2])
+def test_trinity_decode_program_keeps_both_pools_in_place(trinity_runner,
+                                                          steps):
+    """All five layers of the cut in one step program: the full layer's
+    pool grows with the context (40 pages a slot), the four window layers'
+    does not (a ring of 37: 4096 + 512 + 128 tokens), both are handed back
+    where they lay; four ``paged_decode_attention_window`` calls a step and
+    one ``paged_decode_attention`` — six query heads a kv head through the
+    Pallas kernel, no fall to XLA; twelve grouped matmuls; the temporaries
+    under a tenth of the weights."""
+    r, params, state, table = trinity_runner()
+    assert r.ring == (37, 4096) and r.attn_decode_path == "gqa+window"
+    assert state.pool_k.shape == (1, 32 * 40 + 1, 8, PAGE, 128)
+    assert state.wpool_k.shape == (4, 32 * 37 + 1, 8, PAGE, 128)
+    compiled = jax.jit(
+        r._decode_paged_impl, donate_argnums=(1,), static_argnums=(3,)
+    ).lower(params, state, table, steps).compile()
+    ma = compiled.memory_analysis()
+    kept = sum(a.size * a.dtype.itemsize for a in (
+        state.pool_k, state.pool_v, state.wpool_k, state.wpool_v))
+    assert 3.14e9 < kept < 3.17e9
+    assert ma.alias_size_in_bytes >= kept, (ma.alias_size_in_bytes, kept)
+    weights = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree_util.tree_leaves(params))
+    assert 4.39e9 < weights < 4.42e9 and ma.temp_size_in_bytes < weights // 10
+    text = compiled.as_text()
+    assert len(_calls(text, "paged_decode_attention_window")) == 4
+    assert len(_calls(text, "paged_decode_attention")) == 5   # both names
+    assert len(_calls(text, "moe_grouped_matmul")) == 12
+    # a window layer's call takes a table of the pages its window reaches
+    for ln in _calls(text, "paged_decode_attention_window"):
+        assert "s32[32,33]" in ln and "bf16[4,1185,8,128,128]" in ln
+    for ln in text.splitlines():
+        if any(f" {op}(" in ln for op in ("fusion", "copy", "copy-start")):
+            head = ln.split(" = ")[1][:60] if " = " in ln else ""
+            assert "[4,1185,8,128,128]" not in head, ln.strip()[:200]
+            assert "[1,1281,8,128,128]" not in head, ln.strip()[:200]
+
+
+@pytest.mark.parametrize("steps", [1, 2])
+def test_trinity_ragged_step_program_compiles_with_both_pools_in_place(
+        trinity_runner, one_chip, steps):
+    """Decode rows beside a 512-token chunk, the program EVERY admission of
+    the long-prompt cell takes: the v2 ragged kernel over the full pool and,
+    under its own name, over each block's own view of its slot's ring."""
+    r, params, state, table = trinity_runner()
+    assert r.ragged_chunk == CHUNK
+
+    def i32(*shape):
+        return _sds(shape, jnp.int32, one_chip)
+
+    compiled = jax.jit(
+        r._ragged_step_impl, donate_argnums=(1,), static_argnums=(7,)
+    ).lower(params, state, table, i32(steps, CHUNK), i32(steps), i32(),
+            i32(), steps).compile()
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= sum(
+        a.size * a.dtype.itemsize for a in (
+            state.pool_k, state.pool_v, state.wpool_k, state.wpool_v))
+    text = compiled.as_text()
+    assert len(_calls(text, "ragged_paged_attention_window")) == 4
+    assert len(_calls(text, "ragged_paged_attention")) == 5
+    # the decode rows beside the chunk take the decode kernels
+    assert len(_calls(text, "paged_decode_attention_window")) == 4
+    assert len(_calls(text, "paged_decode_attention")) == 5
+    assert len(_calls(text, "moe_grouped_matmul")) == 12
