@@ -41,7 +41,7 @@ from __future__ import annotations
 import logging
 import math
 from dataclasses import dataclass, replace
-from functools import partial
+from functools import cached_property, partial
 
 import jax
 import jax.numpy as jnp
@@ -63,6 +63,8 @@ from crowdllama_tpu.ops.attention import decode_attention, decode_attention_q
 from crowdllama_tpu.ops.pallas.megastep import (run_decode_megastep,
                                                 run_ragged_megastep)
 from crowdllama_tpu.ops.pallas.paged import (
+    decode_grid_steps,
+    decode_work,
     flash_paged_decode_attention,
     flash_paged_decode_attention_tp,
     paged_decode_attention_mla,
@@ -222,6 +224,8 @@ class PagedModelRunner(ModelRunner):
     #: the unified programs' page table has one width, not
     #: :meth:`_ragged_window`'s power of two that grows with the slots
     ragged_width_fixed = False
+    #: a model's window layers' :class:`Ring` (engine/hybrid.py), or None
+    ring = None
 
     def __init__(self, cfg, *args, page_size: int = 128, pool_tokens: int = 0,
                  prefix_cache: bool = True, step_token_budget: int = 0,
@@ -784,6 +788,23 @@ class PagedModelRunner(ModelRunner):
             tokens=next_tokens, recent=recent, keys=carry,
             **changed), next_tokens
 
+    def _decode_views(self, st: PagedDecodeState, page_table, lens,
+                      listed: bool) -> dict:
+        """What a step's decode rows read, by the layer's ring (``None``: the
+        full pool): (page table, ``lens`` counted from its first column, the
+        GQA decode kernel's grid of live page pairs — ``listed`` — or None).
+        Built ONCE a step, before the layer loop: the lengths are every
+        layer's, and a list a layer would be a handful of small device ops
+        a layer."""
+        views = {None: (st.pool_k, page_table, lens)}
+        if self.ring is not None:
+            views[self.ring] = (
+                st.wpool_k, *self.ring.decode_view(lens, self.page_size))
+        return {ring: (table, klens,
+                       decode_work(pool, table, klens, st.active)
+                       if listed else None)
+                for ring, (pool, table, klens) in views.items()}
+
     def _paged_step_body(self, params, page_table):
         """One paged decode step as a ``lax.scan`` body closure — shared
         verbatim by the per-step program (``_decode_paged_impl``) and the
@@ -818,11 +839,16 @@ class PagedModelRunner(ModelRunner):
                                  page_table[slot_idx, positions // pg],
                                  self.total_pages)  # [B]
             offset = positions % pg
+            # the MLA kernel and the shard_map wrapper take no list
+            views = self._decode_views(
+                st, page_table, lens,
+                listed=use_kernel and not sharded and st.pool_v is not None)
 
             def attend(pools, window, li, ring=None):
                 pk, pv, ksc, vsc = pools
                 after = {}
-                pages, table, klens = cur_page, page_table, lens
+                pages = cur_page
+                table, klens, work = views[ring]
                 name = "paged_decode_attention"
                 if ring is not None:
                     # a window layer: its own pool, a ring of it a slot,
@@ -830,7 +856,6 @@ class PagedModelRunner(ModelRunner):
                     pages = jnp.where(
                         st.active, ring.page_of(slot_idx, positions // pg),
                         pk.shape[1] - 1)
-                    table, klens = ring.decode_view(lens, pg)
                     window = ring.window
                     name += "_window"
 
@@ -866,7 +891,7 @@ class PagedModelRunner(ModelRunner):
                             q, pk2, pv2, li, table, klens, scale,
                             softcap=cfg.attn_logit_softcap,
                             sliding_window=window,
-                            k_scale=ks2, v_scale=vs2, name=name)
+                            k_scale=ks2, v_scale=vs2, name=name, work=work)
                     # Virtual-contiguous view of each slot's pages.
                     kc = pk2[li, table].transpose(
                         0, 2, 1, 3, 4).reshape(b, hkv, view_len, dh)
@@ -961,6 +986,8 @@ class PagedModelRunner(ModelRunner):
             kv_lens = jnp.concatenate([
                 lens_dec.astype(jnp.int32),
                 (ctx_i + valid).astype(jnp.int32)[None]])
+            views = self._decode_views(st, page_table, lens_dec,
+                                       listed=use_pallas)
 
             def attend(pools, window, li, ring=None):
                 pk, pv, ksc, vsc = pools
@@ -1006,7 +1033,8 @@ class PagedModelRunner(ModelRunner):
                         q_lens, kv_lens, chunk_slot, scale,
                         softcap=cfg.attn_logit_softcap,
                         sliding_window=window, k_scale=ks2, v_scale=vs2,
-                        use_pallas=use_pallas, ring=ring)
+                        use_pallas=use_pallas, ring=ring,
+                        work=views[ring][2])
 
                 return attn_fn, after
 
@@ -1244,6 +1272,50 @@ class PagedModelRunner(ModelRunner):
                 continue
             self._ensure_slot(slot, steps)
 
+    @cached_property
+    def _pool_shard(self) -> jax.ShapeDtypeStruct:
+        """One page of one layer of the pool as one device holds it: what
+        the decode kernel sizes its grid steps from."""
+        from crowdllama_tpu.parallel.mesh import AXIS_TP
+
+        return jax.ShapeDtypeStruct(
+            (1, 1, self.cfg.num_kv_heads // self.mesh.shape.get(AXIS_TP, 1),
+             self.page_size, self.cfg.resolved_head_dim()),
+            jnp.int8 if self.kv_dtype == "int8" else self.dtype)
+
+    def _advance(self, num_steps: int, ragged_cols: int = 0) -> None:
+        """Dispatch-time host bookkeeping of a flight's decode rows: every
+        decoding slot (all but a ragged job's) is ``num_steps`` tokens
+        longer — and, from those lengths alone (no device read), what the
+        GQA decode kernel's calls of the flight walk and what the
+        rectangular grid would have (crowdllama_attn_grid_steps_total;
+        ``ragged_cols``: the flight is a ragged one, over a table that
+        wide).  The plain step of a latent model calls the MLA kernel, a
+        rectangle still: nothing to book."""
+        slots = [s for s in self._slot_pages if s != self._ragged_slot]
+        path = self.attention_paths["ragged_step" if ragged_cols
+                                    else "decode"]
+        if path != "jnp" and (ragged_cols or not self.cfg.kv_lora_rank):
+            pg = self.page_size
+            # the kernel's lengths count the pending token
+            lens = np.minimum(
+                self._host_seq[slots][None, :]
+                + np.arange(1, num_steps + 1)[:, None], self.max_seq)
+            kinds = {"full": (self.pool_layers, lens,
+                              ragged_cols or self.max_pages_per_slot)}
+            if self.ring is not None:
+                kinds["window"] = (self.kv_layers - self.pool_layers,
+                                   self.ring.decode_lens(lens, pg),
+                                   self.ring.cols(1, pg))
+            for kind, (layers, klens, cols) in kinds.items():
+                live, rectangle = decode_grid_steps(
+                    self._pool_shard, klens, cols, self.max_slots)
+                ENGINE_TELEMETRY.attn_grid_steps_inc(
+                    kind, layers * live, layers * rectangle)
+        for s in slots:
+            self._host_seq[s] = min(self._host_seq[s] + num_steps,
+                                    self.max_seq)
+
     def decode_steps(self, state: PagedDecodeState, num_steps: int = 1):
         tokens, new_state = self.decode_steps_device(state, num_steps)
         return np.asarray(tokens), new_state
@@ -1258,11 +1330,7 @@ class PagedModelRunner(ModelRunner):
         tokens, new_state = self._decode_paged(
             self.params, state, jnp.asarray(self.page_table), num_steps)
         ENGINE_TELEMETRY.compile_end("decode_paged", num_steps, t_c)
-        for slot in self._slot_pages:
-            if slot == self._ragged_slot:
-                continue
-            self._host_seq[slot] = min(self._host_seq[slot] + num_steps,
-                                       self.max_seq)
+        self._advance(num_steps)
         return tokens, new_state
 
     def decode_megastep(self, state: PagedDecodeState, num_steps: int,
@@ -1280,11 +1348,7 @@ class PagedModelRunner(ModelRunner):
             self.params, state, jnp.asarray(self.page_table),
             eos_ids, budgets, num_steps)
         ENGINE_TELEMETRY.compile_end("decode_megastep_paged", num_steps, t_c)
-        for slot in self._slot_pages:
-            if slot == self._ragged_slot:
-                continue
-            self._host_seq[slot] = min(self._host_seq[slot] + num_steps,
-                                       self.max_seq)
+        self._advance(num_steps)
         return tokens, done, new_state
 
     # ----------------------- unified ragged batch (docs/RAGGED_BATCH.md)
@@ -1414,7 +1478,7 @@ class PagedModelRunner(ModelRunner):
         return chunk_tokens, ctx_arr, end, self._ragged_window()
 
     def _ragged_commit(self, job: "RaggedPrefillJob", end: int,
-                       num_steps: int, last) -> None:
+                       num_steps: int, last, wp: int) -> None:
         """Post-dispatch host bookkeeping shared by both unified entry
         points: bank the dispatch-end progress and the final prompt
         token's logits, advance every slot's host sequence mirror, and
@@ -1422,10 +1486,7 @@ class PagedModelRunner(ModelRunner):
         job.done_tokens = end
         job.last_logits = last
         self._host_seq[job.slot] = end
-        for s in self._slot_pages:
-            if s != job.slot:
-                self._host_seq[s] = min(self._host_seq[s] + num_steps,
-                                        self.max_seq)
+        self._advance(num_steps, ragged_cols=wp)
         self._ragged_index(job)
 
     def ragged_step(self, state: PagedDecodeState, job: "RaggedPrefillJob",
@@ -1448,7 +1509,7 @@ class PagedModelRunner(ModelRunner):
             jnp.asarray(chunk_tokens), jnp.asarray(ctx_arr),
             jnp.int32(len(job.prompt_ids)), jnp.int32(job.slot), num_steps)
         ENGINE_TELEMETRY.compile_end("ragged_step", sig, t_c)
-        self._ragged_commit(job, end, num_steps, last)
+        self._ragged_commit(job, end, num_steps, last, wp)
         return tokens, new_state
 
     def ragged_megastep(self, state: PagedDecodeState,
@@ -1480,7 +1541,7 @@ class PagedModelRunner(ModelRunner):
             jnp.int32(len(job.prompt_ids)), jnp.int32(job.slot),
             eos_ids, budgets, num_steps)
         ENGINE_TELEMETRY.compile_end("ragged_megastep", sig, t_c)
-        self._ragged_commit(job, end, num_steps, last)
+        self._ragged_commit(job, end, num_steps, last, wp)
         return tokens, done, new_state
 
     def _ragged_index(self, job: "RaggedPrefillJob") -> None:

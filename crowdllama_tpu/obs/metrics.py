@@ -446,6 +446,13 @@ class EngineTelemetry:
         # from HBM whichever — counted and read back the same way.
         self._moe_banks = {cls: {"routed": 0, "unrouted": 0, "fetched": 0}
                            for cls in FLIGHT_CLASSES}
+        # Grid steps of the GQA decode attention kernel (ops/pallas/paged.py),
+        # by the kind of layer ("full" pool | "window" ring): those its
+        # calls walked — one a live (slot, page pair) — and those of the
+        # slots x table rectangle they would have walked without the list;
+        # booked at dispatch from the lengths the runner holds on the host.
+        self._attn_grid_steps = {kind: {"live": 0, "rectangle": 0}
+                                 for kind in ("full", "window")}
         # Bytes of each kind of per-request state the newest runner's
         # init_state allocated (engine/hybrid.py: KV pool, state-space
         # state, convolution tail).
@@ -601,6 +608,13 @@ class EngineTelemetry:
             by["unrouted"] += banks - routed
             by["fetched"] += fetched
 
+    def attn_grid_steps_inc(self, kind: str, live: int,
+                            rectangle: int) -> None:
+        with self._lock:
+            by = self._attn_grid_steps[kind]
+            by["live"] += max(0, int(live))
+            by["rectangle"] += max(0, int(rectangle))
+
     def state_bytes_set(self, by_kind: dict[str, int]) -> None:
         with self._lock:
             self._state_bytes = {k: int(v) for k, v in by_kind.items()}
@@ -648,6 +662,8 @@ class EngineTelemetry:
             admissions = dict(self._admissions)
             flights = dict(self._flights)
             state_bytes = sorted(self._state_bytes.items())
+            grid_steps = {kind: dict(by)
+                          for kind, by in self._attn_grid_steps.items()}
         out.append("# TYPE crowdllama_engine_attention_path gauge")
         if not attention:
             out.append('crowdllama_engine_attention_path{program="none",'
@@ -724,6 +740,11 @@ class EngineTelemetry:
         for cls in FLIGHT_CLASSES:
             out.append(f'crowdllama_moe_banks_fetched_total{{dispatch="{cls}"'
                        f'}} {moe_banks[cls]["fetched"]}')
+        out.append("# TYPE crowdllama_attn_grid_steps_total counter")
+        for kind, by in grid_steps.items():
+            for walk, n in by.items():
+                out.append(f'crowdllama_attn_grid_steps_total{{kind="{kind}",'
+                           f'walk="{walk}"}} {n}')
         out.append("# TYPE crowdllama_engine_state_bytes gauge")
         if not state_bytes:
             out.append('crowdllama_engine_state_bytes{kind="none"} 0')

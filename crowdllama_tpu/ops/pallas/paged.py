@@ -5,15 +5,24 @@ The jnp paged path (engine/paged.py round 2) materialized a
 ``pool[page_table]`` view per layer — [B, max_pages, Hkv, page, Dh] of HBM
 traffic and scratch for what should be a streaming read (VERDICT r2
 missing #3; PAPERS.md names ragged paged attention as the TPU north star).
-Here the page table is a scalar-prefetch operand, so each (batch,
-page-PAIR) grid step DMAs up to two [Hkv, page, Dh] K tiles and two V
-tiles straight from the slot's pages in the pool — all kv heads at
-once, and two pages per step when VMEM allows, keeping the sequential
-grid short (ceil(NP/pairs); serving-shape per-page compute is tiny, so
-grid bubbles, not bytes, set the kernel's speed); online softmax
-carries (m, l, acc) in VMEM scratch across the sequential innermost
-grid dimension.  HBM traffic is one read of the LIVE pages (dead pages
-are compute-skipped) and one [Hkv, G, Dh] output write per slot.
+Here the page table is a scalar-prefetch operand, so each grid step DMAs
+up to two [Hkv, page, Dh] K tiles and two V tiles straight from a slot's
+pages in the pool — all kv heads at once, and two pages per step when VMEM
+allows (serving-shape per-page compute is tiny, so grid bubbles, not bytes,
+set the kernel's speed); online softmax carries (m, l, acc) in VMEM scratch
+from a slot's first step to its last.  The GQA decode kernel's grid is ONE
+sequential dimension over a list of the LIVE (slot, page pair) entries
+(:class:`DecodeWork`, built once a step on the device from the lengths and
+the page table, each entry with its tiles' pool pages already looked up; its
+length is the grid's run-time bound), so a page pair past a slot's length
+costs no grid step at all — on a rectangular (slot, table column pair) grid
+a dead step skipped its compute and still cost ~0.3 us, 44% of a call at
+eight short contexts on a 16-column table (PERF.md section 6, PR 44) — and
+a slot with no tenant none: no step visits its output row, which aliases
+its query row and keeps it.  The latent (MLA) decode kernel and the ragged
+chunk kernel keep rectangular grids: dead pages are compute-skipped there.
+HBM traffic is one read of the live pages and one [Hkv, G, Dh] output write
+per live slot.
 
 Every kernel takes the WHOLE stacked pool ``[L, P, Hkv, page, Dh]`` and a
 layer index (one more scalar-prefetch operand; the index maps return
@@ -50,6 +59,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -81,50 +91,67 @@ class Ring(NamedTuple):
     def page_of(self, slot, logical):
         return slot * self.pages + logical % self.pages
 
+    def cols(self, span: int, page: int) -> int:
+        """Pages a view of ``span`` consecutive queries reaches."""
+        return -(-(self.window + max(span, 1) - 2) // page) + 1
+
+    def first_page(self, q_start, page: int):
+        """The logical page the oldest key a query at ``q_start`` sees lies
+        in (an array of jax's or, on the host, of numpy's)."""
+        return (q_start - self.window + 1).clip(0) // page
+
     def view(self, slots, q_start, span: int, page: int):
         """For queries at positions ``q_start .. q_start + span - 1`` of
         ``slots`` (both ``[N]``): (first ``[N]`` — the logical page the
         oldest key any of them sees lies in, table ``[N, cols]`` — that page
         and the ``cols - 1`` after it, as pool pages)."""
-        cols = -(-(self.window + max(span, 1) - 2) // page) + 1
+        cols = self.cols(span, page)
         assert cols <= self.pages, (self, span, page)
-        first = jnp.maximum(q_start - self.window + 1, 0) // page
+        first = self.first_page(q_start, page)
         logical = first[:, None] + jnp.arange(cols, dtype=jnp.int32)[None, :]
         return first, self.page_of(slots[:, None], logical).astype(jnp.int32)
+
+    def decode_lens(self, lens, page: int):
+        """``lens`` counted from the first page of each slot's decode view."""
+        return lens - self.first_page(lens - 1, page) * page
 
     def decode_view(self, lens, page: int):
         """For every slot's one decode query, the newest of its ``lens``
         tokens: (table ``[B, cols]``, lengths counted from its first page)."""
         slots = jnp.arange(lens.shape[0], dtype=jnp.int32)
-        first, table = self.view(slots, lens - 1, 1, page)
-        return table, lens - first * page
+        _, table = self.view(slots, lens - 1, 1, page)
+        return table, self.decode_lens(lens, page)
 
 
 def _pairs_bytes(hkv: int, page: int, dh: int, itemsize: int) -> int:
     return 2 * hkv * page * dh * itemsize  # one page's K + V tiles
 
 
-def _page_stream(pool_k, pool_v, k_scale, v_scale, np_: int, page_of):
-    """BlockSpecs + operands that stream ONE layer's pages out of the
-    stacked pool, for a grid whose LAST dimension walks a page-table row.
+def _pairs(pool, np_: int) -> int:
+    """Pages one sequential grid step fetches of a table ``np_`` columns
+    wide: two when the VMEM budget allows (tiles are double-buffered) — the
+    grid is bubble-bound at serving shapes, so halving its length is nearly
+    free bandwidth."""
+    _, _, hkv, page, dh = pool.shape
+    return 2 if (np_ >= 2 and 4 * _pairs_bytes(
+        hkv, page, dh, pool.dtype.itemsize) <= _VMEM_TILE_BUDGET) else 1
 
-    ``page_of(grid_i, idx, *scalar_refs)`` names the pool page a grid row
-    reads at table column ``idx``; the layer index is the last scalar-
-    prefetch operand.  Pages are fetched in pairs per sequential grid step
-    when the VMEM budget allows (tiles are double-buffered) — the grid is
-    bubble-bound at serving shapes, so halving its length is nearly free
-    bandwidth.  The tail pair index clamps to the last page; its compute
-    is skipped by the kernels' length bound.  Returns ``(pairs, steps,
-    in_specs, operands)``."""
+
+def _page_stream(pool_k, pool_v, k_scale, v_scale, pairs: int, page_at):
+    """BlockSpecs + operands that stream ONE layer's pages out of the
+    stacked pool, ``pairs`` (:func:`_pairs`) of them a sequential grid step.
+
+    ``page_at(j, *grid indices, *scalar refs)`` names the pool page a grid
+    step's ``j``-th tile reads (a step past a row's last page names any real
+    page: its compute is skipped by the kernels' length bound); the layer
+    index is the last scalar-prefetch operand.  Returns ``(in_specs,
+    operands)``."""
     _, _, hkv, page, dh = pool_k.shape
-    pairs = 2 if (np_ >= 2 and 4 * _pairs_bytes(
-        hkv, page, dh, pool_k.dtype.itemsize) <= _VMEM_TILE_BUDGET) else 1
 
     # Index maps receive (grid indices..., *scalar-prefetch refs).
     def kv_map_at(j, tail):
-        def kv_map(gi, pi, *refs):
-            idx = jnp.minimum(pi * pairs + j, np_ - 1)
-            return (refs[-1][0], page_of(gi, idx, *refs), *tail)
+        def kv_map(*args):
+            return (args[-1][0], page_at(j, *args), *tail)
         return kv_map
 
     in_specs, operands = [], []
@@ -142,7 +169,7 @@ def _page_stream(pool_k, pool_v, k_scale, v_scale, np_: int, page_of):
             in_specs += [pl.BlockSpec((None, None, hkv, page),
                                       kv_map_at(j, (0, 0)))] * 2
             operands += [k_scale, v_scale]
-    return pairs, -(-np_ // pairs), in_specs, operands
+    return in_specs, operands
 
 
 def _layer_operand(layer) -> jnp.ndarray:
@@ -204,32 +231,97 @@ def paged_pallas_supported(page_size: int, head_dim: int,
                                     num_kv_heads, itemsize, quant)
 
 
+class DecodeWork(NamedTuple):
+    """The grid the GQA decode kernel walks: one entry a LIVE (slot, page
+    pair), sorted by slot and, within a slot, by pair — so a slot's first
+    entry is its pair 0 and its last the pair its length ends in — and, for
+    each entry, the pool pages of its tiles, looked up in the page table
+    HERE, once a step: a grid step's index maps then read a page where they
+    chased slot -> table column -> page.  ``slot``, ``pair`` and each of
+    ``pages`` have the static size ``slots x steps`` of the whole rectangle
+    and hold slot 0 and pair ``steps`` past ``total``, a pair of no table
+    that computes nothing; ``total`` (``[1]``) is the kernel's run-time grid
+    bound, and never under 1: a list of no entries is walked for one such
+    pad, which hands slot 0 its query back.  Lengths and table are the same
+    for every layer of a step, so the engine builds one a step (and a second
+    for its window layers' ring view) and hands it to every layer's call."""
+
+    slot: jnp.ndarray
+    pair: jnp.ndarray
+    pages: tuple[jnp.ndarray, ...]
+    total: jnp.ndarray
+
+
+@jax.named_scope("decode_work")
+def decode_work(pool, page_table, seq_lens, live=None) -> DecodeWork:
+    """:class:`DecodeWork` for ``seq_lens [B]`` (the kernel's own, counted
+    from the first column of ``page_table [B, cols]`` over ``pool``): slot
+    ``b`` gets ``ceil(seq_lens[b] / (page x pairs))`` entries — none where
+    its length is 0 or ``live[b]`` is false.  Such a slot's output row is
+    never written: it keeps its query (:func:`flash_paged_decode_attention`)."""
+    page, cols = pool.shape[3], page_table.shape[1]
+    pairs = _pairs(pool, cols)
+    steps = -(-cols // pairs)
+    lens = jnp.clip(seq_lens.astype(jnp.int32), 0, cols * page)
+    n = -(-lens // (page * pairs))
+    if live is not None:
+        n = jnp.where(live, n, 0)
+    ends = jnp.cumsum(n)
+    i = jnp.arange(lens.shape[0] * steps, dtype=jnp.int32)
+    inside = i < ends[-1]
+    # an entry's slot: how many slots' entries end at or before it
+    slot = jnp.where(inside, jnp.sum(i[:, None] >= ends[None, :], axis=1), 0)
+    pair = jnp.where(inside, i - (ends - n)[slot], steps)
+    table = page_table.astype(jnp.int32)
+    # the tail pair's second column clamps to the last (its compute skipped)
+    pages = tuple(table[slot, jnp.minimum(pair * pairs + j, cols - 1)]
+                  for j in range(pairs))
+    return DecodeWork(slot.astype(jnp.int32), pair.astype(jnp.int32), pages,
+                      jnp.maximum(ends[-1:], 1).astype(jnp.int32))
+
+
+def decode_grid_steps(pool, seq_lens, cols: int, slots: int) -> tuple[int, int]:
+    """On the host, for ``seq_lens [calls, live slots]`` (numpy) as
+    :func:`decode_work` would be handed them call by call: (grid steps the
+    kernel walks over those calls, grid steps of the ``slots x steps``
+    rectangle it walked before it had a list)."""
+    page = pool.shape[3]
+    pairs = _pairs(pool, cols)
+    n = -(-np.clip(seq_lens, 0, cols * page) // (page * pairs))
+    return (int(np.maximum(n.sum(axis=-1), 1).sum()),
+            seq_lens.shape[0] * slots * -(-cols // pairs))
+
+
 def _decode_kernel(
     # scalar prefetch
-    table_ref,    # [B, NP] int32 — page table
     seqlen_ref,   # [B] int32 — valid positions incl. the pending token
     window_ref,   # [1] int32 — sliding window (<=0 disables)
-    layer_ref,    # [1] int32 — pool layer (read by the index maps only)
-    # operands: q, then PAIRS x (k, v), then PAIRS x (ks, vs) if quant;
+    slot_ref,     # [B * steps] int32 — DecodeWork.slot
+    pair_ref,     # [B * steps] int32 — DecodeWork.pair
+    # then PAIRS x DecodeWork.pages and the [1] pool layer (both read by the
+    # index maps only); operands: q [Hkv, G, Dh] — ALL kv heads of this
+    # entry's slot — then PAIRS x (k, v), then PAIRS x (ks, vs) if quant;
     # output + scratch trail (pallas passes refs positionally).
-    q_ref,        # [Hkv, G, Dh] — ALL kv heads of this slot
     *refs,
     scale: float,
     softcap: float,
     page: int,
     pairs: int,
+    np_: int,
     quant: bool,
 ):
+    q_ref, *refs = refs[pairs + 1:]
     kv = refs[: 2 * pairs]                    # [Hkv, page, Dh] tiles
     scs = refs[2 * pairs: 4 * pairs] if quant else ()
     o_ref, acc_ref, m_ref, l_ref = refs[-4:]
 
-    b = pl.program_id(0)
-    p = pl.program_id(1)
-    num_steps = pl.num_programs(1)
-    seq_len = seqlen_ref[b]
+    i = pl.program_id(0)
+    p = pair_ref[i]
+    seq_len = seqlen_ref[slot_ref[i]]
     window = window_ref[0]
 
+    # Entries are sorted by slot, then pair: the carry starts at a slot's
+    # pair 0 and its row is written at the pair its length ends in.
     @pl.when(p == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
@@ -289,11 +381,20 @@ def _decode_kernel(
     for j in range(pairs):
         _tile(j)
 
-    @pl.when(p == num_steps - 1)
+    span = pairs * page
+    end = jnp.minimum(seq_len, np_ * page)
+
+    @pl.when((p * span < end) & ((p + 1) * span >= end))
     def _finalize():
         l = l_ref[:, :, :1]
         l = jnp.where(l == 0.0, 1.0, l)
         o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+    # Every step writes its output block back, so the pad entry an empty
+    # list is walked for (DecodeWork) must fill it: with the row's own query.
+    @pl.when(p * pairs >= np_)
+    def _keep():
+        o_ref[...] = q_ref[...]
 
 
 def flash_paged_decode_attention(
@@ -309,9 +410,18 @@ def flash_paged_decode_attention(
     k_scale: jnp.ndarray | None = None,  # [L, P, Hkv, page] int8 pools only
     v_scale: jnp.ndarray | None = None,
     name: str = "paged_decode_attention",  # the call's name in a trace
+    work: DecodeWork | None = None,
 ) -> jnp.ndarray:
     """One cached decode step over layer ``layer`` of the stacked paged
-    pool; output [B, H, Dh]."""
+    pool; output [B, H, Dh].
+
+    The grid is ONE sequential dimension over ``work``, the live (slot,
+    page pair) entries of ``seq_lens`` (:func:`decode_work` of the same
+    lengths and table width; built here when the caller has none), its
+    length a run-time value: a page pair past a slot's length costs no grid
+    step.  A slot with NO entry (length 0, or not ``live`` in the caller's
+    list) is visited by no step; its output row aliases its query row and
+    keeps it — finite, and no one's answer."""
     b, h, dh = q.shape
     _, _, hkv, page, _ = pool_k.shape
     g = h // hkv
@@ -319,25 +429,29 @@ def flash_paged_decode_attention(
     quant = k_scale is not None
 
     qg = q.reshape(b, hkv, g, dh)
-    table = page_table.astype(jnp.int32)
     seq_lens = seq_lens.astype(jnp.int32)
     window = jnp.asarray(sliding_window, jnp.int32).reshape(1)
+    if work is None:
+        work = decode_work(pool_k, page_table, seq_lens)
+    pairs = _pairs(pool_k, np_)
+    assert len(work.pages) == pairs and work.slot.shape == (
+        b * -(-np_ // pairs),), (work.slot.shape, len(work.pages), b, np_)
 
-    def q_map(bi, pi, *refs):
-        return (bi, 0, 0, 0)
+    def q_map(i, lens, win, slot, *refs):
+        return (slot[i], 0, 0, 0)
 
-    pairs, steps, kv_specs, kv_operands = _page_stream(
-        pool_k, pool_v, k_scale, v_scale, np_,
-        lambda bi, idx, tr, *refs: tr[bi, idx])
+    kv_specs, kv_operands = _page_stream(
+        pool_k, pool_v, k_scale, v_scale, pairs,
+        lambda j, i, lens, win, slot, pair, *pages: pages[j][i])
 
     kernel = functools.partial(
         _decode_kernel,
         scale=scale, softcap=float(softcap or 0.0), page=page,
-        pairs=pairs, quant=quant,
+        pairs=pairs, np_=np_, quant=quant,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(b, steps),
+        num_scalar_prefetch=5 + pairs,
+        grid=(work.total[0],),
         in_specs=[pl.BlockSpec((None, hkv, g, dh), q_map), *kv_specs],
         out_specs=pl.BlockSpec((None, hkv, g, dh), q_map),
         scratch_shapes=[
@@ -350,9 +464,12 @@ def flash_paged_decode_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, dh), q.dtype),
+        # the queries follow the scalars: a row no entry names keeps its query
+        input_output_aliases={5 + pairs: 0},
         interpret=_interpret(),
         name=name,
-    )(table, seq_lens, window, _layer_operand(layer), qg, *kv_operands)
+    )(seq_lens, window, work.slot, work.pair, *work.pages,
+      _layer_operand(layer), qg, *kv_operands)
     return out.reshape(b, h, dh)
 
 
@@ -766,10 +883,13 @@ def flash_ragged_paged_attention(
     k_scale: jnp.ndarray | None = None,
     v_scale: jnp.ndarray | None = None,
     ring: Ring | None = None,
+    work: DecodeWork | None = None,
 ) -> jnp.ndarray:
     """Ragged-paged attention over the mixed batch — B decode sequences +
     one prefill chunk: the decode rows through
-    :func:`flash_paged_decode_attention`, the chunk through the v2 kernel.
+    :func:`flash_paged_decode_attention` (over ``work``, the step's list of
+    their live page pairs; built here from the rows with a query when the
+    caller has none), the chunk through the v2 kernel.
 
     The chunk is one grid of ``ceil(C/QB)`` uniform head-packed query blocks
     whose behavior is driven entirely by a scalar-prefetched ``(q_start,
@@ -802,11 +922,14 @@ def flash_ragged_paged_attention(
     if ring is not None:
         sliding_window = ring.window
         table_dec, lens_dec = ring.decode_view(lens_dec, page)
+    if work is None:
+        work = decode_work(pool_k, table_dec, lens_dec, q_lens[:b] > 0)
     out_dec = flash_paged_decode_attention(
         q[:b], pool_k, pool_v, layer, table_dec, lens_dec, scale,
         softcap=softcap, sliding_window=sliding_window, k_scale=k_scale,
         v_scale=v_scale, name=("paged_decode_attention" if ring is None
-                               else "paged_decode_attention_window"))
+                               else "paged_decode_attention_window"),
+        work=work)
     qc = q[b:].reshape(c, hkv, g, dh).transpose(1, 0, 2, 3)
     if jblocks * qb != c:
         qc = jnp.pad(qc, ((0, 0), (0, jblocks * qb - c), (0, 0), (0, 0)))
@@ -830,9 +953,13 @@ def flash_ragged_paged_attention(
     def q_map(ni, pi, *refs):
         return (ni, 0, 0, 0, 0)
 
-    pairs, steps, kv_specs, kv_operands = _page_stream(
-        pool_k, pool_v, k_scale, v_scale, np_,
-        lambda ni, idx, tr, *refs: tr[ni, idx])
+    pairs = _pairs(pool_k, np_)
+    steps = -(-np_ // pairs)
+    # the tail pair's second column clamps to the last (its compute skipped)
+    kv_specs, kv_operands = _page_stream(
+        pool_k, pool_v, k_scale, v_scale, pairs,
+        lambda j, ni, pi, tr, *refs: tr[
+            ni, jnp.minimum(pi * pairs + j, np_ - 1)])
 
     kernel = functools.partial(
         _ragged_v2_kernel,
@@ -882,6 +1009,7 @@ def ragged_paged_attention(
     v_scale: jnp.ndarray | None = None,
     use_pallas: bool = False,
     ring: Ring | None = None,
+    work: DecodeWork | None = None,
 ) -> jnp.ndarray:
     """Unified ragged batch attention over layer ``layer`` of the stacked
     paged pool.
@@ -903,7 +1031,7 @@ def ragged_paged_attention(
     return flash_ragged_paged_attention(
         q, pool_k, pool_v, layer, page_table, q_lens, kv_lens, chunk_slot,
         scale, softcap=softcap, sliding_window=sliding_window,
-        k_scale=k_scale, v_scale=v_scale, ring=ring)
+        k_scale=k_scale, v_scale=v_scale, ring=ring, work=work)
 
 
 def flash_paged_decode_attention_tp(

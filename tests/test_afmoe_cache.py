@@ -315,3 +315,31 @@ def test_it_is_never_read_as_another_family(tmp_path, change, match):
 
     with pytest.raises(ValueError, match=match):
         resolve_model_config("x", _dir(tmp_path, {**AFMOE, **change}))
+
+
+def test_window_layers_book_their_rings_grid_steps(monkeypatch):
+    """crowdllama_attn_grid_steps_total{kind="window"}: a window layer's
+    decode kernel walks its slot's RING through a table of the pages the
+    window reaches, lengths counted from the page the window starts in —
+    so a long context books what a short one does, and the full layer's
+    list grows with it."""
+    from test_afmoe import admit
+    from test_paged import _grid_steps
+
+    monkeypatch.setenv("CROWDLLAMA_PALLAS_INTERPRET", "1")
+    r = make_runner("float32-kernel", cls=HybridPagedModelRunner)
+    assert r.ring.window == 64 and r.page_size == 32 and r.max_slots == 4
+    st = r.init_state()
+    for slot, n in ((0, 10), (3, 200)):
+        _, st = admit(r, st, slot, prompt_of(n, slot))
+    before = _grid_steps()
+    _, st = r.decode_steps(st, 1)
+    booked = {k: v - before[k] for k, v in _grid_steps().items()}
+    # two pages a grid step.  Window layers (four): a table of 3 columns;
+    # slot 0 reads 11 tokens (one pair), slot 3 the 73 from the page its
+    # window starts in (two).  The full layer: 8 columns; 11 tokens (one
+    # pair) and 201 (four).
+    assert booked == {'{kind="window",walk="live"}': 4 * (1 + 2),
+                      '{kind="window",walk="rectangle"}': 4 * (4 * 2),
+                      '{kind="full",walk="live"}': 1 + 4,
+                      '{kind="full",walk="rectangle"}': 4 * 4}

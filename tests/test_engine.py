@@ -715,6 +715,74 @@ async def test_flight_length_never_changes_the_tokens():
         assert lengths == want, (slots, lengths)
 
 
+@pytest.mark.parametrize("walk", ["rectangle", "gathered"])
+async def test_listed_decode_kernel_never_changes_the_tokens(monkeypatch,
+                                                             walk):
+    """The decode kernel's list of live page pairs changes which grid steps
+    run, never a sum's order within a slot: a mixed batch — a short prompt,
+    a long one admitted in ragged chunks whose context crosses pages, five
+    requests on three slots so that slots are released and re-let mid-run,
+    greedy and sampled — emits, in float32, the tokens it emitted when the
+    kernel walked the whole slots x table rectangle (``rectangle``: the grid
+    before PR 44, every slot listed for every page pair, bit for bit the
+    same sums) and the tokens of the gathered jnp view (``gathered``)."""
+    import jax.numpy as jnp
+    from test_admission_pipeline import _drain
+
+    from crowdllama_tpu.engine import paged
+    from crowdllama_tpu.engine.scheduler import GenRequest, Scheduler
+    from crowdllama_tpu.models.config import get_config
+    from test_pallas import _rectangle
+
+    cfg = get_config("tiny-test", max_context_length=128)
+    long_prompt = [(7 * i + 3) % 500 + 1 for i in range(70)]
+
+    def reqs():
+        return [GenRequest(prompt_ids=[3, 1, 4, 1, 5], max_tokens=9, seed=7,
+                           temperature=0.8, top_p=0.9, eos_id=-1),
+                GenRequest(prompt_ids=long_prompt, max_tokens=40, eos_id=-1),
+                GenRequest(prompt_ids=[9, 9, 8], max_tokens=34, seed=11,
+                           temperature=1.0, top_k=20, eos_id=-1),
+                GenRequest(prompt_ids=long_prompt[:33], max_tokens=12,
+                           eos_id=-1),
+                GenRequest(prompt_ids=[2, 7, 1, 8], max_tokens=21, eos_id=-1)]
+
+    async def serve(kernel: bool, patched: bool):
+        with monkeypatch.context() as m:
+            if kernel:
+                m.setenv("CROWDLLAMA_PALLAS_INTERPRET", "1")
+            if patched:
+                m.setattr(paged, "decode_work", _rectangle)
+            runner = paged.PagedModelRunner(
+                cfg, max_slots=3, max_seq=128, page_size=32, mesh_spec="1",
+                dtype=jnp.float32, step_token_budget=32 + 3)
+            assert runner.ragged_chunk == 32
+            assert (runner.attention_paths["decode"] == "jnp") != kernel
+            chunks, real = [], runner.ragged_step
+
+            def noting(state, job, k=1):
+                chunks.append(k)
+                return real(state, job, k)
+
+            runner.ragged_step = noting
+            sched = Scheduler(runner, decode_chunk=4)
+            sched.start()
+            try:
+                rs = reqs()
+                for r in rs:
+                    await sched.submit(r)
+                out = [(await _drain(r, 300))[0] for r in rs]
+            finally:
+                await sched.stop()
+            assert sum(chunks) >= 3     # 70 tokens, 32 a ragged step
+            return out
+
+    listed = await serve(kernel=True, patched=False)
+    assert [len(t) for t in listed] == [9, 40, 34, 12, 21]
+    was = await serve(kernel=walk == "rectangle", patched=walk == "rectangle")
+    assert listed == was
+
+
 async def test_cancelled_chunked_admission_aborts_runner_job():
     """Cancelling a request mid-chunked-admission must tell the runner the
     job is abandoned (multi-host followers pin the job's KV accumulators

@@ -278,7 +278,8 @@ async def test_gateway_and_worker_metrics_lint():
                         "crowdllama_engine_flights_total",
                         "crowdllama_moe_assignments_total",
                         "crowdllama_moe_banks_total",
-                        "crowdllama_moe_banks_fetched_total"):
+                        "crowdllama_moe_banks_fetched_total",
+                        "crowdllama_attn_grid_steps_total"):
                 assert types.get(fam) == "counter", f"{fam} missing"
             for fam in ("crowdllama_device_memory_bytes_in_use",
                         "crowdllama_device_memory_bytes_limit"):

@@ -5,6 +5,7 @@ accounting, and overcommit exhaustion behavior."""
 import asyncio
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -513,3 +514,48 @@ def test_pool_survives_hundreds_of_unshared_requests():
             dict(pr._page_refs))
     assert all(v >= 0 for v in pr._page_refs.values()), pr._page_refs
     _assert_all_pages_accounted(pr)
+
+
+def _grid_steps() -> dict[str, int]:
+    from crowdllama_tpu.obs.metrics import ENGINE_TELEMETRY
+
+    return {line.split(" ")[0].split("_total")[1]: int(line.split(" ")[1])
+            for line in ENGINE_TELEMETRY.expose()
+            if line.startswith("crowdllama_attn_grid_steps_total{")}
+
+
+@pytest.mark.parametrize("path", ["kernel", "gathered"])
+def test_flight_books_the_decode_kernels_grid_steps(monkeypatch, path):
+    """crowdllama_attn_grid_steps_total{kind,walk}: a flight books, from the
+    lengths the runner holds on the host, the grid steps its decode kernel
+    calls walk (one a live page pair of a live slot, a call a layer a step)
+    and those of the slots x table rectangle — and nothing where the
+    gathered jnp view serves, which walks no grid."""
+    if path == "kernel":
+        monkeypatch.setenv("CROWDLLAMA_PALLAS_INTERPRET", "1")
+    cfg = get_config("tiny-test", max_context_length=128)    # two layers
+    pr = PagedModelRunner(cfg, max_slots=4, max_seq=128, page_size=32,
+                          mesh_spec="1", dtype=jnp.float32)
+    assert (pr.attention_paths["decode"] == "jnp") == (path == "gathered")
+    st = pr.init_state()
+    for slot, n in ((0, 5), (2, 70)):
+        tok, ks, vs, plen = pr.prefill(list(range(1, n + 1)), 0.0, 1.0,
+                                       jax.random.PRNGKey(0))
+        st = pr.insert(st, slot, ks, vs, plen, tok, 0.0, 1.0)
+    before = _grid_steps()
+    _, st = pr.decode_steps(st, 2)
+    booked = {k: v - before[k] for k, v in _grid_steps().items()}
+    # 4 columns, two pages a grid step: slot 0 reads 6 then 7 tokens (one
+    # pair), slot 2 reads 71 then 72 (two); slots 1 and 3 have no tenant
+    want = {'{kind="full",walk="live"}': 2 * (1 + 2) * 2,
+            '{kind="full",walk="rectangle"}': 2 * (4 * 2) * 2,
+            '{kind="window",walk="live"}': 0,
+            '{kind="window",walk="rectangle"}': 0}
+    assert booked == (want if path == "kernel" else dict.fromkeys(want, 0))
+    if path == "kernel":
+        # the slot that left is walked no more; one pair is the least
+        st = pr.release(pr.release(st, 2), 0)
+        _, st = pr.decode_steps(st, 1)
+        after = _grid_steps()
+        assert after['{kind="full",walk="live"}'] - before[
+            '{kind="full",walk="live"}'] == 12 + 1 * 2

@@ -204,9 +204,10 @@ def test_ragged_v2_matches_reference(softcap, window):
     live = [0, 2] + [b + i for i in range(chunk_len)]
     np.testing.assert_allclose(np.asarray(got)[live], np.asarray(ref)[live],
                                rtol=2e-5, atol=2e-5)
-    # The kernel's dead rows are zeros, not NaN (q_valid=0 skips compute).
+    # A dead decode row is visited by no grid step of the decode kernel: it
+    # keeps its query (finite, not NaN), and is no one's answer.
     assert np.isfinite(np.asarray(got)).all()
-    np.testing.assert_array_equal(np.asarray(got)[1], 0.0)
+    np.testing.assert_array_equal(np.asarray(got)[1], np.asarray(q)[1])
 
 
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
@@ -291,3 +292,124 @@ def test_decode_bf16():
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(ref, np.float32),
         rtol=2e-2, atol=2e-2)
+
+
+def _rectangle(pool, page_table, seq_lens=None, live=None):
+    """The grid the decode kernel walked before it had a list, as
+    ``decode_work`` would give it: every (slot, page pair) of the table, dead
+    or live."""
+    from crowdllama_tpu.ops.pallas.paged import DecodeWork, _pairs
+
+    b, cols = page_table.shape
+    pairs = _pairs(pool, cols)
+    steps = -(-cols // pairs)
+    slot = jnp.repeat(jnp.arange(b, dtype=jnp.int32), steps)
+    pair = jnp.tile(jnp.arange(steps, dtype=jnp.int32), b)
+    return DecodeWork(
+        slot, pair,
+        tuple(page_table[slot, jnp.minimum(pair * pairs + j, cols - 1)]
+              for j in range(pairs)),
+        jnp.asarray([b * steps], jnp.int32))
+
+
+@pytest.mark.parametrize("case", [
+    # lens: the kernel's own (a released slot's reads 1, as the engine's)
+    dict(id="skewed", lens=[1, 32, 33, 128]),
+    dict(id="one_page_pair_each", lens=[64, 64, 64, 64]),
+    dict(id="all_but_one_inactive", lens=[1, 1, 70, 1], live=[0, 0, 1, 0]),
+    dict(id="first_slot_inactive", lens=[1, 128, 5, 90], live=[0, 1, 1, 1]),
+    dict(id="none_active", lens=[1, 1, 1, 1], live=[0, 0, 0, 0]),
+    dict(id="zero_lengths_unlisted", lens=[0, 40, 0, 0]),
+    dict(id="one_column", lens=[1, 32, 7, 20], cols=1),
+    dict(id="odd_columns", lens=[96, 65, 1, 33], cols=3),
+    dict(id="bf16", lens=[1, 32, 33, 128], dtype="bf16"),
+    dict(id="int8", lens=[1, 32, 33, 128], dtype="int8"),
+    dict(id="int8_inactive", lens=[1, 90, 1, 128], live=[0, 1, 0, 1],
+         dtype="int8"),
+    dict(id="window", lens=[1, 32, 33, 128], window=40),
+    dict(id="softcap", lens=[100, 32, 33, 128], softcap=30.0),
+    dict(id="ring_before_wrap", lens=[1, 32, 97, 160], ring=(6, 70)),
+    dict(id="ring_after_wrap", lens=[200, 431, 193, 1000], ring=(6, 70)),
+    dict(id="ring_inactive", lens=[1, 431, 1, 1000], live=[0, 1, 0, 1],
+         ring=(6, 70)),
+], ids=lambda c: c["id"])
+def test_listed_decode_kernel_is_the_rectangular_one(case):
+    """The decode kernel over its list of live (slot, page pair) entries
+    gives, for every listed slot, bit for bit what it gives over the whole
+    slots x table rectangle — the grid it walked before PR 44: the list
+    changes which grid steps run, never a sum's order within a slot — and
+    what the gathered jnp view gives; a slot with no entry is visited by no
+    step and keeps its query."""
+    from crowdllama_tpu.ops.attention import (decode_attention,
+                                              decode_attention_q)
+    from crowdllama_tpu.ops.pallas import paged as pp
+    from crowdllama_tpu.ops.quant import quantize_kv
+
+    b, h, hkv, dh, page = 4, 4, 2, 16, 32
+    cols = case.get("cols", 4)
+    dtype = {"bf16": jnp.bfloat16}.get(case.get("dtype"), jnp.float32)
+    window, softcap = case.get("window", 0), case.get("softcap", 0.0)
+    ks = jax.random.split(jax.random.PRNGKey(44), 3)
+    lens = jnp.asarray(case["lens"], jnp.int32)
+    live = (None if "live" not in case
+            else jnp.asarray(case["live"], bool))
+    ring = None
+    if "ring" in case:
+        ring = pp.Ring(*case["ring"])
+        pool_pages = b * ring.pages + 1
+        table, klens = ring.decode_view(lens, page)
+        window = ring.window
+    else:
+        pool_pages = b * cols + 1
+        table = 1 + jnp.arange(b * cols, dtype=jnp.int32).reshape(b, cols)
+        klens = lens
+    q = jax.random.normal(ks[0], (b, h, dh), dtype)
+    pool_k = jax.random.normal(ks[1], (1, pool_pages, hkv, page, dh), dtype)
+    pool_v = jax.random.normal(ks[2], (1, pool_pages, hkv, page, dh), dtype)
+    sk = sv = None
+    if case.get("dtype") == "int8":
+        (pool_k, sk), (pool_v, sv) = quantize_kv(pool_k), quantize_kv(pool_v)
+    np_ = table.shape[1]
+
+    def run(work):
+        return np.asarray(pp.flash_paged_decode_attention(
+            q, pool_k, pool_v, 0, table, klens, dh ** -0.5, softcap=softcap,
+            sliding_window=window, k_scale=sk, v_scale=sv, work=work),
+            np.float32)
+
+    work = pp.decode_work(pool_k, table, klens, live)
+    listed = np.asarray(klens) > 0
+    if live is not None:
+        listed &= np.asarray(live)
+    pairs = pp._pairs(pool_k, np_)
+    want_total = int(sum(-(-min(int(n), np_ * page) // (page * pairs))
+                         for n, on in zip(np.asarray(klens), listed) if on))
+    assert int(work.total[0]) == max(want_total, 1)
+    got = run(work)
+    assert np.isfinite(got).all()
+    # no entry, no visit: the row is its query's
+    np.testing.assert_array_equal(got[~listed],
+                                  np.asarray(q, np.float32)[~listed])
+    if live is None:
+        # the wrapper builds this very list when it is handed none
+        np.testing.assert_array_equal(run(None), got)
+    np.testing.assert_array_equal(
+        got[listed], run(_rectangle(pool_k, table))[listed])
+
+    def view(pool):     # what the CPU path gathers
+        return pool[0, table].transpose(0, 2, 1, 3, 4).reshape(
+            b, hkv, np_ * page, -1)
+
+    if sk is not None:
+        ref = decode_attention_q(
+            q, view(pool_k), view(sk[..., None])[..., 0], view(pool_v),
+            view(sv[..., None])[..., 0], klens, dh ** -0.5, softcap=softcap,
+            sliding_window=window)
+    else:
+        ref = decode_attention(q, view(pool_k), view(pool_v), klens,
+                               dh ** -0.5, softcap=softcap,
+                               sliding_window=window)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+    np.testing.assert_allclose(got[listed],
+                               np.asarray(ref, np.float32)[listed],
+                               rtol=tol, atol=tol)

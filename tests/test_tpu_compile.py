@@ -1127,9 +1127,10 @@ def test_trinity_decode_program_keeps_both_pools_in_place(trinity_runner,
     assert len(_calls(text, "paged_decode_attention_window")) == 4
     assert len(_calls(text, "paged_decode_attention")) == 5   # both names
     assert len(_calls(text, "moe_grouped_matmul")) == 12
-    # a window layer's call takes a table of the pages its window reaches
+    # a window layer's call walks the list of a table of the 33 pages its
+    # window reaches: 17 page pairs a slot
     for ln in _calls(text, "paged_decode_attention_window"):
-        assert "s32[32,33]" in ln and "bf16[4,1185,8,128,128]" in ln
+        assert "s32[544]" in ln and "bf16[4,1185,8,128,128]" in ln
     for ln in text.splitlines():
         if any(f" {op}(" in ln for op in ("fusion", "copy", "copy-start")):
             head = ln.split(" = ")[1][:60] if " = " in ln else ""
@@ -1164,3 +1165,73 @@ def test_trinity_ragged_step_program_compiles_with_both_pools_in_place(
     assert len(_calls(text, "paged_decode_attention_window")) == 4
     assert len(_calls(text, "paged_decode_attention")) == 5
     assert len(_calls(text, "moe_grouped_matmul")) == 12
+
+
+# ------- the decode kernel's list of live page pairs (PR 44): once a step
+
+
+def _walk(jaxpr, loops=()):
+    """Every equation of ``jaxpr`` and of the jaxprs inside it, each with
+    the lengths of the ``scan`` loops around it (outermost first)."""
+    for eqn in jaxpr.eqns:
+        yield eqn, loops
+        inner = loops + ((eqn.params["length"],)
+                         if eqn.primitive.name == "scan" else ())
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _walk(sub, inner)
+
+
+@pytest.mark.parametrize("program", ["decode_1", "decode_chunk",
+                                     "ragged_step"])
+@pytest.mark.parametrize("model,lists,kernels", [
+    # full layers: one rolled call site; Nemotron: its one attention layer
+    ("mistral", 1, {"paged_decode_attention": 1}),
+    ("nemotron", 1, {"paged_decode_attention": 1}),
+    # every attention layer latent: the MLA kernel takes no list; the ragged
+    # step's decode rows meet the GQA kernel on the one latent pool
+    ("kimi", 0, {"paged_decode_attention_mla": 7}),
+    # two lists a step: the full layer's and the four window layers' shared
+    ("trinity", 2, {"paged_decode_attention": 1,
+                    "paged_decode_attention_window": 4}),
+])
+def test_decode_work_is_built_once_a_step(request, model, lists, kernels,
+                                          program):
+    """The list of live page pairs is built beside the lengths, inside the
+    step loop and OUTSIDE the layer loop (the ``cumsum`` under its scope's
+    name is the witness), once for every kind of table the
+    step's GQA decode layers read — and every such layer's kernel call walks
+    it under a run-time grid bound; the calls' names and their count a step
+    are what the benchmark's readers divide by."""
+    build = request.getfixturevalue(f"{model}_runner")
+    r, params, state, table = build("bf16") if model == "mistral" else build()
+    chunk = {"mistral": 8, "nemotron": 2, "kimi": 4, "trinity": 2}[model]
+    steps = chunk if program == "decode_chunk" else 1
+    if program == "ragged_step":
+        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+        jaxpr = jax.make_jaxpr(r._ragged_step_impl, static_argnums=(7,))(
+            params, state, table, i32(1, r.ragged_chunk), i32(1), i32(),
+            i32(), 1)
+        if model == "kimi":
+            lists, kernels = 1, {"paged_decode_attention": 7}
+    else:
+        jaxpr = jax.make_jaxpr(r._decode_paged_impl, static_argnums=(3,))(
+            params, state, table, steps)
+    eqns = list(_walk(jaxpr.jaxpr))
+    # the rolled layer loop of a stacked model; a hybrid's layers are unrolled
+    layer_loop = (r.cfg.num_layers,) if model == "mistral" else ()
+    built = [loops for eqn, loops in eqns
+             if eqn.params.get("name") == "cumsum"    # jnp.cumsum's own jit
+             and "decode_work" in str(eqn.source_info.name_stack)]
+    assert built == [(steps,)] * lists, built
+    calls = {}
+    for eqn, loops in eqns:
+        name = (eqn.params.get("name") or ""
+                ) if eqn.primitive.name == "pallas_call" else ""
+        if name.startswith("paged_decode_attention"):
+            assert loops == (steps,) + layer_loop, (name, loops)
+            calls[name] = calls.get(name, 0) + 1
+            if not name.endswith("_mla"):
+                # the grid's one dimension is the list's length, read at run
+                # time (a dynamic bound is the call's first operand)
+                assert eqn.params["grid_mapping"].num_dynamic_grid_bounds == 1
+    assert calls == kernels

@@ -127,8 +127,8 @@ def test_a_cancelled_ragged_prefill_leaves_no_page_behind(params):
     admitted(r, 0, 64)
     job = r.ragged_begin(list(range(1, 200)), 2, state=None)
     for _ in range(5):      # five dispatches of two chunks: 160 tokens in
-        _, _, end, _ = r._ragged_provision(job, 2)
-        r._ragged_commit(job, end, 2, None)
+        _, _, end, wp = r._ragged_provision(job, 2)
+        r._ragged_commit(job, end, 2, None, wp)
     assert job.done_tokens == 160 and not job.finished
     assert r.window_pages(2) == r.ring.pages and len(r._slot_pages[2]) == 20
     r.ragged_abort(job)
