@@ -7,9 +7,11 @@ and full attention layers, a cache of two kinds of page).
   attention layers (``kv_layers``), not ``num_layers``; page table,
   allocator, Pallas decode / ragged / flash-prefill kernels are the
   parent's.  A latent layer (``L``, MLA served absorbed) keeps ONE row
-  ``[c ; k_rope]`` a token: ``pool_k`` is ``[L_A, P+1, 1, page, row]``,
-  ``pool_v`` is None, and the decode kernel is
-  ``paged_decode_attention_mla`` (ops/pallas/paged.py).
+  ``[c ; k_rope]`` a token: ``pool_k`` is ``[L_A, P+1, 1, page, row]``
+  with ``row`` whole lanes (engine/paged.py ``pool_row_width``: the
+  columns behind ``k_rope`` are zero and stay zero), ``pool_v`` is None,
+  and the decode kernel is ``paged_decode_attention_mla``
+  (ops/pallas/paged.py).
 * A second pool for WINDOW layers (``W``: attention inside
   ``cfg.sliding_window``), ``PagedDecodeState.wpool_k`` / ``wpool_v``
   ``[L_W, B * ring + 1, Hkv, page, Dh]``, whose size does not grow with the
@@ -234,7 +236,9 @@ class HybridPagedModelRunner(PagedModelRunner):
             state, ks = self._insert_window(state, ks, slot, rest[0])
         if ks.v is None:    # latent rows: pages of the one pool
             # a page at a time, in place (engine/paged.py ``_put_rows`` has
-            # why not a scatter: it copied the whole pool twice an insert)
+            # why not a scatter: it copied the whole pool twice an insert);
+            # the prefill's rows are as wide as computed and fill a stored
+            # row's first columns: its pad columns are not written
             pool, pg = state.pool_k, self.page_size
             for j in range(ks.k.shape[3] // pg):
                 pool = jax.lax.dynamic_update_slice(
@@ -363,7 +367,11 @@ class HybridPagedModelRunner(PagedModelRunner):
                             wpool_k=jnp.zeros(shape, state.pool_k.dtype),
                             wpool_v=jnp.zeros(shape, state.pool_k.dtype))
             by_kind["kv_window_pool"] = 2 * state.wpool_k.nbytes
-        ENGINE_TELEMETRY.state_bytes_set(by_kind)
+        latent_row = (0, 0)
+        if state.pool_v is None:    # what of a stored row is pad
+            row = self.cfg.resolved_head_dim()
+            latent_row = (row, state.pool_k.shape[-1] - row)
+        ENGINE_TELEMETRY.state_bytes_set(by_kind, latent_row)
         return state
 
     # --------------------------------------------- the window pool's counts

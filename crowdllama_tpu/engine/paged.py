@@ -77,6 +77,43 @@ from crowdllama_tpu.ops.rope import rope_table
 
 log = logging.getLogger("crowdllama.engine.paged")
 
+_LANES = 128
+
+
+def pool_row_width(cfg) -> int:
+    """Entries of one row of the pool AS IT IS STORED — the one place that
+    decides it.  A head's K or V row lies ``resolved_head_dim`` wide.  A
+    latent row (``cfg.kv_lora_rank``: ``[c ; k_rope]``, key and value in
+    one, no V twin) is rounded up to whole lanes, 576 -> 640: the kernels
+    and the row writes need the row minor, and the TPU's own layout for a
+    BUFFER whose row is four and a half lane tiles puts the page's token
+    axis minor instead, so every program that took the pool and handed it
+    back converted all of it on the way in and again on the way out
+    (PERF.md §6, PR 49).  The pad columns are zero from ``init_state`` on:
+    every row is written with a zero tail and every query meets the cache
+    with one (:func:`_pool_wide`), so a score is the sum it was and the
+    value is still the row's first ``kv_lora_rank`` entries.
+
+    Read by whatever sizes the pool or a view of it: the Pallas gate, the
+    step bodies' views, ``init_state``, ``_page_bytes``, ``_pool_shard``.
+    Not by the prefix cache's gathers (``_prefill_ctx_impl``, ``_seed_ctx``)
+    nor ``import_pages``: they take a V twin, which a latent pool lacks
+    (engine/hybrid.py serves it with the prefix cache off), so a row there is
+    a head's; not by ``_decode_layers``' ``rope_table``, a computed width."""
+    dh = cfg.resolved_head_dim()
+    return -(-dh // _LANES) * _LANES if cfg.kv_lora_rank else dh
+
+
+def _pool_wide(pool, *rows):
+    """``rows`` (``[..., Dh]`` each) with zero columns up to the pool's
+    row; as they are where the pool's row is theirs (every pool but a
+    latent one whose row is not whole lanes: :func:`pool_row_width`)."""
+    pad = pool.shape[-1] - rows[0].shape[-1]
+    if not pad:
+        return rows
+    return tuple(jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, pad)])
+                 for a in rows)
+
 
 def _put_rows(pool, rows, *, layer, pages, offsets):
     """``pool[layer, pages[i], :, offsets[i]] = rows[i]`` for every row, as
@@ -155,7 +192,8 @@ class PagesExhausted(ValueError):
 class PagedDecodeState:
     pool_k: jnp.ndarray    # [L, P, Hkv, page, Dh]
     # None for latent attention (cfg.kv_lora_rank): a page of pool_k holds
-    # one row [c ; k_rope] a token, key and value both
+    # one row [c ; k_rope] a token, key and value both, in a row of whole
+    # lanes (pool_row_width)
     pool_v: jnp.ndarray | None
     seq_lens: jnp.ndarray  # [B]
     tokens: jnp.ndarray    # [B]
@@ -336,7 +374,7 @@ class PagedModelRunner(ModelRunner):
         from crowdllama_tpu.parallel.mesh import AXIS_TP
 
         quant = self.kv_dtype == "int8"
-        gate = (self.page_size, self.cfg.resolved_head_dim(),
+        gate = (self.page_size, pool_row_width(self.cfg),
                 self.mesh.shape.get(AXIS_TP, 1), self.cfg.num_kv_heads,
                 jnp.dtype(jnp.int8 if quant else self.dtype).itemsize,  # pool
                 quant)
@@ -814,7 +852,7 @@ class PagedModelRunner(ModelRunner):
         cfg = self.cfg
         pg = self.page_size
         b = self.max_slots
-        dh = cfg.resolved_head_dim()
+        dh = pool_row_width(cfg)
         hkv = cfg.num_kv_heads
         scale = T.attn_scale(cfg)
         slot_idx = jnp.arange(b)
@@ -866,6 +904,7 @@ class PagedModelRunner(ModelRunner):
                     return _write_kv(put, pk, pv, ksc, vsc, k, v)
 
                 def attn_fn(q, k, v):
+                    q, k = _pool_wide(pk, q, k)
                     after["pools"] = write(k, v)
                     return read(q, *after["pools"])
 
@@ -1015,6 +1054,7 @@ class PagedModelRunner(ModelRunner):
                     return _write_kv(put, pk, pv, ksc, vsc, k, v)
 
                 def attn_fn(q, k, v):
+                    q, k = _pool_wide(pk, q, k)
                     after["pools"] = write(k, v)
                     return read(q, k, v, *after["pools"])
 
@@ -1098,7 +1138,7 @@ class PagedModelRunner(ModelRunner):
         from crowdllama_tpu.parallel.sharding import filter_spec
 
         l = self.pool_layers
-        hkv, dh = self.cfg.num_kv_heads, self.cfg.resolved_head_dim()
+        hkv, dh = self.cfg.num_kv_heads, pool_row_width(self.cfg)
         # +1: reserved dump page absorbing inactive slots' decode writes.
         shape = (l, self.total_pages + 1, hkv, self.page_size, dh)
         # KV heads shard over tp like the contiguous cache (runner.py
@@ -1213,8 +1253,9 @@ class PagedModelRunner(ModelRunner):
 
     @property
     def _page_bytes(self) -> int:
-        """Bytes of one page of one layer, K and V (and their scales)."""
-        hkv, dh = self.cfg.num_kv_heads, self.cfg.resolved_head_dim()
+        """Bytes of one page of one layer, K and V (and their scales), as
+        allocated."""
+        hkv, dh = self.cfg.num_kv_heads, pool_row_width(self.cfg)
         if self.kv_dtype == "int8":
             return 2 * hkv * self.page_size * (dh + 2)
         twins = 1 if self.cfg.kv_lora_rank else 2
@@ -1280,7 +1321,7 @@ class PagedModelRunner(ModelRunner):
 
         return jax.ShapeDtypeStruct(
             (1, 1, self.cfg.num_kv_heads // self.mesh.shape.get(AXIS_TP, 1),
-             self.page_size, self.cfg.resolved_head_dim()),
+             self.page_size, pool_row_width(self.cfg)),
             jnp.int8 if self.kv_dtype == "int8" else self.dtype)
 
     def _advance(self, num_steps: int, ragged_cols: int = 0) -> None:

@@ -457,6 +457,11 @@ class EngineTelemetry:
         # init_state allocated (engine/hybrid.py: KV pool, state-space
         # state, convolution tail).
         self._state_bytes: dict[str, int] = {}
+        # Entries of one stored row of that runner's latent pool: those a
+        # token's row [c ; k_rope] fills and the zero columns that round it
+        # up to whole lanes (engine/paged.py pool_row_width); 0, 0 for a
+        # runner without a latent pool.
+        self._latent_row = {"row": 0, "pad": 0}
         # Seconds each phase of the start took ("ready": from the import
         # of this module, which the CLI does first, to the engine serving).
         self.t_import = time.monotonic()
@@ -615,9 +620,11 @@ class EngineTelemetry:
             by["live"] += max(0, int(live))
             by["rectangle"] += max(0, int(rectangle))
 
-    def state_bytes_set(self, by_kind: dict[str, int]) -> None:
+    def state_bytes_set(self, by_kind: dict[str, int],
+                        latent_row: tuple[int, int] = (0, 0)) -> None:
         with self._lock:
             self._state_bytes = {k: int(v) for k, v in by_kind.items()}
+            self._latent_row = dict(zip(("row", "pad"), map(int, latent_row)))
 
     def startup_set(self, phase: str, seconds: float) -> None:
         with self._lock:
@@ -662,6 +669,7 @@ class EngineTelemetry:
             admissions = dict(self._admissions)
             flights = dict(self._flights)
             state_bytes = sorted(self._state_bytes.items())
+            latent_row = dict(self._latent_row)
             grid_steps = {kind: dict(by)
                           for kind, by in self._attn_grid_steps.items()}
         out.append("# TYPE crowdllama_engine_attention_path gauge")
@@ -761,6 +769,10 @@ class EngineTelemetry:
         out.append("# TYPE crowdllama_latent_cache_bytes gauge")
         out.append(f"crowdllama_latent_cache_bytes "
                    f"{dict(state_bytes).get('latent_cache', 0)}")
+        out.append("# TYPE crowdllama_latent_cache_row_width gauge")
+        for part, n in latent_row.items():
+            out.append(f'crowdllama_latent_cache_row_width{{part="{part}"}} '
+                       f'{n}')
         out.append("# TYPE crowdllama_startup_seconds gauge")
         for phase in STARTUP_PHASES:
             out.append(f'crowdllama_startup_seconds{{phase="{phase}"}} '

@@ -28,6 +28,7 @@ program (``float32-kernel``: a value dim and a latent width of whole lanes,
 which the kernels ask for).
 """
 
+import gc
 import json
 import sys
 from dataclasses import replace
@@ -58,6 +59,19 @@ LIMITS = {"float32": (1e-3, 1e-4), "bfloat16": (0.2, 0.1),
           "int8": (0.2, 0.1)}
 PATHS = ("prefill", "decode", "ragged", "megastep")
 ROWS = [*LIMITS, "float32-kernel"]
+
+
+@pytest.fixture(autouse=True)
+def _programs_go_with_their_test():
+    """A runner and its jitted methods are a reference cycle, and every
+    loaded CPU executable of these unrolled models holds memory maps by the
+    hundred: left to the collector's own schedule the file's programs pile
+    up in its one process (the driver runs a file in one worker) until a
+    load from the compile cache dies of a segmentation fault once a handful
+    more tests join the file (ISSUE 49's warning; PR 49 added nine)."""
+    yield
+    jax.clear_caches()
+    gc.collect()
 
 
 @pytest.fixture
@@ -245,6 +259,76 @@ def test_logits_match_the_reference(row, path, kernels):
     for what, logits, ids, positions in run_path(r, path):
         worst, mean = distance(logits, ids, positions, r)
         assert worst <= worst_lim and mean <= mean_lim, (what, worst, mean)
+
+
+# the latent pool's row is stored in whole lanes (engine/paged.py
+# ``pool_row_width``): the pad columns change no bit and stay zero
+
+@pytest.mark.parametrize("path", ["decode", "ragged", "megastep"])
+@pytest.mark.parametrize("row", ["float32", "float32-kernel"])
+def test_the_stored_rows_pad_changes_no_bit_of_the_logits(row, path, kernels,
+                                                          monkeypatch):
+    """Prefill then insert then decode, a prompt in ragged chunks beside
+    decoding slots, both megasteps: through a pool whose row is whole lanes
+    every logit is BIT-equal to the same program's over a pool whose row is
+    the 48 (144 for the kernels) entries computed — a pad term of a score
+    is an exact zero and the value is the row's first ``kv_lora_rank``
+    entries on both.  But one reading, which is the CPU's and not the
+    pad's: the ragged kernel in INTERPRET mode multiplies a chunk block's
+    128 probability rows by a page of values 256 columns wide where it was
+    144, and XLA's CPU dot tiles the two widths apart (the same 32 products
+    a column, summed in another order: ``p @ v`` against ``p @ pad(v)`` cut
+    back differs in 76% of its entries, 4e-6 at most), so what a chunk's
+    blocks fed is held to a float32 rounding, a hundredth of LIMITS."""
+    from crowdllama_tpu.engine import paged
+
+    kernels(row)
+    r = make_runner(row)
+    width = r.cfg.resolved_head_dim()
+    assert r.init_state().pool_k.shape[-1] == -(-width // 128) * 128 > width
+    padded = run_path(r, path)
+    # the pool as it was: a row as wide as ``mla_body`` computes it
+    monkeypatch.setattr(paged, "pool_row_width",
+                        lambda cfg: cfg.resolved_head_dim())
+    plain = make_runner(row)
+    assert plain.init_state().pool_k.shape[-1] == width
+    for (what, got, ids, _), (_, want, ids2, _) in zip(
+            padded, run_path(plain, path), strict=True):
+        assert ids == ids2, what
+        got, want = np.asarray(got), np.asarray(want)
+        chunk_fed = "chunk" in what and "beside" not in what
+        if row.endswith("-kernel") and chunk_fed:
+            assert np.abs(got - want).max() <= 1e-5 * want.std(), what
+        else:
+            np.testing.assert_array_equal(got, want, err_msg=what)
+
+
+@pytest.mark.parametrize("row", ["float32", "bfloat16", "float32-kernel"])
+def test_the_stored_rows_pad_columns_stay_zero(row, kernels):
+    """A slot served, released and served again out of the pages it gave
+    back — an insert, decode writes, ragged chunks beside another slot's
+    decode writes, a megastep: every pad column of the pool, the dump page's
+    too, is the zero ``init_state`` drew; the columns of the row are not."""
+    kernels(row)
+    r = make_runner(row, cls=HybridPagedModelRunner)
+    width = r.cfg.resolved_head_dim()
+    st = r.init_state()
+    _, st = admit(r, st, 2, prompt_of(40, 1))
+    _, st = admit(r, st, 0, prompt_of(20, 3))
+    _, st = r.decode_steps_device(st, 4)
+    held = set(r._slot_pages[2])
+    st = r.release(st, 2)
+    job = r.ragged_begin(prompt_of(100, 2), 2, state=st)
+    while not job.finished:
+        _, st = r.ragged_step(st, job, 1)
+    assert held & set(r._slot_pages[2])     # pages with a past
+    _, st = r.ragged_finish(st, job, 0.0, 1.0, KEY)
+    _, _, st = r.decode_megastep(st, 3)
+    pool = np.asarray(st.pool_k.astype(jnp.float32))
+    assert pool.shape[-1] > width
+    assert not pool[..., width:].any()
+    written = np.abs(pool[..., :width]).sum(-1) > 0
+    assert written.sum() >= 20 + 4 + 100 + 3
 
 
 @pytest.mark.parametrize("row", ["float32", "float32-kernel"])
@@ -535,7 +619,8 @@ def test_the_latent_pool_and_the_matrix_state_in_one_donated_pytree():
     r = make_runner("bfloat16", cls=HybridPagedModelRunner)
     st = r.init_state()
     assert st.pool_v is None and st.ssm is None
-    assert st.pool_k.shape == (1, 4 * 16 + 1, 1, 16, 32 + 16)
+    # a row [c ; k_rope] of 32 + 16, stored in whole lanes
+    assert st.pool_k.shape == (1, 4 * 16 + 1, 1, 16, 128)
     assert st.kda.shape == (3, 4, 4, 16, 16) and st.kda.dtype == jnp.float32
     assert st.conv.shape == (3, 4, 3, 3 * 64)
     compiled = r._decode_paged.lower(
@@ -639,7 +724,11 @@ async def test_served_through_the_engine_with_its_gauges_and_counters():
                       ) == st.kda.nbytes
         assert series('crowdllama_recurrent_state_bytes{kind="conv"}'
                       ) == st.conv.nbytes
+        # the bytes allocated: a row of 32 + 16 stored in whole lanes
         assert series("crowdllama_latent_cache_bytes") == st.pool_k.nbytes
+        assert st.pool_k.shape[-1] == 128
+        assert series('crowdllama_latent_cache_row_width{part="row"}') == 48
+        assert series('crowdllama_latent_cache_row_width{part="pad"}') == 80
         assert await engine.export_kv_pages(CFG.name, [b"k"], 16) is None
         assert not engine._kv_ship_ready()
     finally:

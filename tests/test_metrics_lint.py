@@ -434,6 +434,33 @@ def test_weight_layout_gauge_lint():
                for ln in lines) == 2
 
 
+def test_latent_cache_row_width_gauge_lint():
+    """crowdllama_latent_cache_row_width{part}: one gauge family beside
+    crowdllama_latent_cache_bytes — the entries a token's latent row fills
+    (``row``) and the zero columns that round it up to whole lanes
+    (``pad``); both 0 from boot and for a runner without a latent pool."""
+    from crowdllama_tpu.obs.metrics import EngineTelemetry
+
+    tele = EngineTelemetry()
+    lines = tele.expose()
+    types = _lint("\n".join(lines))
+    assert types["crowdllama_latent_cache_row_width"] == "gauge"
+    assert types["crowdllama_latent_cache_bytes"] == "gauge"
+    for part in ("row", "pad"):
+        assert f'crowdllama_latent_cache_row_width{{part="{part}"}} 0' in lines
+    pool = 7 * 385 * 128 * 640 * 2
+    tele.state_bytes_set({"latent_cache": pool}, latent_row=(576, 64))
+    lines = tele.expose()
+    _lint("\n".join(lines))
+    assert f"crowdllama_latent_cache_bytes {pool}" in lines
+    assert 'crowdllama_latent_cache_row_width{part="row"} 576' in lines
+    assert 'crowdllama_latent_cache_row_width{part="pad"} 64' in lines
+    tele.state_bytes_set({"kv_pool": 1 << 20})      # the next runner's
+    lines = tele.expose()
+    assert "crowdllama_latent_cache_bytes 0" in lines
+    assert 'crowdllama_latent_cache_row_width{part="row"} 0' in lines
+
+
 def test_startup_phases_lint():
     """crowdllama_startup_seconds{phase}: every phase rendered from boot;
     ``process`` counts from the operating system's record of the process's

@@ -278,6 +278,67 @@ def test_stacked_pool_kernel_reads_its_layer(kernel, kv):
     assert not np.array_equal(outs[1], outs[2])
 
 
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 2e-2)])
+@pytest.mark.parametrize("reader", ["mla_decode", "gqa_decode", "ragged_v2"])
+def test_latent_readers_take_the_row_as_it_is_stored(reader, dtype, tol):
+    """The three kernels that read a latent pool — the MLA decode kernel of
+    the plain step, the GQA decode kernel on the one pool as key AND value
+    for a ragged step's decode rows, the v2 ragged kernel for a chunk's
+    blocks — at the row as Kimi's pool STORES it: ``[c ; k_rope]`` of 576
+    in 640 columns, the last 64 zero in rows and queries alike, the value
+    the first 512.  Each gives what plain attention over the 576 computed
+    columns gives: a pad column adds an exact zero to a score and is no
+    part of a value."""
+    from crowdllama_tpu.ops.attention import decode_attention
+    from crowdllama_tpu.ops.pallas import paged as pp
+
+    row, stored, latent = 576, 640, 512
+    layers, b, h, page, np_, c, ctx = 2, 3, 4, 32, 4, 40, 16
+    ks = jax.random.split(jax.random.PRNGKey(13), 2)
+
+    def stored_wide(a):
+        return jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, stored - row)])
+
+    q = jax.random.normal(ks[0], (b + c, h, row)).astype(dtype)
+    pool = jax.random.normal(
+        ks[1], (layers, b * np_ + 1, 1, page, row)).astype(dtype)
+    table = jnp.arange(b * np_, dtype=jnp.int32).reshape(b, np_)
+    lens = jnp.asarray([1, 40, 128], jnp.int32)  # a page, a pair, all four
+    scale, li = 192 ** -0.5, jnp.int32(1)
+    rows = pool[1, table].transpose(0, 2, 1, 3, 4).reshape(
+        b, 1, np_ * page, row)
+    want = decode_attention(q[:b], rows, rows, lens, scale)[..., :latent]
+    live = slice(0, b)
+    if reader == "mla_decode":
+        got = pp.paged_decode_attention_mla(
+            stored_wide(q[:b]), stored_wide(pool), li, table, lens, scale,
+            latent)
+    elif reader == "gqa_decode":
+        wide = stored_wide(pool)
+        got = pp.flash_paged_decode_attention(
+            stored_wide(q[:b]), wide, wide, li, table, lens, scale)
+    else:
+        # slot 1 takes no decode row: its rows ctx .. ctx + c are the chunk
+        q_lens = jnp.asarray([1, 0, 1, c], jnp.int32)
+        kv_lens = jnp.asarray([1, 0, 128, ctx + c], jnp.int32)
+        wide = stored_wide(pool)
+        got = pp.flash_ragged_paged_attention(
+            stored_wide(q), wide, wide, li, table, q_lens, kv_lens,
+            jnp.int32(1), scale)
+        # a chunk row i is a decode over the slot's first ctx + i + 1 rows
+        chunk = decode_attention(
+            q[b:], jnp.broadcast_to(rows[1], (c, *rows.shape[1:])),
+            jnp.broadcast_to(rows[1], (c, *rows.shape[1:])),
+            ctx + 1 + jnp.arange(c), scale)[..., :latent]
+        want = jnp.concatenate([want, chunk])
+        live = np.r_[0, 2, b:b + c]
+    assert got.shape[-1] in (latent, stored)
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32)[live, :, :latent],
+        np.asarray(want, np.float32)[live], rtol=tol, atol=tol)
+
+
 def test_decode_bf16():
     b, s, h, hkv, dh = 2, 64, 4, 4, 32
     key = jax.random.PRNGKey(5)

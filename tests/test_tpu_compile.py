@@ -902,29 +902,76 @@ def _calls(text: str, name: str) -> list[str]:
             and f"%{name}" in ln.split(" = ")[0]]
 
 
-@pytest.mark.parametrize("steps", [1, 2])
+# the cell's latent pool as it is allocated: 7 MLA layers, 32 slots x 12
+# pages + the dump page, rows [c ; k_rope] of 576 rounded up to whole lanes
+KIMI_POOL = (7, 32 * 12 + 1, 1, PAGE, 640)
+
+
+def _assert_latent_pool_is_worked_where_it_lies(compiled, state):
+    """The program takes the latent pool in the layout its loop uses (row
+    minor) and hands it back so: wherever an array of the pool's shape
+    stands in the compiled text with a layout it lies row-minor, no ``copy``,
+    ``copy-start`` or ``transpose`` yields one and no fusion but a
+    ``dynamic-update-slice`` of the buffer itself (a row write; a ragged
+    chunk's page, merged under its row mask, is one such fusion), and the
+    temporaries are under the pool's own bytes — a 576-wide pool's program
+    held a 640-wide working copy of all of it, 441.5 MB, and converted the
+    buffer on the way in and again on the way out (PERF.md §6, PR 49)."""
+    assert state.pool_v is None and state.pool_k.shape == KIMI_POOL
+    ma = compiled.memory_analysis()
+    kept = sum(a.size * a.dtype.itemsize
+               for a in (state.pool_k, state.kda, state.conv))
+    assert ma.alias_size_in_bytes >= kept, (ma.alias_size_in_bytes, kept)
+    pool_bytes = state.pool_k.size * state.pool_k.dtype.itemsize
+    assert pool_bytes == 7 * 385 * 128 * 640 * 2
+    assert ma.temp_size_in_bytes < pool_bytes, (ma.temp_size_in_bytes,
+                                                pool_bytes)
+    text = compiled.as_text()
+    pool = "bf16[" + ",".join(map(str, KIMI_POOL)) + "]"
+    assert "bf16[7,385,1,128,576]" not in text
+    roots, name = {}, None      # computation -> its ROOT instruction
+    for ln in text.splitlines():
+        if ln.startswith("%") and ln.endswith("{"):
+            name = ln.split(" ", 1)[0]
+        elif ln.lstrip().startswith("ROOT "):
+            roots[name] = ln
+    lines = [ln for ln in text.splitlines() if pool in ln]
+    assert any(" parameter(" in ln.split(pool, 1)[1] for ln in lines)
+    for ln in lines:
+        for at in ln.split(pool)[1:]:
+            assert at.startswith("{4,3,2,1,0") or at[0] != "{", (
+                ln.strip()[:200])
+        result = ln.split(" = ", 1)[-1]
+        for op in ("copy", "copy-start", "transpose", "fusion"):
+            if f" {op}(" not in result or pool not in result.split(
+                    f" {op}(")[0]:
+                continue
+            called = re.search(r"calls=(%[\w.\-]+)", ln)
+            assert op == "fusion" and called, ln.strip()[:200]
+            assert re.search(r" dynamic-update-slice\(%param_0[., ]",
+                             roots[called.group(1)]), ln.strip()[:200]
+
+
+@pytest.mark.parametrize("steps", [1, 2, 4])
 def test_kimi_decode_program_keeps_its_state_in_place(kimi_runner, steps):
-    """All 27 layers of the cut in one step program: the latent pool (no V
-    twin), the KDA matrices and the convolutions' tails are handed back
-    where they lay; twenty ``kda_update`` calls a step on the WHOLE stack,
-    seven ``paged_decode_attention_mla``, 78 grouped matmuls (26 expert
-    layers, three int8 banks); no fusion or copy with the state or a bank
-    among its operands; the temporaries are under a tenth of the weights."""
+    """All 27 layers of the cut in one step program, at a short flight's
+    length, at two steps and at the cell's own flight (``decode_chunk`` 4):
+    the latent pool (no V twin), the KDA matrices and the convolutions'
+    tails are handed back where they lay; twenty ``kda_update`` calls a
+    step on the WHOLE stack, seven ``paged_decode_attention_mla``, 78
+    grouped matmuls (26 expert layers, three int8 banks); no fusion or copy
+    with the state or a bank among its operands, none that yields the
+    pool."""
     r, params, state, table = kimi_runner()
-    assert state.pool_v is None and state.pool_k.shape == (
-        7, 32 * 12 + 1, 1, PAGE, 576)
     assert state.kda.shape == (20, 32, 32, 128, 128)
     assert state.conv.shape == (20, 32, 3, 12288)
     compiled = jax.jit(
         r._decode_paged_impl, donate_argnums=(1,), static_argnums=(3,)
     ).lower(params, state, table, steps).compile()
-    ma = compiled.memory_analysis()
-    kept = sum(a.size * a.dtype.itemsize
-               for a in (state.pool_k, state.kda, state.conv))
-    assert ma.alias_size_in_bytes >= kept, (ma.alias_size_in_bytes, kept)
+    _assert_latent_pool_is_worked_where_it_lies(compiled, state)
     weights = sum(a.size * a.dtype.itemsize
                   for a in jax.tree_util.tree_leaves(params))
-    assert 7.2e9 < weights < 7.4e9 and ma.temp_size_in_bytes < weights // 10
+    assert 7.2e9 < weights < 7.4e9
     text = compiled.as_text()
     assert len(_calls(text, "kda_update")) == 20
     assert len(_calls(text, "paged_decode_attention_mla")) == 7
@@ -944,8 +991,9 @@ def test_kimi_decode_program_keeps_its_state_in_place(kimi_runner, steps):
 def test_kimi_ragged_step_program_compiles_with_its_state_in_place(
         kimi_runner, one_chip):
     """Decode rows beside a 512-token chunk: the v2 ragged kernel over one
-    576-wide latent row a token (key and value both), the chunk's rows
-    through the chunkwise delta rule into the slot's own slab."""
+    latent row a token (key and value both, 640 wide as stored), the
+    chunk's rows through the chunkwise delta rule into the slot's own
+    slab; the pool worked where it lies, as in the decode program."""
     r, params, state, table = kimi_runner()
     assert r.ragged_chunk == CHUNK
 
@@ -956,10 +1004,7 @@ def test_kimi_ragged_step_program_compiles_with_its_state_in_place(
         r._ragged_step_impl, donate_argnums=(1,), static_argnums=(7,)
     ).lower(params, state, table, i32(1, CHUNK), i32(1), i32(), i32(),
             1).compile()
-    ma = compiled.memory_analysis()
-    assert ma.alias_size_in_bytes >= sum(
-        a.size * a.dtype.itemsize
-        for a in (state.pool_k, state.kda, state.conv))
+    _assert_latent_pool_is_worked_where_it_lies(compiled, state)
     text = compiled.as_text()
     assert len(_calls(text, "kda_update")) == 20
     assert len(_calls(text, "moe_grouped_matmul")) == 78
@@ -967,6 +1012,67 @@ def test_kimi_ragged_step_program_compiles_with_its_state_in_place(
     # latent pool (key and value both), not the decode STEP's _mla kernel
     assert len(_calls(text, "paged_decode_attention")) == 7
     assert not _calls(text, "paged_decode_attention_mla")
+
+
+def test_kimi_insert_program_writes_its_pages_in_place(kimi_runner,
+                                                       one_chip):
+    """A prefilled 256-token prompt's rows, 576 wide as the cache-less
+    prefill left them, go into columns ``0:576`` of two pages of the pool,
+    two ``dynamic-update-slice`` on the buffer itself: the pad columns are
+    not written, nothing of the pool's size is made."""
+    from crowdllama_tpu.engine.hybrid import HybridPrefill
+    from crowdllama_tpu.models import hybrid as HY
+
+    r, _, state, _ = kimi_runner()
+    t = 256
+    rec = _on_chip(jax.eval_shape(
+        lambda: HY.zero_recurrent(r.cfg, 1, jnp.bfloat16)), one_chip)
+    ks = HybridPrefill(_sds((7, 1, 1, t, 576), jnp.bfloat16, one_chip),
+                       None, rec)
+
+    def scalar(dtype=jnp.int32):
+        return _sds((), dtype, one_chip)
+
+    compiled = jax.jit(r._insert_paged_impl, donate_argnums=(0,)).lower(
+        state, _sds((t // PAGE,), jnp.int32, one_chip), ks, None, scalar(),
+        scalar(), scalar(), scalar(jnp.float32), scalar(jnp.float32),
+        scalar(), scalar(jnp.float32),
+        _sds(state.recent.shape[1:], jnp.int32, one_chip),
+        _sds(state.keys.shape[1:], state.keys.dtype, one_chip)).compile()
+    _assert_latent_pool_is_worked_where_it_lies(compiled, state)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+    assert compiled.as_text().count(" dynamic-update-slice(") >= 2
+
+
+@pytest.mark.parametrize("model,pools", [
+    ("mistral", {"pool_k": (LAYERS, POOL_PAGES, HKV, PAGE, DH),
+                 "pool_v": (LAYERS, POOL_PAGES, HKV, PAGE, DH)}),
+    ("mixtral", {"pool_k": (4, 16 * 16 + 1, 8, PAGE, 128),
+                 "pool_v": (4, 16 * 16 + 1, 8, PAGE, 128)}),
+    ("nemotron", {"pool_k": (1, 32 * 16 + 1, 2, PAGE, 128),
+                  "pool_v": (1, 32 * 16 + 1, 2, PAGE, 128)}),
+    ("trinity", {"pool_k": (1, 32 * 40 + 1, 8, PAGE, 128),
+                 "pool_v": (1, 32 * 40 + 1, 8, PAGE, 128),
+                 "wpool_k": (4, 32 * 37 + 1, 8, PAGE, 128),
+                 "wpool_v": (4, 32 * 37 + 1, 8, PAGE, 128)}),
+    ("kimi", {"pool_k": KIMI_POOL, "pool_v": None}),
+])
+def test_only_a_latent_pool_takes_a_wider_row(request, model, pools):
+    """``engine/paged.py`` ``pool_row_width`` engages for a latent row that
+    is not whole lanes alone: a pool of K and V heads (128 wide in every
+    other configuration) keeps its shape to the byte, the kernels' gates
+    and the grid-step counter see the shape the pool has."""
+    from crowdllama_tpu.engine.paged import pool_row_width
+
+    build = request.getfixturevalue(f"{model}_runner")
+    r, _, state, _ = build("bf16") if model == "mistral" else build()
+    for name, shape in pools.items():
+        got = getattr(state, name)
+        assert (got is None) if shape is None else got.shape == shape, name
+    row = pool_row_width(r.cfg)
+    assert row == state.pool_k.shape[-1] == r._pool_shard.shape[-1]
+    assert (row == r.cfg.resolved_head_dim()) == (model != "kimi")
+    assert r.cfg.resolved_head_dim() == {"kimi": 576}.get(model, 128)
 
 
 def test_kda_update_kernel_compiles_at_the_cell_shape(one_chip):
@@ -990,15 +1096,19 @@ def test_kda_update_kernel_compiles_at_the_cell_shape(one_chip):
 
 
 def test_latent_attention_kernels_compile_at_the_cell_shape(one_chip):
-    """One shared kv head, 32 query heads, rows of 576: the latent decode
-    kernel, and the accepted prefill and ragged kernels with the row as key
-    AND value (the prefill kernel's query block shrinks to fit VMEM)."""
+    """One shared kv head, 32 query heads, rows of 576 — 640 as the pool
+    stores them, the value the first 512: the latent decode kernel and the
+    accepted ragged kernel on the stored row, the accepted prefill kernel
+    (cache-less: it never meets the pool) on the row as computed, each with
+    the row as key AND value (the prefill kernel's query block shrinks to
+    fit VMEM)."""
     from crowdllama_tpu.ops.pallas.paged import (paged_decode_attention_mla,
                                                  ragged_paged_attention)
 
     bf16, i32 = jnp.bfloat16, jnp.int32
-    layers, slots, heads, row, latent, np_ = 7, 32, 32, 576, 512, 12
-    pool = _sds((layers, slots * np_ + 1, 1, PAGE, row), bf16, one_chip)
+    slots, heads, row, latent, np_ = 32, 32, 576, 512, 12
+    stored = KIMI_POOL[-1]
+    pool = _sds(KIMI_POOL, bf16, one_chip)
     table = _sds((slots, np_), i32, one_chip)
     scale = 192 ** -0.5
 
@@ -1007,7 +1117,7 @@ def test_latent_attention_kernels_compile_at_the_cell_shape(one_chip):
                                           latent)
 
     compiled = jax.jit(decode).lower(
-        _sds((slots, heads, row), bf16, one_chip), pool,
+        _sds((slots, heads, stored), bf16, one_chip), pool,
         _sds((), i32, one_chip), table, _sds((slots,), i32, one_chip)
     ).compile()
     assert len(_calls(compiled.as_text(), "paged_decode_attention_mla")) == 1
@@ -1028,8 +1138,8 @@ def test_latent_attention_kernels_compile_at_the_cell_shape(one_chip):
                                       kl, cs, scale, use_pallas=True)
 
     _assert_kernel(jax.jit(ragged).lower(
-        _sds((slots + CHUNK, heads, row), bf16, one_chip),
-        _sds((1, 1, CHUNK, row), bf16, one_chip), pool,
+        _sds((slots + CHUNK, heads, stored), bf16, one_chip),
+        _sds((1, 1, CHUNK, stored), bf16, one_chip), pool,
         _sds((), i32, one_chip), table, _sds((slots + 1,), i32, one_chip),
         _sds((slots + 1,), i32, one_chip), _sds((), i32, one_chip)
     ).compile())
