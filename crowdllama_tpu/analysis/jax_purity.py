@@ -29,13 +29,11 @@ argument of ``pl.pallas_call``) — and flags, inside the traced bodies:
 
 ``host-sync-in-decode-loop``
     A ``for``/``while`` loop that both dispatches decode work
-    (``decode_steps_device`` / ``decode_megastep`` / ``ragged_step`` /
-    ``ragged_megastep`` / ``decode_steps``) and materializes device
-    values on the host
+    (``decode_steps_device`` / ``ragged_step`` / ``decode_steps``) and
+    materializes device values on the host
     (``np.asarray``/``np.array`` — called directly or handed to
     ``run_in_executor`` — or ``.item()``/``.tolist()``).  A per-step
-    readback inside the dispatch loop serializes host and device and is
-    exactly what the megastep exists to remove (docs/MEGASTEP.md): read
+    readback inside the dispatch loop serializes host and device: read
     the packed ``[K, B]`` block back ONCE per flight with
     ``jax.device_get`` instead.  Unlike the other rules this walks every
     function, not just traced ones — the scheduler's dispatch loop is
@@ -67,12 +65,9 @@ _IMPURE_PREFIXES = ("time.", "random.", "np.random.", "numpy.random.",
 
 # host-sync-in-decode-loop: decode dispatch entry points (the device-side
 # flights the scheduler's loop launches) and the host-materializing calls
-# that must not share a loop body with them.  ragged_megastep is the
-# fused ragged flight (K unified steps per dispatch) — a per-flight sync
-# creep there forfeits exactly the dispatches the fusion reclaimed.
+# that must not share a loop body with them.
 _DISPATCH_CALLS = frozenset({
-    "decode_steps_device", "decode_megastep", "ragged_step",
-    "ragged_megastep", "decode_steps",
+    "decode_steps_device", "ragged_step", "decode_steps",
 })
 _LOOP_SYNC_NAMES = frozenset({
     "np.asarray", "np.array", "numpy.asarray", "numpy.array",
@@ -348,7 +343,7 @@ def _loop_sync_findings(src: SourceFile) -> list[Finding]:
                         f"`{what}` in the same loop as a decode dispatch "
                         "serializes host and device per step — read the "
                         "packed [K, B] block back once per flight with "
-                        "jax.device_get (docs/MEGASTEP.md)"))
+                        "jax.device_get"))
         for child in ast.iter_child_nodes(node):
             visit(child, fname)
 

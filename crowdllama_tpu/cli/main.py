@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import contextlib
 import logging
 import re
 import signal
@@ -497,13 +498,6 @@ def render_top(text: str) -> str:
         elif name == "crowdllama_engine_duty_cycle":
             # highest-duty dispatch class is the one that matters
             row["duty"] = max(row.get("duty", 0.0), value)
-        elif name == "crowdllama_autotune_dial":
-            # autopilot dial positions (docs/AUTOTUNE.md) render as one
-            # compact K/k/B/C column: megastep K, spec draft cap k,
-            # step-token budget B, prefill chunk C.
-            row.setdefault("dials", {})[labels.get("dial", "")] = value
-        elif name == "crowdllama_autotune_moves_total":
-            row["moves"] = value
     lines = [
         f"workers {rollups.get('workers_total', 0):g} "
         f"(scraped {rollups.get('workers_scraped', 0):g})   "
@@ -512,25 +506,16 @@ def render_top(text: str) -> str:
         f"kv {rollups.get('kv_cache_utilization', 0):.2f}   "
         f"inflight {rollups.get('inflight', 0):g}",
         f"{'WORKER':<18}{'OK':>3}{'LOAD':>7}{'TOK/S':>8}{'ACT':>5}"
-        f"{'PEND':>6}{'OCC':>6}{'KV':>6}{'DUTY':>6}  {'DIALS':<20}",
+        f"{'PEND':>6}{'OCC':>6}{'KV':>6}{'DUTY':>6}",
     ]
     for wid in sorted(rows):
         r = rows[wid]
-        dials = r.get("dials") or {}
-        if dials:
-            dial_col = (f"K{dials.get('megastep_k', 0):g}"
-                        f"/k{dials.get('draft_k', 0):g}"
-                        f"/B{dials.get('step_token_budget', 0):g}"
-                        f"/C{dials.get('prefill_chunk', 0):g}"
-                        f" m{r.get('moves', 0):g}")
-        else:
-            dial_col = "-"
         lines.append(
             f"{wid:<18}{'y' if r.get('ok', 0) else 'n':>3}"
             f"{r.get('load', 0.0):>7.2f}{r.get('tok/s', 0.0):>8.1f}"
             f"{r.get('act', 0.0):>5.0f}{r.get('pend', 0.0):>6.0f}"
             f"{r.get('occ', 0.0):>6.2f}{r.get('kv', 0.0):>6.2f}"
-            f"{r.get('duty', 0.0):>6.2f}  {dial_col:<20}")
+            f"{r.get('duty', 0.0):>6.2f}")
     if not rows:
         lines.append("(no workers visible)")
     return "\n".join(lines)
@@ -698,18 +683,32 @@ async def run_node(cfg: Configuration, worker_mode: bool) -> None:
     """Worker: engine + peer.  Consumer: peer + gateway.  Either may add IPC."""
     from crowdllama_tpu.gateway.gateway import Gateway
     from crowdllama_tpu.ipc.server import IPCServer
+    from crowdllama_tpu.net.host import bind_listener
     from crowdllama_tpu.peer.peer import Peer
 
     km = KeyManager(cfg.key_path or None)
     component = "worker" if worker_mode else "consumer"
     key = km.get_or_create_private_key(component)
 
-    engine = _make_engine(cfg, worker_mode)
-    log.info("starting %s node (%s)", component, version_string())
-    await engine.start()
+    # The node's ports are taken BEFORE the engine starts and served on
+    # after it: an engine start lasts minutes, and a port that a launcher
+    # found free before it (the benchmark's bind-and-close) may not be
+    # free after (ROADMAP W0(k)).  Until the node listens a dial is
+    # refused as by a closed port.
+    with contextlib.ExitStack() as held:    # closed if the start fails
+        listen_sock = held.enter_context(
+            bind_listener(cfg.listen_host, cfg.listen_port))
+        obs_sock = None
+        if worker_mode and cfg.worker_metrics_port:
+            obs_sock = held.enter_context(
+                bind_listener(cfg.listen_host, cfg.worker_metrics_port))
+        engine = _make_engine(cfg, worker_mode)
+        log.info("starting %s node (%s)", component, version_string())
+        await engine.start()
+        held.pop_all()
 
     peer = Peer(key, cfg, engine=engine, worker_mode=worker_mode)
-    await peer.start()
+    await peer.start(listen_sock)
 
     gateway = None
     gossip = None
@@ -755,24 +754,11 @@ async def run_node(cfg: Configuration, worker_mode: bool) -> None:
             gossip.metrics = gateway.obs.metrics
             await gossip.start()
         await gateway.start()
-    else:
-        if cfg.autotune and cfg.gateway_peers:
-            # Autopilot warm-start plane (docs/AUTOTUNE.md): the worker
-            # joins the gossip plane directly — peer.py dispatches
-            # gossip_frame on every node — so its tuner reads/writes the
-            # tune/<model> keys the gateways replicate.  The join sync
-            # pulls the swarm's current operating points immediately.
-            from crowdllama_tpu.swarm.gossip import GossipNode
-
-            gossip = GossipNode(peer, peers=cfg.gateway_peers,
-                                interval=cfg.gossip_interval)
-            await gossip.start()
-            engine.set_gossip(gossip)
-        if cfg.worker_metrics_port:
-            from crowdllama_tpu.obs.http import ObsServer
-            obs_server = ObsServer(peer, host=cfg.listen_host,
-                                   port=cfg.worker_metrics_port)
-            await obs_server.start()
+    elif cfg.worker_metrics_port:
+        from crowdllama_tpu.obs.http import ObsServer
+        obs_server = ObsServer(peer, host=cfg.listen_host,
+                               port=cfg.worker_metrics_port, sock=obs_sock)
+        await obs_server.start()
 
     ipc = None
     if cfg.ipc_socket:

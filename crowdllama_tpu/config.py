@@ -129,29 +129,6 @@ class Configuration:
     # alternating chunked-prefill dispatch.
     step_token_budget: int = 0
     ragged_prefill: bool = True
-    # Kernel-looped decode megastep (docs/MEGASTEP.md): K full decode
-    # steps per host dispatch with on-device sampling + done-flags.
-    # 0 = legacy per-step-chunk path; runners without supports_megastep
-    # (replicated/sharded) fall back to legacy regardless.
-    megastep_k: int = 0
-    # Closed-loop performance autopilot (docs/AUTOTUNE.md): coordinate
-    # descent over megastep K / spec draft cap / step_token_budget /
-    # prefill chunk, scored from the duty-cycle + tokens-per-dispatch
-    # gauges with an SLO burn penalty.  Off by default — the dials stay
-    # wherever the flags above put them.
-    autotune: bool = False
-    # Retire windows per measurement phase (baseline and trial phases
-    # alternate, so one dial move lands per ~2x this many windows).
-    autotune_interval: int = 32
-    # Dial ceilings for the coordinate grids (floors are structural:
-    # page-size alignment, >= 1 draft, K = 0 allowed).
-    autotune_megastep_max: int = 16
-    autotune_draft_max: int = 8
-    autotune_budget_max: int = 4096
-    autotune_prefill_max: int = 1024
-    # Ceiling for the remote-draft pipeline-depth dial (the depth_hint
-    # advertised to gateways, docs/SPECULATIVE.md).
-    autotune_depth_max: int = 32
     warmup: bool = True  # compile prefill/decode at engine start
     quantize: str = ""  # "" (bf16) | "int8" | "int4" weight-only (ops/quant.py)
     # KV cache layout: "paged" (engine/paged.py, the default: page pool +
@@ -328,24 +305,6 @@ class Configuration:
         if env.get("CROWDLLAMA_TPU_RAGGED_PREFILL"):
             cfg.ragged_prefill = env["CROWDLLAMA_TPU_RAGGED_PREFILL"] in (
                 "1", "true")
-        cfg.megastep_k = int(env.get(
-            "CROWDLLAMA_TPU_MEGASTEP_K", cfg.megastep_k))
-        if env.get("CROWDLLAMA_TPU_AUTOTUNE"):
-            cfg.autotune = env["CROWDLLAMA_TPU_AUTOTUNE"] in ("1", "true")
-        cfg.autotune_interval = int(env.get(
-            "CROWDLLAMA_TPU_AUTOTUNE_INTERVAL", cfg.autotune_interval))
-        cfg.autotune_megastep_max = int(env.get(
-            "CROWDLLAMA_TPU_AUTOTUNE_MEGASTEP_MAX",
-            cfg.autotune_megastep_max))
-        cfg.autotune_draft_max = int(env.get(
-            "CROWDLLAMA_TPU_AUTOTUNE_DRAFT_MAX", cfg.autotune_draft_max))
-        cfg.autotune_budget_max = int(env.get(
-            "CROWDLLAMA_TPU_AUTOTUNE_BUDGET_MAX", cfg.autotune_budget_max))
-        cfg.autotune_prefill_max = int(env.get(
-            "CROWDLLAMA_TPU_AUTOTUNE_PREFILL_MAX",
-            cfg.autotune_prefill_max))
-        cfg.autotune_depth_max = int(env.get(
-            "CROWDLLAMA_TPU_AUTOTUNE_DEPTH_MAX", cfg.autotune_depth_max))
         cfg.shard_group = env.get("CROWDLLAMA_TPU_SHARD_GROUP", cfg.shard_group)
         cfg.shard_index = int(env.get("CROWDLLAMA_TPU_SHARD_INDEX", cfg.shard_index))
         cfg.shard_count = int(env.get("CROWDLLAMA_TPU_SHARD_COUNT", cfg.shard_count))
@@ -497,24 +456,6 @@ class Configuration:
         if cfg.trace_ttl < 0:
             raise ValueError(f"trace_ttl must be >= 0, "
                              f"got {cfg.trace_ttl}")
-        if cfg.autotune_interval < 1:
-            raise ValueError(f"autotune_interval must be >= 1, "
-                             f"got {cfg.autotune_interval}")
-        if cfg.autotune_megastep_max < 0:
-            raise ValueError(f"autotune_megastep_max must be >= 0, "
-                             f"got {cfg.autotune_megastep_max}")
-        if cfg.autotune_draft_max < 1:
-            raise ValueError(f"autotune_draft_max must be >= 1, "
-                             f"got {cfg.autotune_draft_max}")
-        if cfg.autotune_budget_max < 1:
-            raise ValueError(f"autotune_budget_max must be >= 1, "
-                             f"got {cfg.autotune_budget_max}")
-        if cfg.autotune_prefill_max < 64:
-            raise ValueError(f"autotune_prefill_max must be >= 64, "
-                             f"got {cfg.autotune_prefill_max}")
-        if cfg.autotune_depth_max < 1:
-            raise ValueError(f"autotune_depth_max must be >= 1, "
-                             f"got {cfg.autotune_depth_max}")
         if cfg.slo_ttft_ms < 0:
             raise ValueError(f"slo_ttft_ms must be >= 0, "
                              f"got {cfg.slo_ttft_ms}")
@@ -656,36 +597,6 @@ class Configuration:
                             help="unified ragged batch: per-step token "
                                  "budget (decode slots + one prefill "
                                  "chunk; 0 = auto)")
-        parser.add_argument("--megastep-k", dest="megastep_k", type=int,
-                            help="kernel-looped decode megastep: K full "
-                                 "decode steps per host dispatch with "
-                                 "on-device sampling (0 = legacy per-step "
-                                 "path)")
-        parser.add_argument("--autotune", dest="autotune",
-                            action="store_const", const=True, default=None,
-                            help="closed-loop performance autopilot "
-                                 "(docs/AUTOTUNE.md): coordinate descent "
-                                 "over megastep K, spec draft cap, "
-                                 "step_token_budget and prefill chunk, "
-                                 "scored from the observatory gauges")
-        parser.add_argument("--autotune-interval", dest="autotune_interval",
-                            type=int,
-                            help="retire windows per autotune measurement "
-                                 "phase (one dial move per ~2x this)")
-        parser.add_argument("--autotune-megastep-max",
-                            dest="autotune_megastep_max", type=int,
-                            help="autotune ceiling for megastep K")
-        parser.add_argument("--autotune-draft-max",
-                            dest="autotune_draft_max", type=int,
-                            help="autotune ceiling for the adaptive spec "
-                                 "draft-length cap")
-        parser.add_argument("--autotune-budget-max",
-                            dest="autotune_budget_max", type=int,
-                            help="autotune ceiling for the ragged "
-                                 "step_token_budget")
-        parser.add_argument("--autotune-prefill-max",
-                            dest="autotune_prefill_max", type=int,
-                            help="autotune ceiling for the prefill chunk")
         parser.add_argument("--no-ragged-prefill", dest="ragged_prefill",
                             action="store_const", const=False, default=None,
                             help="disable unified ragged prefill: long "
@@ -804,10 +715,7 @@ class Configuration:
                 "kv_dtype", "relay_mode", "spec_decode", "spec_draft",
                 "spec_draft_model", "spec_draft_path", "spec_draft_max",
                 "gateway_spec_pipeline",
-                "step_token_budget", "ragged_prefill", "megastep_k",
-                "autotune", "autotune_interval", "autotune_megastep_max",
-                "autotune_draft_max", "autotune_budget_max",
-                "autotune_prefill_max",
+                "step_token_budget", "ragged_prefill",
                 "profile_dir", "trace_buffer", "worker_metrics_port",
                 "flight_recorder", "trace_ttl", "metrics_exemplars",
                 "slo_ttft_ms", "slo_decode_ms",

@@ -32,7 +32,7 @@ from crowdllama_tpu.core.messages import (
     verify_result_msg,
 )
 from crowdllama_tpu.obs.metrics import (
-    ENGINE_TELEMETRY, process_age_seconds,
+    DISPATCH_CLASSES, ENGINE_TELEMETRY, process_age_seconds,
 )
 from crowdllama_tpu.testing import faults
 
@@ -168,22 +168,12 @@ class Engine:
         """
         g = {"pending_depth": 0.0, "active_slots": 0.0,
              "batch_occupancy": 0.0, "kv_cache_utilization": 0.0,
-             "prefill_chunk_slots": 0.0, "step_token_budget_used": 0.0,
              "host_dispatches_total": 0.0, "tokens_per_dispatch": 0.0}
         # Duty-cycle gauges (PR 13): labeled children, one per dispatch
         # class, zero on engines without a scheduler for the same
         # absent()-alert reason.
-        for cls in ("plain", "megastep", "ragged", "ragged_mega", "spec"):
+        for cls in DISPATCH_CLASSES:
             g[f"duty_cycle|dispatch={cls}"] = 0.0
-        # Autopilot plane (ISSUE 17, docs/AUTOTUNE.md): the
-        # crowdllama_autotune_* families exist on every worker, zeros on
-        # engines that do not tune.
-        g.update({"autotune_score": 0.0, "autotune_moves_total": 0.0,
-                  "autotune_reverts_total": 0.0,
-                  "autotune_backoffs_total": 0.0})
-        for dial in ("megastep_k", "draft_k", "step_token_budget",
-                     "prefill_chunk", "pipeline_depth"):
-            g[f"autotune_dial|dial={dial}"] = 0.0
         return g
 
     def _verify_frame_fields(self) -> tuple[int, int]:
@@ -192,11 +182,6 @@ class Engine:
         it; 0 = drafting paused, send pure acks) and the pipeline depth
         the worker is willing to absorb."""
         return 0, 1
-
-    def set_gossip(self, gossip) -> None:
-        """Hand the node's GossipNode to the engine (CLI wiring) so the
-        autopilot can warm-start from / publish to the ``tune/<model>``
-        CRDT keys (docs/AUTOTUNE.md).  No-op on engines that don't tune."""
 
     async def drain(self, timeout: float = 30.0) -> bool:
         """Finish in-flight work before shutdown; True when drained."""
@@ -544,10 +529,6 @@ class JaxEngine(Engine):
         self._runner = None
         self._peer = None  # set by attach_peer (KV fetch dials through it)
         self._kv_streams = None  # pooled donor streams (lazy StreamPool)
-        # Closed-loop autopilot (docs/AUTOTUNE.md): built in start() when
-        # config.autotune is set; gossip may be wired before OR after.
-        self.autotuner = None
-        self._gossip = None
         # The profiler's single flight: the latch holds from the start of
         # profile_start to the end of profile_stop; _profile is the running
         # trace (profile_start's answer) while it can be stopped.
@@ -569,14 +550,6 @@ class JaxEngine(Engine):
         r, s = self._runner, self.scheduler
         return (int(getattr(r, "draft_len", 0)),
                 int(getattr(s, "spec_pipeline_depth", 1)))
-
-    def set_gossip(self, gossip) -> None:
-        """CLI wiring for the autopilot's warm-start/publish plane.  The
-        GossipNode starts after the engine, so this may land either side
-        of start(): stash for construction AND forward to a live tuner."""
-        self._gossip = gossip
-        if self.autotuner is not None:
-            self.autotuner.set_gossip(gossip)
 
     async def start(self) -> None:
         """Build tokenizer/params/runner (compiles on first use)."""
@@ -650,26 +623,8 @@ class JaxEngine(Engine):
             admission_pending_max=self.config.admission_pending_max,
             spec_draft_max=self.config.spec_draft_max,
             ragged=self.config.ragged_prefill,
-            megastep_k=self.config.megastep_k,
             wedge_multiplier=self.config.wedge_multiplier)
         self.scheduler.drain_requested_cb = self._chaos_drain
-        if self.config.autotune:
-            from crowdllama_tpu.engine.autotune import AutoTuner
-
-            self.autotuner = AutoTuner(
-                self.scheduler,
-                model_id=self.config.model,
-                interval=self.config.autotune_interval,
-                bounds={
-                    "megastep_k": self.config.autotune_megastep_max,
-                    "draft_k": self.config.autotune_draft_max,
-                    "step_token_budget": self.config.autotune_budget_max,
-                    "prefill_chunk": self.config.autotune_prefill_max,
-                    "pipeline_depth": self.config.autotune_depth_max,
-                },
-                decode_ms=self.config.slo_decode_ms,
-                gossip=self._gossip)
-            self.scheduler.attach_autotuner(self.autotuner)
         self.scheduler.start()
         ENGINE_TELEMETRY.startup_set(
             "ready", time.monotonic() - ENGINE_TELEMETRY.t_import)
@@ -699,11 +654,6 @@ class JaxEngine(Engine):
         state = r.insert(state, 0, ks, vs, plen, tok, 0.0, 1.0)
         for k in {1, self.config.decode_chunk}:
             _, state = r.decode_steps(state, k)
-        if self.config.megastep_k and getattr(r, "supports_megastep", False):
-            # The megastep program (docs/MEGASTEP.md) is its own XLA
-            # signature; compile it now so the first saturated chunk
-            # doesn't pay for it.
-            _, _, state = r.decode_megastep(state, self.config.megastep_k)
         if getattr(r, "prefix_cache", False):
             r.warmup_ctx_prefill(state)
         if getattr(r, "prefill_chunk", 0) and r.max_seq > r.prefill_chunk + 1:
@@ -1074,11 +1024,6 @@ class JaxEngine(Engine):
                     "retunes": self.scheduler.spec_retunes,
                     "probes": self.scheduler.spec_probes,
                 }
-        if self.autotuner is not None:
-            # Autopilot snapshot (docs/AUTOTUNE.md): the live operating
-            # point + move accounting, next to the spec controller it
-            # generalizes.
-            d["autotune"] = self.autotuner.describe()
         return d
 
     # ---- the profiler control (obs/http.py, the IPC "profile" op) --------

@@ -33,8 +33,8 @@ and full attention layers, a cache of two kinds of page).
 * Per-slot recurrent state for the Mamba or KDA layers
   (``PagedDecodeState.ssm`` or ``.kda``, and ``.conv``): written by prefill
   (``insert`` places it, or the ragged step's chunk continues the slot's
-  own), carried IN PLACE through the decode and ragged steps and both
-  megasteps as part of the donated state, zeroed on release.
+  own), carried IN PLACE through the decode and ragged steps as part of
+  the donated state, zeroed on release.
 
 What rests on "tokens done == pages of KV that can be handed over" cannot
 be right for such a slot — a page of KV says nothing of the state the
@@ -442,21 +442,10 @@ class HybridPagedModelRunner(PagedModelRunner):
         tokens, state = super().decode_steps_device(state, num_steps)
         return tokens, self._bank(state)
 
-    def decode_megastep(self, state, num_steps, eos_ids=None, budgets=None):
-        tokens, done, state = super().decode_megastep(state, num_steps,
-                                                      eos_ids, budgets)
-        return tokens, done, self._bank(state)
-
     def ragged_step(self, state, job, num_steps: int = 1):
         tokens, state = super().ragged_step(self._clean(state, job), job,
                                             num_steps)
         return tokens, self._bank(state)
-
-    def ragged_megastep(self, state, job, num_steps: int = 1, eos_ids=None,
-                        budgets=None):
-        tokens, done, state = super().ragged_megastep(
-            self._clean(state, job), job, num_steps, eos_ids, budgets)
-        return tokens, done, self._bank(state)
 
     def ragged_abort(self, job) -> None:
         if self._ragged_slot == job.slot and job.done_tokens:

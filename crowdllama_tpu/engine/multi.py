@@ -106,13 +106,6 @@ class MultiEngine(Engine):
         for eng in self._engines.values():
             eng.attach_peer(peer)
 
-    def set_gossip(self, gossip) -> None:
-        """Autopilot warm-start plane (docs/AUTOTUNE.md): every child
-        tunes its own model, so each one gets the node's GossipNode."""
-        self._gossip = gossip
-        for eng in self._engines.values():
-            eng.set_gossip(gossip)
-
     def model_dir(self, model: str) -> str | None:
         eng = self._engines.get(model)
         return eng.model_dir(model) if eng is not None else None
@@ -134,26 +127,19 @@ class MultiEngine(Engine):
         log.info("hot-registered model %s from %s", name, path or "<default>")
 
     # Point-in-time gauges (spec_draft_len is the controller's CURRENT k,
-    # the ratios a per-child fullness, step_token_budget_used the last
-    # dispatched step's load): max across children.  Everything else
-    # (depths, counts — prefill_chunk_slots included — spec acceptance
-    # totals) sums.
+    # the ratios a per-child fullness): max across children.  Everything
+    # else (depths, counts, spec acceptance totals) sums.
     _GAUGE_MAX = frozenset(
         {"batch_occupancy", "kv_cache_utilization", "spec_draft_len",
-         "step_token_budget_used", "tokens_per_dispatch",
-         "autotune_score"})
+         "tokens_per_dispatch"})
 
     def obs_gauges(self) -> dict:
         out: dict = {}
         for eng in self._engines.values():
             for k, v in eng.obs_gauges().items():
                 # duty_cycle|dispatch=... is a ratio, not a depth: max,
-                # like the other point-in-time gauges.  Autotune dial
-                # positions are point-in-time too (a summed K would read
-                # as a dial value no child actually runs); the autotune
-                # move/revert/backoff counters sum like any counter.
-                if (k in self._GAUGE_MAX or k.startswith("duty_cycle")
-                        or k.startswith("autotune_dial")):
+                # like the other point-in-time gauges.
+                if k in self._GAUGE_MAX or k.startswith("duty_cycle"):
                     out[k] = max(out.get(k, 0.0), v)
                 else:
                     out[k] = out.get(k, 0.0) + v

@@ -60,8 +60,6 @@ from crowdllama_tpu.engine.sampling import (
 from crowdllama_tpu.models import transformer as T
 from crowdllama_tpu.obs.metrics import ENGINE_TELEMETRY
 from crowdllama_tpu.ops.attention import decode_attention, decode_attention_q
-from crowdllama_tpu.ops.pallas.megastep import (run_decode_megastep,
-                                                run_ragged_megastep)
 from crowdllama_tpu.ops.pallas.paged import (
     decode_grid_steps,
     decode_work,
@@ -351,18 +349,12 @@ class PagedModelRunner(ModelRunner):
                                      donate_argnums=(0,))
         self._decode_paged = jax.jit(self._decode_paged_impl,
                                      donate_argnums=(1,), static_argnums=(3,))
-        self._decode_mega_paged = jax.jit(self._decode_mega_paged_impl,
-                                          donate_argnums=(1,),
-                                          static_argnums=(5,))
         self._release_paged = jax.jit(self._release_paged_impl,
                                       donate_argnums=(0,))
         self._prefill_ctx = jax.jit(self._prefill_ctx_impl)
         self._ragged_step_fn = jax.jit(self._ragged_step_impl,
                                        donate_argnums=(1,),
                                        static_argnums=(7,))
-        self._ragged_mega_fn = jax.jit(self._ragged_mega_impl,
-                                       donate_argnums=(1,),
-                                       static_argnums=(9,))
 
     @property
     def pool_layers(self) -> int:
@@ -844,11 +836,9 @@ class PagedModelRunner(ModelRunner):
                 for ring, (pool, table, klens) in views.items()}
 
     def _paged_step_body(self, params, page_table):
-        """One paged decode step as a ``lax.scan`` body closure — shared
-        verbatim by the per-step program (``_decode_paged_impl``) and the
-        megastep (``_decode_mega_paged_impl``) so the two paths cannot
-        drift (byte-identity contract, docs/MEGASTEP.md).  The layer loop
-        is :meth:`_decode_layers`."""
+        """One paged decode step as the ``lax.scan`` body closure of
+        ``_decode_paged_impl``.  The layer loop is
+        :meth:`_decode_layers`."""
         cfg = self.cfg
         pg = self.page_size
         b = self.max_slots
@@ -967,21 +957,10 @@ class PagedModelRunner(ModelRunner):
             length=num_steps)
         return tokens, new_state
 
-    def _decode_mega_paged_impl(self, params, state: PagedDecodeState,
-                                page_table, eos_ids, budgets, num_steps: int):
-        """K paged decode steps with on-device done-flags in one dispatch;
-        returns (tokens [K, B], done [K, B] bool, new state)."""
-        return run_decode_megastep(self._paged_step_body(params, page_table),
-                                   state, eos_ids, budgets, num_steps)
-
     def _ragged_step_body(self, params, page_table, total_len, chunk_slot,
                           c: int):
-        """One unified ragged step (docs/RAGGED_BATCH.md) as a ``lax.scan``
-        body closure — shared verbatim by the per-dispatch program
-        (``_ragged_step_impl``) and the fused ragged megastep
-        (``_ragged_mega_impl``), the same single-body contract that keeps
-        ``_paged_step_body``'s two consumers from drifting (byte-identity,
-        docs/MEGASTEP.md).
+        """One unified ragged step (docs/RAGGED_BATCH.md) as the
+        ``lax.scan`` body closure of ``_ragged_step_impl``.
 
         One call of the returned ``step(state, (ctx_i, ctoks))`` runs ONE
         jitted forward over B+C query rows: one decode token per active
@@ -1113,21 +1092,6 @@ class PagedModelRunner(ModelRunner):
         # chunk rows (later steps past the prompt end leave it untouched).
         ridx = (num_steps - 1) - jnp.argmax(flags[::-1])
         return tokens, chunk_logits[ridx], new_state
-
-    def _ragged_mega_impl(self, params, state: PagedDecodeState, page_table,
-                          chunk_tokens, ctx_arr, total_len, chunk_slot,
-                          eos_ids, budgets, num_steps: int):
-        """Fused ragged megastep: ``num_steps`` unified steps in ONE
-        device-resident while_loop with on-device sampling and per-slot
-        done-flags for the decode rows (docs/MEGASTEP.md, "Fused ragged
-        megastep").  The loop body is the SAME closure the scan path
-        uses, so the two programs cannot drift.  Returns (tokens [K, B],
-        done [K, B] bool, last prompt-token logits [V], state)."""
-        step = self._ragged_step_body(params, page_table, total_len,
-                                      chunk_slot, chunk_tokens.shape[1])
-        return run_ragged_megastep(step, state, eos_ids, budgets,
-                                   ctx_arr, chunk_tokens, total_len,
-                                   num_steps, vocab=self.cfg.vocab_size)
 
     # ------------------------------------------------------------------ API
 
@@ -1374,24 +1338,6 @@ class PagedModelRunner(ModelRunner):
         self._advance(num_steps)
         return tokens, new_state
 
-    def decode_megastep(self, state: PagedDecodeState, num_steps: int,
-                        eos_ids=None, budgets=None):
-        """Paged megastep (docs/MEGASTEP.md): see ModelRunner
-        .decode_megastep.  Page growth assumes the full ``num_steps`` even
-        when the scan early-exits — a conservative host-side overestimate
-        (the extra pages free at release, exactly like EOS overshoot in
-        the per-step chunked path)."""
-        eos_ids, budgets = self._mega_limits_dev(eos_ids, budgets)
-        self._ensure_capacity(num_steps)
-        t_c = ENGINE_TELEMETRY.compile_begin("decode_megastep_paged",
-                                             num_steps)
-        tokens, done, new_state = self._decode_mega_paged(
-            self.params, state, jnp.asarray(self.page_table),
-            eos_ids, budgets, num_steps)
-        ENGINE_TELEMETRY.compile_end("decode_megastep_paged", num_steps, t_c)
-        self._advance(num_steps)
-        return tokens, done, new_state
-
     # ----------------------- unified ragged batch (docs/RAGGED_BATCH.md)
 
     class RaggedPrefillJob:
@@ -1486,13 +1432,12 @@ class PagedModelRunner(ModelRunner):
         return min(wp, self.max_pages_per_slot)
 
     def _ragged_provision(self, job: "RaggedPrefillJob", num_steps: int):
-        """Dispatch-time host bookkeeping shared by :meth:`ragged_step`
-        and :meth:`ragged_megastep`: grow the chunk slot's pages to the
-        dispatch end (so ``done_tokens == exportable KV`` holds even
-        while the flight is still running on device), grow every
-        decoding slot for ``num_steps`` tokens, and build the [K, C]
-        chunk-token block + per-step context array.  Returns
-        ``(chunk_tokens, ctx_arr, end, wp)``."""
+        """Dispatch-time host bookkeeping of :meth:`ragged_step`: grow
+        the chunk slot's pages to the dispatch end (so ``done_tokens ==
+        exportable KV`` holds even while the flight is still running on
+        device), grow every decoding slot for ``num_steps`` tokens, and
+        build the [K, C] chunk-token block + per-step context array.
+        Returns ``(chunk_tokens, ctx_arr, end, wp)``."""
         c = self.ragged_chunk
         pg = self.page_size
         slot = job.slot
@@ -1520,10 +1465,10 @@ class PagedModelRunner(ModelRunner):
 
     def _ragged_commit(self, job: "RaggedPrefillJob", end: int,
                        num_steps: int, last, wp: int) -> None:
-        """Post-dispatch host bookkeeping shared by both unified entry
-        points: bank the dispatch-end progress and the final prompt
-        token's logits, advance every slot's host sequence mirror, and
-        prefix-index the job's freshly completed pages."""
+        """Post-dispatch host bookkeeping of :meth:`ragged_step`: bank
+        the dispatch-end progress and the final prompt token's logits,
+        advance every slot's host sequence mirror, and prefix-index the
+        job's freshly completed pages."""
         job.done_tokens = end
         job.last_logits = last
         self._host_seq[job.slot] = end
@@ -1552,38 +1497,6 @@ class PagedModelRunner(ModelRunner):
         ENGINE_TELEMETRY.compile_end("ragged_step", sig, t_c)
         self._ragged_commit(job, end, num_steps, last, wp)
         return tokens, new_state
-
-    def ragged_megastep(self, state: PagedDecodeState,
-                        job: "RaggedPrefillJob", num_steps: int = 1,
-                        eos_ids=None, budgets=None):
-        """Fused ragged megastep (docs/MEGASTEP.md): ``num_steps`` unified
-        steps in ONE host dispatch — every decode slot advances one token
-        per step with ON-DEVICE sampling and per-slot done-flags, AND the
-        job prefills up to ``ragged_chunk`` prompt tokens per step, chunk
-        KV scattering to its pool pages each iteration.
-
-        Decode-side contract matches :meth:`decode_megastep` (tokens +
-        flags stay on device, one transfer per flight; early exit only
-        once every live slot fired AND the chunk is complete).  Prefill-
-        side contract matches :meth:`ragged_step` (``done_tokens``
-        advances to the dispatch end, ``last_logits`` banked, pages
-        pre-provisioned at dispatch so ``done_tokens == exportable KV``
-        even mid-flight).  Returns (tokens [K, B], done [K, B] bool, new
-        state)."""
-        c = self.ragged_chunk
-        eos_ids, budgets = self._mega_limits_dev(eos_ids, budgets)
-        chunk_tokens, ctx_arr, end, wp = self._ragged_provision(job,
-                                                                num_steps)
-        sig = f"{num_steps}x{c}w{wp}"
-        t_c = ENGINE_TELEMETRY.compile_begin("ragged_megastep", sig)
-        tokens, done, last, new_state = self._ragged_mega_fn(
-            self.params, state, jnp.asarray(self.page_table[:, :wp]),
-            jnp.asarray(chunk_tokens), jnp.asarray(ctx_arr),
-            jnp.int32(len(job.prompt_ids)), jnp.int32(job.slot),
-            eos_ids, budgets, num_steps)
-        ENGINE_TELEMETRY.compile_end("ragged_megastep", sig, t_c)
-        self._ragged_commit(job, end, num_steps, last, wp)
-        return tokens, done, new_state
 
     def _ragged_index(self, job: "RaggedPrefillJob") -> None:
         """Prefix-index the job's freshly completed full pages.

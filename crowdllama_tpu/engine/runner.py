@@ -39,7 +39,6 @@ from crowdllama_tpu.engine.sampling import (
 from crowdllama_tpu.models import transformer as T
 from crowdllama_tpu.models.config import ModelConfig
 from crowdllama_tpu.obs.metrics import ENGINE_TELEMETRY
-from crowdllama_tpu.ops.pallas.megastep import NO_BUDGET, run_decode_megastep
 from crowdllama_tpu.parallel.mesh import (
     AXIS_DP,
     AXIS_PP,
@@ -115,11 +114,6 @@ def prefill_buckets(max_seq: int) -> list[int]:
 
 
 class ModelRunner:
-    # Megastep decode (ops/pallas/megastep.py): K full steps per host
-    # dispatch with on-device sampling + done-flags.  Wrapper runners that
-    # replay frames (parallel/replicated.py) opt out explicitly; sharded
-    # multi-process runners lack the attribute (getattr default False).
-    supports_megastep = True
     #: a model whose layers differ in kind keeps state this runner has no
     #: place for; engine/hybrid.py's runner says True
     serves_hybrid = False
@@ -214,11 +208,6 @@ class ModelRunner:
         )
         self._decode = jax.jit(self._decode_impl, donate_argnums=(1,),
                                static_argnums=(2,))
-        # Megastep: the same step body plus on-device done-flags and a
-        # whole-batch early exit (ops/pallas/megastep.py).  num_steps is
-        # static → each K claims its own "decode_megastep" compile bucket.
-        self._decode_mega = jax.jit(self._decode_mega_impl,
-                                    donate_argnums=(1,), static_argnums=(4,))
         self._insert = jax.jit(self._insert_impl, donate_argnums=(0,))
         self._release = jax.jit(self._release_impl, donate_argnums=(0,))
         self._announce_attention_paths()
@@ -366,9 +355,7 @@ class ModelRunner:
 
     def _decode_step_body(self, params):
         """One decode step as a ``lax.scan`` body closure — THE hot-path
-        step, shared verbatim by the per-step program (``_decode_impl``)
-        and the megastep (``_decode_mega_impl``) so the two paths cannot
-        drift (byte-identity contract, docs/MEGASTEP.md)."""
+        step of ``_decode_impl``."""
 
         def step(st: DecodeState, _):
             positions = jnp.minimum(st.seq_lens, self.max_seq - 1)
@@ -429,13 +416,6 @@ class ModelRunner:
         new_state, tokens = jax.lax.scan(self._decode_step_body(params),
                                          state, length=num_steps)
         return tokens, new_state
-
-    def _decode_mega_impl(self, params, state: DecodeState, eos_ids, budgets,
-                          num_steps: int):
-        """K decode steps with on-device done-flags in one dispatch;
-        returns (tokens [K, B], done [K, B] bool, new state)."""
-        return run_decode_megastep(self._decode_step_body(params), state,
-                                   eos_ids, budgets, num_steps)
 
     # ------------------------------------------------------------------ API
 
@@ -753,42 +733,3 @@ class ModelRunner:
         out = self._decode(self.params, state, num_steps)
         ENGINE_TELEMETRY.compile_end("decode", num_steps, t_c)
         return out
-
-    def decode_megastep(self, state: DecodeState, num_steps: int,
-                        eos_ids=None, budgets=None):
-        """K full decode steps per host dispatch with on-device sampling
-        and per-slot done-flags (docs/MEGASTEP.md).
-
-        Returns ``(tokens [K, B], done [K, B], state)`` — tokens and flags
-        stay on device so the host pays ONE transfer per megastep.
-        ``eos_ids`` [B] int32 (-1 disables) and ``budgets`` [B] int32
-        (tokens the host still wants from each slot) drive the flags and
-        the whole-batch early exit; the defaults disable both, degenerating
-        to :meth:`decode_steps_device` plus all-false flags.
-        """
-        eos_ids, budgets = self._mega_limits_dev(eos_ids, budgets)
-        t_c = ENGINE_TELEMETRY.compile_begin("decode_megastep", num_steps)
-        tokens, done, new_state = self._decode_mega(
-            self.params, state, eos_ids, budgets, num_steps)
-        ENGINE_TELEMETRY.compile_end("decode_megastep", num_steps, t_c)
-        return tokens, done, new_state
-
-    def _mega_limits_dev(self, eos_ids, budgets):
-        """Device-resident eos/budget vectors; the no-limit defaults are
-        cached (a fresh host alloc + H2D pair per flight is measurable
-        against a tiny-model CPU step)."""
-        if eos_ids is None:
-            if not hasattr(self, "_mega_no_eos"):
-                self._mega_no_eos = jnp.full((self.max_slots,), -1,
-                                             jnp.int32)
-            eos_ids = self._mega_no_eos
-        else:
-            eos_ids = jnp.asarray(eos_ids, jnp.int32)
-        if budgets is None:
-            if not hasattr(self, "_mega_no_budget"):
-                self._mega_no_budget = jnp.full((self.max_slots,),
-                                                NO_BUDGET, jnp.int32)
-            budgets = self._mega_no_budget
-        else:
-            budgets = jnp.asarray(budgets, jnp.int32)
-        return eos_ids, budgets

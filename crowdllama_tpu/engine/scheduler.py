@@ -48,7 +48,7 @@ import jax
 import numpy as np
 
 from crowdllama_tpu.engine.runner import ModelRunner
-from crowdllama_tpu.obs.metrics import ENGINE_TELEMETRY
+from crowdllama_tpu.obs.metrics import DISPATCH_CLASSES, ENGINE_TELEMETRY
 from crowdllama_tpu.obs.trace import (
     SCHED_ADMIT,
     SCHED_DISPATCH,
@@ -174,13 +174,9 @@ class _InFlightChunk:
     snapshot: list["_SlotInfo | None"]  # slot infos at dispatch time
     dispatched_at: float
     # Unified ragged dispatch (docs/RAGGED_BATCH.md): how many prefill
-    # chunks rode along in this decode chunk (0 = plain decode).  Retire
-    # observes crowdllama_prefill_chunk_seconds from this.
+    # chunks rode along in this decode chunk (0 = plain decode): the
+    # flight's dispatch class (``Scheduler._flight_class``).
     ragged_steps: int = 0
-    # Megastep dispatch (docs/MEGASTEP.md): the on-device per-slot
-    # done-flags [K, B], read back in the same transfer as the tokens.
-    # None for legacy per-step-chunk dispatches.
-    done_dev: object = None
     # Remote-draft pacing (docs/SPECULATIVE.md): the (slot, chunk_id)
     # credits this flight consumed — retire answers each with a _VERIFY
     # payload carrying the tokens that slot emitted in the flight.
@@ -195,18 +191,10 @@ class Scheduler:
     def __init__(self, runner: ModelRunner, max_queue: int = 256,
                  decode_chunk: int = 8, admission_pending_max: int = 0,
                  spec_draft_max: int = 0, ragged: bool = True,
-                 megastep_k: int = 0, wedge_multiplier: float = 0.0,
+                 wedge_multiplier: float = 0.0,
                  clock=time.monotonic):
         self.runner = runner
         self.decode_chunk = max(1, decode_chunk)
-        # Kernel-looped megastep (docs/MEGASTEP.md): K full decode steps
-        # per host dispatch with on-device sampling + done-flags.  0 keeps
-        # the legacy per-step-chunk path; wrapper runners that replay
-        # frames and sharded multi-process runners opt out via
-        # supports_megastep (attribute absent = False).
-        self.megastep_k = max(0, megastep_k)
-        self._megastep = (self.megastep_k > 0
-                          and getattr(runner, "supports_megastep", False))
         # Load shedding (docs/ROBUSTNESS.md): reject at submit() once the
         # pending depth reaches this, instead of queueing work whose
         # deadline will expire before admission.  0 = no threshold (the
@@ -292,9 +280,9 @@ class Scheduler:
         # Gateway-drafted pipeline (ISSUE 20, docs/SPECULATIVE.md): slots
         # whose request carries a DraftFeed advance one verify round per
         # wire credit.  spec_pipeline_depth is the depth hint advertised
-        # back on every VerifyResult (the AutoTuner's fifth dial); the
-        # stall budget releases a creditless stream to full speed
-        # (free_run) so a dead gateway pump can never park a batch.
+        # back on every VerifyResult; the stall budget releases a
+        # creditless stream to full speed (free_run) so a dead gateway
+        # pump can never park a batch.
         self.spec_pipeline_depth = 8
         self.spec_pipeline_stall_s = 2.0
         self.spec_verifies = 0         # hosted/ack verify rounds answered
@@ -305,20 +293,16 @@ class Scheduler:
         # dispatch (fixed-token chunks riding the per-step token budget)
         # instead of alternating whole prefill steps with decode chunks.
         self._ragged = ragged and getattr(runner, "supports_ragged", False)
-        # Tokens of work the last dispatched step carried (live decode
-        # slots + prefill-chunk tokens per step); telemetry gauge.
-        self._step_budget_used = 0.0
-        # Host-dispatch accounting (the megastep's reason to exist): every
-        # decode flight (plain / ragged / spec / megastep) counts one
-        # dispatch; tokens_per_dispatch is what the last retired flight
-        # actually emitted.
+        # Host-dispatch accounting: every decode flight (plain / ragged /
+        # spec) counts one dispatch; tokens_per_dispatch is what the last
+        # retired flight actually emitted.
         self.host_dispatches = 0
         self._tokens_per_dispatch = 0.0
         # Duty-cycle profiler (PR 13, docs/OBSERVABILITY.md): per dispatch
         # class, an EWMA of device-window / (device-window + host-gap) —
         # both sides measured from host timestamps already on the retire
         # path (no new device syncs).  ~1.0 = the device never waits on
-        # the host between flights (the megastep's whole point).
+        # the host between flights.
         self._duty: dict[str, float] = {}
         self.ragged_chunks = 0  # prefill chunks dispatched unified
         # Chaos hook: the "scheduler.ragged_chunk" fault site's "drain"
@@ -346,20 +330,8 @@ class Scheduler:
         self.wedged_events = 0
         self._wedge_drain_fired = False
         self._watchdog_task: asyncio.Task | None = None
-        # Closed-loop autopilot (ISSUE 17, engine/autotune.py): the
-        # scheduler HOSTS the tuner because the retire path is the
-        # between-dispatch safe point — the same boundary drain/migrate
-        # and _spec_retune already use, so every dial move lands with no
-        # program in flight.  None = autotune off (the default).
-        self._autotune = None
 
     # ---------------------------------------------------------------- public
-
-    def attach_autotuner(self, tuner) -> None:
-        """Wire the performance autopilot (engine/autotune.py).  The
-        retire path feeds it one sample per token-emitting flight and
-        lets it move dials inline — i.e. between device dispatches."""
-        self._autotune = tuner
 
     def start(self) -> None:
         self._draining = False
@@ -537,8 +509,6 @@ class Scheduler:
         belong to a hung transfer).  Same classification _retire_inflight
         applies after readback: a jax device array reports the same ndim
         before and after device_get."""
-        if fl.done_dev is not None:
-            return "ragged_mega" if fl.ragged_steps else "megastep"
         if fl.ragged_steps:
             return "ragged"
         return "spec" if getattr(fl.tokens_dev, "ndim", 2) == 3 else "plain"
@@ -551,8 +521,8 @@ class Scheduler:
 
         The threshold is ``wedge_multiplier × flight-duration EWMA`` for
         the flight's dispatch class (floored at wedge_floor_s), so a
-        megastep flight that legitimately runs 50× longer than a plain
-        chunk is judged against megastep history, not a global constant.
+        ragged flight that legitimately runs longer than a plain chunk
+        is judged against ragged history, not a global constant.
         A class with NO retired flight yet is never judged: its first
         flight may legitimately include XLA compilation."""
         if self.wedged:
@@ -682,42 +652,20 @@ class Scheduler:
         # Paged KV by kind of pool (engine/paged.py, engine/hybrid.py):
         # capacity, bytes live, window pages written over.
         g.update(getattr(r, "kv_gauges", dict)())
-        # Unified ragged batch (docs/RAGGED_BATCH.md): slots mid-chunked-
-        # prefill (0 or 1 — one chunked admission at a time) and the token
-        # budget the last dispatched step actually carried (live decode
-        # rows + prefill-chunk tokens).
-        g["prefill_chunk_slots"] = 1.0 if self._chunking is not None else 0.0
-        g["step_token_budget_used"] = float(self._step_budget_used)
-        # Host-dispatch economy (docs/MEGASTEP.md): the counter measures
-        # device programs launched, the gauge what the LAST retired flight
-        # emitted — together they show what megastep K is buying.
+        # Host-dispatch economy: the counter measures device programs
+        # launched, the gauge what the LAST retired flight emitted.
         g["host_dispatches_total"] = float(self.host_dispatches)
         g["tokens_per_dispatch"] = float(self._tokens_per_dispatch)
         # Duty cycle per dispatch class (PR 13): always present (zeros
-        # for classes this engine never dispatched) so dashboards can
-        # compare megastep (high duty) vs per-step (low duty) directly.
+        # for classes this engine never dispatched).
         duty = getattr(self, "_duty", {})
-        for cls in ("plain", "megastep", "ragged", "ragged_mega", "spec"):
+        for cls in DISPATCH_CLASSES:
             g[f"duty_cycle|dispatch={cls}"] = float(duty.get(cls, 0.0))
         # Dispatch self-watchdog (docs/ROBUSTNESS.md): level gauge (1 =
         # this engine declared itself wedged and self-drained) + the
         # monotonic trip counter, always present so absent()-alerts work.
         g["wedged"] = 1.0 if getattr(self, "wedged", False) else 0.0
         g["wedged_events_total"] = float(getattr(self, "wedged_events", 0))
-        # Autopilot plane (ISSUE 17, docs/AUTOTUNE.md): always present —
-        # zeros with the tuner off, live dials/score/counters with it on
-        # — so the crowdllama_autotune_* families render on every worker
-        # (the absent()-alert invariant the other gauges keep).
-        tuner = getattr(self, "_autotune", None)
-        if tuner is not None:
-            g.update(tuner.gauges())
-        else:
-            g.update({"autotune_score": 0.0, "autotune_moves_total": 0.0,
-                      "autotune_reverts_total": 0.0,
-                      "autotune_backoffs_total": 0.0})
-            for dial in ("megastep_k", "draft_k", "step_token_budget",
-                         "prefill_chunk", "pipeline_depth"):
-                g[f"autotune_dial|dial={dial}"] = 0.0
         # Remote-draft pipeline plane (ISSUE 20, docs/SPECULATIVE.md):
         # always present so the crowdllama_spec_pipeline_* families exist
         # on every worker (absent()-alert invariant) — zeros until a
@@ -1015,29 +963,6 @@ class Scheduler:
             return 1
         return self.decode_chunk
 
-    def _mega_limits(self) -> tuple[np.ndarray, np.ndarray]:
-        """Per-slot EOS ids and remaining token budgets for a megastep
-        dispatch, assembled from host bookkeeping.  The device done-flags
-        these drive must fire exactly when ``_emit`` would retire the slot
-        (same eos compare; budget = min of the request budget and context
-        headroom) — _emit remains the authority, the flags only let the
-        scan early-exit and spare the host per-step readbacks."""
-        b = len(self.slots)
-        eos = np.full((b,), -1, np.int32)
-        budgets = np.zeros((b,), np.int32)
-        for i, info in enumerate(self.slots):
-            if not isinstance(info, _SlotInfo):
-                continue
-            req = info.req
-            if req.eos_id is not None and req.eos_id >= 0:
-                eos[i] = req.eos_id
-            # a first token still on the device is as good as emitted
-            generated = info.generated + (info.first_dev is not None)
-            budgets[i] = max(0, min(
-                req.max_tokens - generated,
-                (self.runner.max_seq - 1) - info.prompt_len - generated))
-        return eos, budgets
-
     def _spec_retune(self, accepted: int, offered: int) -> None:
         """Fold one retired chunk's acceptance into the window; retune
         draft_len when the window holds enough evidence (≥ 2k offered
@@ -1199,7 +1124,6 @@ class Scheduler:
             tokens_dev, self.state = await self._call(
                 loop, "decode", self.runner.decode_steps_device, self.state,
                 1, note={"dispatch": "plain", "steps": 1})
-        self._step_budget_used = float(len(meta))
         self.host_dispatches += 1
         return _InFlightChunk(
             tokens_dev=tokens_dev, snapshot=list(self.slots),
@@ -1362,28 +1286,6 @@ class Scheduler:
             # the token stream is byte-identical either way).
             paced = self._paced_slots(rjob)
             k = 1 if paced else self._chunk_size()
-            # Megastep upgrade (docs/MEGASTEP.md): only full-size decode
-            # chunks become megasteps — size-1 dispatches (a slot free,
-            # spec probes) keep their latency purpose,
-            # and a draft-speculating runner already packs K verify steps
-            # per dispatch (verify chunk = K is the megastep of that
-            # path).  An in-flight ragged prefill no longer demotes the
-            # batch: full-size unified chunks upgrade to the FUSED ragged
-            # megastep (K unified steps per dispatch with on-device
-            # decode sampling + done-flags, the prompt chunk advancing
-            # inside the device loop — docs/MEGASTEP.md "Fused ragged
-            # megastep") whenever the runner provides it; the unified
-            # step body is draft-independent (drafting pauses during a
-            # ragged prefill), so no draft_len gate.  Deciding BEFORE
-            # pre_decode_check sizes page growth for the real step count.
-            use_mega = (self._megastep and rjob is None and not paced
-                        and k == self.decode_chunk
-                        and getattr(self.runner, "draft_len", 0) == 0)
-            use_ragged_mega = (self._megastep and rjob is not None
-                               and k == self.decode_chunk
-                               and hasattr(self.runner, "ragged_megastep"))
-            if use_mega or use_ragged_mega:
-                k = self.megastep_k
             # Paged-KV runners grow page tables before the chunk; slots an
             # overcommitted pool cannot grow finish with "length" (their
             # pages free on release) instead of failing the whole engine.
@@ -1443,21 +1345,10 @@ class Scheduler:
                     req.dispatch_watch = self._watch_ready(loop,
                                                            self._inflight)
                 try:
-                    if use_ragged_mega:
-                        eos_ids, budgets = self._mega_limits()
-                        tokens_dev, rdone_dev, self.state = (
-                            await self._call(
-                                loop, "ragged", self.runner.ragged_megastep,
-                                self.state, job, k, eos_ids=eos_ids,
-                                budgets=budgets,
-                                note={"dispatch": "ragged_mega",
-                                      "steps": k}))
-                    else:
-                        rdone_dev = None
-                        tokens_dev, self.state = await self._call(
-                            loop, "ragged", self.runner.ragged_step,
-                            self.state, job, k,
-                            note={"dispatch": "ragged", "steps": k})
+                    tokens_dev, self.state = await self._call(
+                        loop, "ragged", self.runner.ragged_step,
+                        self.state, job, k,
+                        note={"dispatch": "ragged", "steps": k})
                 except ValueError as e:
                     # Pool cannot cover the job's next chunk pages
                     # (PagesExhausted is a ValueError): fail THIS request,
@@ -1474,13 +1365,11 @@ class Scheduler:
                     # On BaseException _chunking stays set: _loop's
                     # recovery fails the request and resets state.
                     self.ragged_chunks += n_chunks
-                    self._step_budget_used = float(
-                        live + chunk_toks / max(1, k))
                     self.host_dispatches += 1
                     dispatched = _InFlightChunk(
                         tokens_dev=tokens_dev, snapshot=list(self.slots),
                         dispatched_at=time.monotonic(),
-                        ragged_steps=n_chunks, done_dev=rdone_dev,
+                        ragged_steps=n_chunks,
                         counters_dev=self._flight_counters())
                     if not req.exec_start_at:
                         req.exec_start_at = dispatched.dispatched_at
@@ -1534,26 +1423,14 @@ class Scheduler:
                 await self._emit_firsts(loop, self._firsts())
                 dispatched = await self._dispatch_paced(loop, paced)
             elif live:
-                done_dev = None
-                if use_mega:
-                    # K full steps in ONE device program, sampling +
-                    # done-flags on device; the host reads the packed
-                    # [K, B] block back in a single transfer at retire.
-                    eos_ids, budgets = self._mega_limits()
-                    tokens_dev, done_dev, self.state = await self._call(
-                        loop, "decode", self.runner.decode_megastep,
-                        self.state, k, eos_ids=eos_ids, budgets=budgets,
-                        note={"dispatch": "megastep", "steps": k})
-                else:
-                    tokens_dev, self.state = await self._call(
-                        loop, "decode", self.runner.decode_steps_device,
-                        self.state, k,  # [K,B] on device
-                        note={"dispatch": "plain", "steps": k})
-                self._step_budget_used = float(live)
+                tokens_dev, self.state = await self._call(
+                    loop, "decode", self.runner.decode_steps_device,
+                    self.state, k,  # [K,B] on device
+                    note={"dispatch": "plain", "steps": k})
                 self.host_dispatches += 1
                 dispatched = _InFlightChunk(
                     tokens_dev=tokens_dev, snapshot=list(self.slots),
-                    dispatched_at=time.monotonic(), done_dev=done_dev,
+                    dispatched_at=time.monotonic(),
                     counters_dev=self._flight_counters())
 
         # Every slot placed so far rides the flight just queued: the host
@@ -1726,25 +1603,24 @@ class Scheduler:
         if self._inflight is None:
             return
         fl, self._inflight = self._inflight, None
-        # ONE host transfer per flight: tokens and (megastep) done-flags
-        # come back together — device_get over the pair is the whole
-        # readback, there is no per-step host sync anywhere in the loop.
+        # ONE host transfer per flight: tokens and counters come back
+        # together — device_get over the pair is the whole readback,
+        # there is no per-step host sync anywhere in the loop.
         cls = self._flight_class(fl)
 
         def readback():
             with jax.profiler.TraceAnnotation(SCHED_READBACK, dispatch=cls):
-                tokens, done, counters = jax.device_get(
-                    (fl.tokens_dev, fl.done_dev, fl.counters_dev))
+                tokens, counters = jax.device_get(
+                    (fl.tokens_dev, fl.counters_dev))
                 # [K,B] (or packed [K,2+J,B]) on the host
-                return np.asarray(tokens), done, counters
+                return np.asarray(tokens), counters
 
-        tokens, done, counters = await loop.run_in_executor(self._exec,
-                                                            readback)
+        tokens, counters = await loop.run_in_executor(self._exec, readback)
         if counters is not None:
             ENGINE_TELEMETRY.moe_counts_inc(cls, counters)
         now = time.monotonic()
         with jax.profiler.TraceAnnotation(SCHED_EMIT, dispatch=cls):
-            emitted, dt = self._account_and_emit(fl, cls, tokens, done, now)
+            emitted, dt = self._account_and_emit(fl, cls, tokens, now)
         await self._flush_releases(loop)
         if emitted == 0:
             # Pure-overshoot chunk (dispatched before its slots' EOS was
@@ -1757,7 +1633,7 @@ class Scheduler:
         )
 
     def _account_and_emit(self, fl: _InFlightChunk, cls: str,
-                          tokens: np.ndarray, done, now: float
+                          tokens: np.ndarray, now: float
                           ) -> tuple[int, float]:
         """The host side of one retire, between readback and the release
         flush: duty-cycle and flight accounting, then every token of the
@@ -1780,41 +1656,20 @@ class Scheduler:
         # Flight-duration EWMA per dispatch class: the self-watchdog's
         # baseline.  dt is the wall time attributed to waiting on THIS
         # flight, so a healthy class's EWMA tracks its real cadence and
-        # wedge thresholds scale with megastep K / chunk size instead of
-        # being a global constant.
+        # wedge thresholds scale with the chunk size instead of being a
+        # global constant.
         e = self._flight_ewma.get(cls)
         self._flight_ewma[cls] = dt if e is None else 0.9 * e + 0.1 * dt
         self._last_retire_at = now
-        if fl.ragged_steps:
-            # Per-chunk prefill latency inside the unified dispatch (the
-            # chunks ran back-to-back in one program; attribute the wall
-            # time evenly).
-            per = max(now - fl.dispatched_at, 1e-6) / fl.ragged_steps
-            for _ in range(fl.ragged_steps):
-                ENGINE_TELEMETRY.prefill_chunk_seconds.observe(per)
         # Decode chunks run the full fixed batch shape: every slot that was
         # empty at dispatch computed throwaway rows for the whole chunk.
         live = sum(1 for s in fl.snapshot if isinstance(s, _SlotInfo))
         steps = tokens.shape[0]
         batch = tokens.shape[-1]
-        steps_run = steps
-        if done is not None:
-            # Megastep early exit: once every live slot fired its
-            # done-flag the scan's remaining iterations took the idle
-            # branch — count only the steps that computed.
-            d = np.asarray(done)
-            live_cols = np.array([isinstance(s, _SlotInfo)
-                                  for s in fl.snapshot], bool)
-            if live_cols.any() and d[:, live_cols].any(axis=0).all():
-                steps_run = int(d[:, live_cols].argmax(axis=0).max()) + 1
-                if fl.ragged_steps:
-                    # Fused ragged flight: the chunk pins the loop open
-                    # past all-fired, so every token-carrying step ran.
-                    steps_run = max(steps_run, fl.ragged_steps)
         ENGINE_TELEMETRY.flight_inc(
-            cls, seconds=dt, steps=steps_run, useful=live * steps_run,
-            waste=max(0, batch - live) * steps_run,
-            short=done is None and steps < self.decode_chunk)
+            cls, seconds=dt, steps=steps, useful=live * steps,
+            waste=max(0, batch - live) * steps,
+            short=steps < self.decode_chunk)
         emitted = 0
         chunk_acc = 0  # draft tokens accepted in this chunk (live slots)
         chunk_off = 0  # draft tokens offered in this chunk (live slots)
@@ -1892,13 +1747,6 @@ class Scheduler:
                 self.spec_probes += 1
                 self.runner.set_draft_len(1)
         self._tokens_per_dispatch = float(emitted)
-        if self._autotune is not None and emitted:
-            # Autopilot sample + (maybe) a dial move, HERE because retire
-            # runs strictly between device dispatches — the same safe
-            # point _spec_retune writes draft_len from.  Overshoot-only
-            # windows are skipped for the same reason the EMA skips them.
-            self._autotune.on_window(cls, self._duty.get(cls, 0.0),
-                                     emitted, dt)
         if fl.verify_meta:
             # One VerifyResult per consumed credit: position is the slot's
             # post-round generated count, accepted = emitted - 1 (the last

@@ -253,13 +253,6 @@ class Gateway:
         # ring only keeps the newest N complete traces anyway.
         self._flight_inflight = 0
         self._flight_max_inflight = 4
-        # Autopilot backoff capture (docs/AUTOTUNE.md): the tuner's
-        # process-wide backoff log is edge-checked per finished request —
-        # the first request retired after a hard back-off carries the
-        # offending dial move into the flight-recorder ring.
-        from crowdllama_tpu.engine.autotune import BACKOFF_LOG
-
-        self._autotune_backoffs_seen = BACKOFF_LOG.snapshot()[0]
         # Swarm observatory (PR 13): the /metrics/cluster scraper and the
         # SLO burn-rate engine (objectives in ms; 0 = disabled).
         from crowdllama_tpu.obs.cluster import ClusterScraper
@@ -1948,15 +1941,6 @@ class Gateway:
             if self.slo.fast_burn() \
                     and self.slo.fast_burn_episodes_total > before:
                 reasons.append("slo_fast_burn")
-        from crowdllama_tpu.engine.autotune import BACKOFF_LOG
-
-        backoffs, _ = BACKOFF_LOG.snapshot()
-        if backoffs > self._autotune_backoffs_seen:
-            # Edge-triggered like slo_fast_burn: only the first request
-            # retired after an autopilot hard back-off is captured, and
-            # _flight_capture attaches the offending dial move.
-            self._autotune_backoffs_seen = backoffs
-            reasons.append("autotune_backoff")
         rec = self.obs.trace.get(tid)
         if rec is not None:
             names = {s.get("name", "") for s in rec.get("spans", [])}
@@ -1996,15 +1980,6 @@ class Gateway:
             if stitched is None:
                 return
             final = list(reasons)
-            if "autotune_backoff" in final:
-                # Attach the offending dial move so the captured trace
-                # explains WHICH knob tripped the fast-burn guard.
-                from crowdllama_tpu.engine.autotune import BACKOFF_LOG
-
-                last = BACKOFF_LOG.snapshot()[1]
-                if last:
-                    stitched = dict(stitched)
-                    stitched["autotune_backoff"] = dict(last)
             if "kv_hint" in final:
                 final.remove("kv_hint")
                 if any(s.get("name") == "kv_fetch"

@@ -30,12 +30,14 @@ async def new_host_and_dht(
     listen_host: str = "0.0.0.0",
     listen_port: int = 0,
     advertise_host: str | None = None,
+    listen_sock=None,
 ) -> tuple[Host, DHTNode]:
-    """Build and start a host plus DHT in server mode (discovery.go:48-84)."""
+    """Build and start a host plus DHT in server mode (discovery.go:48-84);
+    ``listen_sock``: a socket already bound to the listen address."""
     host = Host(key, listen_host=listen_host, listen_port=listen_port,
                 advertise_host=advertise_host)
     dht = DHTNode(host, server_mode=True)
-    await host.start()
+    await host.start(sock=listen_sock)
     return host, dht
 
 

@@ -221,6 +221,31 @@ def _reuse_socket(local_port: int, remote_host: str = ""):
     return sock
 
 
+def bind_listener(host: str, port: int):
+    """A TCP socket BOUND to ``host:port`` and not yet listening, with the
+    options ``asyncio.start_server`` gives the one it binds itself
+    (SO_REUSEADDR).  A node takes its ports with this before its engine
+    starts and hands the sockets to :meth:`Host.start` / ``ObsServer``
+    afterwards (cli/main.py ``run_node``): a port somebody else holds
+    ends the process with ``OSError: [Errno 98]`` before any weight is
+    loaded, nobody can take the port during the minutes an engine start
+    lasts, and until the node serves a dial is refused as by a closed
+    port."""
+    import socket as _socket
+
+    family, kind, proto, _, addr = _socket.getaddrinfo(
+        host or None, port, type=_socket.SOCK_STREAM,
+        flags=_socket.AI_PASSIVE)[0]
+    sock = _socket.socket(family, kind, proto)
+    try:
+        sock.setsockopt(_socket.SOL_SOCKET, _socket.SO_REUSEADDR, 1)
+        sock.bind(addr)
+    except BaseException:
+        sock.close()
+        raise
+    return sock
+
+
 async def punch_establish(local_port: int, host: str, port: int,
                           on_established, attempts: int = PUNCH_ATTEMPTS,
                           listen_sock=None):
@@ -465,10 +490,16 @@ class Host:
 
     # -- lifecycle ---------------------------------------------------------
 
-    async def start(self) -> None:
-        self._server = await asyncio.start_server(
-            self._on_connection, self.listen_host, self.listen_port
-        )
+    async def start(self, sock=None) -> None:
+        """Listen on ``listen_host:listen_port``, or on ``sock``: a socket
+        the caller bound earlier (:func:`bind_listener`)."""
+        if sock is not None:
+            self._server = await asyncio.start_server(self._on_connection,
+                                                      sock=sock)
+        else:
+            self._server = await asyncio.start_server(
+                self._on_connection, self.listen_host, self.listen_port
+            )
         self.listen_port = self._server.sockets[0].getsockname()[1]
         log.debug("host %s listening on %s:%d", self.peer_id[:8], self.listen_host, self.listen_port)
 

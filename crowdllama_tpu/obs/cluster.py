@@ -210,18 +210,6 @@ class ClusterScraper:
             "# TYPE crowdllama_cluster_inflight gauge",
             f"crowdllama_cluster_inflight {_fmt(inflight)}",
         ]
-        # Autopilot rollup (docs/AUTOTUNE.md): swarm-wide dial-move count
-        # — one number that says whether the fleet's tuners have settled.
-        moves = 0.0
-        for _, _, text in snapshots:
-            m = re.search(r"^crowdllama_autotune_moves_total ([0-9.eE+-]+)"
-                          r"\s*$", text, re.M)
-            if m:
-                moves += float(m.group(1))
-        lines += [
-            "# TYPE crowdllama_cluster_autotune_moves_total counter",
-            f"crowdllama_cluster_autotune_moves_total {_fmt(moves)}",
-        ]
         return lines
 
 

@@ -108,10 +108,14 @@ def node_metric_lines(peer) -> list[str]:
 class ObsServer:
     """Per-worker metrics/trace endpoint, mirroring the gateway's."""
 
-    def __init__(self, peer, host: str = "127.0.0.1", port: int = 0) -> None:
+    def __init__(self, peer, host: str = "127.0.0.1", port: int = 0,
+                 sock=None) -> None:
         self.peer = peer
         self.host = host
         self.port = port
+        # A socket already bound to host:port (net/host.py
+        # ``bind_listener``), which ``start`` serves on; None: bind there.
+        self._sock = sock
         self._runner: web.AppRunner | None = None
         self.app = web.Application()
         self.app.router.add_get("/metrics", self.handle_metrics)
@@ -130,7 +134,9 @@ class ObsServer:
     async def start(self) -> None:
         self._runner = web.AppRunner(self.app, access_log=None)
         await self._runner.setup()
-        site = web.TCPSite(self._runner, self.host, self.port)
+        site = (web.SockSite(self._runner, self._sock)
+                if self._sock is not None
+                else web.TCPSite(self._runner, self.host, self.port))
         await site.start()
         # Resolve the bound port (port=0 binds ephemeral).
         self.port = self._runner.addresses[0][1]

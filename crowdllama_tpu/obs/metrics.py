@@ -42,11 +42,14 @@ _LABEL_VALUE_RE = re.compile(r"^[A-Za-z0-9_.:/\-]{1,64}$")
 
 # The scheduler's decode dispatch classes (docs/OBSERVABILITY.md duty
 # cycle): how a flight reached the device — plain per-step chunk,
-# kernel-looped megastep, unified ragged step, or speculative verify.
+# unified ragged step, or speculative verify; every per-class family
+# renders one series per class from the first scrape.  No flight is a
+# "megastep" any more: its series stay, constant 0, because
+# benchmarks/chip/layer_metrics/step.decode_wall_ms.json sums
+# crowdllama_engine_flight_{seconds,steps}_total over it by name and reads
+# nothing if one is absent.  ROADMAP W0(h) removes that term, then this
+# entry goes.
 DISPATCH_CLASSES = ("plain", "megastep", "ragged", "spec")
-# Every class Scheduler._flight_class names: the flight counters render
-# one series per class from the first scrape.
-FLIGHT_CLASSES = ("plain", "megastep", "ragged", "ragged_mega", "spec")
 # Phases of a worker's start that crowdllama_startup_seconds reports.
 STARTUP_PHASES = ("weights", "warmup", "ready", "process")
 
@@ -368,7 +371,7 @@ def engine_gauge_lines(gauges: dict) -> list[str]:
     device programs launched and only ever grows).  A ``base|label=value``
     key renders as a labeled child of the ``base`` family (one TYPE line
     per family) — the duty-cycle gauges use this to keep one family
-    across the four dispatch classes."""
+    across the dispatch classes."""
     out: list[str] = []
     typed: set[str] = set()
     for key in sorted(gauges):
@@ -377,12 +380,7 @@ def engine_gauge_lines(gauges: dict) -> list[str]:
         except (TypeError, ValueError):
             continue
         base, _, label = key.partition("|")
-        # Autopilot keys are their own exposition plane (ISSUE 17,
-        # docs/AUTOTUNE.md): crowdllama_autotune_* rather than an
-        # engine_-prefixed family, because the dials belong to the
-        # control loop, not the batch-shape gauges dashboards rate().
-        name = (f"crowdllama_{base}" if base.startswith("autotune_")
-                else f"crowdllama_engine_{base}")
+        name = f"crowdllama_engine_{base}"
         kind = "counter" if base.endswith("_total") else "gauge"
         if name not in typed:
             typed.add(name)
@@ -420,9 +418,9 @@ class EngineTelemetry:
         self.bucket_guard = LabelGuard(max_values=256)
         self._compiles: dict[tuple[str, str], int] = {}
         self._seen: set[tuple[str, str]] = set()
-        # Cached-hit witness (ISSUE 17 satellite): dispatches whose
-        # (program, bucket) signature was already claimed — the proof
-        # that flipping a dial BACK is free (no recompile).  Keyed by
+        # Cached-hit witness: dispatches whose (program, bucket)
+        # signature was already claimed — the proof that a chunk size
+        # seen before costs no recompile.  Keyed by
         # program only: the interesting fact is "this entry point reused
         # a signature", not which bucket did.
         self._cache_hits: dict[str, int] = {}
@@ -433,8 +431,8 @@ class EngineTelemetry:
         self._prefix = {"tokens_reused": 0, "hits": 0, "prompt_tokens": 0}
         # Per dispatch class, the wall time and decode steps of every
         # retired flight: seconds / steps is the wall time of one step.
-        self._flight_seconds = {cls: 0.0 for cls in FLIGHT_CLASSES}
-        self._flight_steps = {cls: 0 for cls in FLIGHT_CLASSES}
+        self._flight_seconds = {cls: 0.0 for cls in DISPATCH_CLASSES}
+        self._flight_steps = {cls: 0 for cls in DISPATCH_CLASSES}
         # An expert layer that holds a share of its experts (models/
         # hybrid.py): token-expert rows it computed ("yes") and rows it left
         # to the ranks that hold their expert ("no"), counted on the device
@@ -445,7 +443,7 @@ class EngineTelemetry:
         # routed to, those none was, and those the grouped matmuls read
         # from HBM whichever — counted and read back the same way.
         self._moe_banks = {cls: {"routed": 0, "unrouted": 0, "fetched": 0}
-                           for cls in FLIGHT_CLASSES}
+                           for cls in DISPATCH_CLASSES}
         # Grid steps of the GQA decode attention kernel (ops/pallas/paged.py),
         # by the kind of layer ("full" pool | "window" ring): those its
         # calls walked — one a live (slot, page pair) — and those of the
@@ -490,11 +488,6 @@ class EngineTelemetry:
         # placed arrays (parallel/sharding.py placed_layouts; set at
         # JaxEngine.start).
         self._weight_layouts: dict[str, str] = {}
-        # Unified ragged batch (docs/RAGGED_BATCH.md): wall time per
-        # prefill chunk carried inside a decode dispatch.  Engine-plane
-        # like the compile histogram (the scheduler's dispatch loop
-        # records it), rendered on both scrape surfaces.
-        self.prefill_chunk_seconds = Histogram(DECODE_STEP_BUCKETS)
         # Decode duty-cycle profiler (PR 13, docs/OBSERVABILITY.md): the
         # host-side gap between one flight's retire and the next flight's
         # dispatch, per dispatch class.  Children pre-created so every
@@ -514,7 +507,7 @@ class EngineTelemetry:
         # Retired decode flights by length (Scheduler._chunk_size): "short"
         # — fewer steps than decode_chunk, which outside spec probes and
         # gateway-paced rounds means a slot was free; "full" — decode_chunk
-        # steps (or a megastep's K): every slot was taken.
+        # steps: every slot was taken.
         self._flights = {"short": 0, "full": 0}
 
     def _key(self, program: str, bucket: object) -> tuple[str, str]:
@@ -704,8 +697,8 @@ class EngineTelemetry:
         for (program, bucket), n in compiles:
             out.append(f'crowdllama_xla_compiles_total{{'
                        f'program="{program}",bucket="{bucket}"}} {n}')
-        # Cached-hit witness (docs/AUTOTUNE.md): signature reuse per jit
-        # entry point — a dial revert shows up here instead of as a new
+        # Cached-hit witness: signature reuse per jit entry point — a
+        # chunk size seen before shows up here instead of as a new
         # crowdllama_xla_compiles_total child.
         out.append("# TYPE crowdllama_xla_compile_cache_hits_total counter")
         if not cache_hits:
@@ -728,11 +721,11 @@ class EngineTelemetry:
         out.append(f"crowdllama_prompt_tokens_total "
                    f"{prefix['prompt_tokens']}")
         out.append("# TYPE crowdllama_engine_flight_seconds_total counter")
-        for cls in FLIGHT_CLASSES:
+        for cls in DISPATCH_CLASSES:
             out.append(f'crowdllama_engine_flight_seconds_total{{'
                        f'dispatch="{cls}"}} {flight_seconds[cls]:.6f}')
         out.append("# TYPE crowdllama_engine_flight_steps_total counter")
-        for cls in FLIGHT_CLASSES:
+        for cls in DISPATCH_CLASSES:
             out.append(f'crowdllama_engine_flight_steps_total{{'
                        f'dispatch="{cls}"}} {flight_steps[cls]}')
         out.append("# TYPE crowdllama_moe_assignments_total counter")
@@ -740,12 +733,12 @@ class EngineTelemetry:
             out.append(f'crowdllama_moe_assignments_total{{held="{held}"}} '
                        f'{n}')
         out.append("# TYPE crowdllama_moe_banks_total counter")
-        for cls in FLIGHT_CLASSES:
+        for cls in DISPATCH_CLASSES:
             for state in ("routed", "unrouted"):
                 out.append(f'crowdllama_moe_banks_total{{dispatch="{cls}",'
                            f'state="{state}"}} {moe_banks[cls][state]}')
         out.append("# TYPE crowdllama_moe_banks_fetched_total counter")
-        for cls in FLIGHT_CLASSES:
+        for cls in DISPATCH_CLASSES:
             out.append(f'crowdllama_moe_banks_fetched_total{{dispatch="{cls}"'
                        f'}} {moe_banks[cls]["fetched"]}')
         out.append("# TYPE crowdllama_attn_grid_steps_total counter")
@@ -777,9 +770,6 @@ class EngineTelemetry:
         for phase in STARTUP_PHASES:
             out.append(f'crowdllama_startup_seconds{{phase="{phase}"}} '
                        f'{startup.get(phase, 0.0):.3f}')
-        out.append("# TYPE crowdllama_prefill_chunk_seconds histogram")
-        out.extend(self.prefill_chunk_seconds.lines(
-            "crowdllama_prefill_chunk_seconds"))
         out.extend(self.host_gap_seconds.expose(
             "crowdllama_host_gap_seconds"))
         out.append("# TYPE crowdllama_admissions_total counter")

@@ -97,57 +97,6 @@ class BurnRateTracker:
         return all(r >= FAST_BURN for r in rates.values())
 
 
-class WindowBurn:
-    """Burn-rate math over retire WINDOWS instead of wall-clock buckets —
-    the engine autopilot's worker-local SLO signal (engine/autotune.py,
-    docs/AUTOTUNE.md).
-
-    The scheduler retires a window every few milliseconds under load and
-    not at all when idle, so wall-clock cells (BurnRateTracker) would
-    read empty exactly when a bad dial move stalls the loop.  Counting
-    the last N windows instead makes the signal traffic-relative: each
-    observation is one window's per-token latency classified against
-    ``objective_ms``; burn over a deque is ``bad_fraction / budget``;
-    *fast burn* needs the short deque FULL and both deques at/above
-    FAST_BURN — the same multiwindow shape as the gateway tracker, with
-    the same page-worthy threshold."""
-
-    def __init__(self, objective_ms: float = 0.0, short: int = 8,
-                 long: int = 32, budget: float = DEFAULT_BUDGET) -> None:
-        import collections
-
-        self.objective_ms = float(objective_ms)
-        self.budget = min(1.0, max(1e-6, float(budget)))
-        self._short: "collections.deque" = collections.deque(
-            maxlen=max(1, int(short)))
-        self._long: "collections.deque" = collections.deque(
-            maxlen=max(1, int(long)))
-        self.breaches_total = 0
-
-    def observe(self, ms: float) -> bool:
-        """Record one window's per-token latency; True when it breached.
-        With no objective configured yet every window counts good (the
-        tuner derives an objective from its first baseline phase)."""
-        bad = self.objective_ms > 0.0 and ms > self.objective_ms
-        self._short.append(1 if bad else 0)
-        self._long.append(1 if bad else 0)
-        if bad:
-            self.breaches_total += 1
-        return bad
-
-    def _rate(self, dq) -> float:
-        return (sum(dq) / len(dq) / self.budget) if dq else 0.0
-
-    def burn(self) -> float:
-        """The long-window burn rate — the score penalty input."""
-        return self._rate(self._long)
-
-    def in_fast_burn(self) -> bool:
-        return (len(self._short) == self._short.maxlen
-                and self._rate(self._short) >= FAST_BURN
-                and self._rate(self._long) >= FAST_BURN)
-
-
 class SloEngine:
     """The gateway's objectives + the edge-triggered fast-burn episode
     flag.  An objective set to 0 is disabled (no tracker, no gauges)."""

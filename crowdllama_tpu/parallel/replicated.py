@@ -134,11 +134,6 @@ class ReplicatedRunner:
     # op yet); same explicit-False pattern keeps the scheduler on the
     # monolithic/legacy-chunked path for replicated engines.
     supports_ragged = False
-    # Megastep decode (docs/MEGASTEP.md) has no replay frame op either,
-    # and its done-flag early exit depends on leader-local eos/budget
-    # inputs followers never see.  Explicit False — __getattr__ would
-    # otherwise leak the inner runner's True.
-    supports_megastep = False
 
     def __init__(self, inner):
         self.inner = inner

@@ -136,11 +136,14 @@ class Peer:
 
     # ----------------------------------------------------------- lifecycle
 
-    async def start(self) -> None:
+    async def start(self, listen_sock=None) -> None:
+        """``listen_sock``: a socket already bound to the listen address
+        (net/host.py ``bind_listener``) to listen on; None: bind there."""
         self.host, self.dht = await new_host_and_dht(
             self.key,
             listen_host=self.config.listen_host,
             listen_port=self.config.listen_port,
+            listen_sock=listen_sock,
         )
         self.resource.peer_id = self.host.peer_id
         self.update_metadata()

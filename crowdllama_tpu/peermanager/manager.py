@@ -125,10 +125,6 @@ class PeerManager:
         self._route_epoch = 0
         self._route_cache: dict[str, _RouteSnapshot] = {}
         self.route_snapshot_rebuilds = 0  # stat: rebuilds (not lookups)
-        # Discovery idle backoff: consecutive rounds that found nothing
-        # stretch the discovery cadence (capped), so a settled swarm stops
-        # paying per-interval provider lookups that cannot find anyone new.
-        self._discovery_idle_rounds = 0
 
     # ------------------------------------------------------------- mutation
 
@@ -183,8 +179,6 @@ class PeerManager:
                                       )[:excess]:
                         del self.recently_removed[pid]
             self._bump_routing_epoch()
-            # A shrinking table should search for replacements promptly.
-            self._discovery_idle_rounds = 0
             if self.on_peer_removed is not None:
                 try:
                     self.on_peer_removed(peer_id)
@@ -456,10 +450,6 @@ class PeerManager:
                 if t > cutoff
             }
 
-    #: Discovery idle-backoff cap: after enough empty rounds the cadence
-    #: stretches to idle_factor x intervals.discovery and stays there.
-    _DISCOVERY_IDLE_MAX_FACTOR = 8
-
     async def run_discovery_once(self) -> None:
         if self.discovery is None:
             return
@@ -468,42 +458,8 @@ class PeerManager:
         except Exception as e:
             log.debug("discovery round failed: %s", e)
             return
-        new = 0
         for resource in found:
-            before = len(self.peers)
             self.add_or_update_peer(resource)
-            new += len(self.peers) - before
-        # Only genuinely NEW peers reset the idle backoff: the skip set
-        # already filters known peers, so steady-state rounds return [].
-        self._discovery_idle_rounds = (
-            0 if new else self._discovery_idle_rounds + 1)
-
-    def discovery_interval(self) -> float:
-        """Current discovery cadence: the configured interval stretched by
-        the idle backoff (2x per consecutive empty round, capped).  A
-        settled 16-worker swarm converges to 1/8th the provider-lookup
-        chatter; any membership change snaps it back to the base rate."""
-        factor = min(2 ** self._discovery_idle_rounds,
-                     self._DISCOVERY_IDLE_MAX_FACTOR)
-        return self.config.intervals.discovery * factor
-
-    async def _discovery_loop(self) -> None:
-        """run_every with an adaptive interval (utils/aio.run_every takes a
-        fixed one): jittered like every other background loop so swarm-wide
-        ticks do not synchronize into handshake bursts."""
-        iv = self.config.intervals
-        await asyncio.sleep(random.random() * iv.discovery * 0.25)
-        while True:
-            try:
-                await self.run_discovery_once()
-            except asyncio.CancelledError:
-                raise
-            except Exception:
-                log.error("background loop error (run_discovery_once)",
-                          exc_info=True)
-            sleep = self.discovery_interval()
-            sleep *= 1 + 0.25 * (2 * random.random() - 1)
-            await asyncio.sleep(sleep)
 
     # ----------------------------------------------------------- lifecycle
 
@@ -512,7 +468,8 @@ class PeerManager:
 
         iv = self.config.intervals
         self._tasks = [
-            asyncio.create_task(self._discovery_loop(), name="pm-discovery"),
+            asyncio.create_task(run_every(iv.discovery, self.run_discovery_once, log),
+                                name="pm-discovery"),
             asyncio.create_task(run_every(iv.health_check, self.perform_health_checks, log),
                                 name="pm-health"),
             asyncio.create_task(run_every(iv.cleanup, self.perform_cleanup, log),

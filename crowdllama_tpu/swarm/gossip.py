@@ -51,10 +51,6 @@ log = logging.getLogger("crowdllama.gossip")
 
 AFFINITY_PREFIX = "aff/"
 QUARANTINE_PREFIX = "quar/"
-# Autopilot operating points (ISSUE 17, docs/AUTOTUNE.md): one LWW entry
-# per model, value = canonical-JSON dial dict.  Workers that join the
-# gossip plane warm-start their tuner from these instead of cold-searching.
-TUNE_PREFIX = "tune/"
 
 # Tombstones + quarantine entries older than this are pruned from the
 # map (and from snapshots): after the horizon every replica has either
@@ -427,33 +423,6 @@ class GossipNode:
     def drop_affinity(self, akey: str) -> None:
         self.state.delete(AFFINITY_PREFIX + akey)
         self._gauge()
-
-    def record_operating_point(self, model_id: str, point: dict) -> None:
-        """Publish a tuner's learned dial point for ``model_id``
-        (engine/autotune.py).  Same no-churn idiom as record_affinity:
-        an unchanged point must not bump the LWW version on every keep."""
-        from crowdllama_tpu.engine.autotune import encode_point
-
-        value = encode_point(point)
-        cur = self.state.get(TUNE_PREFIX + model_id)
-        if cur is not None and cur.value == value:
-            return
-        self.state.set(TUNE_PREFIX + model_id, value)
-        self._gauge()
-
-    def lookup_operating_point(self, model_id: str,
-                               max_age_s: float = 0.0) -> dict:
-        """The gossiped dial dict for ``model_id``, {} when absent,
-        expired (hybrid-clock write time vs ``max_age_s``) or invalid."""
-        from crowdllama_tpu.engine.autotune import decode_point
-
-        e = self.state.get(TUNE_PREFIX + model_id)
-        if e is None or not e.value:
-            return {}
-        if max_age_s and (time.time() * 1000 - e.version
-                          > max_age_s * 1000):
-            return {}
-        return decode_point(e.value)
 
     def record_quarantine(self, worker_id: str, reason: str = "drain") -> None:
         cur = self.state.get(QUARANTINE_PREFIX + worker_id)

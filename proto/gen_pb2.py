@@ -213,8 +213,7 @@ def apply_schema_edits(fdp: descriptor_pb2.FileDescriptorProto) -> None:
     # stream handshake (carries prompt_ids + the first emitted token so
     # the gateway's draft session needs no tokenizer); ``draft_k`` is the
     # worker's preferred drafts-per-chunk (0 = stop drafting, send pure
-    # credits) and ``depth_hint`` its max-in-flight window (an AutoTuner
-    # dial on the worker).
+    # credits) and ``depth_hint`` its max-in-flight window.
     vr = descriptor_pb2.DescriptorProto(name="VerifyResult")
     _ensure_field(vr, _field("chunk_id", 1, U64))
     _ensure_field(vr, _field("position", 2, I32))

@@ -76,6 +76,24 @@ def _release_compiled_executables():
     gc.collect()
 
 
+@pytest.fixture
+def _programs_go_with_their_test():
+    """For the files whose tests each build an unrolled runner of their own
+    (applied by name: ``pytestmark = pytest.mark.usefixtures(...)``): drop
+    every compiled executable after EVERY test, not only the file's last.
+
+    A runner and its jitted methods are a reference cycle, and every loaded
+    CPU executable of these unrolled models holds memory maps by the
+    hundred: left to the collector's own schedule the file's programs pile
+    up in its one process (the driver runs a file in one worker) until a
+    load from the compile cache dies of a segmentation fault, or XLA's
+    compiler does with the process at vm.max_map_count (tests/test_afmoe.py
+    at its 38th test; tests/test_kimi_linear.py: ISSUE 49's warning)."""
+    yield
+    jax.clear_caches()
+    gc.collect()
+
+
 # Minimal asyncio runner so tests don't depend on pytest-asyncio being
 # installed: any `async def test_*` is run to completion on a fresh loop.
 @pytest.hookimpl(tryfirst=True)

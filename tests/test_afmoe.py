@@ -6,7 +6,7 @@ TWO kinds of page, against its plain reference
 (benchmarks/chip/harness/reference/afmoe.py) — LOGITS, at tiny size on the
 CPU, seeded random weights: prefill; prefill then decode steps through both
 pools, far past the window; a prompt admitted in chunks through the ragged
-step beside decoding slots; both megasteps; the legacy chunked prefill.
+step beside decoding slots; the legacy chunked prefill.
 
 THE CACHE.  Window 16, page 8, ragged chunk 16: a window layer's slot owns a
 ring of ``(16 + 16 + 8) / 8 = 5`` pages, 40 tokens; the decoding slot runs
@@ -31,7 +31,6 @@ the position, (worst position, mean over positions):
   ``ALL_CHOSEN`` has why).
 """
 
-import gc
 import json
 import sys
 from dataclasses import replace
@@ -59,19 +58,11 @@ ALL_CHOSEN = replace(CFG, num_experts=8, num_experts_per_tok=8,
                      experts_held=4)
 LIMITS = {"float32": (1e-3, 1e-4), "bfloat16": (0.2, 0.1),
           "int8": (0.2, 0.1)}
-PATHS = ("prefill", "decode", "ragged", "megastep", "chunked")
+PATHS = ("prefill", "decode", "ragged", "chunked")
 ROWS = [*LIMITS, "float32-kernel"]
 
 
-@pytest.fixture(autouse=True)
-def _release_compiled_executables():
-    """After EVERY test here, not only the file's last (tests/conftest.py
-    has why): each builds a runner of its own, whose unrolled step programs
-    keep thousands of memory mappings apiece, and the 38th test died inside
-    XLA's compiler with the process at vm.max_map_count."""
-    yield
-    jax.clear_caches()
-    gc.collect()
+pytestmark = pytest.mark.usefixtures("_programs_go_with_their_test")
 
 
 @pytest.fixture
@@ -216,14 +207,11 @@ def run_path(r, path: str) -> list[tuple]:
     seq = a + [int(first)]
     out = []
 
-    def advance(st, n, mega):
-        if mega:
-            toks, _, st = r.decode_megastep(st, n)
-        else:
-            toks, st = r.decode_steps_device(st, n)
+    def advance(st, n):
+        toks, st = r.decode_steps_device(st, n)
         return np.asarray(toks), st
 
-    toks, st = advance(st, 48, path == "megastep")
+    toks, st = advance(st, 48)
     seq += [int(t) for t in toks[:, 1]]
     out.append(("decode", slot_rows(r, 1), seq[:-1], range(70, 118)))
     # the window pool's bound held while the full pool grew
@@ -236,11 +224,7 @@ def run_path(r, path: str) -> list[tuple]:
     n0 = len(seq)
     steps = iter((1, 2, 2, 2, 2, 2, 2, 2, 2, 2))
     while not job.finished:
-        k = next(steps)
-        if path == "megastep":
-            toks, _, st = r.ragged_megastep(st, job, k)
-        else:
-            toks, st = r.ragged_step(st, job, k)
+        toks, st = r.ragged_step(st, job, next(steps))
         seq += [int(t) for t in np.asarray(toks)[:, 1]]
     n = len(seq) - n0
     out.append(("decode beside chunks", slot_rows(r, 1), seq[:-1],
@@ -248,7 +232,7 @@ def run_path(r, path: str) -> list[tuple]:
     out.append(("chunked prompt's last token", job.last_logits[None], b,
                 [139]))
     first_b, st = r.ragged_finish(st, job, 0.0, 1.0, KEY)
-    toks, st = advance(st, 24, path == "megastep")
+    toks, st = advance(st, 24)
     seq_b = b + [int(first_b)] + [int(t) for t in toks[:, 2]]
     out.append(("decode after chunks", slot_rows(r, 2), seq_b[:-1],
                 range(140, 164)))
@@ -258,9 +242,7 @@ def run_path(r, path: str) -> list[tuple]:
 # every layout against the reference's full forward pass
 
 @pytest.mark.parametrize("row, path", [
-    (row, path) for row in ROWS for path in PATHS
-    # both megasteps share the step bodies: the float32 rows are enough
-    if path != "megastep" or row.startswith("float32")])
+    (row, path) for row in ROWS for path in PATHS])
 def test_logits_match_the_reference(row, path, kernels):
     precision = kernels(row)
     r = make_runner(row)

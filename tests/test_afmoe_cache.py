@@ -11,12 +11,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from test_afmoe import (CFG, KEY, LIMITS, R, _release_compiled_executables,  # noqa: F401
-                        distance, hf_of, make_runner, prompt_of, run_path)
+from test_afmoe import (CFG, KEY, LIMITS, R, distance, hf_of, make_runner,
+                        prompt_of, run_path)
 
 from crowdllama_tpu.engine.hybrid import HybridPagedModelRunner
 from crowdllama_tpu.models import hybrid as H
 from crowdllama_tpu.models import transformer as T
+
+pytestmark = pytest.mark.usefixtures("_programs_go_with_their_test")
 
 
 # the controls: what the float32 row is shown to see

@@ -144,3 +144,65 @@ async def test_stop_publishes_departure_before_stream_teardown():
         assert w.resource.draining is True
     finally:
         await boot_host.close()
+
+
+async def test_a_steady_discovery_round_is_one_lookup_and_no_metadata_fetch(
+        monkeypatch):
+    """The benchmark's three nodes (a DHT server, a worker, a gateway):
+    once the gateway knows the worker, a discovery round is ONE provider
+    lookup — asked with every known peer in the skip set — and fetches
+    nobody's metadata, however often it runs; a second worker that joins
+    is fetched once, in the next round, and then skipped like the first."""
+    from crowdllama_tpu.net import discovery
+
+    boot_host, _ = await new_host_and_dht(
+        Ed25519PrivateKey.generate(), listen_host="127.0.0.1")
+    bootstrap = f"127.0.0.1:{boot_host.listen_port}"
+    workers = [await _worker(bootstrap)]
+    consumer = Peer(Ed25519PrivateKey.generate(), _cfg(bootstrap),
+                    engine=FakeEngine(models=[]), worker_mode=False)
+    await consumer.start()
+    try:
+        pm = consumer.peer_manager
+        await _wait_for(lambda: workers[0].peer_id in pm.peers,
+                        what="the worker behind the gateway")
+        # the rounds below are this test's own
+        for t in pm._tasks:
+            if t.get_name() == "pm-discovery":
+                t.cancel()
+        lookups, fetched = [], []
+        find, fetch = consumer.dht.find_providers, (
+            discovery.request_peer_metadata)
+
+        async def counted_find(key, **kw):
+            lookups.append(set(kw["skip"]))
+            return await find(key, **kw)
+
+        async def counted_fetch(host, contact, **kw):
+            if host is consumer.host:     # the workers look around too
+                fetched.append(contact.peer_id)
+            return await fetch(host, contact, **kw)
+
+        monkeypatch.setattr(consumer.dht, "find_providers", counted_find)
+        monkeypatch.setattr(discovery, "request_peer_metadata",
+                            counted_fetch)
+        for _ in range(5):
+            await pm.run_discovery_once()
+        assert len(lookups) == 5 and fetched == []
+        assert all(workers[0].peer_id in skip for skip in lookups)
+
+        workers.append(await _worker(bootstrap))
+        for _ in range(50):
+            await pm.run_discovery_once()
+            if workers[1].peer_id in pm.peers:
+                break
+            await asyncio.sleep(0.2)
+        assert fetched == [workers[1].peer_id]
+        for _ in range(3):
+            await pm.run_discovery_once()
+        assert fetched == [workers[1].peer_id]
+    finally:
+        await consumer.stop()
+        for w in workers:
+            await w.stop()
+        await boot_host.close()

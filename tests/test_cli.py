@@ -7,6 +7,7 @@ import sys
 from pathlib import Path
 
 import jax
+import pytest
 
 from crowdllama_tpu.cli.dht import main as dht_main
 from crowdllama_tpu.cli.main import build_parser, main
@@ -182,3 +183,30 @@ def test_consumer_peer_start_leaves_jax_backends_uninitialised():
                           timeout=120)
     assert proc.returncode == 0, proc.stderr[-2000:]
     assert proc.stdout.strip().endswith("clean")
+
+
+@pytest.mark.parametrize("flag", [
+    ["--megastep-k", "4"], ["--autotune"], ["--autotune-interval", "8"],
+    ["--autotune-megastep-max", "8"], ["--autotune-draft-max", "4"],
+    ["--autotune-budget-max", "512"], ["--autotune-prefill-max", "256"]])
+def test_a_flag_of_the_second_dispatch_or_the_tuner_is_refused(flag, capsys):
+    """Decode has one dispatch, ``decode_chunk`` steps a flight, and nothing
+    moves it at run time: the flags that chose otherwise are no flags."""
+    with pytest.raises(SystemExit) as e:
+        build_parser().parse_args(["start", "--worker-mode", *flag])
+    assert e.value.code == 2
+    assert "unrecognized arguments" in capsys.readouterr().err
+
+
+def test_their_environment_variables_choose_nothing(monkeypatch):
+    import dataclasses
+
+    want = Configuration.from_environment()
+    for name in ("MEGASTEP_K", "AUTOTUNE", "AUTOTUNE_INTERVAL",
+                 "AUTOTUNE_MEGASTEP_MAX", "AUTOTUNE_DRAFT_MAX",
+                 "AUTOTUNE_BUDGET_MAX", "AUTOTUNE_PREFILL_MAX",
+                 "AUTOTUNE_DEPTH_MAX"):
+        monkeypatch.setenv(f"CROWDLLAMA_TPU_{name}", "1")
+    assert Configuration.from_environment() == want
+    assert not [f.name for f in dataclasses.fields(Configuration)
+                if "megastep" in f.name or "autotune" in f.name]

@@ -171,14 +171,13 @@ class _StubRunner:
         return None
 
 
-def _flight(dispatched_at: float, megastep: bool = False):
+def _flight(dispatched_at: float, ragged: bool = False):
     """Host-side metadata of an in-flight chunk — exactly the fields
     Scheduler._flight_class inspects (the watchdog never touches the
     device, so a stand-in object is a faithful double)."""
     return types.SimpleNamespace(
         tokens_dev=types.SimpleNamespace(ndim=2),
-        ragged_steps=0,
-        done_dev=object() if megastep else None,
+        ragged_steps=2 if ragged else 0,
         dispatched_at=dispatched_at)
 
 
@@ -207,11 +206,11 @@ async def test_self_watchdog_threshold_arithmetic_on_fake_clock():
         assert sched.wedged_events == 1
 
         # A class whose EWMA puts the threshold ABOVE the floor is
-        # judged against its own history: 3 × 10s = 30s.  A megastep
-        # flight is judged as "megastep", not "plain".
-        sched2._flight_ewma["megastep"] = 10.0
+        # judged against its own history: 3 × 10s = 30s.  A ragged
+        # flight is judged as "ragged", not "plain".
+        sched2._flight_ewma["ragged"] = 10.0
         sched2._flight_ewma["plain"] = 0.1
-        sched2._inflight = _flight(dispatched_at=0.0, megastep=True)
+        sched2._inflight = _flight(dispatched_at=0.0, ragged=True)
         assert sched2.check_wedged(now=29.0) is False
         assert sched2.check_wedged(now=31.0) is True
     finally:

@@ -4,7 +4,7 @@ rotation) on the paged engine, against its plain reference
 (benchmarks/chip/harness/reference/nemotron_h.py) — LOGITS, at tiny size on
 the CPU, seeded random weights: prefill; prefill then decode steps through
 the paged state; a prompt admitted in chunks through the ragged step beside
-decoding slots; both megasteps.
+decoding slots.
 
 THE LIMITS (``LIMITS``), in standard deviations of the reference's logits at
 the position, (worst position, mean over positions):
@@ -63,9 +63,11 @@ ALL_CHOSEN = replace(CFG, num_experts=8, num_experts_per_tok=8,
                      experts_held=4)
 LIMITS = {"float32": (1e-3, 1e-4), "bfloat16": (0.15, 0.08),
           "int8": (0.15, 0.08)}
-PATHS = ("prefill", "decode", "ragged", "megastep")
+PATHS = ("prefill", "decode", "ragged")
 # the rows of LIMITS, and the float32 row with the state-update kernel
 ROWS = [*LIMITS, "float32-kernel"]
+
+pytestmark = pytest.mark.usefixtures("_programs_go_with_their_test")
 
 
 @pytest.fixture
@@ -194,29 +196,23 @@ def run_path(r, path: str) -> list[tuple]:
     seq = a + [first]
     out = []
 
-    def advance(st, n, mega):
-        if mega:
-            toks, _, st = r.decode_megastep(st, n)
-        else:
-            toks, st = r.decode_steps_device(st, n)
+    def advance(st, n):
+        toks, st = r.decode_steps_device(st, n)
         return np.asarray(toks), st
 
-    toks, st = advance(st, 8, path == "megastep")
+    toks, st = advance(st, 8)
     seq += [int(t) for t in toks[:, 1]]
     out.append(("decode", slot_rows(r, 1), seq[:-1], range(40, 48)))
     if path == "decode":
         return out
     # a 100-token prompt admitted in chunks of 32 beside slot 1's decoding:
-    # one step a dispatch, then two; the megastep path fuses two a dispatch
+    # one step a dispatch, then two
     b = prompt_of(100, 2)
     assert r.ragged_chunk == 32
     job = r.ragged_begin(b, 2, state=st)
     n0 = len(seq)
-    for k in (1, 2, 2) if path == "ragged" else (2, 2):
-        if path == "megastep":
-            toks, _, st = r.ragged_megastep(st, job, k)
-        else:
-            toks, st = r.ragged_step(st, job, k)
+    for k in (1, 2, 2):
+        toks, st = r.ragged_step(st, job, k)
         seq += [int(t) for t in np.asarray(toks)[:, 1]]
     assert job.finished
     n = len(seq) - n0
@@ -225,7 +221,7 @@ def run_path(r, path: str) -> list[tuple]:
     out.append(("chunked prompt's last token", job.last_logits[None], b,
                 [99]))
     first_b, st = r.ragged_finish(st, job, 0.0, 1.0, KEY)
-    toks, st = advance(st, 4, path == "megastep")
+    toks, st = advance(st, 4)
     seq_b = b + [first_b] + [int(t) for t in toks[:, 2]]
     out.append(("decode after chunks", slot_rows(r, 2), seq_b[:-1],
                 range(100, 104)))
@@ -484,7 +480,7 @@ def check_banks(grew: dict, banks_a_step: int) -> None:
 
 async def test_served_through_the_engine_with_its_counters():
     """The normal path: JaxEngine -> scheduler -> the hybrid runner, ragged
-    admission and megastep on; every admission a prefix miss; the expert
+    admission on; every admission a prefix miss; the expert
     layers' assignment counts read back with the flights; nothing to
     export for the KV plane or a drain."""
     from crowdllama_tpu.config import Configuration, Intervals
@@ -498,7 +494,7 @@ async def test_served_through_the_engine_with_its_counters():
 
     engine = JaxEngine(Configuration(
         model=CFG.name, max_context_length=256, max_batch_slots=2,
-        warmup=False, kv_page_size=16, step_token_budget=34, megastep_k=4,
+        warmup=False, kv_page_size=16, step_token_budget=34,
         kv_ship=True, intervals=Intervals.default()))
     await engine.start()
     try:

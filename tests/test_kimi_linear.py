@@ -5,7 +5,7 @@ on the paged engine, against its plain reference
 (benchmarks/chip/harness/reference/kimi_linear.py) — LOGITS, at tiny size on
 the CPU, seeded random weights: prefill; prefill then decode steps through
 the paged state; a prompt admitted in chunks through the ragged step beside
-decoding slots; both megasteps.
+decoding slots.
 
 THE LIMITS (``LIMITS``), in standard deviations of the reference's logits at
 the position, (worst position, mean over positions):
@@ -28,7 +28,6 @@ program (``float32-kernel``: a value dim and a latent width of whole lanes,
 which the kernels ask for).
 """
 
-import gc
 import json
 import sys
 from dataclasses import replace
@@ -57,21 +56,11 @@ ALL_CHOSEN = replace(CFG, num_experts=8, num_experts_per_tok=8,
                      experts_held=4)
 LIMITS = {"float32": (1e-3, 1e-4), "bfloat16": (0.2, 0.1),
           "int8": (0.2, 0.1)}
-PATHS = ("prefill", "decode", "ragged", "megastep")
+PATHS = ("prefill", "decode", "ragged")
 ROWS = [*LIMITS, "float32-kernel"]
 
 
-@pytest.fixture(autouse=True)
-def _programs_go_with_their_test():
-    """A runner and its jitted methods are a reference cycle, and every
-    loaded CPU executable of these unrolled models holds memory maps by the
-    hundred: left to the collector's own schedule the file's programs pile
-    up in its one process (the driver runs a file in one worker) until a
-    load from the compile cache dies of a segmentation fault once a handful
-    more tests join the file (ISSUE 49's warning; PR 49 added nine)."""
-    yield
-    jax.clear_caches()
-    gc.collect()
+pytestmark = pytest.mark.usefixtures("_programs_go_with_their_test")
 
 
 @pytest.fixture
@@ -208,14 +197,11 @@ def run_path(r, path: str) -> list[tuple]:
     seq = a + [int(first)]
     out = []
 
-    def advance(st, n, mega):
-        if mega:
-            toks, _, st = r.decode_megastep(st, n)
-        else:
-            toks, st = r.decode_steps_device(st, n)
+    def advance(st, n):
+        toks, st = r.decode_steps_device(st, n)
         return np.asarray(toks), st
 
-    toks, st = advance(st, 8, path == "megastep")
+    toks, st = advance(st, 8)
     seq += [int(t) for t in toks[:, 1]]
     out.append(("decode", slot_rows(r, 1), seq[:-1], range(40, 48)))
     if path == "decode":
@@ -224,11 +210,8 @@ def run_path(r, path: str) -> list[tuple]:
     assert r.ragged_chunk == 32
     job = r.ragged_begin(b, 2, state=st)
     n0 = len(seq)
-    for k in (1, 2, 2) if path == "ragged" else (2, 2):
-        if path == "megastep":
-            toks, _, st = r.ragged_megastep(st, job, k)
-        else:
-            toks, st = r.ragged_step(st, job, k)
+    for k in (1, 2, 2):
+        toks, st = r.ragged_step(st, job, k)
         seq += [int(t) for t in np.asarray(toks)[:, 1]]
     assert job.finished
     n = len(seq) - n0
@@ -237,7 +220,7 @@ def run_path(r, path: str) -> list[tuple]:
     out.append(("chunked prompt's last token", job.last_logits[None], b,
                 [99]))
     first_b, st = r.ragged_finish(st, job, 0.0, 1.0, KEY)
-    toks, st = advance(st, 4, path == "megastep")
+    toks, st = advance(st, 4)
     seq_b = b + [int(first_b)] + [int(t) for t in toks[:, 2]]
     out.append(("decode after chunks", slot_rows(r, 2), seq_b[:-1],
                 range(100, 104)))
@@ -264,12 +247,12 @@ def test_logits_match_the_reference(row, path, kernels):
 # the latent pool's row is stored in whole lanes (engine/paged.py
 # ``pool_row_width``): the pad columns change no bit and stay zero
 
-@pytest.mark.parametrize("path", ["decode", "ragged", "megastep"])
+@pytest.mark.parametrize("path", ["decode", "ragged"])
 @pytest.mark.parametrize("row", ["float32", "float32-kernel"])
 def test_the_stored_rows_pad_changes_no_bit_of_the_logits(row, path, kernels,
                                                           monkeypatch):
     """Prefill then insert then decode, a prompt in ragged chunks beside
-    decoding slots, both megasteps: through a pool whose row is whole lanes
+    decoding slots: through a pool whose row is whole lanes
     every logit is BIT-equal to the same program's over a pool whose row is
     the 48 (144 for the kernels) entries computed — a pad term of a score
     is an exact zero and the value is the row's first ``kv_lora_rank``
@@ -307,7 +290,7 @@ def test_the_stored_rows_pad_changes_no_bit_of_the_logits(row, path, kernels,
 def test_the_stored_rows_pad_columns_stay_zero(row, kernels):
     """A slot served, released and served again out of the pages it gave
     back — an insert, decode writes, ragged chunks beside another slot's
-    decode writes, a megastep: every pad column of the pool, the dump page's
+    decode writes, a flight: every pad column of the pool, the dump page's
     too, is the zero ``init_state`` drew; the columns of the row are not."""
     kernels(row)
     r = make_runner(row, cls=HybridPagedModelRunner)
@@ -323,7 +306,7 @@ def test_the_stored_rows_pad_columns_stay_zero(row, kernels):
         _, st = r.ragged_step(st, job, 1)
     assert held & set(r._slot_pages[2])     # pages with a past
     _, st = r.ragged_finish(st, job, 0.0, 1.0, KEY)
-    _, _, st = r.decode_megastep(st, 3)
+    _, st = r.decode_steps_device(st, 3)
     pool = np.asarray(st.pool_k.astype(jnp.float32))
     assert pool.shape[-1] > width
     assert not pool[..., width:].any()
@@ -666,7 +649,7 @@ def test_what_rests_on_exportable_pages_declines_by_name():
 
 async def test_served_through_the_engine_with_its_gauges_and_counters():
     """(h) the normal path: JaxEngine -> scheduler -> the hybrid runner,
-    ragged admission and megastep on; every admission a prefix miss and its
+    ragged admission on; every admission a prefix miss and its
     first token the device's; the expert layers' assignment counts read back
     with the flights; the family's gauges; nothing to export for the KV
     plane or a drain."""
@@ -682,7 +665,7 @@ async def test_served_through_the_engine_with_its_gauges_and_counters():
 
     engine = JaxEngine(Configuration(
         model=CFG.name, max_context_length=256, max_batch_slots=2,
-        warmup=False, kv_page_size=16, step_token_budget=34, megastep_k=4,
+        warmup=False, kv_page_size=16, step_token_budget=34,
         kv_ship=True, intervals=Intervals.default()))
     await engine.start()
     try:

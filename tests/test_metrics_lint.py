@@ -19,6 +19,7 @@ from crowdllama_tpu.engine.engine import FakeEngine
 from crowdllama_tpu.gateway.gateway import Gateway
 from crowdllama_tpu.net.discovery import new_host_and_dht
 from crowdllama_tpu.obs.http import ObsServer
+from crowdllama_tpu.obs.metrics import DISPATCH_CLASSES
 from crowdllama_tpu.peer.peer import Peer
 
 _NAME_RE = re.compile(r"^[a-zA-Z_:][a-zA-Z0-9_:]*$")
@@ -250,22 +251,14 @@ async def test_gateway_and_worker_metrics_lint():
                 assert types.get(fam) == kind, f"{fam} missing"
             for g in ("pending_depth", "active_slots", "batch_occupancy",
                       "kv_cache_utilization",
-                      # Unified ragged batch (docs/RAGGED_BATCH.md):
-                      # chunked-prefill occupancy + per-step token load,
-                      # present on every engine kind (zero on FakeEngine).
-                      "prefill_chunk_slots", "step_token_budget_used",
-                      # Megastep dispatch accounting (docs/MEGASTEP.md):
-                      # amortization visible per worker even at K=0.
+                      # Host-dispatch accounting: what the last
+                      # retired flight emitted.
                       "tokens_per_dispatch"):
                 assert types.get(f"crowdllama_engine_{g}") == "gauge"
             # host_dispatches_total is monotone — it must render as a
             # counter (the `_total` suffix drives the TYPE line).
             assert types.get(
                 "crowdllama_engine_host_dispatches_total") == "counter"
-            # Per-chunk prefill latency inside the unified dispatch rides
-            # the engine-telemetry plane onto both surfaces.
-            assert types.get(
-                "crowdllama_prefill_chunk_seconds") == "histogram"
             # Engine flight-recorder telemetry (docs/OBSERVABILITY.md):
             # XLA compile timing/counters + padding-waste accounting +
             # device memory, present on BOTH surfaces (zero-valued on a
@@ -298,12 +291,19 @@ async def test_gateway_and_worker_metrics_lint():
                 for outcome in ("ok", "fail"):
                     assert (f'crowdllama_dial_ladder_attempts_total{{'
                             f'rung="{rung}",outcome="{outcome}"}}') in text
-        # Duty cycle: one labeled child per dispatch class, including
-        # the fused ragged-megastep class (pre-rendered at zero from
-        # boot so dashboards see the series before the first flight).
-        for cls in ("plain", "megastep", "ragged", "ragged_mega", "spec"):
-            assert (f'crowdllama_engine_duty_cycle{{dispatch="{cls}"}}'
-                    in gw_text)
+        # Duty cycle: one labeled child per dispatch class and no other
+        # (pre-rendered at zero from boot so dashboards see the series
+        # before the first flight).
+        assert set(re.findall(
+            r'^crowdllama_engine_duty_cycle\{dispatch="(\w+)"\}', gw_text,
+            re.M)) == set(DISPATCH_CLASSES)
+        # The benchmark's step.decode_wall_ms names both flight counters'
+        # dispatch="megastep" series: a worker's scrape carries them, at 0
+        # (obs/metrics.py DISPATCH_CLASSES has why).
+        for fam in ("seconds", "steps"):
+            assert re.search(
+                rf'^crowdllama_engine_flight_{fam}_total'
+                r'\{dispatch="megastep"\} 0(\.0+)?$', wk_text, re.M), fam
         # SLO burn-rate plane (gateway-only; objectives were configured).
         for fam, kind in (("crowdllama_slo_objective_ms", "gauge"),
                           ("crowdllama_slo_requests_total", "counter"),
@@ -380,14 +380,14 @@ def test_moe_bank_counters_lint():
     rendered at 0 before any flight; a flight's counts land under its own
     class, a bank as routed or as unrouted; the assignment counter beside
     them keeps its two series."""
-    from crowdllama_tpu.obs.metrics import FLIGHT_CLASSES, EngineTelemetry
+    from crowdllama_tpu.obs.metrics import EngineTelemetry
 
     tele = EngineTelemetry()
     lines = tele.expose()
     types = _lint("\n".join(lines))
     assert types["crowdllama_moe_banks_total"] == "counter"
     assert types["crowdllama_moe_banks_fetched_total"] == "counter"
-    for cls in FLIGHT_CLASSES:
+    for cls in DISPATCH_CLASSES:
         for state in ("routed", "unrouted"):
             assert (f'crowdllama_moe_banks_total{{dispatch="{cls}",'
                     f'state="{state}"}} 0') in lines
@@ -408,7 +408,7 @@ def test_moe_bank_counters_lint():
                  'crowdllama_moe_banks_total{dispatch="ragged",'
                  'state="unrouted"} 0',
                  'crowdllama_moe_banks_fetched_total{dispatch="ragged"} 8',
-                 'crowdllama_moe_banks_fetched_total{dispatch="megastep"} 0',
+                 'crowdllama_moe_banks_fetched_total{dispatch="spec"} 0',
                  'crowdllama_moe_assignments_total{held="yes"} 61',
                  'crowdllama_moe_assignments_total{held="no"} 35'):
         assert line in lines, line
@@ -510,42 +510,8 @@ def test_spec_gauges_lint():
         assert types.get(f"crowdllama_engine_{g}") == "gauge", g
 
 
-def test_ragged_gauges_lint():
-    """The unified-ragged-batch gauges (scheduler.telemetry_gauges) render
-    as lint-clean crowdllama_engine_* families, and the per-chunk latency
-    histogram renders lint-clean through the engine-telemetry plane."""
-    from crowdllama_tpu.engine.scheduler import Scheduler
-    from crowdllama_tpu.obs.metrics import (
-        ENGINE_TELEMETRY,
-        engine_gauge_lines,
-    )
-
-    class _Runner:  # gauge rendering needs no device work
-        max_slots = 2
-        max_seq = 128
-
-    r = _Runner()
-    sched = Scheduler.__new__(Scheduler)
-    sched.runner = r
-    sched.slots = [None, None]
-    import asyncio
-
-    sched.pending = asyncio.Queue()
-    sched._deferred = []
-    sched._admitting = 0
-    sched._chunking = None
-    sched._step_budget_used = 3.5
-    sched.host_dispatches = 0
-    sched._tokens_per_dispatch = 0.0
-    types = _lint("\n".join(engine_gauge_lines(sched.telemetry_gauges())))
-    for g in ("prefill_chunk_slots", "step_token_budget_used"):
-        assert types.get(f"crowdllama_engine_{g}") == "gauge", g
-    types = _lint("\n".join(ENGINE_TELEMETRY.expose()))
-    assert types.get("crowdllama_prefill_chunk_seconds") == "histogram"
-
-
-def test_megastep_gauges_lint():
-    """The megastep dispatch-accounting pair (scheduler.telemetry_gauges)
+def test_host_dispatch_gauges_lint():
+    """The host-dispatch accounting pair (scheduler.telemetry_gauges)
     renders lint-clean: host_dispatches_total as a counter (monotone,
     `_total`-suffixed), tokens_per_dispatch as a gauge."""
     import asyncio
@@ -564,7 +530,6 @@ def test_megastep_gauges_lint():
     sched._deferred = []
     sched._admitting = 0
     sched._chunking = None
-    sched._step_budget_used = 0.0
     sched.host_dispatches = 17
     sched._tokens_per_dispatch = 6.0
     types = _lint("\n".join(engine_gauge_lines(sched.telemetry_gauges())))

@@ -392,7 +392,7 @@ def test_dense_dispatch_reads_a_riding_bank(monkeypatch, program):
         assert new == old
 
 
-HYBRID_PATHS = ("prefill", "decode", "ragged", "megastep")
+HYBRID_PATHS = ("prefill", "decode", "ragged")
 
 
 @pytest.mark.parametrize("path", HYBRID_PATHS)

@@ -1,7 +1,7 @@
 """Swarm observatory (PR 13, docs/OBSERVABILITY.md): cluster metric
 fan-in over a real 2-worker loopback swarm (partial snapshot when a
 worker dies mid-scrape — never a 500), SLO burn-rate window math on a
-fake clock, duty-cycle gauges under a real megastep scheduler run, shed
+fake clock, duty-cycle gauges under a real scheduler run, shed
 requests landing in the flight recorder, and the `top` table renderer.
 """
 
@@ -163,10 +163,10 @@ def test_autoscale_parses_worst_burn_rate():
 # ------------------------------------------------- duty-cycle profiler
 
 
-async def test_duty_cycle_gauges_under_megastep_run():
-    """A real megastep scheduler run moves ONLY the megastep duty-cycle
-    gauge (per-step control moves only `plain`), both stay in (0, 1],
-    and the host-gap histogram collects per-class samples."""
+async def test_duty_cycle_gauges_under_plain_run():
+    """A real scheduler run of plain flights, one step a dispatch or
+    eight, moves ONLY the `plain` duty-cycle gauge, which stays in (0, 1],
+    and the host-gap histogram collects samples of that class alone."""
     import jax
     import jax.numpy as jnp
 
@@ -174,17 +174,17 @@ async def test_duty_cycle_gauges_under_megastep_run():
     from crowdllama_tpu.engine.scheduler import DONE, Scheduler
     from crowdllama_tpu.models import transformer as T
     from crowdllama_tpu.models.config import get_config
-    from crowdllama_tpu.obs.metrics import ENGINE_TELEMETRY
+    from crowdllama_tpu.obs.metrics import DISPATCH_CLASSES, ENGINE_TELEMETRY
 
     cfg = get_config("tiny-test", max_context_length=256)
     params = T.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.bfloat16)
     runner = PagedModelRunner(cfg, params=params, max_slots=2, max_seq=256,
                               page_size=32, mesh_spec="1")
 
-    async def _run(megastep_k):
+    async def _run(decode_chunk):
         from crowdllama_tpu.engine.scheduler import GenRequest
 
-        sched = Scheduler(runner, megastep_k=megastep_k, decode_chunk=1)
+        sched = Scheduler(runner, decode_chunk=decode_chunk)
         sched.start()
         try:
             reqs = [GenRequest(prompt_ids=[3, 1, 4], max_tokens=12, seed=7),
@@ -200,24 +200,23 @@ async def test_duty_cycle_gauges_under_megastep_run():
         finally:
             await sched.stop()
 
-    mega_before = ENGINE_TELEMETRY.host_gap_seconds.labels("megastep").count
-    plain_before = ENGINE_TELEMETRY.host_gap_seconds.labels("plain").count
+    def gaps():
+        return {cls: ENGINE_TELEMETRY.host_gap_seconds.labels(cls).count
+                for cls in DISPATCH_CLASSES}
 
-    mega = await _run(8)
-    plain = await _run(0)
-
-    for g in (mega, plain):  # all four classes always present
-        for cls in ("plain", "megastep", "ragged", "spec"):
-            assert f"duty_cycle|dispatch={cls}" in g
-    assert 0.0 < mega["duty_cycle|dispatch=megastep"] <= 1.0
-    assert mega["duty_cycle|dispatch=plain"] == 0.0
-    assert 0.0 < plain["duty_cycle|dispatch=plain"] <= 1.0
-    assert plain["duty_cycle|dispatch=megastep"] == 0.0
-    # The host-gap histogram collected per-class samples from both runs.
-    assert ENGINE_TELEMETRY.host_gap_seconds.labels("megastep").count \
-        > mega_before
-    assert ENGINE_TELEMETRY.host_gap_seconds.labels("plain").count \
-        > plain_before
+    before = gaps()
+    for g in (await _run(8), await _run(1)):
+        # every class always present, and no other
+        assert {k.partition("=")[2] for k in g
+                if k.startswith("duty_cycle|")} == set(DISPATCH_CLASSES)
+        assert 0.0 < g["duty_cycle|dispatch=plain"] <= 1.0
+        assert g["duty_cycle|dispatch=ragged"] == 0.0
+        assert g["duty_cycle|dispatch=spec"] == 0.0
+    # The host-gap histogram collected samples of the one class that flew.
+    after = gaps()
+    assert after["plain"] > before["plain"]
+    assert after["ragged"] == before["ragged"]
+    assert after["spec"] == before["spec"]
 
 
 @pytest.mark.parametrize("slots,length", [(4, "short"), (1, "full")],
@@ -261,7 +260,7 @@ def test_multi_engine_max_merges_duty_cycle():
     class _Child:
         def __init__(self, duty):
             self._g = {"pending_depth": 1.0,
-                       "duty_cycle|dispatch=megastep": duty}
+                       "duty_cycle|dispatch=plain": duty}
 
         def obs_gauges(self):
             return dict(self._g)
@@ -269,7 +268,7 @@ def test_multi_engine_max_merges_duty_cycle():
     me = MultiEngine.__new__(MultiEngine)
     me._engines = {"a": _Child(0.9), "b": _Child(0.4)}
     g = me.obs_gauges()
-    assert g["duty_cycle|dispatch=megastep"] == pytest.approx(0.9)
+    assert g["duty_cycle|dispatch=plain"] == pytest.approx(0.9)
     assert g["pending_depth"] == pytest.approx(2.0)  # depths still sum
 
 
@@ -437,7 +436,7 @@ def test_render_top_joins_routing_and_engine_views():
         'crowdllama_worker_healthy{peer="bbbb"} 0',
         'crowdllama_engine_batch_occupancy{worker="aaaa"} 0.75',
         'crowdllama_engine_pending_depth{worker="aaaa"} 2',
-        'crowdllama_engine_duty_cycle{worker="aaaa",dispatch="megastep"}'
+        'crowdllama_engine_duty_cycle{worker="aaaa",dispatch="ragged"}'
         ' 0.93',
         'crowdllama_engine_duty_cycle{worker="aaaa",dispatch="plain"} 0.1',
     ])

@@ -200,3 +200,80 @@ def test_discovery_applies_results():
     pm = _pm(discovery=disc)
     asyncio.run(pm.run_discovery_once())
     assert set(pm.peers) == {"found-1", "found-2"}
+
+
+def _discovery_on_a_fake_clock(monkeypatch, disc, until, interval=10.0):
+    """Run a manager's background loops with every ``asyncio.sleep`` taken
+    for slept at once, until ``until()`` holds; the seconds the discovery
+    loop's task asked to sleep, in order."""
+    real_sleep = asyncio.sleep
+    asked: list[float] = []
+
+    async def sleep(seconds, *a):
+        if asyncio.current_task().get_name() == "pm-discovery":
+            asked.append(seconds)
+        await real_sleep(0)
+
+    async def run():
+        pm = PeerManager(self_peer_id="self", discovery=disc,
+                         config=PeerHealthConfig(Intervals(
+                             discovery=interval, health_check=1e6,
+                             cleanup=1e6)))
+        monkeypatch.setattr(asyncio, "sleep", sleep)
+        pm.start()
+        try:
+            for _ in range(10_000):
+                if until(pm):
+                    break
+                await real_sleep(0)
+            assert until(pm)
+        finally:
+            await pm.stop()
+
+    asyncio.run(run())
+    return asked
+
+
+def test_empty_rounds_leave_discovery_at_its_one_cadence(monkeypatch):
+    """However many rounds in a row find nobody, the next is
+    ``intervals.discovery`` away (run_every's jitter aside), and every
+    round asks with the skip set of that moment."""
+    rounds = []
+
+    async def disc(skip):
+        rounds.append(set(skip))
+        return []
+
+    asked = _discovery_on_a_fake_clock(monkeypatch, disc,
+                                       lambda pm: len(rounds) >= 40)
+    first, *periods = asked
+    assert 0.0 <= first <= 2.5            # the first tick's phase jitter
+    assert len(periods) >= 39
+    assert all(7.5 <= s <= 12.5 for s in periods), periods
+    assert rounds == [set()] * len(rounds)
+
+
+def test_a_worker_that_appears_after_a_long_idle_is_seen_in_one_interval(
+        monkeypatch):
+    """A gateway that has been alone for forty rounds sees a worker that
+    joins within one discovery interval (and jitter) of its appearing, and
+    from then on the worker is in the skip set: discovery asks for NEW
+    providers only."""
+    rounds = []
+    appeared_at = []
+
+    async def disc(skip):
+        rounds.append(set(skip))
+        if len(rounds) <= 40 or "w" in skip:
+            return []
+        return [_res("w")]
+
+    def until(pm):
+        if len(rounds) == 40 and not appeared_at:
+            appeared_at.append(True)     # the worker comes up in this sleep
+        return len(rounds) >= 44
+
+    asked = _discovery_on_a_fake_clock(monkeypatch, disc, until)
+    # round 41 is the first after the worker appeared: one sleep away
+    assert rounds[40] == set() and 7.5 <= asked[40] <= 12.5
+    assert all("w" in skip for skip in rounds[41:]), rounds[41:]

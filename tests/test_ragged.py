@@ -216,10 +216,8 @@ def test_ragged_across_mid_prefill_retune():
 
 async def test_ragged_scheduler_streams_identical():
     """End to end: the scheduler's unified ragged admission must produce
-    the same token streams as the legacy chunked-prefill path, populate
-    the new gauges, and observe the chunk histogram."""
+    the same token streams as the legacy chunked-prefill path."""
     from crowdllama_tpu.engine.scheduler import DONE, GenRequest, Scheduler
-    from crowdllama_tpu.obs.metrics import ENGINE_TELEMETRY
 
     cfg = get_config("tiny-test", max_context_length=2048)
     params = T.init_params(cfg, KEY, dtype=jnp.bfloat16)
@@ -248,19 +246,14 @@ async def test_ragged_scheduler_streams_identical():
                         outs.append((toks, reason))
                         break
                     toks.append(tok)
-            return outs, sched.telemetry_gauges(), sched.ragged_chunks
+            return outs, sched.ragged_chunks
         finally:
             await sched.stop()
 
-    a, gauges, chunks = await run_once(ragged=True)
+    a, chunks = await run_once(ragged=True)
     assert chunks >= 2, chunks  # the 900-token prompt alone needs 2
-    assert gauges["prefill_chunk_slots"] == 0.0  # idle again when drained
-    assert "step_token_budget_used" in gauges
-    b, _, legacy_chunks = await run_once(ragged=False)
+    b, legacy_chunks = await run_once(ragged=False)
     assert legacy_chunks == 0
     for (ta, ra), (tb, rb) in zip(a, b):
         assert ra == rb, (ra, rb)
         assert ta == tb, (ta, tb)
-    lines = [ln for ln in ENGINE_TELEMETRY.expose()
-             if "prefill_chunk_seconds" in ln and "_count" in ln]
-    assert lines and not lines[0].endswith(" 0"), lines

@@ -282,25 +282,23 @@ _LOOP_SYNC_FIXTURE = """
                 tokens = await loop.run_in_executor(
                     self._exec, np.asarray, tokens_dev)
 
-        async def loop_bad_fused(self, loop, state, job):
+        async def loop_bad_ragged(self, loop, state, job):
             while not job.finished:
-                tokens_dev, done_dev, state = self.runner.ragged_megastep(
-                    state, job, 8)
-                done = np.asarray(done_dev)
+                tokens_dev, state = self.runner.ragged_step(state, job, 8)
+                tokens = np.asarray(tokens_dev)
 
         async def loop_ok(self, loop, state):
             while True:
-                tokens_dev, done_dev, state = self.runner.decode_megastep(
+                tokens_dev, state = self.runner.decode_steps_device(
                     state, 8)
-                tokens, done = await loop.run_in_executor(
-                    self._exec, jax.device_get, (tokens_dev, done_dev))
+                tokens, counters = await loop.run_in_executor(
+                    self._exec, jax.device_get, (tokens_dev, counters_dev))
 
-        async def loop_ok_fused(self, loop, state, job):
+        async def loop_ok_ragged(self, loop, state, job):
             while not job.finished:
-                tokens_dev, done_dev, state = self.runner.ragged_megastep(
-                    state, job, 8)
-                tokens, done = await loop.run_in_executor(
-                    self._exec, jax.device_get, (tokens_dev, done_dev))
+                tokens_dev, state = self.runner.ragged_step(state, job, 8)
+                tokens, counters = await loop.run_in_executor(
+                    self._exec, jax.device_get, (tokens_dev, counters_dev))
 
         def retire_ok(self, fl):
             tokens = np.asarray(fl.tokens_dev)
@@ -315,11 +313,10 @@ def test_host_sync_in_decode_loop_seeded(tmp_path):
     hits = {(f.code, f.symbol) for f in check_jax_purity(root, ("engine",))}
     # Direct per-step readback AND the executor-wrapped form (np.asarray
     # handed to run_in_executor) are both the seeded bug class, and the
-    # fused ragged flight (ragged_megastep) is covered the same way — a
-    # per-flight sync there forfeits the dispatches the fusion reclaimed.
+    # ragged flight (ragged_step) is covered the same way.
     assert ("host-sync-in-decode-loop", "loop_bad") in hits
     assert ("host-sync-in-decode-loop", "loop_bad_executor") in hits
-    assert ("host-sync-in-decode-loop", "loop_bad_fused") in hits
+    assert ("host-sync-in-decode-loop", "loop_bad_ragged") in hits
 
 
 def test_host_sync_in_decode_loop_true_negatives(tmp_path):
@@ -327,11 +324,11 @@ def test_host_sync_in_decode_loop_true_negatives(tmp_path):
                       {"crowdllama_tpu/engine/fx.py": _LOOP_SYNC_FIXTURE})
     loop_hits = {f.symbol for f in check_jax_purity(root, ("engine",))
                  if f.code == "host-sync-in-decode-loop"}
-    # The sanctioned megastep pattern (one jax.device_get of the packed
-    # block per flight) — plain or fused ragged — and a dispatch-free
-    # emit loop stay clean.
+    # The sanctioned pattern (one jax.device_get of the packed block per
+    # flight) — plain or ragged — and a dispatch-free emit loop stay
+    # clean.
     assert "loop_ok" not in loop_hits
-    assert "loop_ok_fused" not in loop_hits
+    assert "loop_ok_ragged" not in loop_hits
     assert "retire_ok" not in loop_hits
 
 
