@@ -45,8 +45,12 @@ construction (engine/factory.py, engine/spec.py): each with
 ``hybrid.NO_PAGES`` in its message.  ROADMAP "state snapshots" is what
 would lift them.  A model with window layers declines the same four for a
 reason of its own, ``hybrid.NO_WINDOW_PAGES``: the ring has written over a
-prefix's pages by the time anyone could share, ship or roll back to them
-(:func:`why_no_pages` picks the reason).
+prefix's pages by the time anyone could share, ship or roll back to them.
+A model whose every layer is latent attention (family ``sarvam_mla``) keeps
+all the pages of all its layers, and still declines the four in this
+runner, for a third reason, ``hybrid.NO_LATENT_PAGES``: the prefix gathers
+and ``import_pages`` take a page of K and its twin of V, and a latent pool
+has one row a token and no twin (:func:`why_no_pages` picks the reason).
 """
 
 from __future__ import annotations
@@ -91,14 +95,17 @@ class HybridPrefill:
 def why_no_pages(cfg) -> str:
     """The reason a hybrid model's slots have no pages to share, ship or
     roll back to."""
-    recurrent = any(kind in cfg.layer_pattern for kind in H.STATE)
-    return H.NO_PAGES if recurrent else H.NO_WINDOW_PAGES
+    if any(kind in cfg.layer_pattern for kind in H.STATE):
+        return H.NO_PAGES
+    return H.NO_WINDOW_PAGES if "W" in cfg.layer_pattern else H.NO_LATENT_PAGES
 
 
 def refuse_speculation(cfg, what: str) -> None:
     """Speculation rolls rejected tokens back by forgetting their KV; the
-    recurrent layers' state has already absorbed them, and a window
-    layer's ring may have written them over what they replaced."""
+    recurrent layers' state has already absorbed them, a window layer's
+    ring may have written them over what they replaced, and the
+    speculating runners verify over pages of K and V, which a latent pool
+    does not keep."""
     if cfg.is_hybrid:
         raise ValueError(
             f"{what} cannot serve {cfg.name!r}: a rejected draft token "
@@ -176,14 +183,18 @@ class HybridPagedModelRunner(PagedModelRunner):
 
     @property
     def ragged_width_fixed(self) -> bool:
-        return self.ring is not None
+        """A model with no recurrent layer (window and full attention, or
+        latent attention throughout) admits every prompt longer than a
+        chunk through the unified programs, so they take ONE page-table
+        width, compiled at both flight lengths by the warm-up."""
+        return not self._rec_names
 
     def _ragged_window(self) -> int:
-        """With window layers the unified programs take the whole table:
-        those layers never read it, the full layers' kernel skips the
-        columns past a slot's length, and every admission of a long prompt
-        then dispatches a program the warm-up compiled."""
-        if self.ring is not None:
+        """Such a model's unified programs take the whole table: window
+        layers never read it, the other layers' kernels skip the columns
+        past a slot's length, and every admission of a long prompt then
+        dispatches a program the warm-up compiled."""
+        if self.ragged_width_fixed:
             return self.max_pages_per_slot
         return super()._ragged_window()
 

@@ -376,7 +376,7 @@ class PagedModelRunner(ModelRunner):
         # dispatch, which is what the decode-jitter problem is about.
         ragged = (f"mesh has {self.mesh.size} devices (the ragged kernel is "
                   f"not shard_map-wrapped)" if self.mesh.size > 1
-                  else ragged_pallas_refusal(*gate))
+                  else ragged_pallas_refusal(*gate, self.cfg.num_heads))
         decode = paged_pallas_refusal(*gate)
         if not decode and self.cfg.kv_lora_rank % 128:
             decode = (f"the latent row's value is its first "
@@ -1230,12 +1230,14 @@ class PagedModelRunner(ModelRunner):
         """What the pools hold and could hold, for the scheduler's gauges
         (rendered as the engine's ``kv_pool_bytes{kind}`` and
         ``kv_live_bytes{kind}``): ``full`` is this pool, whose pages a slot
-        keeps for its whole context; live = pages some slot or the prefix
-        index holds."""
+        keeps for its whole context — ``latent`` where a page holds one row
+        a token, key and value both, and has no twin; live = pages some
+        slot or the prefix index holds."""
         page = self._page_bytes * self.pool_layers
+        kind = "latent" if self.cfg.kv_lora_rank else "full"
         return {
-            "kv_pool_bytes|kind=full": float(self.total_pages * page),
-            "kv_live_bytes|kind=full": float(
+            f"kv_pool_bytes|kind={kind}": float(self.total_pages * page),
+            f"kv_live_bytes|kind={kind}": float(
                 (self.total_pages - len(self._free_pages)) * page)}
 
     def release(self, state: PagedDecodeState, slot: int):

@@ -217,8 +217,10 @@ class ModelRunner:
     def kv_layers(self) -> int:
         """Layers that keep KV: all of them, but for a model whose layers
         differ in kind (engine/hybrid.py): its attention layers of every
-        kind (``*``, latent ``L``, gated window ``W`` and full ``F``)."""
-        return sum(self.cfg.layers_of(kind) for kind in "*LWF")
+        kind (``models/hybrid.py`` ``ATTENTION``)."""
+        from crowdllama_tpu.models.hybrid import ATTENTION
+
+        return sum(self.cfg.layers_of(kind) for kind in ATTENTION)
 
     # ------------------------------------------------------- attention paths
 
@@ -229,8 +231,13 @@ class ModelRunner:
 
         itemsize = jnp.dtype(self.dtype).itemsize
         dh = self.cfg.resolved_head_dim()
+        # The buckets the monolithic prefill is dispatched at: the scheduler
+        # admits every prompt over ``prefill_chunk`` tokens in chunks (or in
+        # ragged steps), so no larger bucket ever runs.
+        top = (self.bucket_for(min(self.prefill_chunk, self.max_seq))
+               if self.prefill_chunk else self.max_seq)
         refused: dict[str, list[int]] = {}  # reason -> buckets it refuses
-        for b in self.buckets:
+        for b in (b for b in self.buckets if b <= top):
             why = pallas_refusal(b, dh, itemsize, self.mesh.size)
             if why:
                 refused.setdefault(why, []).append(b)

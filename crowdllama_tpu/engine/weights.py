@@ -188,11 +188,15 @@ def _to_np(arr) -> np.ndarray:
     return a
 
 
-def _rope_scaling_from_hf(d: dict | None):
+def _rope_scaling_from_hf(d: dict | None,
+                          supported: tuple[str, ...] = ("llama3", "linear")):
     """Map config.json ``rope_scaling`` to a RopeScaling (None passes
-    through; "default" means no scaling).  Unsupported schemes (yarn,
-    dynamic, longrope) raise — serving with silently-wrong position
-    embeddings would corrupt every long-context generation."""
+    through; "default" means no scaling).  A scheme outside ``supported`` —
+    the family's: the dense families are served with llama3 and linear, the
+    latent-attention family ``sarvam_mla`` with ``yarn`` (``deepseek_yarn``
+    is the same scheme under the name its family publishes) — raises, and
+    names them: serving with silently-wrong position embeddings would
+    corrupt every long-context generation."""
     if not d:
         return None
     from crowdllama_tpu.models.config import RopeScaling
@@ -200,6 +204,9 @@ def _rope_scaling_from_hf(d: dict | None):
     kind = d.get("rope_type") or d.get("type") or ""
     if kind in ("", "default"):
         return None
+    if kind not in supported:
+        raise ValueError(f"unsupported rope_scaling type {kind!r} "
+                         f"(supported: {', '.join(supported)})")
     if kind == "llama3":
         return RopeScaling(
             rope_type="llama3", factor=float(d["factor"]),
@@ -209,8 +216,14 @@ def _rope_scaling_from_hf(d: dict | None):
                 d.get("original_max_position_embeddings", 8192)))
     if kind == "linear":
         return RopeScaling(rope_type="linear", factor=float(d["factor"]))
-    raise ValueError(f"unsupported rope_scaling type {kind!r} "
-                     f"(supported: llama3, linear)")
+    return RopeScaling(
+        rope_type="yarn", factor=float(d["factor"]),
+        original_max_position_embeddings=int(
+            d["original_max_position_embeddings"]),
+        beta_fast=float(d.get("beta_fast", 32.0)),
+        beta_slow=float(d.get("beta_slow", 1.0)),
+        mscale=float(d.get("mscale", 1.0)),
+        mscale_all_dim=float(d.get("mscale_all_dim", 0.0)))
 
 
 def resolve_clamped_model_config(config) -> ModelConfig:
@@ -277,7 +290,8 @@ def resolve_model_config(name: str, model_path: str = "",
 _FAMILIES = {"llama": "llama", "mistral": "mistral", "mixtral": "mixtral",
              "gemma2": "gemma2", "qwen2": "qwen2", "qwen3": "qwen3",
              "qwen3_moe": "qwen3", "nemotron_h": "nemotron_h",
-             "kimi_linear": "kimi_linear", "afmoe": "afmoe"}
+             "kimi_linear": "kimi_linear", "afmoe": "afmoe",
+             "sarvam_mla": "sarvam_mla"}
 #: keys that say the block is not the one the dense families share: reading
 #: past them would serve another model under this one's name
 _FOREIGN_KEYS = ("hybrid_override_pattern", "n_routed_experts",
@@ -307,13 +321,16 @@ def config_from_hf_dir(path: str | Path) -> ModelConfig:
         else "qwen2" if "qwen2" in arch
         else "nemotron_h" if "nemotronh" in arch
         else "kimi_linear" if "kimilinear" in arch
-        else "afmoe" if "afmoe" in arch else "llama")
+        else "afmoe" if "afmoe" in arch
+        else "sarvam_mla" if "sarvammla" in arch else "llama")
     if family == "nemotron_h":
         return _nemotron_h_config(d)
     if family == "kimi_linear":
         return _kimi_linear_config(d)
     if family == "afmoe":
         return _afmoe_config(d)
+    if family == "sarvam_mla":
+        return _sarvam_mla_config(d)
     odd = sorted(k for k, v in d.items() if v not in (None, 0, False)
                  and (k in _FOREIGN_KEYS or k.startswith(_FOREIGN_PREFIXES)))
     if odd:
@@ -465,6 +482,70 @@ def _kimi_linear_config(d: dict) -> ModelConfig:
                                       * d["moe_intermediate_size"]),
         moe_routed_scaling=float(d.get("routed_scaling_factor", 1.0)),
         moe_norm_topk=bool(d.get("moe_renormalize", True)),
+    )
+
+
+def _sarvam_mla_config(d: dict) -> ModelConfig:
+    """``model_type: sarvam_mla``.  A published layer is two sublayers of
+    the pattern: latent attention whose decoupled part is rotated (``R``),
+    then its feed-forward, ``D`` for the first ``first_k_dense_replace``
+    layers and ``S`` after.  ``num_experts`` counts the experts held HERE;
+    where that is a share, ``num_experts_published`` gives the router's
+    width and ``expert_parallel_rank`` which share (the benchmark's cut
+    states both).  The latent attention is served absorbed: one kv head
+    whose row is ``kv_lora_rank + qk_rope_head_dim`` wide — the config's own
+    ``head_dim`` — and no low-rank stage on the query.  ``use_qk_norm`` is
+    read as the family's norm of the compressed kv stream (``kv_norm``),
+    which every latent layer here has; a per-head norm of the decompressed
+    keys could not be folded into the query.  The softmax scale is
+    ``q_head_dim^-1/2`` times the square of the yarn scaling's
+    ``mscale_all_dim`` correction, kept as ``query_pre_attn_scalar``."""
+    n = d["num_hidden_layers"]
+    served = {"hidden_act": "silu", "q_lora_rank": None, "n_group": 1,
+              "topk_group": 1, "moe_router_enable_expert_bias": True, "use_qk_norm": True,
+              "attention_bias": False, "norm_topk_prob": True}
+    odd = {k: d[k] for k, v in served.items() if d.get(k, v) != v}
+    if odd:
+        raise ValueError(f"sarvam_mla is served with {served} only; this "
+                         f"config.json says {odd}")
+    dq = d["qk_nope_head_dim"] + d["qk_rope_head_dim"]
+    row = d["kv_lora_rank"] + d["qk_rope_head_dim"]
+    if d.get("q_head_dim", dq) != dq or d.get("head_dim", row) != row:
+        raise ValueError(
+            f"q_head_dim {d.get('q_head_dim')} and head_dim "
+            f"{d.get('head_dim')} must be qk_nope + qk_rope = {dq} and "
+            f"kv_lora_rank + qk_rope = {row}: the absorbed row")
+    if d["v_head_dim"] != d["qk_nope_head_dim"]:
+        raise ValueError("the kv up-projection is read as heads of "
+                         "[k_nope | v] of one width each")
+    scaling = _rope_scaling_from_hf(d.get("rope_scaling"),
+                                    supported=("yarn", "deepseek_yarn"))
+    mscale = scaling.yarn_mscale(scaling.mscale_all_dim) if scaling else 1.0
+    dense = d.get("first_k_dense_replace", 0)
+    held = d["num_experts"]
+    return ModelConfig(
+        name=d.get("_name_or_path", "hf-model"), family="sarvam_mla",
+        vocab_size=d["vocab_size"], hidden_size=d["hidden_size"],
+        intermediate_size=d["intermediate_size"], num_layers=n,
+        num_heads=d["num_attention_heads"], num_kv_heads=1, head_dim=row,
+        # scale = dq^-1/2 mscale^2, as the value whose -1/2 power it is
+        query_pre_attn_scalar=dq / mscale ** 4,
+        rope_theta=float(d.get("rope_theta", 10000.0)), rope_scaling=scaling,
+        rms_norm_eps=d.get("rms_norm_eps", 1e-6),
+        tie_word_embeddings=d.get("tie_word_embeddings", False),
+        max_context_length=d.get("max_position_embeddings", 4096),
+        layer_pattern="".join("R" + ("D" if i < dense else "S")
+                              for i in range(n)),
+        kv_lora_rank=d["kv_lora_rank"],
+        qk_nope_head_dim=d["qk_nope_head_dim"],
+        qk_rope_head_dim=d["qk_rope_head_dim"], v_head_dim=d["v_head_dim"],
+        num_experts=d.get("num_experts_published", held),
+        experts_held=held, expert_rank=d.get("expert_parallel_rank", 0),
+        num_experts_per_tok=d["num_experts_per_tok"],
+        moe_intermediate_size=d["moe_intermediate_size"],
+        moe_shared_intermediate_size=(d["num_shared_experts"]
+                                      * d["moe_intermediate_size"]),
+        moe_routed_scaling=float(d.get("routed_scaling_factor", 1.0)),
     )
 
 

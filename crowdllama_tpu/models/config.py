@@ -1,14 +1,19 @@
 """Model architecture configs and the named-model registry.
 
-Covers every family named by the driver's benchmark configs
-(/root/repo/BASELINE.json): TinyLlama-1.1B, Llama-3 8B/70B, Mixtral 8x7B
-(MoE), Gemma-2 27B — plus tiny variants for tests.  One config dataclass
-describes all three families; family-specific behavior (Gemma logit
-softcapping, sliding-window interleave, MoE routing) is driven by fields.
+One config dataclass describes every family the program serves: the dense
+and mixture-of-experts transformers of models/transformer.py (llama,
+mistral, gemma2, mixtral, qwen2, qwen3) and the families whose layers
+differ in kind (models/hybrid.py: nemotron_h, kimi_linear, afmoe,
+sarvam_mla).  Family-specific behavior (Gemma logit softcapping,
+sliding-window interleave, MoE routing, a layer pattern) is driven by
+fields.  The registry holds the named production models and a tiny variant
+of each family for the tests; a model that is not in it is read from its
+directory's config.json (engine/weights.py).
 """
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, replace
 
 
@@ -17,29 +22,48 @@ class RopeScaling:
     """Long-context RoPE scaling (HF config.json ``rope_scaling``).
 
     ``rope_type`` "llama3" is the Llama-3.1/3.2 frequency-dependent
-    scheme; "linear" is plain position interpolation.  A frozen
-    dataclass (not a dict) so ModelConfig stays hashable.
+    scheme; "linear" is plain position interpolation; "yarn" blends
+    interpolated and original frequencies between the two correction
+    dimensions that ``beta_fast`` / ``beta_slow`` rotations over
+    ``original_max_position_embeddings`` give, and scales cos and sin by
+    ``mscale`` over ``mscale_all_dim`` (ops/rope.py has the equations; the
+    factor a latent-attention family puts on its softmax scale is that
+    family's: engine/weights.py).  A frozen dataclass (not a dict) so
+    ModelConfig stays hashable.
     """
+
+    #: the schemes ops/rope.py computes
+    TYPES = ("llama3", "linear", "yarn")
 
     rope_type: str = "llama3"
     factor: float = 8.0
     low_freq_factor: float = 1.0
     high_freq_factor: float = 4.0
     original_max_position_embeddings: int = 8192
+    # yarn only
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
     def __post_init__(self) -> None:
-        if self.rope_type not in ("llama3", "linear"):
+        if self.rope_type not in self.TYPES:
             raise ValueError(
                 f"unsupported rope scaling type {self.rope_type!r} "
-                f"(supported: llama3, linear)")
+                f"(supported: {', '.join(self.TYPES)})")
+
+    def yarn_mscale(self, m: float) -> float:
+        """YaRN's magnitude correction for a multiplier ``m`` (``mscale`` or
+        ``mscale_all_dim``): ``0.1 m ln(factor) + 1``."""
+        return 0.1 * m * math.log(self.factor) + 1.0 if self.factor > 1 else 1.0
 
 
 @dataclass(frozen=True)
 class ModelConfig:
     name: str = "custom"
     # "llama" | "mistral" | "gemma2" | "mixtral" | "qwen2" | "qwen3" |
-    # "nemotron_h" | "kimi_linear" | "afmoe" (layers that differ in kind:
-    # models/hybrid.py)
+    # "nemotron_h" | "kimi_linear" | "afmoe" | "sarvam_mla" (layers that
+    # differ in kind: models/hybrid.py)
     family: str = "llama"
     vocab_size: int = 32000
     hidden_size: int = 2048
@@ -86,7 +110,9 @@ class ModelConfig:
     # family "afmoe" — "W" gated attention inside ``sliding_window`` with
     # rotary embedding, "F" gated full attention without it, then "D" or
     # "S", two sublayers a layer, each sublayer's output normed AGAIN before
-    # it joins the residual stream (``post_norms``).
+    # it joins the residual stream (``post_norms``); family "sarvam_mla" —
+    # "R" latent attention whose decoupled part is rotated (``rope_theta``,
+    # ``rope_scaling``, over ``qk_rope_head_dim``), then "D" or "S".
     # ``num_experts`` is the router's width; this worker holds
     # ``experts_held`` of them (0 = all), those of ``expert_rank``.
     layer_pattern: str = ""
@@ -113,7 +139,7 @@ class ModelConfig:
     # MLA, served ABSORBED: the cache keeps one row [c ; k_rope] a token, so
     # ``num_kv_heads`` is 1 and ``head_dim`` the row's width (kv_lora_rank +
     # qk_rope_head_dim); the softmax scale (qk_nope + qk_rope)^-1/2 is
-    # ``query_pre_attn_scalar``.
+    # ``query_pre_attn_scalar`` (with a yarn scaling's mscale^2 in it).
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
@@ -125,8 +151,9 @@ class ModelConfig:
                 f"moe_dispatch must be 'sorted' or 'dense', "
                 f"got {self.moe_dispatch!r}")
         if self.layer_pattern:
-            kinds, per = {"kimi_linear": ("KLDS", 2),
-                          "afmoe": ("WFDS", 2)}.get(self.family, ("ME*", 1))
+            kinds, per = {"kimi_linear": ("KLDS", 2), "afmoe": ("WFDS", 2),
+                          "sarvam_mla": ("RDS", 2)}.get(self.family,
+                                                        ("ME*", 1))
             odd = set(self.layer_pattern) - set(kinds)
             if odd or len(self.layer_pattern) != per * self.num_layers:
                 raise ValueError(
@@ -277,6 +304,27 @@ TINY_TEST_AFMOE = _register(ModelConfig(
     num_experts=16, num_experts_per_tok=4, experts_held=8,
     moe_intermediate_size=32, moe_shared_intermediate_size=32,
     moe_routed_scaling=2.448, max_context_length=256,
+))
+
+# Latent attention in every layer, its decoupled part rotated under a yarn
+# scaling whose original length (32) is SHORTER than the context, so that
+# positions past it are served; a dense first layer, 16 experts behind the
+# router of which this worker holds 8 (rank 0 of 2), top-4.  The score
+# scale is (16 + 16)^-1/2 times mscale^2, mscale = 0.1 ln 8 + 1.
+_TINY_YARN = RopeScaling(
+    rope_type="yarn", factor=8.0, original_max_position_embeddings=32,
+    beta_fast=4.0, beta_slow=1.0, mscale=1.0, mscale_all_dim=1.0)
+TINY_TEST_SARVAM_MLA = _register(ModelConfig(
+    name="tiny-test-sarvam-mla", family="sarvam_mla", vocab_size=512,
+    hidden_size=64, intermediate_size=96, num_layers=3, num_heads=4,
+    num_kv_heads=1, head_dim=48,
+    query_pre_attn_scalar=32.0 / _TINY_YARN.yarn_mscale(1.0) ** 4,
+    rope_scaling=_TINY_YARN,
+    layer_pattern="RDRSRS", kv_lora_rank=32, qk_nope_head_dim=16,
+    qk_rope_head_dim=16, v_head_dim=16, num_experts=16,
+    num_experts_per_tok=4, experts_held=8, moe_intermediate_size=32,
+    moe_shared_intermediate_size=32, moe_routed_scaling=2.5,
+    max_context_length=256, rms_norm_eps=1e-6,
 ))
 
 # ---- production models (BASELINE.json configs) ----------------------------

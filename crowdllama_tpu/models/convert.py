@@ -104,6 +104,25 @@ def params_from_hf(cfg: ModelConfig, get: TensorSource, dtype=jnp.bfloat16) -> d
     return params
 
 
+def rotary_halves_from_interleaved(w, heads: int, head_dim: int,
+                                   rope_dim: int):
+    """``w [..., heads * head_dim]`` — a projection (or its output) whose
+    every head ends in ``rope_dim`` rotary entries in the PUBLISHED order,
+    rotated as interleaved pairs ``(2i, 2i+1)`` — with those entries put
+    ``[evens | odds]``, the order in which ``ops/rope.py`` ``rotate_half``
+    rotates entry ``i`` against entry ``i + rope_dim/2`` by the same angle.
+    A dot product of two vectors permuted alike is the one it was, so a
+    latent-attention family's ``W_q`` (``heads`` heads of ``qk_nope +
+    qk_rope``) and ``W_kva`` (one head of ``kv_lora_rank + qk_rope``)
+    converted so serve the published equations (models/hybrid.py
+    ``mla_body``; tests/test_sarvam_mla.py holds the two forms together)."""
+    lead = head_dim - rope_dim
+    within = np.concatenate([np.arange(lead), lead + np.arange(0, rope_dim, 2),
+                             lead + np.arange(1, rope_dim, 2)])
+    cols = (np.arange(heads)[:, None] * head_dim + within[None, :]).reshape(-1)
+    return w[..., cols]
+
+
 def state_dict_source(state_dict: Mapping[str, "object"]) -> TensorSource:
     """TensorSource over a torch state_dict (detaches to numpy)."""
 

@@ -17,11 +17,14 @@ dense SwiGLU (``D``) or a SwiGLU mixture of experts with a shared expert
 ``afmoe``: gated attention with a per-head RMSNorm of q and k, inside a
 sliding window and rotated (``W``) or over the whole context and not
 (``F``), then ``D`` or ``S``; the second residual form; the embedding times
-``cfg.embedding_multiplier`` (muP).
+``cfg.embedding_multiplier`` (muP).  Family ``sarvam_mla``: latent
+attention whose decoupled part is rotated (``R``: the ``L`` layer's body
+and parameters, given each row's angles) in every layer, then ``D`` or
+``S``.
 
 The equations are written out in the plain references
 (benchmarks/chip/harness/reference/nemotron_h.py, kimi_linear.py,
-afmoe.py); this
+afmoe.py, sarvam_mla.py); this
 file is the program's side of them.  Parameters are kept per KIND
 (``params["layers"][STACK[kind]]``: a list, one dict of leaves for each
 sublayer of that kind in order) and the layer loop is unrolled over
@@ -80,10 +83,10 @@ F32 = jnp.float32
 
 #: the parameter stack of each kind of sublayer
 STACK = {"M": "mamba", "E": "moe", "*": "attn",
-         "K": "kda", "L": "mla", "D": "mlp", "S": "smoe",
+         "K": "kda", "L": "mla", "R": "mla", "D": "mlp", "S": "smoe",
          "W": "wattn", "F": "fattn"}
 #: the kinds that keep paged KV, and the per-slot state of the recurrent ones
-ATTENTION = "*LWF"
+ATTENTION = "*LRWF"
 STATE = {"M": "ssm", "K": "kda"}
 #: why whatever rests on "tokens done == pages of KV to hand over" declines
 #: a model with recurrent layers (prefix reuse, page export and import, the
@@ -93,6 +96,11 @@ NO_PAGES = "recurrent state has no page to export"
 #: slot (engine/hybrid.py), so the pages of a prompt's first tokens are gone
 #: by the time anyone could share, ship or roll back to them
 NO_WINDOW_PAGES = "a window layer no longer holds a prefix's pages"
+#: why the same declines a model whose every layer keeps all of its pages,
+#: one latent row a token: the prefix gathers and ``import_pages`` take a
+#: page of K and its twin of V (engine/paged.py ``pool_row_width``)
+NO_LATENT_PAGES = ("a latent pool has no V twin for the prefix gathers and "
+                   "import_pages to take")
 
 
 def attn_kinds(cfg: ModelConfig) -> str:
@@ -107,15 +115,16 @@ def sizes(cfg: ModelConfig) -> dict[str, int]:
     z = {"d_inner": d_inner, "bc": bc, "conv_dim": d_inner + 2 * bc,
          "in_proj": 2 * d_inner + 2 * bc + cfg.ssm_heads,
          "held": cfg.experts_held or cfg.num_experts}
+    if cfg.kv_lora_rank:    # latent attention: every head's [q_nope ; q_rope]
+        z["q_dim"] = cfg.num_heads * (cfg.qk_nope_head_dim
+                                      + cfg.qk_rope_head_dim)
     if cfg.kda_heads:   # family kimi_linear
         hk = cfg.kda_heads * cfg.kda_head_dim
         z.update({
             "hk": hk,
             # the channels a KDA layer convolves: [q | k | v]
             "conv_dim": 3 * hk,
-            "kda_in": 3 * hk + 2 * cfg.kda_gate_rank + cfg.kda_heads,
-            "q_dim": cfg.num_heads * (cfg.qk_nope_head_dim
-                                      + cfg.qk_rope_head_dim)})
+            "kda_in": 3 * hk + 2 * cfg.kda_gate_rank + cfg.kda_heads})
     return z
 
 
@@ -419,12 +428,16 @@ def kda_body(lp: Params, cfg: ModelConfig, x, rec_fn):
         return x + qeinsum("...k,kd->...d", o.astype(x.dtype), lp["wo"])
 
 
-def mla_body(lp: Params, cfg: ModelConfig, x, attn_fn):
+def mla_body(lp: Params, cfg: ModelConfig, x, attn_fn, angles=None):
     """One latent-attention layer minus its cache policy, ABSORBED: the
     key half of the kv up-projection is folded into the query and the value
     half applied after the softmax, so that attention is every head's
     ``[q~ ; q_rope]`` against ONE row ``[c ; k_rope]`` a token, whose first
-    ``kv_lora_rank`` values are also the value.  No rotation."""
+    ``kv_lora_rank`` values are also the value.  With ``angles`` (cos, sin
+    ``[..., qk_rope_head_dim/2]`` of each row's position: an ``R`` layer)
+    the decoupled part is rotated — every head's ``q_rope``, and ``k_rope``
+    BEFORE the row goes to the cache, which so keeps rotated keys; None (an
+    ``L`` layer): no rotation."""
     r, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
     dq = dn + cfg.qk_rope_head_dim
     lead = x.shape[:-1]
@@ -433,11 +446,18 @@ def mla_body(lp: Params, cfg: ModelConfig, x, attn_fn):
         q = zin[..., :sizes(cfg)["q_dim"]].reshape(*lead, cfg.num_heads, dq)
         c = rms_norm(zin[..., -(r + cfg.qk_rope_head_dim):-cfg.qk_rope_head_dim],
                      lp["kv_norm"], cfg.rms_norm_eps)
-        row = jnp.concatenate([c, zin[..., -cfg.qk_rope_head_dim:]], -1)
+        k_r = zin[..., -cfg.qk_rope_head_dim:]
+        if angles is not None:
+            with jax.named_scope("rope"):
+                k_r = rotate_half(k_r[..., None, :], *angles)[..., 0, :]
+        row = jnp.concatenate([c, k_r], -1)
         w_kvb = dequant(lp["w_kvb"]).reshape(r, cfg.num_heads, -1)
-        q = jnp.concatenate([
-            jnp.einsum("...hd,rhd->...hr", q[..., :dn], w_kvb[..., :dn]),
-            q[..., dn:]], -1)
+        q_n = jnp.einsum("...hd,rhd->...hr", q[..., :dn], w_kvb[..., :dn])
+        q_r = q[..., dn:]
+        if angles is not None:
+            with jax.named_scope("rope"):
+                q_r = rotate_half(q_r, *angles)
+        q = jnp.concatenate([q_n, q_r], -1)
     attn = attn_fn(q, row[..., None, :], None)[..., :r]
     with jax.named_scope("attn_proj"):
         o = jnp.einsum("...hr,rhd->...hd", attn, w_kvb[..., dn:])
@@ -576,8 +596,15 @@ def run_layers(layers: Params, cfg: ModelConfig, x, rec_fn, attn_fn, live,
     """The layer loop, unrolled over ``cfg.layer_pattern``; ``positions``
     (as ``x`` without its last axis) for the layers that rotate.  Returns
     (x, the expert layers' :data:`COUNTS` summed, int32 ``[5]``)."""
-    angles = (rope_angles(positions, cfg.resolved_head_dim(), cfg.rope_theta)
-              if "W" in cfg.layer_pattern else None)
+    # a pattern has one kind that rotates: a window layer's whole head, or
+    # a latent layer's decoupled part under the family's scaling
+    angles = None
+    if "W" in cfg.layer_pattern:
+        angles = rope_angles(positions, cfg.resolved_head_dim(),
+                             cfg.rope_theta)
+    elif "R" in cfg.layer_pattern:
+        angles = rope_angles(positions, cfg.qk_rope_head_dim, cfg.rope_theta,
+                             cfg.rope_scaling)
     # scalars until the end: a vector a layer was a concatenate a layer
     # (1.2 us each on the chip: PERF.md §6, PR 40)
     counts = (0,) * len(COUNTS)
@@ -602,8 +629,10 @@ def run_layers(layers: Params, cfg: ModelConfig, x, rec_fn, attn_fn, live,
             attn_seen += 1
             if kind in "WF":
                 x = gattn_body(lp, cfg, x, fn, angles if kind == "W" else None)
+            elif kind in "LR":
+                x = mla_body(lp, cfg, x, fn, angles if kind == "R" else None)
             else:
-                x = (attn_body if kind == "*" else mla_body)(lp, cfg, x, fn)
+                x = attn_body(lp, cfg, x, fn)
         elif kind == "D":
             x = mlp_body(lp, cfg, x)
         else:

@@ -589,11 +589,28 @@ def paged_decode_attention_mla(
 _CHUNK_QB = 32
 
 
+def chunk_query_block(hkv: int, g: int, head_dim: int) -> int:
+    """Queries a block of the v2 ragged kernel holds: :data:`_CHUNK_QB`,
+    halved (to 8 at the least) while the block's float32 scratch — ``[Hkv,
+    QB*G, Dh]`` of accumulator and two ``[Hkv, QB*G, _LANES]`` carries — is
+    over half the tile budget.  The block's query and output tiles, each
+    double-buffered, and the scores lie beside the scratch under the
+    compiler's 16 MiB: 64 query heads on one 640-wide latent row took 18.7
+    MB at 32 queries and were refused (deviceless compile, PR 54); every
+    shape served before keeps its 32."""
+    qb = _CHUNK_QB
+    while qb > 8 and (hkv * qb * g * (head_dim + 2 * _LANES) * 4
+                      > _VMEM_TILE_BUDGET // 2):
+        qb //= 2
+    return qb
+
+
 def ragged_pallas_refusal(page_size: int, head_dim: int,
                           n_shards: int = 1,
                           num_kv_heads: int = 0,
                           itemsize: int = 2,
-                          quant: bool = False) -> str:
+                          quant: bool = False,
+                          num_heads: int = 0) -> str:
     """Why the fused ragged (decode + prefill-chunk) kernel does NOT apply
     ("" when it does).
 
@@ -607,14 +624,15 @@ def ragged_pallas_refusal(page_size: int, head_dim: int,
     if why:
         return why
     # A query block holds [Hkv, QB*G, Dh] fp32 acc + 2x [Hkv, QB*G, _LANES]
-    # carries; with num_kv_heads=0 (availability probe) assume one head.
+    # carries; with num_kv_heads=0 (availability probe) assume one head,
+    # with num_heads=0 a generous 16 query heads a kv head.
     hkv_local = max(max(num_kv_heads, 1) // max(n_shards, 1), 1)
-    # G is unknown at probe time; bound by a generous 16 query groups.
-    rows = _CHUNK_QB * 16
+    g = num_heads // max(num_kv_heads, 1) if num_heads else 16
+    rows = chunk_query_block(hkv_local, g, head_dim) * g
     scratch = hkv_local * rows * (head_dim + 2 * _LANES) * 4
-    if scratch > 2 * _VMEM_TILE_BUDGET:
-        return (f"chunk scratch ({scratch} B for {hkv_local} kv heads) "
-                f"exceeds the VMEM budget")
+    if scratch > _VMEM_TILE_BUDGET // 2:
+        return (f"chunk scratch ({scratch} B for {hkv_local} kv heads of "
+                f"{g} query heads) exceeds the VMEM budget")
     return ""
 
 
@@ -622,9 +640,10 @@ def ragged_pallas_supported(page_size: int, head_dim: int,
                             n_shards: int = 1,
                             num_kv_heads: int = 0,
                             itemsize: int = 2,
-                            quant: bool = False) -> bool:
+                            quant: bool = False,
+                            num_heads: int = 0) -> bool:
     return not ragged_pallas_refusal(page_size, head_dim, n_shards,
-                                     num_kv_heads, itemsize, quant)
+                                     num_kv_heads, itemsize, quant, num_heads)
 
 
 def ragged_paged_attention_ref(
@@ -909,7 +928,7 @@ def flash_ragged_paged_attention(
     np_ = page_table.shape[1]
     quant = k_scale is not None
 
-    qb = _CHUNK_QB
+    qb = chunk_query_block(hkv, g, dh)
     jblocks = -(-c // qb)
     # The decode rows go through the decode kernel, the very call of the
     # plain decode step; only the chunk's rows are packed into QB-row

@@ -13,6 +13,7 @@ module-scoped fixture: only one process at a time may load the TPU's
 library, so nothing here touches it at import or collection time.
 """
 
+import math
 import re
 
 import jax
@@ -907,7 +908,8 @@ def _calls(text: str, name: str) -> list[str]:
 KIMI_POOL = (7, 32 * 12 + 1, 1, PAGE, 640)
 
 
-def _assert_latent_pool_is_worked_where_it_lies(compiled, state):
+def _assert_latent_pool_is_worked_where_it_lies(compiled, state,
+                                                pool_shape=KIMI_POOL):
     """The program takes the latent pool in the layout its loop uses (row
     minor) and hands it back so: wherever an array of the pool's shape
     stands in the compiled text with a layout it lies row-minor, no ``copy``,
@@ -917,18 +919,19 @@ def _assert_latent_pool_is_worked_where_it_lies(compiled, state):
     temporaries are under the pool's own bytes — a 576-wide pool's program
     held a 640-wide working copy of all of it, 441.5 MB, and converted the
     buffer on the way in and again on the way out (PERF.md §6, PR 49)."""
-    assert state.pool_v is None and state.pool_k.shape == KIMI_POOL
+    assert state.pool_v is None and state.pool_k.shape == pool_shape
     ma = compiled.memory_analysis()
     kept = sum(a.size * a.dtype.itemsize
-               for a in (state.pool_k, state.kda, state.conv))
+               for a in (state.pool_k, state.kda, state.conv)
+               if a is not None)
     assert ma.alias_size_in_bytes >= kept, (ma.alias_size_in_bytes, kept)
     pool_bytes = state.pool_k.size * state.pool_k.dtype.itemsize
-    assert pool_bytes == 7 * 385 * 128 * 640 * 2
+    assert pool_bytes == math.prod(pool_shape) * 2
     assert ma.temp_size_in_bytes < pool_bytes, (ma.temp_size_in_bytes,
                                                 pool_bytes)
     text = compiled.as_text()
-    pool = "bf16[" + ",".join(map(str, KIMI_POOL)) + "]"
-    assert "bf16[7,385,1,128,576]" not in text
+    pool = "bf16[" + ",".join(map(str, pool_shape)) + "]"
+    assert "bf16[" + ",".join(map(str, pool_shape[:-1])) + ",576]" not in text
     roots, name = {}, None      # computation -> its ROOT instruction
     for ln in text.splitlines():
         if ln.startswith("%") and ln.endswith("{"):
@@ -1345,3 +1348,163 @@ def test_decode_work_is_built_once_a_step(request, model, lists, kernels,
                 # time (a dynamic bound is the call's first operand)
                 assert eqn.params["grid_mapping"].num_dynamic_grid_bounds == 1
     assert calls == kernels
+
+
+# ---- family sarvam_mla: latent attention in EVERY layer, 64 heads a row
+
+@pytest.fixture
+def sarvam_runner(one_chip, monkeypatch, tmp_path, expert_kernel):
+    """``() -> (runner, params, state, page table)``: the hybrid runner at
+    the widths, depth and share of ``sarvam-105b-p1-ep8-int8`` (the
+    benchmark's configuration file, read as the worker reads it), int8, 32
+    slots, the served context (5120), built from shapes alone."""
+    import json
+    from pathlib import Path
+
+    from crowdllama_tpu.engine import runner as runner_mod
+    from crowdllama_tpu.engine.hybrid import HybridPagedModelRunner
+    from crowdllama_tpu.engine.weights import resolve_model_config
+    from crowdllama_tpu.ops.quant import random_quantized_params
+
+    monkeypatch.setattr(runner_mod, "shard_params", lambda p, cfg, mesh: p)
+    doc = json.loads((Path(__file__).resolve().parents[1] / "benchmarks"
+                      / "chip" / "configs"
+                      / "sarvam-105b-p1-ep8-int8.json").read_text())
+    slots, ctx = doc["bench"]["slots"], doc["bench"]["context"]
+    (tmp_path / "config.json").write_text(json.dumps(
+        {k: v for k, v in doc.items() if k != "bench"}))
+
+    def build():
+        cfg = resolve_model_config(doc["bench"]["name"], str(tmp_path),
+                                   max_context_length=ctx)
+        shapes = jax.eval_shape(lambda: random_quantized_params(
+            cfg, jax.random.PRNGKey(0), dtype=jnp.bfloat16))
+        r = HybridPagedModelRunner(cfg, params=shapes, mesh_spec="1x1",
+                                   max_slots=slots, max_seq=ctx,
+                                   page_size=PAGE)
+        assert r.kda_update_path == r.ssm_update_path == ""
+        # the gates let every kernel through (asked past the backend test,
+        # which here sees the CPU)
+        with monkeypatch.context() as m:
+            m.setenv("CROWDLLAMA_PALLAS_INTERPRET", "1")
+            assert not any(r._attention_refusals().values())
+        r.attention_paths = {**r.attention_paths, "decode": "pallas",
+                             "ragged_step": "pallas"}
+        table = _sds((slots, ctx // PAGE), jnp.int32, one_chip)
+        return (r, _placed(shapes, one_chip),
+                _on_chip(jax.eval_shape(r.init_state), one_chip), table)
+
+    return build
+
+
+# the cell's latent pool as it is allocated: 9 layers, 32 slots x 40 pages +
+# the dump page, rows [c ; k_rope] of 576 rounded up to whole lanes
+SARVAM_POOL = (9, 32 * 40 + 1, 1, PAGE, 640)
+
+
+@pytest.mark.parametrize("steps", [1, 2])
+def test_sarvam_decode_program_keeps_its_pool_in_place(sarvam_runner, steps):
+    """All nine layers of the cut in one step program, at a short flight's
+    length and at the cell's own (``decode_chunk`` 2): the one latent pool
+    (no V twin, no state beside it) is handed back where it lay — no
+    ``copy`` of its shape — nine ``paged_decode_attention_mla`` a step, 24
+    grouped matmuls (8 expert layers, three int8 banks), 4.89 GB of
+    weights."""
+    r, params, state, table = sarvam_runner()
+    assert state.kda is None and state.ssm is None and state.wpool_k is None
+    compiled = jax.jit(
+        r._decode_paged_impl, donate_argnums=(1,), static_argnums=(3,)
+    ).lower(params, state, table, steps).compile()
+    _assert_latent_pool_is_worked_where_it_lies(compiled, state, SARVAM_POOL)
+    weights = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree_util.tree_leaves(params))
+    assert 4.85e9 < weights < 4.95e9
+    text = compiled.as_text()
+    assert len(_calls(text, "paged_decode_attention_mla")) == 9
+    assert len(_calls(text, "moe_grouped_matmul")) == 24
+    for ln in text.splitlines():
+        if any(f" {op}(" in ln for op in ("fusion", "copy", "copy-start")):
+            assert not any(f"= {t}[16,{d}]" in ln for t in ("bf16", "s8")
+                           for d in ("4096,2048", "2048,4096")), (
+                ln.strip()[:200])
+
+
+@pytest.mark.parametrize("steps", [1, 2])
+def test_sarvam_ragged_step_program_compiles_with_its_pool_in_place(
+        sarvam_runner, one_chip, steps):
+    """Decode rows beside a 512-token chunk, at both flight lengths the
+    warm-up compiles: the v2 ragged kernel over one latent row a token (key
+    and value both, 640 wide as stored) in blocks of 16 queries of 64
+    heads, the decode rows through the GQA decode kernel on the same pool;
+    the pool worked where it lies, as in the decode program."""
+    from crowdllama_tpu.ops.pallas.paged import chunk_query_block
+
+    r, params, state, table = sarvam_runner()
+    assert r.ragged_chunk == CHUNK and r.ragged_width_fixed
+    assert r._ragged_window() == r.max_pages_per_slot == 40
+    assert chunk_query_block(1, 64, 640) == 16
+
+    def i32(*shape):
+        return _sds(shape, jnp.int32, one_chip)
+
+    compiled = jax.jit(
+        r._ragged_step_impl, donate_argnums=(1,), static_argnums=(7,)
+    ).lower(params, state, table, i32(steps, CHUNK), i32(steps), i32(),
+            i32(), steps).compile()
+    _assert_latent_pool_is_worked_where_it_lies(compiled, state, SARVAM_POOL)
+    text = compiled.as_text()
+    assert len(_calls(text, "moe_grouped_matmul")) == 24
+    assert len(_calls(text, "ragged_paged_attention")) == 9
+    assert f"bf16[{CHUNK // 16},1,16,64,640]" in text
+    # the decode rows beside the chunk: the GQA decode kernel on the one
+    # latent pool (key and value both), not the decode STEP's _mla kernel
+    assert len(_calls(text, "paged_decode_attention")) == 9
+    assert not _calls(text, "paged_decode_attention_mla")
+
+
+def test_sixty_four_head_latent_kernels_compile_at_the_cell_shape(one_chip):
+    """One shared kv head, 64 query heads, rows of 576 — 640 as the pool
+    stores them: the latent decode kernel, the ragged kernel (refused at 32
+    queries a block: 18.7 MB of scoped VMEM against 16) and the cache-less
+    prefill kernel at the largest bucket a prompt takes whole (512: a longer
+    prompt is admitted in chunks, and rows of 576 stay in VMEM up to 1,820)."""
+    from crowdllama_tpu.ops.pallas.paged import (paged_decode_attention_mla,
+                                                 ragged_paged_attention)
+
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    slots, heads, row, latent, np_ = 32, 64, 576, 512, 40
+    stored = SARVAM_POOL[-1]
+    pool = _sds(SARVAM_POOL, bf16, one_chip)
+    table = _sds((slots, np_), i32, one_chip)
+    scale = 0.135234
+
+    def decode(q, pool, li, table, lens):
+        return paged_decode_attention_mla(q, pool, li, table, lens, scale,
+                                          latent)
+
+    compiled = jax.jit(decode).lower(
+        _sds((slots, heads, stored), bf16, one_chip), pool,
+        _sds((), i32, one_chip), table, _sds((slots,), i32, one_chip)
+    ).compile()
+    assert len(_calls(compiled.as_text(), "paged_decode_attention_mla")) == 1
+
+    def prefill(q, k, pos, valid):
+        return flash_prefill_attention(q, k, k, pos, scale, kv_valid=valid)
+
+    t = 512
+    _assert_kernel(jax.jit(prefill).lower(
+        _sds((1, t, heads, row), bf16, one_chip),
+        _sds((1, 1, t, row), bf16, one_chip),
+        _sds((1, t), i32, one_chip),
+        _sds((1, t), jnp.bool_, one_chip)).compile())
+
+    def ragged(q, ck, pool, li, table, ql, kl, cs):
+        return ragged_paged_attention(q, ck, ck, pool, pool, li, table, ql,
+                                      kl, cs, scale, use_pallas=True)
+
+    _assert_kernel(jax.jit(ragged).lower(
+        _sds((slots + CHUNK, heads, stored), bf16, one_chip),
+        _sds((1, 1, CHUNK, stored), bf16, one_chip), pool,
+        _sds((), i32, one_chip), table, _sds((slots + 1,), i32, one_chip),
+        _sds((slots + 1,), i32, one_chip), _sds((), i32, one_chip)
+    ).compile())
